@@ -37,7 +37,8 @@ class ChipSpec:
 
 
 # catalogue values (vendor datasheets, rounded); "cpu" is a deliberate
-# small stand-in so CPU smoke runs produce finite utilizations
+# small stand-in so CPU smoke runs produce finite utilizations, and is
+# only ever returned for the cpu backend
 CHIP_SPECS = {
     "tpu-v4": ChipSpec("tpu-v4", 275e12, 1228.0, 50.0),
     "tpu-v5e": ChipSpec("tpu-v5e", 197e12, 819.0, 50.0),
@@ -49,8 +50,10 @@ CHIP_SPECS = {
 
 
 def chip_spec(backend: str, device_kind: str = "") -> ChipSpec:
-    """Best-effort spec lookup from ``jax.default_backend()`` plus the
-    device's ``device_kind`` string (e.g. "TPU v5 lite")."""
+    """Spec lookup from ``jax.default_backend()`` plus the device's
+    ``device_kind`` string (e.g. "TPU v5 lite"). A device that is not
+    in ``CHIP_SPECS`` raises: a utilization against another chip's
+    peak is a wrong number, not an estimate."""
     kind = (device_kind or "").lower()
     if backend == "tpu":
         if "v5 lite" in kind or "v5e" in kind or "v5litepod" in kind:
@@ -59,10 +62,13 @@ def chip_spec(backend: str, device_kind: str = "") -> ChipSpec:
             return CHIP_SPECS["tpu-v5p"]
         if "v6" in kind:
             return CHIP_SPECS["tpu-v6e"]
-        return CHIP_SPECS["tpu-v4"]
-    if backend == "gpu":
-        return CHIP_SPECS["gpu"]
-    return CHIP_SPECS["cpu"]
+        if "v4" in kind:
+            return CHIP_SPECS["tpu-v4"]
+    elif backend in ("gpu", "cpu"):
+        return CHIP_SPECS[backend]
+    raise ValueError(f"no chip spec for backend {backend!r}, "
+                     f"device_kind {device_kind!r} — add it to "
+                     "analysis/cost.py CHIP_SPECS with its source")
 
 
 def ring_allreduce_wire_bytes(payload_bytes: float,

@@ -118,7 +118,10 @@ class Config:
     port: int = 5315  # kept for CLI parity; unused (no sockets in SPMD runtime)
     num_clients: Optional[int] = None
     num_workers: int = 1  # participating clients per round
-    device: str = "tpu"
+    # None = whatever platform JAX reports; the trainers resolve it at
+    # start-up and refuse a named platform that is not the one found
+    # (parallel/mesh.maybe_initialize_multihost_cli)
+    device: Optional[str] = None
     # number of TPU devices for the mesh; <= 0 = all available (the
     # reference's flag counted GPUs and defaulted to 1 — here a single
     # jitted program spans the mesh, so "all" is the natural default)
@@ -939,7 +942,10 @@ def build_parser(default_lr: Optional[float] = None,
     parser.add_argument("--num_clients", type=int)
     parser.add_argument("--num_workers", type=int, default=1)
     parser.add_argument("--device", type=str,
-                        choices=["cpu", "tpu", "cuda"], default="tpu")
+                        choices=["cpu", "tpu", "cuda"], default=None,
+                        help="platform the run must be on (default: "
+                        "the one JAX reports); naming one that JAX "
+                        "did not find is an error")
     parser.add_argument("--num_devices", type=int, default=-1)
     parser.add_argument("--share_ps_gpu", action="store_true")
     parser.add_argument("--iid", action="store_true", dest="do_iid")

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from commefficient_tpu.compat import axis_size
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            lm_nll_sums_chunked,
                                            token_nll)
@@ -146,7 +145,7 @@ def build_sp_gpt2_round(cfg: GPT2Config, mesh: Mesh,
         assert mask.ndim == 2, f"mask must be (W, B), got {mask.shape}"
         ex_mask = mask  # (Wl, B) per-example
         w = (jnp.sum(ex_mask, axis=1) > 0).astype(jnp.float32)  # (Wl,)
-        seq_n = axis_size(SEQ_AXIS)
+        seq_n = jax.lax.axis_size(SEQ_AXIS)
 
         def local_objective(f):
             def per_client(ids_c, tt_c, labels_c, mc_c, mcl_c, ex_c):
@@ -167,14 +166,9 @@ def build_sp_gpt2_round(cfg: GPT2Config, mesh: Mesh,
 
         (_, losses), g = jax.value_and_grad(
             local_objective, has_aux=True)(flat)
-        if not hasattr(jax.lax, "pvary"):
-            # pre-varying-axes jax: differentiating the replicated
-            # ``flat`` inside the block has no pvary transpose to
-            # insert the cross-device reduction, so g is only the
-            # local share — reduce explicitly (current jax already
-            # returns it summed; doing both would double-count)
-            g = jax.lax.psum(g, (CLIENT_AXIS, SEQ_AXIS))
-        # g is already Sum_c w_c * grad_c, replicated everywhere
+        # g is already Sum_c w_c * grad_c, replicated everywhere:
+        # differentiating the replicated ``flat`` inside the block
+        # makes shard_map's transpose insert the cross-device psum
         n_clients = jnp.maximum(
             jax.lax.psum(jnp.sum(w), CLIENT_AXIS), 1.0)
         # per-client reported losses, zeroed for non-participating

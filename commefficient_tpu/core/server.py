@@ -366,6 +366,21 @@ def _psum_l2(x, axis_name) -> jax.Array:
                                  axis_name))
 
 
+def _gather_replicated(x, axis_name: str) -> jax.Array:
+    """Tiled all-gather of a 1-D shard whose result ``shard_map``
+    types as replicated over ``axis_name``: each peer writes its shard
+    at its own offset of a zero buffer and the buffers are psum'd
+    (adding zeros is exact). ``jax.lax.all_gather`` returns the same
+    values but typed *varying*, and jax 0.9's public API has no
+    invariant all-gather — so everything derived from it is refused by
+    replicated ``out_specs``. Only for small operands: this moves an
+    all-reduce's bytes, not an all-gather's."""
+    buf = jnp.zeros((jax.lax.axis_size(axis_name),) + x.shape, x.dtype)
+    buf = jax.lax.dynamic_update_index_in_dim(
+        buf, x, jax.lax.axis_index(axis_name), 0)
+    return jax.lax.psum(buf, axis_name).reshape(-1)
+
+
 def sketched_update_2d(cfg: Config, sketch: CountSketch,
                        sketched_grad_loc: jax.Array,
                        state: ServerState, lr,
@@ -426,14 +441,20 @@ def sketched_update_2d(cfg: Config, sketch: CountSketch,
     slot_ok = jnp.arange(k) < n_take
     cand_idx = jnp.where(slot_ok, start + pos.astype(jnp.int32), d)
     cand_val = jnp.where(slot_ok, est[pos], 0.0)
-    cand_idx = jax.lax.all_gather(cand_idx, axis_name, tiled=True)
-    cand_val = jax.lax.all_gather(cand_val, axis_name, tiled=True)
+    # the k winners feed the three outputs that leave the shard_map
+    # replicated (update, support, probes), so they are gathered in
+    # the form shard_map can type as replicated — M·k elements
+    cand_idx = _gather_replicated(cand_idx, axis_name)
+    cand_val = _gather_replicated(cand_val, axis_name)
     sel = jnp.nonzero(cand_idx < d, size=k, fill_value=0)[0]
     idx = jnp.minimum(cand_idx[sel], d - 1)  # ascending global order
     vals = cand_val[sel]
 
-    dense_mass = (jax.lax.square(CountSketch.l2estimate(table))
-                  if probes else None)
+    # CountSketch.l2estimate of the full table, from shard-local row
+    # sums: the psum is what makes the probe replicated for shard_map
+    dense_mass = (jnp.median(jax.lax.psum(
+        jnp.sum(jax.lax.square(Verr), axis=1), axis_name))
+        if probes else None)
     update = jnp.zeros(d, jnp.float32).at[idx].add(
         vals, mode="promise_in_bounds", unique_indices=True,
         indices_are_sorted=True)

@@ -27,7 +27,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from commefficient_tpu.compat import axis_size
 from commefficient_tpu.models import register_model
 
 
@@ -223,7 +222,7 @@ class GPT2DoubleHeads(nn.Module):
             # mc_token_ids are GLOBAL positions; the owning shard
             # contributes its hidden state, psum broadcasts it
             ax = self.cfg.seq_axis
-            n_shards = axis_size(ax)
+            n_shards = jax.lax.axis_size(ax)
             gpos = jax.lax.axis_index(ax) * T + jnp.arange(T)
             idx = jnp.clip(mc_token_ids, 0, n_shards * T - 1)
             sel = (gpos[None, None, :] == idx[..., None]).astype(h.dtype)

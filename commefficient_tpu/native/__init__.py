@@ -12,6 +12,7 @@ queues + torchvision C++ transform kernels, SURVEY.md §2.9).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,17 +28,24 @@ _build_failed = False
 
 
 def _compile() -> Optional[str]:
-    so = os.path.join(_BUILD_DIR, "libfed_dataplane.so")
+    """Path of the library built from ``fed_dataplane.cpp`` as it is
+    now, building it if absent. The file name carries a hash of the
+    source: ``_build/`` is git-ignored but survives on disk and in
+    copies of the tree, where mtimes say nothing, and a binary that
+    does not match the source must never be loaded."""
     try:
-        if (os.path.exists(so)
-                and os.path.getmtime(so) >= os.path.getmtime(_SRC)):
+        with open(_SRC, "rb") as f:
+            tag = hashlib.sha256(f.read()).hexdigest()[:12]
+        so = os.path.join(_BUILD_DIR, f"libfed_dataplane-{tag}.so")
+        if os.path.exists(so):
             return so
         os.makedirs(_BUILD_DIR, exist_ok=True)
+        tmp = f"{so}.{os.getpid()}.tmp"  # concurrent first uses
         subprocess.run(
             ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-             "-pthread", _SRC, "-o", so + ".tmp"],
+             "-pthread", _SRC, "-o", tmp],
             check=True, capture_output=True)
-        os.replace(so + ".tmp", so)
+        os.replace(tmp, so)
         return so
     except (OSError, subprocess.CalledProcessError):
         return None

@@ -39,11 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the params class was renamed TPUCompilerParams -> CompilerParams
-# across JAX releases; accept either so the kernels (and their
-# interpret-mode tests) run on both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from commefficient_tpu.ops.pallas_common import out_struct
 
 # Default tiles: (1024, 2048) keeps the weight-streaming traffic low
 # (W is re-read once per token block: M/BM * |W|) while the f32
@@ -221,15 +217,15 @@ def _flce_fwd_impl(x, w, labels, block_m, block_v, interpret):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+            out_struct((mp, 1), jnp.float32, lp, xp, wp),
+            out_struct((mp, 1), jnp.float32, lp, xp, wp),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, _STATS_LANES), jnp.float32),
             pltpu.VMEM((bm, _STATS_LANES), jnp.float32),
             pltpu.VMEM((bm, _STATS_LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(lp, xp, wp)
@@ -253,6 +249,7 @@ def _flce_vjp_bwd(block_m, block_v, interpret, res, g):
     lsep = jnp.pad(lse, (0, mp - m)).reshape(mp, 1)
     glp = jnp.pad(g_lse.astype(jnp.float32), (0, mp - m)).reshape(mp, 1)
     gtp = jnp.pad(g_tok.astype(jnp.float32), (0, mp - m)).reshape(mp, 1)
+    operands = (lp, xp, wp, lsep, glp, gtp)
 
     dxp, dw = pl.pallas_call(
         partial(_bwd_kernel, nm=nm, v_actual=v, block_v=block_v,
@@ -279,16 +276,16 @@ def _flce_vjp_bwd(block_m, block_v, interpret, res, g):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nv, mp, c), x.dtype),
-            jax.ShapeDtypeStruct((vp, c), jnp.float32),
+            out_struct((nv, mp, c), x.dtype, *operands),
+            out_struct((vp, c), jnp.float32, *operands),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_v, c), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(lp, xp, wp, lsep, glp, gtp)
+    )(*operands)
 
     # f32 partials reduction: the nv per-vocab-block dX contributions
     # are near-cancelling around softmax mass, so a bf16 tree-sum

@@ -46,6 +46,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from commefficient_tpu.ops.pallas_common import out_struct
 from commefficient_tpu.ops.sketch import _mix as _mix_u32  # noqa: E402
 # (single source of truth for the murmur mix: the psum-mixing contract
 # requires the Pallas and XLA sign streams to stay bit-identical)
@@ -58,17 +59,11 @@ _TABLE_VMEM_LIMIT = 20 * 1024 * 1024
 _VMEM_CEILING = 64 * 1024 * 1024
 
 
-# the params class was renamed TPUCompilerParams -> CompilerParams
-# across JAX releases; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
 def _compiler_params(table_bytes: int):
     # table resident + r per-chunk temp rows (~table again) + double-
     # buffered chunk blocks + relayout scratch, with margin
     want = min(_VMEM_CEILING, max(32 * 1024 * 1024, 3 * table_bytes))
-    return _CompilerParams(vmem_limit_bytes=want)
+    return pltpu.CompilerParams(vmem_limit_bytes=want)
 
 
 def _pick_lanes(c: int) -> int | None:
@@ -283,7 +278,7 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((r * S, L), lambda t: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((r * S, L), jnp.float32),
+        out_shape=out_struct((r * S, L), jnp.float32, *operands),
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
     )(*operands)
@@ -291,27 +286,25 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 11))
+                   static_argnums=(2, 3, 4, 5, 6, 7, 8, 10))
 def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
-                        wire: str = "int8", interpret: bool = False,
+                        interpret: bool = False,
                         lanes: int | None = None, one_mix: bool = False,
                         rot_step: int = 0, sgn=None,
                         row_offset: int = 0):
-    """Fused emit + quantize: ``sketch_pallas`` whose f32 table lives
-    ONLY in a VMEM scratch accumulator — after the last chunk the
-    kernel computes each row's maxabs, quantizes the row at full wire
-    range against it (ops/quant.py ``quantize_local`` semantics,
-    bit-identical math), and writes the wire-dtype table + per-row
-    f32 maxabs. The full-width f32 table never reaches HBM: on the
-    model-sharded 2D path the shard-local tile leaves the kernel at
-    wire width, ready for the harmonize + reduce-scatter that follows
-    (core/rounds.py ``_quantize_for_collective`` does the same
-    harmonize on this kernel's outputs, so fused and unfused paths
-    share one quantization algebra).
+    """Fused emit + int8 quantize: ``sketch_pallas`` whose f32 table
+    lives ONLY in a VMEM scratch accumulator — after the last chunk
+    the kernel computes each row's maxabs, quantizes the row at full
+    int8 range against it (ops/quant.py ``quantize_local`` semantics,
+    bit-identical math), and writes the int8 table + per-row f32
+    maxabs. The full-width f32 table never reaches HBM.
 
-    Returns ``(q, rowmax)``: q (r, c) in the wire dtype, rowmax
-    (r, 1) f32. ``wire`` is "int8" or "fp8" (bf16 has no scale and is
-    a plain cast of ``sketch_pallas``'s output — nothing to fuse).
+    Returns ``(q, rowmax)``: q (r, c) int8, rowmax (r, 1) f32. int8 is
+    the only wire dtype with anything to fuse that Mosaic can emit:
+    bf16 has no scale (a plain cast of ``sketch_pallas``'s output),
+    and fp8's bit-exact contract needs an f16 -> float8_e4m3fn cast
+    Mosaic does not have (CountSketch.sketch_quantized keeps fp8
+    unfused).
 
     ``row_offset`` (--overlap_depth chunked emission): ``r`` is then
     the CHUNK row count and ``rot`` the chunk's row slice of the
@@ -322,9 +315,8 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
     depth-N pipeline holds one chunk-sized accumulator per in-flight
     chunk instead of N full-table scratches."""
     from commefficient_tpu.ops.quant import QMAX, wire_jnp_dtype
-    assert wire in QMAX, wire
-    qmax = QMAX[wire]
-    out_dtype = wire_jnp_dtype(wire)
+    qmax = QMAX["int8"]
+    out_dtype = wire_jnp_dtype("int8")
     L = lanes or _pick_lanes(c)
     assert L is not None and c % L == 0
     S = c // L
@@ -373,14 +365,8 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
                 # identical scale algebra to quantize_local: full
                 # range against the local rowmax, zero-row guard 1.0
                 s = jnp.where(rm > 0.0, rm / qmax, 1.0)
-                if wire == "int8":
-                    q = jnp.clip(jnp.round(block / s), -qmax, qmax)
-                    q_ref[sl, :] = q.astype(out_dtype)
-                else:
-                    # explicit f16 intermediate, matching
-                    # quant._to_fp8 bit-for-bit on every backend
-                    q_ref[sl, :] = (block / s).astype(
-                        jnp.float16).astype(out_dtype)
+                q = jnp.clip(jnp.round(block / s), -qmax, qmax)
+                q_ref[sl, :] = q.astype(out_dtype)
                 rm_ref[row, :] = jnp.full((L,), rm, jnp.float32)
 
     in_specs = [
@@ -402,8 +388,8 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((r, L), lambda t: (0, 0),
                                 memory_space=pltpu.VMEM)),
-        out_shape=(jax.ShapeDtypeStruct((r * S, L), out_dtype),
-                   jax.ShapeDtypeStruct((r, L), jnp.float32)),
+        out_shape=(out_struct((r * S, L), out_dtype, *operands),
+                   out_struct((r, L), jnp.float32, *operands)),
         scratch_shapes=[pltpu.VMEM((r * S, L), jnp.float32)],
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
@@ -478,7 +464,7 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((c,), lambda t: (t,),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m * c,), jnp.float32),
+        out_shape=out_struct((m * c,), jnp.float32, *operands),
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
     )(*operands)

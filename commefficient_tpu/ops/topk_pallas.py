@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from commefficient_tpu.ops.pallas_common import out_struct
+
 # chunk geometry: 512 x 128 = 64K f32 elements = 256 KB VMEM per
 # buffered block — well within budget, big enough to amortise grid
 # overhead at d ~ 1e8 (~1900 steps)
@@ -95,6 +97,9 @@ def take_mask_pallas(sq, t_key, need, interpret: bool = False):
         out_ref[:] = take.astype(jnp.int8)  # audit: allow(wire-dtype-crossing)
         cnt_ref[0] = cnt_ref[0] + jnp.sum(eqf).astype(jnp.int32)
 
+    operands = (t_key.astype(jnp.uint32).reshape(1),
+                need.astype(jnp.int32).reshape(1),
+                sqp.astype(jnp.float32).reshape(m * _S, _L))
     out = pl.pallas_call(
         kernel,
         grid=(m,),
@@ -106,10 +111,8 @@ def take_mask_pallas(sq, t_key, need, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((_S, _L), lambda t: (t, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m * _S, _L), jnp.int8),
+        out_shape=out_struct((m * _S, _L), jnp.int8, *operands),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
-    )(t_key.astype(jnp.uint32).reshape(1),
-      need.astype(jnp.int32).reshape(1),
-      sqp.astype(jnp.float32).reshape(m * _S, _L))
+    )(*operands)
     return out.reshape(-1)[:d].astype(bool)

@@ -31,7 +31,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from commefficient_tpu.compat import axis_size
 
 _NEG_INF = -1e30  # finite mask value: keeps the online softmax NaN-free
                   # for fully-masked (future) KV blocks
@@ -63,7 +62,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True):
     Must run inside shard_map; q/k/v are the local shards
     (B, T_local, H, D). Returns the local output shard.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, T, H, D = q.shape
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
@@ -112,7 +111,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True):
     """All-to-all sequence parallelism (DeepSpeed-Ulysses style):
     reshard seq->heads, dense attention on the full sequence, reshard
     back. Requires H % axis_size == 0. Exact."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, T, H, D = q.shape
     assert H % n == 0, f"n_head {H} must divide axis size {n}"
 

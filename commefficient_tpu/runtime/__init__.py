@@ -3,4 +3,5 @@ from commefficient_tpu.runtime.fed_model import (  # noqa: F401
     drain_rounds,
     FedOptimizer,
     LambdaLR,
+    TrainRun,
 )

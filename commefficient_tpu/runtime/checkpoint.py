@@ -511,7 +511,7 @@ def load_checkpoint(path: str, model, opt, scheduler=None,
         import jax.numpy as jnp
 
         from commefficient_tpu.parallel.mesh import (
-            client_sharding, model_axis_size, padded_rows,
+            client_sharding, padded_rows, replicated,
             server_state_sharding)
 
         # per-client state rows were sharded over the clients axis at
@@ -534,7 +534,8 @@ def load_checkpoint(path: str, model, opt, scheduler=None,
             # shard to its device without a replicated stopover
             return jax.device_put(arr, csh)
 
-        model.ps_weights = jnp.asarray(z["ps_weights"])
+        model.ps_weights = jax.device_put(
+            np.asarray(z["ps_weights"]), replicated(model.mesh))
         store = getattr(model, "client_store", None)
         if store is not None:
             # this run keeps client state in the host store
@@ -613,14 +614,10 @@ def load_checkpoint(path: str, model, opt, scheduler=None,
         # column sharding (values untouched, hence bit-exact vs an
         # unresized run). The <=1 model-axis case restores replicated,
         # exactly the layout FedOptimizer initialised.
-        if model_axis_size(model.mesh) > 1:
-            ssh = server_state_sharding(
-                model.mesh, tuple(model.args.transmit_shape))
-        else:
-            ssh = None
         opt.server_state = ServerState.restore(
             np.asarray(z["ss_Vvelocity"]), np.asarray(z["ss_Verror"]),
-            sharding=ssh)
+            sharding=server_state_sharding(
+                model.mesh, tuple(model.args.transmit_shape)))
         model.last_updated = np.asarray(z["last_updated"])
         model.client_last_seen = np.asarray(z["client_last_seen"])
         if getattr(model, "model_state", None) is not None:

@@ -33,7 +33,7 @@ r x c bytes + r f32 row scales, not 4 x r x c.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +59,7 @@ from commefficient_tpu.telemetry.core import compile_delta, compile_mark
 from commefficient_tpu.ops.vec import flatten_params
 from commefficient_tpu.parallel import make_mesh, make_mesh2d
 from commefficient_tpu.parallel.mesh import (client_sharding,
-                                             model_axis_size,
+                                             replicated,
                                              server_state_sharding,
                                              shard_batch)
 
@@ -166,7 +166,10 @@ class FedModel:
         assert num_clients is not None, "num_clients unresolved"
         self.num_clients = num_clients
 
-        self.ps_weights = flat
+        # committed to every device of the mesh: left uncommitted the
+        # vector lives on device 0 and each round program re-replicates
+        # it on entry
+        self.ps_weights = jax.device_put(flat, replicated(self.mesh))
         # per-client state placement (commefficient_tpu/clientstore):
         # device = dense (num_clients, ...) HBM arrays (below); host =
         # budgeted host arena + mmap spill, with only the round's W
@@ -666,7 +669,8 @@ class FedModel:
         with tel.span("h2d"), trace.phase("h2d"):
             dev_batch = shard_batch(self.mesh, jax.tree_util.tree_map(
                 jnp.asarray, dev_batch))
-            ids = jax.device_put(jnp.asarray(ids_np, jnp.int32))
+            ids = jax.device_put(jnp.asarray(ids_np, jnp.int32),
+                                 replicated(self.mesh))
 
         rng = jax.random.fold_in(self._rng, self.round_index)
         cs_in = self.client_states
@@ -704,10 +708,14 @@ class FedModel:
             # jit wrapper — AOT executables don't re-lower)
             self._emit_cost_model(jit_fn, rargs)
         if self._round_abstract is None:
+            # uncommitted arguments (the round key, the scalar LR) stay
+            # unplaced: their default-device sharding would clash with
+            # the mesh's when lowering for more than one device
             self._round_abstract = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(
                     a.shape, a.dtype,
-                    sharding=getattr(a, "sharding", None)), rargs)
+                    sharding=a.sharding if a.committed else None),
+                rargs)
         cmark = (compile_mark() if flavor not in var.compiled
                  else None)
         with tel.span("round_dispatch"), trace.phase("round_dispatch"):
@@ -1238,19 +1246,15 @@ class FedOptimizer:
                 v[group["index"]] = 1.0
                 inds.append(jnp.asarray(v))
             self._lr_indicators = inds
-        # 2D mesh: server momentum/error buffers are created (and the
-        # server round built) model-sharded — per-device server state
-        # is 1/M from the first round, never resharded from a
-        # replicated allocation. Cx1/1-D meshes keep today's exact
-        # replicated construction.
-        mesh = self.model.mesh
-        sharded = model_axis_size(mesh) > 1
-        self._mesh, self._sharded = mesh, sharded
+        # server momentum/error buffers are created in the layout the
+        # server round keeps them in: model-sharded on a 2D mesh
+        # (per-device server state is 1/M from the first round, never
+        # resharded from a replicated allocation), replicated over
+        # every device of a Cx1/1-D mesh
+        mesh = self._mesh = self.model.mesh
         self.server_state = ServerState.init(
-            self.args,
-            sharding=(server_state_sharding(mesh,
-                                            self.args.transmit_shape)
-                      if sharded else None))
+            self.args, sharding=server_state_sharding(
+                mesh, self.args.transmit_shape))
         # geometry the live server state was allocated for: a knob
         # move that changes transmit_shape (--autopilot_geometry)
         # re-inits the momentum/error tables at the new shape
@@ -1262,7 +1266,7 @@ class FedOptimizer:
                            or 0) > 0
         self._server_round = jax.jit(
             build_server_round(self.args, probes=self._probes,
-                               mesh=mesh if sharded else None),
+                               mesh=mesh),
             donate_argnums=(0, 1))
         # legacy --do_dp server-mode noise stream: the seed+1 root key
         # comes from privacy/ (the one module allowed raw jax.random
@@ -1318,8 +1322,7 @@ class FedOptimizer:
             if svar.server_fn is None:
                 svar.server_fn = jax.jit(
                     build_server_round(svar.cfg, probes=self._probes,
-                                       mesh=(self._mesh if self._sharded
-                                             else None)),
+                                       mesh=self._mesh),
                     donate_argnums=(0, 1))
             geom = tuple(svar.cfg.transmit_shape)
             if geom != self._server_geom:
@@ -1329,8 +1332,7 @@ class FedOptimizer:
                 # this reason)
                 self.server_state = ServerState.init(
                     svar.cfg,
-                    sharding=(server_state_sharding(self._mesh, geom)
-                              if self._sharded else None))
+                    sharding=server_state_sharding(self._mesh, geom))
                 self._server_geom = geom
             server_fn = svar.server_fn
         sfirst = svar is not None and "server" not in svar.compiled
@@ -1384,10 +1386,8 @@ class FedOptimizer:
             elif self.args.mode in ("local_topk", "fedavg") \
                     or lr_np.ndim > 0:
                 # != 0 compare, packed ON DEVICE: shipping the dense
-                # f32 update to the host costs 4*d bytes per round
-                # through the (slow) dispatch link — the bitmap is
-                # 1/32 of that (measured: the dense transfer dominated
-                # local_topk wall time at d=6.6M on the relay)
+                # f32 update to the host costs 4*d bytes of D2H per
+                # round — the bitmap is 1/32 of that
                 support = {"bitmap": jnp.packbits(update != 0)}
         m.note_update(support)
         if sprobes is not None:
@@ -1407,6 +1407,18 @@ class FedOptimizer:
     def zero_grad(self):
         raise NotImplementedError(
             "functional runtime: there is no gradient to zero")
+
+
+class TrainRun(NamedTuple):
+    """What one trainer invocation (``cv_train.run`` /
+    ``gpt2_train.run``) built and returned: the epoch rows plus the
+    runtime objects, so the trainers' ``cli`` can turn divergence into
+    an exit status and ``chip_smoke.py`` can inspect the programs and
+    placements the run actually used."""
+    results: list
+    model: FedModel
+    opt: FedOptimizer
+    train_loader: object
 
 
 class LambdaLR:

@@ -41,6 +41,7 @@ from commefficient_tpu.core.rounds import args2sketch
 from commefficient_tpu.core.rounds_sp import (build_sp_gpt2_round,
                                               make_sp_mesh,
                                               shift_lm_labels)
+from commefficient_tpu.parallel.mesh import on_every_device
 from commefficient_tpu.runtime.fed_model import FedModel
 from commefficient_tpu.telemetry import clock, trace
 
@@ -95,8 +96,11 @@ class SeqParallelFedModel(FedModel):
                 dense = agg
                 if sketch is not None:
                     # linearity: sketch(mean of grads) == mean of
-                    # sketches
-                    agg = sketch.sketch(dense)
+                    # sketches. ``dense`` is replicated; every device
+                    # sketches it whole (a Mosaic kernel cannot sit in
+                    # a partitioned jit)
+                    agg = on_every_device(sketch.sketch,
+                                          self._sp_mesh)(dense)
                 pr = None
                 if probes_on:
                     from commefficient_tpu.core.rounds import _agg_probes
@@ -104,8 +108,10 @@ class SeqParallelFedModel(FedModel):
                     if with_recovery and sketch is not None:
                         # the dense aggregate exists pre-sketch on
                         # this path, so ground truth is free here
-                        pr["recovery_error"] = sketch.recovery_error(
-                            agg, dense, args.k)
+                        pr["recovery_error"] = on_every_device(
+                            lambda t, g: sketch.recovery_error(
+                                t, g, args.k),
+                            self._sp_mesh)(agg, dense)
                 return agg, loss, pr
             return round_and_compress
 

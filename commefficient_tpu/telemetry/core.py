@@ -88,7 +88,10 @@ def compile_mark():
     """Snapshot of the process-wide compile accumulator; pair with
     ``compile_delta`` to attribute the compiles between two points to a
     specific cause (fed_model stamps first-dispatch compiles of a round
-    variant onto the round record as ``vcompile_*:<key>`` counters)."""
+    variant onto the round record as ``vcompile_*:<key>`` counters).
+    Starts the accumulator if nothing has yet: a mark with no listener
+    behind it would read every delta as zero."""
+    _ensure_compile_listener()
     return (_COMPILE["events"], _COMPILE["secs"])
 
 
@@ -106,7 +109,11 @@ def _ensure_compile_listener():
         from jax import monitoring
 
         def _on_duration(event, secs, **kw):
-            if "compile" in event:
+            # trace, lower and backend-compile durations. Not every
+            # event with "compile" in its name: on a persistent-cache
+            # hit jax also reports compile_time_saved_sec, the time
+            # that was NOT spent
+            if event.startswith("/jax/core/compile/"):
                 _COMPILE["events"] += 1
                 _COMPILE["secs"] += float(secs)
 

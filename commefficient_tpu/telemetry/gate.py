@@ -12,9 +12,9 @@ outside BOTH a relative tolerance and a ``k x MAD`` noise band:
                                       mad_k x MAD_base)
     higher-is-better: symmetric, below the baseline
 
-Median-of-N + MAD instead of mean + stddev because bench samples are
-dispatch-latency contaminated (the relay adds rare 2-3x outliers):
-one bad draw must move neither the baseline nor the verdict.
+Median-of-N + MAD instead of mean + stddev because bench samples
+carry rare dispatch-latency outliers: one bad draw must move neither
+the baseline nor the verdict.
 
 Metrics extracted from a ledger (``metrics_from_records``):
 

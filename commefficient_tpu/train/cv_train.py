@@ -28,7 +28,7 @@ from commefficient_tpu.data import (FedLoader, FedSampler, ValLoader,
 from commefficient_tpu.data import transforms as T
 from commefficient_tpu.models import get_model
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
-                                       drain_rounds)
+                                       TrainRun, drain_rounds)
 from commefficient_tpu.telemetry import clock
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
@@ -328,6 +328,7 @@ def train(model, opt, lr_scheduler, train_loader, val_loader, args,
                                   round_hook=round_hook, epoch=epoch)
             if out is None:
                 print("NaN detected, aborting training")
+                model.diverged = True
                 return results
             train_loss, train_acc, download, upload = out
             train_time = timer()
@@ -478,6 +479,17 @@ DEFAULT_LR = 0.4
 
 
 def main(argv=None):
+    """The epoch rows of ``run(argv)`` (what the tests read)."""
+    return run(argv).results
+
+
+def cli() -> int:
+    """Process entry (console script, ``python -m``)."""
+    from commefficient_tpu.train import cli_exit_status
+    return cli_exit_status(run)
+
+
+def run(argv=None) -> TrainRun:
     args = parse_args(default_lr=DEFAULT_LR, argv=argv)
     from commefficient_tpu.parallel.mesh import \
         maybe_initialize_multihost_cli
@@ -635,8 +647,8 @@ def main(argv=None):
                                   getattr(model, "model_state", None),
                                   tpath)
             print(f"saved torch state_dict to {tpath}")
-    return results
+    return TrainRun(results, model, opt, train_loader)
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    sys.exit(cli())

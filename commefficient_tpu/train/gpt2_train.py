@@ -32,7 +32,7 @@ from commefficient_tpu.data.tokenizer import (SPECIAL_TOKENS,
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            token_nll)
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
-                                       drain_rounds)
+                                       TrainRun, drain_rounds)
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
                                      Timer, steps_per_epoch)
@@ -393,6 +393,18 @@ def get_data_loaders(args: Config, tokenizer):
 
 
 def main(argv=None):
+    """The epoch rows (or, under --finetune, the eval tuple) of
+    ``run(argv)`` — what the tests read."""
+    return run(argv).results
+
+
+def cli() -> int:
+    """Process entry (console script, ``python -m``)."""
+    from commefficient_tpu.train import cli_exit_status
+    return cli_exit_status(run)
+
+
+def run(argv=None) -> TrainRun:
     args = parse_args(default_lr=4e-2, argv=argv)
     from commefficient_tpu.parallel.mesh import \
         maybe_initialize_multihost_cli
@@ -447,7 +459,7 @@ def main(argv=None):
         out = run_batches(model, opt, lr_scheduler, val_loader, args,
                           training=False)
         print({"val_nll": out[0], "val_acc": out[1], "val_ppl": out[2]})
-        return out
+        return TrainRun(out, model, opt, train_loader)
 
     from commefficient_tpu.runtime.checkpoint import setup_resume
     start_epoch, epoch_hook, round_hook = setup_resume(
@@ -506,8 +518,8 @@ def main(argv=None):
         tokenizer.save_pretrained(logdir)
         print(f"saved model + tokenizer to {logdir}"
               + (" (HF torch format)" if args.do_hf_export else ""))
-    return results
+    return TrainRun(results, model, opt, train_loader)
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    sys.exit(cli())

@@ -18,6 +18,29 @@ import numpy as np
 from commefficient_tpu.telemetry import clock
 
 
+def setup_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a place that can be
+    chosen from outside; returns the directory in use.
+
+    For process entry points only (``chip_smoke.py``, ``bench.py``,
+    ``scripts/tpu_selftest.py``, the trainers' ``cli``), before first
+    device use — never from ``main(argv)``, which the tests call
+    in-process dozens of times and would fill the checkout with CPU
+    entries. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and nothing is set here. Otherwise the cache lives at
+    ``<checkout>/.jax_cache``: fixed by the package's location because
+    the directory is part of the cache key, so a path from a tempdir,
+    a pid or a clock would never hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 class GracefulShutdown(Exception):
     """Raised in the main thread when a termination signal arrives
     (``sigterm_raises``). Unwinds the round loop so the trainer can run

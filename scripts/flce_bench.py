@@ -37,7 +37,7 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--iters", type=int, default=20,
                     help="op pairs per timed call (amortizes "
-                    "dispatch through the relay)")
+                    "dispatch)")
     ap.add_argument("--allow_fallback", action="store_true",
                     help="bench even when the fused path cannot "
                     "engage (the 'fused' column is then the chunked "

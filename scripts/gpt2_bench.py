@@ -2,9 +2,8 @@
 
 Measures ms/round and tokens/s for the reference's LM workload
 (gpt2_train.py round loop) at configurable batch geometry, with an
-optional xplane profile parsed into a per-op time breakdown
-(the only profiling recipe that works through this environment's
-relay — see BENCHMARKS.md).
+optional xplane profile parsed into a per-op time breakdown (see
+BENCHMARKS.md).
 
 Usage:
   python scripts/gpt2_bench.py [--clients 4] [--examples 2]

@@ -12,7 +12,7 @@
 The committed baseline pins median + MAD per metric (host-span times,
 schema-v3/v4 device-time buckets and skew stats, bench clients/s);
 ``--check`` fails — exit 1 — only outside a noise band of
-``max(rel_tol x median, k x MAD)`` (telemetry/gate.py), so relay
+``max(rel_tol x median, k x MAD)`` (telemetry/gate.py), so timing
 jitter passes and a real regression cannot. ``--write-baseline`` over
 an existing baseline first gates the new run against it and REFUSES
 to re-baseline over a hard regression (``--force`` overrides, for

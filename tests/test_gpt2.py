@@ -466,11 +466,6 @@ class TestFullCandidateValidation:
 
 
 class TestRemat:
-    @pytest.mark.xfail(
-        strict=False,
-        reason="jax 0.4.x remat reschedules the backward: one grad "
-               "element lands ~3e-6 off, past the 1e-6 identity "
-               "tolerance; exact on current jax")
     def test_remat_identical_outputs_and_grads(self):
         """--remat must not change the math — same forward logits and
         same gradients, only the backward's memory/FLOP schedule."""

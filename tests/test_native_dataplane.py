@@ -189,3 +189,25 @@ def test_prefetch_ring_soak():
             np.testing.assert_array_equal(x, expected[i][0])
             np.testing.assert_array_equal(y, expected[i][1])
             np.testing.assert_array_equal(m, expected[i][2])
+
+
+def test_library_is_keyed_by_its_source(tmp_path, monkeypatch):
+    """A binary that does not match fed_dataplane.cpp is never loaded:
+    the file name carries the source's hash (mtimes mean nothing in a
+    copied tree, and _build/ is git-ignored but survives on disk)."""
+    import hashlib
+    import os
+
+    from commefficient_tpu import native as native_mod
+    with open(native_mod._SRC, "rb") as f:
+        src = f.read()
+    tag = hashlib.sha256(src).hexdigest()[:12]
+    assert os.path.basename(native_mod._compile()) == \
+        f"libfed_dataplane-{tag}.so"
+    # another source -> another library, whatever sits in _build/
+    edited = tmp_path / "fed_dataplane.cpp"
+    edited.write_bytes(src + b"\n// edited\n")
+    monkeypatch.setattr(native_mod, "_SRC", str(edited))
+    monkeypatch.setattr(native_mod, "_BUILD_DIR", str(tmp_path))
+    other = native_mod._compile()
+    assert other is not None and tag not in other

@@ -58,12 +58,6 @@ def test_kernel_zero_threshold_edge():
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="jax 0.4.x Mosaic lowering refuses non-interpret "
-           "pallas_call when the process backend is CPU, so the "
-           "cross-platform lower(lowering_platforms=('tpu',)) probe "
-           "cannot run; works on current jax / real TPU")
 def test_branch_selected_at_lowering_not_trace():
     """The Pallas-vs-XLA branch is a lax.platform_dependent, decided
     per LOWERING platform — not frozen from jax.default_backend() at
@@ -81,8 +75,8 @@ def test_branch_selected_at_lowering_not_trace():
     assert "tpu_custom_call" not in cpu_txt
     assert "tpu_custom_call" in tpu_txt
     # and the cpu lowering executes correctly end to end
-    got = np.asarray(jax.jit(
-        lambda v: threshold_topk_mask_1d(v, k), backend="cpu")(sq))
+    got = np.asarray(traced.lower(
+        lowering_platforms=("cpu",)).compile()(sq))
     want = np.asarray(_threshold_topk_mask(sq, k))
     assert got.sum() == k
     np.testing.assert_array_equal(got, want)

@@ -154,6 +154,17 @@ class TestFlopInventory:
         assert cost["expected_round_s"] >= cost["compute_floor_s"]
         assert cost["expected_round_s"] >= cost["collective_floor_s"]
 
+    def test_chip_spec_is_a_lookup_not_a_guess(self):
+        from commefficient_tpu.analysis.cost import CHIP_SPECS, chip_spec
+        assert chip_spec("tpu", "TPU v5 lite") is CHIP_SPECS["tpu-v5e"]
+        assert chip_spec("cpu", "cpu") is CHIP_SPECS["cpu"]
+        # an unknown TPU is not a v4, and only the cpu backend gets
+        # the cpu stand-in
+        with pytest.raises(ValueError, match="TPU v9"):
+            chip_spec("tpu", "TPU v9")
+        with pytest.raises(ValueError, match="rocm"):
+            chip_spec("rocm", "")
+
 
 # --- perf-gate math ---------------------------------------------------
 
