@@ -71,9 +71,10 @@ def accumulate_and_compress(cfg: Config,
 
     if cfg.mode == "local_topk":
         assert cfg.error_type in ("local", "none")
-        to_transmit = topk(to_transmit, k=cfg.k,
-                           approx=cfg.approx_topk,
-                           recall=cfg.approx_recall)
+        with jax.named_scope("compress"):
+            to_transmit = topk(to_transmit, k=cfg.k,
+                               approx=cfg.approx_topk,
+                               recall=cfg.approx_recall)
         kept = to_transmit != 0
         if has_error:
             error = jnp.where(kept, 0.0, error)      # error feedback

@@ -66,9 +66,11 @@ def make_forward_grad(cfg: Config,
         lambda p, b: loss_fn(p, b)[0], argnums=0)
 
     def one_microbatch(params_flat, microbatch):
-        loss, metrics = loss_fn(params_flat, microbatch)
+        with jax.named_scope("fwd_bwd"):
+            loss, metrics = loss_fn(params_flat, microbatch)
         n = jnp.sum(microbatch["mask"])
-        g = grad_loss(params_flat, microbatch)
+        with jax.named_scope("fwd_bwd"):
+            g = grad_loss(params_flat, microbatch)
         # an all-padding microbatch contributes nothing (the reference
         # never creates one; padding does)
         valid = n > 0
@@ -148,7 +150,8 @@ def make_forward_grad(cfg: Config,
         # compression (fed_worker.py:314-322)
         if cfg.mode == "sketch":
             assert sketch is not None
-            table = sketch.sketch(g)
+            with jax.named_scope("compress"):
+                table = sketch.sketch(g)
             if cfg.max_grad_norm is not None:
                 table = clip_record(table, cfg.max_grad_norm,
                                     is_sketch=True)

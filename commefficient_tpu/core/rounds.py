@@ -571,8 +571,9 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
 
         if tree_sketch:
             tree = unravel(ps_weights)
-            (_, metrics), g_tree = jax.value_and_grad(
-                make_local_loss(tree_loss), has_aux=True)(tree)
+            with jax.named_scope("fwd_bwd"):
+                (_, metrics), g_tree = jax.value_and_grad(
+                    make_local_loss(tree_loss), has_aux=True)(tree)
             if cfg.weight_decay != 0:
                 coef = _wd_coef()
                 # decay in f32 regardless of leaf dtype: the flat path
@@ -593,25 +594,30 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                 flat = jnp.concatenate(
                     [jnp.ravel(l).astype(jnp.float32)
                      for l in leaves])
+                with jax.named_scope("compress"):
+                    table = emit(flat)
                 if with_dense:
-                    return emit(flat), metrics, flat
-                return emit(flat), metrics
-            table = sketch.sketch_from_leaves(leaves)
+                    return table, metrics, flat
+                return table, metrics
+            with jax.named_scope("compress"):
+                table = sketch.sketch_from_leaves(leaves)
             if with_dense:
                 return table, metrics, jnp.concatenate(
                     [jnp.ravel(l).astype(jnp.float32)
                      for l in leaves])
             return table, metrics
 
-        (_, metrics), g = jax.value_and_grad(
-            make_local_loss(loss_fn), has_aux=True)(ps_weights)
+        with jax.named_scope("fwd_bwd"):
+            (_, metrics), g = jax.value_and_grad(
+                make_local_loss(loss_fn), has_aux=True)(ps_weights)
         if cfg.weight_decay != 0:
             # Σ_i (wd/num_workers)·p·n_i / total = (wd/num_workers)·p
             g = g + _wd_coef() * ps_weights
-        if emit is not None:
-            t = emit(g)
+        if cfg.mode != "sketch":
+            t = g
         else:
-            t = sketch.sketch(g) if cfg.mode == "sketch" else g
+            with jax.named_scope("compress"):
+                t = emit(g) if emit is not None else sketch.sketch(g)
         if with_dense:
             return t, metrics, g
         return t, metrics
@@ -879,11 +885,12 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                                               probes=probes,
                                               weights=cw)
         elif sketch_late:
-            aggregated = _sketch_after_local_sum(
-                sketch, t_fold, mesh,
-                emit=_partial_table_emit if shard2d_late else None,
-                wire="f32" if dp_on else wire,
-                depth=depth if overlap else 1) / total
+            with jax.named_scope("compress"):
+                aggregated = _sketch_after_local_sum(
+                    sketch, t_fold, mesh,
+                    emit=_partial_table_emit if shard2d_late else None,
+                    wire="f32" if dp_on else wire,
+                    depth=depth if overlap else 1) / total
         else:
             aggregated = jnp.sum(t_fold, axis=0) / total
 
@@ -1006,8 +1013,9 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                 (chunk_sum, states), ys = body(
                     (jnp.zeros(cfg.grad_size, jnp.float32), states),
                     inp)
-                return (table_acc + sketch.sketch(chunk_sum),
-                        states), ys
+                with jax.named_scope("compress"):
+                    table_acc = table_acc + sketch.sketch(chunk_sum)
+                return (table_acc, states), ys
 
             (table, states), ys = jax.lax.scan(
                 body_sketch,
@@ -1032,7 +1040,8 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                 (jnp.zeros(init_shape, jnp.float32), client_states),
                 (ids_p, rngs_p, batch_p))
             if sketch_late:
-                table = sketch.sketch(acc)
+                with jax.named_scope("compress"):
+                    table = sketch.sketch(acc)
                 if quantized:
                     table = (_qdq_local_overlapped(table)
                              if overlap else _qdq_local(table))
@@ -1466,12 +1475,14 @@ def build_server_round(cfg: Config, probes: bool = False,
             unique = not selection_may_duplicate(cfg.grad_size,
                                                  cfg.approx_topk)
             idx, scaled = res.support
-            order = jnp.argsort(idx)
-            new_ps = ps_weights.at[idx[order]].add(
-                -scaled[order], mode="promise_in_bounds",
-                unique_indices=unique, indices_are_sorted=True)
+            with jax.named_scope("apply"):
+                order = jnp.argsort(idx)
+                new_ps = ps_weights.at[idx[order]].add(
+                    -scaled[order], mode="promise_in_bounds",
+                    unique_indices=unique, indices_are_sorted=True)
         else:
-            new_ps = ps_weights - res.weight_update
+            with jax.named_scope("apply"):
+                new_ps = ps_weights - res.weight_update
         new_vel = client_velocities
         if (cfg.mode == "true_topk" and cfg.local_momentum > 0
                 and client_velocities is not None):
@@ -1527,7 +1538,8 @@ def _build_server_round_2d_sketch(cfg: Config, sketch: CountSketch,
         out = step(server_state, aggregated,
                    jnp.asarray(lr, jnp.float32))
         weight_update, new_state, support = out[:3]
-        new_ps = ps_weights - weight_update
+        with jax.named_scope("apply"):
+            new_ps = ps_weights - weight_update
         ret = (new_ps, new_state, client_velocities, weight_update,
                support)
         return ret + (out[3],) if probes else ret
@@ -1559,8 +1571,9 @@ def _build_server_round_2d_dense(cfg: Config, mesh,
             lambda x: jax.lax.with_sharding_constraint(x, state_sh),
             res.state)
         upd = jax.lax.with_sharding_constraint(res.weight_update, repl)
-        out = (ps_weights - upd, new_state, client_velocities, upd,
-               res.support)
+        with jax.named_scope("apply"):
+            new_ps = ps_weights - upd
+        out = (new_ps, new_state, client_velocities, upd, res.support)
         return out + (res.probes,) if probes else out
 
     return server_round

@@ -196,7 +196,8 @@ def _fedavg(cfg, avg_update, state, lr, sketch, noise_rng,
     # (fed_aggregator.py:485-497) — avg_update is the data-weighted
     # mean of client weight *deltas*, LR already applied locally
     assert cfg.error_type == "none" and cfg.local_momentum == 0
-    Vvel = avg_update + cfg.virtual_momentum * state.Vvelocity
+    with jax.named_scope("apply"):
+        Vvel = avg_update + cfg.virtual_momentum * state.Vvelocity
     new_state = ServerState(Vvel, state.Verror)
     pr = _state_probes(_l2(Vvel), new_state) if probes else None
     return ServerUpdate(Vvel, new_state, None, probes=pr)
@@ -205,7 +206,8 @@ def _fedavg(cfg, avg_update, state, lr, sketch, noise_rng,
 def _uncompressed(cfg, gradient, state, lr, sketch, noise_rng,
                   probes=False):
     # (fed_aggregator.py:499-511)
-    Vvel = gradient + cfg.virtual_momentum * state.Vvelocity
+    with jax.named_scope("apply"):
+        Vvel = gradient + cfg.virtual_momentum * state.Vvelocity
     if cfg.do_dp and cfg.dp_mode == "server" and cfg.noise_multiplier != 0:
         assert noise_rng is not None, \
             "server-mode DP with noise needs a noise_rng"
@@ -226,8 +228,9 @@ def _true_topk(cfg, gradient, state, lr, sketch, noise_rng,
                probes=False):
     # (fed_aggregator.py:513-544)
     assert cfg.error_type == "virtual"
-    Vvel = gradient + cfg.virtual_momentum * state.Vvelocity
-    Verr = state.Verror + Vvel
+    with jax.named_scope("apply"):
+        Vvel = gradient + cfg.virtual_momentum * state.Vvelocity
+        Verr = state.Verror + Vvel
 
     k = min(cfg.k, cfg.grad_size)
     if _use_threshold_select(cfg):
@@ -237,18 +240,22 @@ def _true_topk(cfg, gradient, state, lr, sketch, noise_rng,
         # compare semantics as _lr_scaled_support (lr==0 coordinates
         # read as unchanged)
         from commefficient_tpu.ops.topk import threshold_topk_mask_1d
-        mask = threshold_topk_mask_1d(jax.lax.square(Verr), k)
-        update = jnp.where(mask, Verr, 0.0)
+        with jax.named_scope("select"):
+            mask = threshold_topk_mask_1d(jax.lax.square(Verr), k)
+            update = jnp.where(mask, Verr, 0.0)
         support = {"bitmap": jnp.packbits((update * lr) != 0)}
     else:
-        update, idx, vals = topk_with_support(
-            Verr, k, approx=cfg.approx_topk, recall=cfg.approx_recall)
+        with jax.named_scope("select"):
+            update, idx, vals = topk_with_support(
+                Verr, k, approx=cfg.approx_topk,
+                recall=cfg.approx_recall)
         support = _lr_scaled_support(idx, vals, lr)
     dense_mass = jnp.sum(jax.lax.square(Verr)) if probes else None
-    keep = update == 0
-    # error feedback + momentum factor masking at transmitted coords
-    Verr = jnp.where(keep, Verr, 0.0)
-    Vvel = jnp.where(keep, Vvel, 0.0)
+    with jax.named_scope("apply"):
+        keep = update == 0
+        # error feedback + momentum factor masking at transmitted coords
+        Verr = jnp.where(keep, Verr, 0.0)
+        Vvel = jnp.where(keep, Vvel, 0.0)
     new_state = ServerState(Vvel, Verr)
     pr = None
     if probes:
@@ -270,7 +277,8 @@ def _local_topk(cfg, local_topk_grad, state, lr, sketch, noise_rng,
     # error is impossible (the transmitted quantity is already sparse)
     # and masking virtual momentum would zero all of it every round
     assert cfg.error_type in ("local", "none")
-    Vvel = local_topk_grad + cfg.virtual_momentum * state.Vvelocity
+    with jax.named_scope("apply"):
+        Vvel = local_topk_grad + cfg.virtual_momentum * state.Vvelocity
     new_state = ServerState(Vvel, state.Verror)
     pr = _state_probes(_l2(Vvel * lr), new_state) if probes else None
     return ServerUpdate(Vvel * lr, new_state, None, probes=pr)
@@ -289,14 +297,16 @@ def _sketched(cfg, sketched_grad, state, lr, sketch, noise_rng,
     elif cfg.error_type == "virtual":
         assert cfg.local_momentum == 0
 
-    Vvel = sketched_grad + cfg.virtual_momentum * state.Vvelocity
-    if cfg.error_type == "local":
-        Verr = Vvel
-    elif cfg.error_type == "virtual":
-        Verr = state.Verror + Vvel
-    else:  # "none": Verror stays zero forever -> zero updates, exactly
-        # like the reference (fed_aggregator.py:581-587 never assigns)
-        Verr = state.Verror
+    with jax.named_scope("apply"):
+        Vvel = sketched_grad + cfg.virtual_momentum * state.Vvelocity
+        if cfg.error_type == "local":
+            Verr = Vvel
+        elif cfg.error_type == "virtual":
+            Verr = state.Verror + Vvel
+        else:  # "none": Verror stays zero forever -> zero updates,
+            # exactly like the reference (fed_aggregator.py:581-587
+            # never assigns)
+            Verr = state.Verror
 
     # At large d the k-sparse form wins everywhere: re-sketching the
     # recovered update costs O(r*k) scatter-adds instead of the O(d)
@@ -327,20 +337,22 @@ def _sketched(cfg, sketched_grad, state, lr, sketch, noise_rng,
 
     # re-sketch the recovered update to find which table buckets it
     # occupies (fed_aggregator.py:595-597)
-    if sparse:
-        sketched_update = sketch.sketch_sparse(idx, vals)
-    else:
-        sketched_update = sketch.sketch(update)
-    keep = sketched_update == 0
+    with jax.named_scope("resketch"):
+        if sparse:
+            sketched_update = sketch.sketch_sparse(idx, vals)
+        else:
+            sketched_update = sketch.sketch(update)
+        keep = sketched_update == 0
 
-    if cfg.error_type == "virtual":
-        Verr = jnp.where(keep, Verr, 0.0)
-    # momentum factor masking in table space (both error types; with
-    # error "local" this also masks Verror since they alias,
-    # fed_aggregator.py:612-613)
-    Vvel = jnp.where(keep, Vvel, 0.0)
-    if cfg.error_type == "local":
-        Verr = Vvel
+    with jax.named_scope("apply"):
+        if cfg.error_type == "virtual":
+            Verr = jnp.where(keep, Verr, 0.0)
+        # momentum factor masking in table space (both error types;
+        # with error "local" this also masks Verror since they alias,
+        # fed_aggregator.py:612-613)
+        Vvel = jnp.where(keep, Vvel, 0.0)
+        if cfg.error_type == "local":
+            Verr = Vvel
 
     new_state = ServerState(Vvel, Verr)
     pr = None
@@ -409,13 +421,14 @@ def sketched_update_2d(cfg: Config, sketch: CountSketch,
 
     d = cfg.grad_size
     k = min(cfg.k, d)
-    Vvel = sketched_grad_loc + cfg.virtual_momentum * state.Vvelocity
-    if cfg.error_type == "local":
-        Verr = Vvel
-    elif cfg.error_type == "virtual":
-        Verr = state.Verror + Vvel
-    else:  # "none": zero updates forever, like the 1-D path
-        Verr = state.Verror
+    with jax.named_scope("apply"):
+        Vvel = sketched_grad_loc + cfg.virtual_momentum * state.Vvelocity
+        if cfg.error_type == "local":
+            Verr = Vvel
+        elif cfg.error_type == "virtual":
+            Verr = state.Verror + Vvel
+        else:  # "none": zero updates forever, like the 1-D path
+            Verr = state.Verror
 
     table = jax.lax.all_gather(Verr, axis_name, axis=1, tiled=True)
 
@@ -427,12 +440,14 @@ def sketched_update_2d(cfg: Config, sketch: CountSketch,
     start = (p * n_loc).astype(jnp.int32)
     gidx = start + jnp.arange(n_loc, dtype=jnp.int32)
     valid = gidx < d
-    est = sketch.estimates_at(table, jnp.minimum(gidx, d - 1))
-    est = jnp.where(valid, est, 0.0)
+    with jax.named_scope("estimates"):
+        est = sketch.estimates_at(table, jnp.minimum(gidx, d - 1))
+        est = jnp.where(valid, est, 0.0)
 
     from commefficient_tpu.ops.topk import distributed_threshold_mask_1d
-    take = distributed_threshold_mask_1d(jax.lax.square(est), k,
-                                         axis_name, valid=valid)
+    with jax.named_scope("select"):
+        take = distributed_threshold_mask_1d(jax.lax.square(est), k,
+                                             axis_name, valid=valid)
     # candidate extraction: pack this shard's winners into k slots
     # (index d = "empty"), gather all M·k slots, compact to exactly k —
     # the distributed mask selects exactly k coordinates globally
@@ -461,16 +476,18 @@ def sketched_update_2d(cfg: Config, sketch: CountSketch,
     support = _lr_scaled_support(idx, vals, lr)
 
     # re-sketch the recovered update, slice this peer's columns, mask
-    st = sketch.sketch_sparse(idx, vals)
-    c_loc = Verr.shape[1]
-    st_loc = jax.lax.dynamic_slice(st, (0, p * c_loc),
-                                   (st.shape[0], c_loc))
-    keep = st_loc == 0
-    if cfg.error_type == "virtual":
-        Verr = jnp.where(keep, Verr, 0.0)
-    Vvel = jnp.where(keep, Vvel, 0.0)
-    if cfg.error_type == "local":
-        Verr = Vvel
+    with jax.named_scope("resketch"):
+        st = sketch.sketch_sparse(idx, vals)
+        c_loc = Verr.shape[1]
+        st_loc = jax.lax.dynamic_slice(st, (0, p * c_loc),
+                                       (st.shape[0], c_loc))
+        keep = st_loc == 0
+    with jax.named_scope("apply"):
+        if cfg.error_type == "virtual":
+            Verr = jnp.where(keep, Verr, 0.0)
+        Vvel = jnp.where(keep, Vvel, 0.0)
+        if cfg.error_type == "local":
+            Verr = Vvel
     new_state = ServerState(Vvel, Verr)
 
     pr = None

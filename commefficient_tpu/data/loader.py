@@ -11,6 +11,18 @@ shape (SURVEY.md §7).
 ``_RoundLoaderBase`` holds the shared mechanics (B/W resolution,
 incomplete-round skipping, epoch length); subclasses provide only
 ``collate``. Same split for the sharded validation loaders.
+
+The train loaders open up the trainer's ``sampler`` span (the wait in
+``next(loader)``): spans ``data.sample`` (advancing the sampler),
+``data.index``, ``data.submit``, ``data.pop_alloc``, ``data.pop_wait``
+(the native ring: only the C++ plane is behind it), ``data.ring_open``
+/ ``data.ring_close`` (the ring made and torn down at every epoch's
+ends, native/__init__.py), ``data.collate``, and the counter
+``data.epoch_start`` (1 on the ``next()`` that entered a fresh
+``__iter__``). A loader is built before the run's ``Telemetry``: who
+builds both hands it over (``loader.telemetry = model.telemetry``);
+left unset, each epoch looks it up once, on the consumer's thread, in
+``telemetry.current()``.
 """
 
 from __future__ import annotations
@@ -18,6 +30,8 @@ from __future__ import annotations
 from typing import Iterator, Optional
 
 import numpy as np
+
+from commefficient_tpu import telemetry
 
 __all__ = ["FedLoader", "ValLoader", "PersonaFedLoader",
            "PersonaValLoader", "NativeFedLoader", "make_fed_loader"]
@@ -64,11 +78,44 @@ class _RoundLoaderBase:
             batch["mask"] = mask
         return batch
 
+    #: the run's recorder; None: ``telemetry.current()`` at each epoch
+    telemetry = None
+
+    def _epoch_telemetry(self):
+        """The recorder of the epoch that starts here, with its
+        ``data.epoch_start`` counted."""
+        tel = self.telemetry
+        if tel is None:
+            tel = telemetry.current()
+        tel.count("data.epoch_start")
+        return tel
+
+    def _round_specs(self, tel):
+        """The sampler's complete rounds (fewer than ``W`` clients:
+        skipped), each advance of the sampler under a ``data.sample``
+        span."""
+        it = None
+        while True:
+            with tel.span("data.sample"):
+                if it is None:
+                    # a sampler's __iter__ may do an epoch's work
+                    # (FedSampler permutes every client's indices)
+                    it = iter(self.sampler)
+                round_spec = next(it, None)
+            if round_spec is None:
+                return
+            if len(round_spec) >= self.W:
+                yield round_spec
+
+    def _batches(self, tel) -> Iterator[dict]:
+        """One epoch's batches, on whichever thread iterates."""
+        for round_spec in self._round_specs(tel):
+            with tel.span("data.collate"):
+                batch = self.collate(round_spec)
+            yield self._apply_dropout(batch)
+
     def __iter__(self) -> Iterator[dict]:
-        for round_spec in self.sampler:
-            if len(round_spec) < self.W:
-                continue  # incomplete round: skip
-            yield self._apply_dropout(self.collate(round_spec))
+        yield from self._batches(self._epoch_telemetry())
 
     def peek_next_client_ids(self):
         """Next round's participant ids one round ahead (the
@@ -181,13 +228,13 @@ class NativeFedLoader(_RoundLoaderBase):
     def __iter__(self):
         from commefficient_tpu import native
 
-        with native.Prefetcher(self.plane, self.depth,
-                               self.n_threads) as pf:
+        tel = self._epoch_telemetry()
+        with native.Prefetcher(self.plane, self.depth, self.n_threads,
+                               telemetry=tel) as pf:
             pending: list = []
-            for round_spec in self.sampler:
-                if len(round_spec) < self.W:
-                    continue
-                ids, idx = self._spec_to_indices(round_spec)
+            for round_spec in self._round_specs(tel):
+                with tel.span("data.index"):
+                    ids, idx = self._spec_to_indices(round_spec)
                 pf.submit(idx, self.seed + self._round_counter)
                 self._round_counter += 1
                 pending.append(ids)
@@ -253,6 +300,7 @@ class PersonaFedLoader(_RoundLoaderBase):
         if self.prefetch_depth <= 1:
             yield from super().__iter__()
             return
+        tel = self._epoch_telemetry()
         import queue
         import threading
 
@@ -276,7 +324,7 @@ class PersonaFedLoader(_RoundLoaderBase):
             try:
                 # the synchronous path's own iterator: skip-guard,
                 # collate and dropout stay defined in ONE place
-                for batch in _RoundLoaderBase.__iter__(self):
+                for batch in self._batches(tel):
                     if stop.is_set() or not put_or_stop(("batch",
                                                          batch)):
                         return
@@ -290,7 +338,8 @@ class PersonaFedLoader(_RoundLoaderBase):
         t.start()
         try:
             while True:
-                kind, val = q.get()
+                with tel.span("data.pop_wait"):
+                    kind, val = q.get()
                 if kind == "batch":
                     yield val
                 elif kind == "error":

@@ -211,10 +211,11 @@ class GPT2DoubleHeads(nn.Module):
         flat_h = h
         if not return_hidden:
             # tied weights; logits accumulate in float32
-            lm_logits = jnp.einsum("btc,vc->btv",
-                                   h.astype(self.cfg.dtype),
-                                   wte.astype(self.cfg.dtype),
-                                   preferred_element_type=jnp.float32)
+            with jax.named_scope("lm_head"):
+                lm_logits = jnp.einsum(
+                    "btc,vc->btv", h.astype(self.cfg.dtype),
+                    wte.astype(self.cfg.dtype),
+                    preferred_element_type=jnp.float32)
             lm_logits = lm_logits.reshape(B, N, T, -1)
 
         h = h.reshape(B, N, T, -1)
@@ -276,10 +277,11 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     # f32 out of the last LayerNorm) and slice inside the scan rather
     # than pre-transposing to a (chunks, E, tc, C) copy — the copy
     # measured ~15 ms at 65k tokens
-    hp = jnp.pad(h.astype(dtype), ((0, 0), (0, pad), (0, 0)))
-    lp = jnp.pad(labels, ((0, 0), (0, pad)),
-                 constant_values=ignore_index)
-    wte_c = wte.astype(dtype)  # cast once, outside the scan
+    with jax.named_scope("lm_head"):
+        hp = jnp.pad(h.astype(dtype), ((0, 0), (0, pad), (0, 0)))
+        lp = jnp.pad(labels, ((0, 0), (0, pad)),
+                     constant_values=ignore_index)
+        wte_c = wte.astype(dtype)  # cast once, outside the scan
 
     @jax.checkpoint
     def chunk_sums(hc, lc, w):
@@ -301,8 +303,9 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     # scan carry-type check when this runs on a sequence shard
     init = (jnp.sum(hp[:, :, 0] * 0.0, axis=1, dtype=jnp.float32),
             jnp.sum(lp * 0, axis=1).astype(jnp.float32))
-    (sn, sv), _ = jax.lax.scan(body, init,
-                               jnp.arange(num_chunks, dtype=jnp.int32))
+    with jax.named_scope("lm_head"):
+        (sn, sv), _ = jax.lax.scan(
+            body, init, jnp.arange(num_chunks, dtype=jnp.int32))
     return sn, sv
 
 

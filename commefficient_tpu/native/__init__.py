@@ -177,24 +177,37 @@ class Prefetcher:
     regardless of thread scheduling)."""
 
     def __init__(self, plane: NativeDataplane, depth: int = 4,
-                 n_threads: int = 2):
+                 n_threads: int = 2, telemetry=None):
+        """``telemetry``: the caller's span recorder (the loader's);
+        None records nothing."""
+        if telemetry is None:
+            from commefficient_tpu.telemetry import NULL_TELEMETRY
+            telemetry = NULL_TELEMETRY
         self.plane = plane
-        self._handle = plane._lib.cet_ring_create(
-            *plane._common_args(), depth, n_threads)
+        self._tel = telemetry
+        # allocates and zero-fills ``depth`` rounds of output
+        with telemetry.span("data.ring_open"):
+            self._handle = plane._lib.cet_ring_create(
+                *plane._common_args(), depth, n_threads)
         assert self._handle
 
     def submit(self, indices: np.ndarray, seed: int):
-        idx = np.ascontiguousarray(indices, dtype=np.int64)
-        assert idx.shape == (self.plane.slots, self.plane.B)
-        self.plane._lib.cet_ring_submit(
-            self._handle, _ptr(idx, ctypes.c_int64),
-            ctypes.c_uint64(seed & (2**64 - 1)))
+        with self._tel.span("data.submit"):
+            idx = np.ascontiguousarray(indices, dtype=np.int64)
+            assert idx.shape == (self.plane.slots, self.plane.B)
+            self.plane._lib.cet_ring_submit(
+                self._handle, _ptr(idx, ctypes.c_int64),
+                ctypes.c_uint64(seed & (2**64 - 1)))
 
     def pop(self):
-        x, y, m = self.plane._alloc_out()
-        seq = self.plane._lib.cet_ring_pop(
-            self._handle, _ptr(x, ctypes.c_float),
-            _ptr(y, ctypes.c_int32), _ptr(m, ctypes.c_float))
+        tel = self._tel
+        with tel.span("data.pop_alloc"):
+            x, y, m = self.plane._alloc_out()
+        # the wait for the C++ plane, and nothing else
+        with tel.span("data.pop_wait"):
+            seq = self.plane._lib.cet_ring_pop(
+                self._handle, _ptr(x, ctypes.c_float),
+                _ptr(y, ctypes.c_int32), _ptr(m, ctypes.c_float))
         assert seq >= 0, "ring stopped"
         oob = self.plane._lib.cet_ring_oob(self._handle)
         if oob:
@@ -204,7 +217,8 @@ class Prefetcher:
 
     def close(self):
         if self._handle:
-            self.plane._lib.cet_ring_destroy(self._handle)
+            with self._tel.span("data.ring_close"):
+                self.plane._lib.cet_ring_destroy(self._handle)
             self._handle = None
 
     def __enter__(self):

@@ -228,6 +228,7 @@ def _flce_fwd_impl(x, w, labels, block_m, block_v, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="flce_fwd_pallas",
     )(lp, xp, wp)
     return lse[:m, 0], tok[:m, 0]
 
@@ -285,6 +286,7 @@ def _flce_vjp_bwd(block_m, block_v, interpret, res, g):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="flce_bwd_pallas",
     )(*operands)
 
     # f32 partials reduction: the nv per-vocab-block dX contributions
@@ -367,12 +369,14 @@ def lm_nll_sums_fused(h, wte, labels, dtype, ignore_index=-100,
         return lm_nll_sums_chunked(h, wte, labels, dtype,
                                    ignore_index=ignore_index,
                                    tokens_per_chunk=tokens_per_chunk)
-    x = h.astype(dtype).reshape(e * tm, c)
-    w = wte.astype(dtype)
-    lab = labels.reshape(e * tm)
-    valid = lab != ignore_index
-    safe = jnp.where(valid, lab, 0)
-    lse, tok = flce_lse_tok(x, w, safe, _BLOCK_M, _BLOCK_V, interpret)
-    nll = jnp.where(valid, lse - tok, 0.0).reshape(e, tm)
-    sv = valid.reshape(e, tm).astype(jnp.float32)
-    return jnp.sum(nll, axis=1), jnp.sum(sv, axis=1)
+    with jax.named_scope("lm_head"):
+        x = h.astype(dtype).reshape(e * tm, c)
+        w = wte.astype(dtype)
+        lab = labels.reshape(e * tm)
+        valid = lab != ignore_index
+        safe = jnp.where(valid, lab, 0)
+        lse, tok = flce_lse_tok(x, w, safe, _BLOCK_M, _BLOCK_V,
+                                interpret)
+        nll = jnp.where(valid, lse - tok, 0.0).reshape(e, tm)
+        sv = valid.reshape(e, tm).astype(jnp.float32)
+        return jnp.sum(nll, axis=1), jnp.sum(sv, axis=1)

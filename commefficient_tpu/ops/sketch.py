@@ -555,11 +555,19 @@ class CountSketch:
         # picks the identical set (see ``estimates``); the small-d
         # lax.top_k path keeps the slice (d == padded_d there is
         # common, and the sort dominates anyway)
-        from commefficient_tpu.ops.topk import (
-            _THRESHOLD_SELECT_MIN_D, threshold_topk_indices,
-            use_threshold_select)
+        from commefficient_tpu.ops.topk import _THRESHOLD_SELECT_MIN_D
         big_d = self.d >= _THRESHOLD_SELECT_MIN_D
-        est = self.estimates(table, padded=big_d)
+        with jax.named_scope("estimates"):
+            est = self.estimates(table, padded=big_d)
+        with jax.named_scope("select"):
+            return self._select(est, k, big_d, with_support, with_dense)
+
+    def _select(self, est, k: int, big_d: bool, with_support: bool,
+                with_dense: bool):
+        """``unsketch`` after the estimates: the k largest-magnitude
+        ones as (dense, idx, vals)."""
+        from commefficient_tpu.ops.topk import (threshold_topk_indices,
+                                                use_threshold_select)
         if self.approx_topk:
             _, idx = jax.lax.approx_max_k(
                 jax.lax.square(est), k,
@@ -623,9 +631,11 @@ class CountSketch:
         exact path (lowest-index tie-break, tested)."""
         from commefficient_tpu.ops.topk import threshold_topk_mask_1d
         k = min(k, self.d)
-        est = self.estimates(table)
-        mask = threshold_topk_mask_1d(jax.lax.square(est), k)
-        return jnp.where(mask, est, 0.0), mask
+        with jax.named_scope("estimates"):
+            est = self.estimates(table)
+        with jax.named_scope("select"):
+            mask = threshold_topk_mask_1d(jax.lax.square(est), k)
+            return jnp.where(mask, est, 0.0), mask
 
     def prefer_threshold_unsketch(self, k: int) -> bool:
         """Dense-regime exact recovery via the threshold mask: wins

@@ -281,6 +281,7 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
         out_shape=out_struct((r * S, L), jnp.float32, *operands),
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
+        name="sketch_pallas",
     )(*operands)
     return out.reshape(r, c)
 
@@ -393,6 +394,7 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
         scratch_shapes=[pltpu.VMEM((r * S, L), jnp.float32)],
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
+        name="sketch_quant_pallas",
     )(*operands)
     return q.reshape(r, c), rm[:, :1]
 
@@ -467,5 +469,6 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
         out_shape=out_struct((m * c,), jnp.float32, *operands),
         compiler_params=_compiler_params(4 * r * c),
         interpret=interpret,
+        name="estimates_pallas",
     )(*operands)
     return out

@@ -114,5 +114,6 @@ def take_mask_pallas(sq, t_key, need, interpret: bool = False):
         out_shape=out_struct((m * _S, _L), jnp.int8, *operands),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
+        name="take_mask_pallas",
     )(*operands)
     return out.reshape(-1)[:d].astype(bool)

@@ -55,7 +55,9 @@ from commefficient_tpu.core.rounds import (ClientStates,
 from commefficient_tpu.core.server import ServerState
 from commefficient_tpu.privacy import build_accountant, noise_stream
 from commefficient_tpu.telemetry import build_telemetry, clock, trace
-from commefficient_tpu.telemetry.core import compile_delta, compile_mark
+from commefficient_tpu.telemetry.core import (compile_delta,
+                                              compile_mark, set_current,
+                                              setup_span)
 from commefficient_tpu.ops.vec import flatten_params
 from commefficient_tpu.parallel import make_mesh, make_mesh2d
 from commefficient_tpu.parallel.mesh import (client_sharding,
@@ -112,6 +114,7 @@ class FedModel:
     callbacks of cv_train.py:67-83 / gpt2_train.py:77-99).
     """
 
+    @setup_span("model_build")
     def __init__(self, module, params, compute_loss: Callable,
                  args: Config, compute_loss_val: Optional[Callable] = None,
                  padded_batch_size: Optional[int] = None,
@@ -356,6 +359,9 @@ class FedModel:
         # the accounting above, memory/compile watermarks. Disabled
         # (no --ledger/--telemetry_console) it's a no-op fast path.
         self.telemetry = build_telemetry(args)
+        # a loader built before this model and not handed its
+        # telemetry finds it there, while this is the one live model
+        set_current(self.telemetry)
         # probe bookkeeping: _probe_host holds materialised client-
         # pass values until the server pass completes the round's dict
         # (sync path); _probe_log holds DEVICE scalars for pipelined
@@ -638,7 +644,6 @@ class FedModel:
     # --- rounds ----------------------------------------------------------
 
     def _call_train(self, batch):
-        args = self.args
         tel = self.telemetry
         ridx = self.round_index
         tel.begin_round(ridx)
@@ -646,6 +651,14 @@ class FedModel:
         # (closed by the next round's begin): a flag check when no
         # profiler trace window is open
         trace.begin_round_marker(ridx)
+        # the parent of every client-pass span: what its children do
+        # not cover is its self time
+        with tel.span("client_pass"):
+            return self._client_pass(batch, ridx)
+
+    def _client_pass(self, batch, ridx):
+        args = self.args
+        tel = self.telemetry
         eng = self.alarm_engine
         step_t0 = (clock.tick()
                    if eng is not None and eng.step_time_ratio > 0
@@ -666,7 +679,7 @@ class FedModel:
         ids_np = np.asarray(batch["client_ids"])
         dev_batch = {k: v for k, v in batch.items()
                      if k != "client_ids"}
-        with tel.span("h2d"), trace.phase("h2d"):
+        with tel.span("h2d"):
             dev_batch = shard_batch(self.mesh, jax.tree_util.tree_map(
                 jnp.asarray, dev_batch))
             ids = jax.device_put(jnp.asarray(ids_np, jnp.int32),
@@ -718,7 +731,7 @@ class FedModel:
                 rargs)
         cmark = (compile_mark() if flavor not in var.compiled
                  else None)
-        with tel.span("round_dispatch"), trace.phase("round_dispatch"):
+        with tel.span("round_dispatch"):
             res = round_fn(*rargs)
         if cmark is not None:
             # ledger compile events carry the variant cache key — jit
@@ -785,7 +798,7 @@ class FedModel:
             if res.probes is not None:
                 self._probe_log.setdefault(ridx, {}).update(res.probes)
             return None
-        with tel.span("metrics_host"), trace.phase("metrics_host"):
+        with tel.span("metrics_host"):
             metrics = [_host(m) for m in res.metrics]
             probe_vals = (None if res.probes is None else
                           {k: float(_host(v))
@@ -828,8 +841,9 @@ class FedModel:
                 len(ids_np), -1).sum(axis=1) > 0
             acct_ids = ids_np[alive]
             acct_mask = np.asarray(acct_mask)[alive]
-        down, up = self._account_bytes(acct_ids, acct_mask,
-                                       cfg=var.cfg)
+        with tel.span("account"):
+            down, up = self._account_bytes(acct_ids, acct_mask,
+                                           cfg=var.cfg)
         tel.set_round_bytes(ridx, float(down.sum()), float(up.sum()))
         return metrics + [down, up]
 
@@ -1225,6 +1239,7 @@ class FedOptimizer:
     schedulers port unchanged; per-group LRs become a concatenated LR
     vector (fed_aggregator.py:413-429) via each group's ``size``."""
 
+    @setup_span("model_build")
     def __init__(self, param_groups=None, args: Config = None,
                  model: Optional[FedModel] = None):
         self.model = model or _CURRENT_MODEL
@@ -1292,6 +1307,14 @@ class FedOptimizer:
         return jnp.asarray(np.concatenate(lr_vec))
 
     def step(self):
+        # the parent of every server-pass span; round ridx's ledger
+        # record is still current (the next _call_train's begin_round
+        # closes it), so the spans land on the round whose aggregate
+        # the step consumes
+        with self.model.telemetry.span("server_pass"):
+            self._server_pass()
+
+    def _server_pass(self):
         m = self.model
         assert m.pending_aggregated is not None, \
             "call model(batch) before opt.step()"
@@ -1337,10 +1360,7 @@ class FedOptimizer:
             server_fn = svar.server_fn
         sfirst = svar is not None and "server" not in svar.compiled
         cmark = compile_mark() if sfirst else None
-        # round ridx's ledger record is still current (the next
-        # _call_train's begin_round closes it), so the server span
-        # lands on the round whose aggregate it consumes
-        with m.telemetry.span("server"), trace.phase("server"):
+        with m.telemetry.span("server"):
             out = server_fn(
                 m.ps_weights, self.server_state,
                 m.pending_aggregated,
@@ -1371,25 +1391,26 @@ class FedOptimizer:
             # under the delta-encode host work below
             m._prefetch_after_writeback = False
             m._submit_prefetch()
-        if support is None:
-            # dense-update modes. fedavg/momentum updates touch every
-            # coordinate; the exceptions that don't: a zero scalar LR
-            # (nothing moved) and local_topk (even with virtual
-            # momentum the update's support is only the union of past
-            # top-k selections, ~W*k coords early on — the reference
-            # value-compares weight_update != 0, so marking all
-            # grad_size coords would overcount download bytes)
-            lr_np = np.asarray(lr)
-            if (self.args.mode != "fedavg" and lr_np.ndim == 0
-                    and float(lr_np) == 0):
-                support = (np.zeros(0, np.int64), np.zeros(0))
-            elif self.args.mode in ("local_topk", "fedavg") \
-                    or lr_np.ndim > 0:
-                # != 0 compare, packed ON DEVICE: shipping the dense
-                # f32 update to the host costs 4*d bytes of D2H per
-                # round — the bitmap is 1/32 of that
-                support = {"bitmap": jnp.packbits(update != 0)}
-        m.note_update(support)
+        with m.telemetry.span("note_update"):
+            if support is None:
+                # dense-update modes. fedavg/momentum updates touch every
+                # coordinate; the exceptions that don't: a zero scalar LR
+                # (nothing moved) and local_topk (even with virtual
+                # momentum the update's support is only the union of past
+                # top-k selections, ~W*k coords early on — the reference
+                # value-compares weight_update != 0, so marking all
+                # grad_size coords would overcount download bytes)
+                lr_np = np.asarray(lr)
+                if (self.args.mode != "fedavg" and lr_np.ndim == 0
+                        and float(lr_np) == 0):
+                    support = (np.zeros(0, np.int64), np.zeros(0))
+                elif self.args.mode in ("local_topk", "fedavg") \
+                        or lr_np.ndim > 0:
+                    # != 0 compare, packed ON DEVICE: shipping the dense
+                    # f32 update to the host costs 4*d bytes of D2H per
+                    # round — the bitmap is 1/32 of that
+                    support = {"bitmap": jnp.packbits(update != 0)}
+            m.note_update(support)
         if sprobes is not None:
             # the round this server pass belongs to (round_index was
             # already advanced by _call_train)

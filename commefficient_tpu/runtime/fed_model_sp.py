@@ -43,7 +43,7 @@ from commefficient_tpu.core.rounds_sp import (build_sp_gpt2_round,
                                               shift_lm_labels)
 from commefficient_tpu.parallel.mesh import on_every_device
 from commefficient_tpu.runtime.fed_model import FedModel
-from commefficient_tpu.telemetry import clock, trace
+from commefficient_tpu.telemetry import clock
 
 
 class SeqParallelFedModel(FedModel):
@@ -120,11 +120,8 @@ class SeqParallelFedModel(FedModel):
             make_round(True)
             if probes_on and sketch is not None else None)
 
-    def _call_train(self, batch):
+    def _client_pass(self, batch, ridx):
         tel = self.telemetry
-        ridx = self.round_index
-        tel.begin_round(ridx)
-        trace.begin_round_marker(ridx)
         eng = self.alarm_engine
         step_t0 = (clock.tick()
                    if eng is not None and eng.step_time_ratio > 0
@@ -135,7 +132,7 @@ class SeqParallelFedModel(FedModel):
             raise ValueError(
                 f"num_workers {W} must be divisible by the client "
                 f"axis {self._sp_mesh.shape['clients']}")
-        with tel.span("h2d"), trace.phase("h2d"):
+        with tel.span("h2d"):
             sp_batch = {
                 "input_ids": jnp.asarray(batch["input_ids"]),
                 "token_type_ids": jnp.asarray(batch["token_type_ids"]),
@@ -153,7 +150,7 @@ class SeqParallelFedModel(FedModel):
                 and getattr(self.args, "do_profile", False)):
             self._emit_cost_model(round_fn,
                                   (self.ps_weights, sp_batch))
-        with tel.span("round_dispatch"), trace.phase("round_dispatch"):
+        with tel.span("round_dispatch"):
             agg, per_client_loss, probes = round_fn(self.ps_weights,
                                                     sp_batch)
         self.pending_aggregated = agg
@@ -165,7 +162,7 @@ class SeqParallelFedModel(FedModel):
         # device_get: the (W,) vector is client-axis sharded and not
         # fully addressable on a multi-process mesh
         from commefficient_tpu.runtime.fed_model import _host
-        with tel.span("metrics_host"), trace.phase("metrics_host"):
+        with tel.span("metrics_host"):
             metrics = [np.asarray(_host(per_client_loss), np.float64)]
             probe_vals = (None if probes is None else
                           {k: float(_host(v))
@@ -175,6 +172,7 @@ class SeqParallelFedModel(FedModel):
             self._probe_host[ridx] = probe_vals
         if step_t0 is not None:
             eng.check_step_time(ridx, clock.tick() - step_t0)
-        down, up = self._account_bytes(ids_np, batch["mask"])
+        with tel.span("account"):
+            down, up = self._account_bytes(ids_np, batch["mask"])
         tel.set_round_bytes(ridx, float(down.sum()), float(up.sum()))
         return metrics + [down, up]

@@ -17,9 +17,12 @@ from commefficient_tpu.telemetry.causal import (CausalTracer,
                                                 assemble_traces,
                                                 build_causal_tracer)
 from commefficient_tpu.telemetry.core import (NULL_TELEMETRY, Telemetry,
-                                              build_telemetry,
+                                              build_telemetry, current,
                                               hbm_peak_bytes,
-                                              host_rss_peak_bytes)
+                                              hbm_reserved_peak_bytes,
+                                              host_rss_peak_bytes,
+                                              set_current, setup_span,
+                                              setup_spans)
 from commefficient_tpu.telemetry.critpath import (critical_path,
                                                   critpath_diff,
                                                   median_buckets)
@@ -51,8 +54,13 @@ __all__ = [
     "NULL_TELEMETRY",
     "Telemetry",
     "build_telemetry",
+    "current",
+    "set_current",
+    "setup_span",
+    "setup_spans",
     "host_rss_peak_bytes",
     "hbm_peak_bytes",
+    "hbm_reserved_peak_bytes",
     "LEDGER_SCHEMA_VERSION",
     "make_bench_record",
     "make_meta_record",

@@ -15,6 +15,27 @@ One ``Telemetry`` instance observes one run.  The hot-path contract:
   bytes (``set_round_bytes`` — deferred under ``--pipeline_depth``
   until the trainer drains).  ``close()`` flushes whatever remains.
 
+The span model (one for the whole program): besides the accumulated
+seconds in ``rec["spans"][name]``, every span is one entry
+``[name, t0, t1, parent, thread]`` of the record's ``timeline``
+(``clock.tick`` seconds; ``parent`` = index, in the same timeline, of
+the span that was open on the same thread when this one opened, else
+None; ``thread`` = the thread's name). Entries are appended when the
+span opens, so a thread's entries are in start order and a child can
+name its parent; ``t1`` is filled in when it closes. A span's self
+time is its duration minus what its children cover. While a profiler
+trace window is open the outermost span of the round loop's thread
+also is a ``fed_phase::<name>`` annotation (telemetry/trace.py), and
+the window's ``fed_clock`` annotations put every timeline entry on
+the device trace's clock.
+
+Code that is built before the run's ``Telemetry`` (the loaders) is
+handed it afterwards (``loader.telemetry = model.telemetry``, as the
+trainers do) or, failing that, finds it through ``current()``, which
+answers only while exactly one ``FedModel``'s is live; set-up, which
+precedes every round, is timed by ``setup_span`` into a process-level
+list.
+
 Round lifecycle (mirrors runtime/fed_model.py):
 
     begin_round(r)        # top of FedModel._call_train
@@ -31,56 +52,63 @@ compile count/seconds observed while it was current.
 
 from __future__ import annotations
 
+import contextlib
+import threading
+import weakref
 from collections import OrderedDict
 
-from commefficient_tpu.telemetry import clock
-from commefficient_tpu.telemetry.record import (make_meta_record,
+from commefficient_tpu.telemetry import clock, trace
+from commefficient_tpu.telemetry.record import (TIMELINE_CAP,
+                                                make_meta_record,
                                                 make_round_record)
 
-
-class _NullSpan:
-    """Shared, allocation-free no-op context manager."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
+#: the shared, allocation-free no-op context manager
+NULL_SPAN = trace.NULL_PHASE
 
 
 class _Span:
-    __slots__ = ("_spans", "_name", "_t0", "_causal")
+    __slots__ = ("_tel", "_rec", "_name", "_entry", "_causal", "_ann")
 
-    def __init__(self, spans, name, causal=None):
-        self._spans = spans
+    def __init__(self, tel, rec, name):
+        self._tel = tel
+        self._rec = rec
         self._name = name
-        self._causal = causal
+        self._causal = tel.causal
 
     def __enter__(self):
-        self._t0 = clock.tick()
+        tel, rec, name = self._tel, self._rec, self._name
+        stack = tel._open_spans()
+        # parent: the span open on this thread, if it is on the same
+        # record (a span that straddles begin_round is not)
+        parent = stack[-1][1] if stack and stack[-1][0] is rec else None
+        self._ann = trace.phase(name)
+        self._ann.__enter__()
+        entry = self._entry = [name, clock.tick(), None, parent,
+                               threading.current_thread().name]
+        stack.append((rec, tel._enter_timeline(rec, entry)))
         if self._causal is not None:
             # open AFTER t0 so the causal frame nests inside the
             # accumulated span second-for-second; nesting (driver
             # spans inside async_fold) comes from the tracer's stack
-            self._causal.open(self._name)
+            self._causal.open(name)
         return self
 
     def __exit__(self, *exc):
         if self._causal is not None:
             self._causal.close_span()
-        dt = clock.tick() - self._t0
-        self._spans[self._name] = self._spans.get(self._name, 0.0) + dt
+        entry = self._entry
+        entry[2] = t1 = clock.tick()
+        self._ann.__exit__(None, None, None)
+        self._tel._open_spans().pop()
+        spans = self._rec["spans"]
+        spans[self._name] = spans.get(self._name, 0.0) + t1 - entry[1]
         return False
 
 
 # --- process-wide compile-event accounting -----------------------------
 # jax.monitoring listeners cannot be unregistered, so one module-level
 # listener accumulates and each Telemetry snapshots deltas.
-_COMPILE = {"events": 0, "secs": 0.0}
+_COMPILE = {"events": 0, "secs": 0.0, "cache_hits": 0}
 _LISTENER_STATE = {"done": False}
 
 
@@ -117,9 +145,42 @@ def _ensure_compile_listener():
                 _COMPILE["events"] += 1
                 _COMPILE["secs"] += float(secs)
 
+        def _on_event(event, **kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                _COMPILE["cache_hits"] += 1
+
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception:  # jax too old/new: compile fields stay zero
         pass
+
+
+# --- set-up: what happens before any round ------------------------------
+# Process-level, always on: the loaders and the model are built before
+# a sink exists, often before a Telemetry does. A few dozen entries at
+# most, so the list is capped and never cleared.
+SETUP_SPAN_CAP = 64
+_SETUP_SPANS = []
+
+
+@contextlib.contextmanager
+def setup_span(name: str):
+    """Time one piece of set-up (``data_build``, ``model_build``) as a
+    ``[name, t0, t1]`` entry of ``setup_spans()``, in ``clock.tick``
+    seconds. Also starts the compile accumulator, so that the first
+    round record's ``compile_*_before`` counters count from the first
+    piece of set-up on."""
+    _ensure_compile_listener()
+    t0 = clock.tick()
+    try:
+        yield
+    finally:
+        if len(_SETUP_SPANS) < SETUP_SPAN_CAP:
+            _SETUP_SPANS.append([name, t0, clock.tick()])
+
+
+def setup_spans() -> list:
+    return [list(e) for e in _SETUP_SPANS]
 
 
 def host_rss_peak_bytes():
@@ -140,17 +201,29 @@ def host_rss_peak_bytes():
     return None
 
 
-def hbm_peak_bytes():
-    """Peak accelerator bytes-in-use on local device 0, or None (CPU
-    backends don't report; any failure degrades to None)."""
+def _memory_stat(key):
     try:
         from commefficient_tpu.parallel import mesh
         stats = mesh.first_local_device().memory_stats()
         if stats:
-            return int(stats.get("peak_bytes_in_use", 0)) or None
+            return int(stats.get(key, 0)) or None
     except Exception:
         pass
     return None
+
+
+def hbm_peak_bytes():
+    """Peak accelerator bytes-in-use on local device 0, or None (CPU
+    backends don't report; any failure degrades to None)."""
+    return _memory_stat("peak_bytes_in_use")
+
+
+def hbm_reserved_peak_bytes():
+    """Peak bytes the runtime reserved on local device 0 beside the
+    allocator's own (``peak_bytes_reserved``), or None. On the TPU the
+    compiled programs' temporaries live there, so the chip's peak is
+    this plus ``hbm_peak_bytes``."""
+    return _memory_stat("peak_bytes_reserved")
 
 
 class Telemetry:
@@ -162,7 +235,8 @@ class Telemetry:
         self._closed_rounds = set()     # indices no longer current
         self._alarm_counts = {}         # rule -> fires this run
         self._current = None            # the open round record
-        self._compile_mark = (0, 0.0)
+        self._compile_mark = dict(_COMPILE)
+        self._seen_round = False
         self._shut = False
         # emission hold: a profiler trace window buffers closed
         # records until its trace is parsed, so per-round device-time
@@ -184,6 +258,10 @@ class Telemetry:
         # span DAG onto the record as the optional v7 ``causal`` key.
         # None (the default) keeps the hot path byte-identical.
         self.causal = None
+        # per-thread stack of the spans open on that thread, as
+        # (record, index in its timeline): a span's parent
+        self._open = threading.local()
+        self._timeline_lock = threading.Lock()
         if self._sinks:
             _ensure_compile_listener()
 
@@ -223,7 +301,15 @@ class Telemetry:
         rec = make_round_record(index)
         self._records[index] = rec
         self._current = rec
-        self._compile_mark = (_COMPILE["events"], _COMPILE["secs"])
+        mark = self._compile_mark = dict(_COMPILE)
+        if not self._seen_round:
+            # what compiled before this run's first round (set-up):
+            # with the per-round deltas, all the listener has counted
+            self._seen_round = True
+            c = rec["counters"]
+            c["compile_events_before"] = mark["events"]
+            c["compile_secs_before"] = round(mark["secs"], 6)
+            c["compile_cache_hits_before"] = mark["cache_hits"]
         if self.causal is not None:
             self.causal.begin_round(index)
         return rec
@@ -234,10 +320,13 @@ class Telemetry:
             return
         rec["host_rss_peak_bytes"] = host_rss_peak_bytes()
         rec["hbm_peak_bytes"] = hbm_peak_bytes()
-        ev0, s0 = self._compile_mark
-        rec["counters"]["compile_events"] = _COMPILE["events"] - ev0
-        rec["counters"]["compile_secs"] = round(
-            _COMPILE["secs"] - s0, 6)
+        rec["hbm_reserved_peak_bytes"] = hbm_reserved_peak_bytes()
+        mark = self._compile_mark
+        c = rec["counters"]
+        c["compile_events"] = _COMPILE["events"] - mark["events"]
+        c["compile_secs"] = round(_COMPILE["secs"] - mark["secs"], 6)
+        c["compile_cache_hits"] = (_COMPILE["cache_hits"]
+                                   - mark["cache_hits"])
         if self.causal is not None:
             stamp = self.causal.end_round()
             if stamp is not None:
@@ -247,10 +336,35 @@ class Telemetry:
 
     def span(self, name: str):
         """Context manager accumulating wall-time into the current
-        round record; the shared no-op outside a round / disabled."""
-        if self._current is None:
-            return NULL_SPAN
-        return _Span(self._current["spans"], name, self.causal)
+        round record and entering the span on its ``timeline``.
+        Outside a round / disabled it records nothing: the shared
+        no-op, or, inside a profiler trace window, the bare
+        ``fed_phase::<name>`` annotation (``--profile`` without a
+        ledger keeps its phases)."""
+        rec = self._current
+        if rec is None:
+            return trace.phase(name)
+        return _Span(self, rec, name)
+
+    def _open_spans(self) -> list:
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    def _enter_timeline(self, rec, entry):
+        """Append ``entry`` to ``rec``'s timeline and return its index
+        there; None (and a ``timeline_dropped`` count) past the cap.
+        Under a lock: the loaders' producer threads open spans too."""
+        with self._timeline_lock:
+            timeline = rec["timeline"]
+            if len(timeline) < TIMELINE_CAP:
+                timeline.append(entry)
+                return len(timeline) - 1
+            c = rec["counters"]
+            c["timeline_dropped"] = c.get("timeline_dropped", 0) + 1
+        return None
 
     def count(self, name: str, n: int = 1):
         if self._current is not None:
@@ -401,6 +515,38 @@ class Telemetry:
 #: module-level disabled instance — importers needing "a telemetry"
 #: without plumbing can use this; everything on it no-ops.
 NULL_TELEMETRY = Telemetry()
+
+# The Telemetry of each FedModel built and not yet closed, for code
+# built before the model and not handed its Telemetry (a loader under
+# a harness that builds it first). Weak: a model dropped unclosed
+# stops counting.
+_LIVE = []
+
+
+def _live():
+    keep = []
+    for ref in _LIVE:
+        tel = ref()
+        if tel is not None and not tel._shut:
+            keep.append(ref)
+    _LIVE[:] = keep
+    return _LIVE
+
+
+def current() -> Telemetry:
+    """The Telemetry of the process's one live ``FedModel``;
+    ``NULL_TELEMETRY`` before one is built, and while more than one is
+    live (several tenants in one process: a caller that cannot say
+    whose it is records nothing rather than on a stranger's record)."""
+    live = _LIVE if len(_LIVE) == 1 else _live()
+    tel = live[0]() if len(live) == 1 else None
+    return NULL_TELEMETRY if tel is None or tel._shut else tel
+
+
+def set_current(tel: Telemetry):
+    """Called by ``FedModel`` when it builds its Telemetry."""
+    if all(r() is not tel for r in _live()):
+        _LIVE.append(weakref.ref(tel))
 
 
 def build_telemetry(args, extra_sinks=(), process_index=None,

@@ -1,4 +1,4 @@
-"""Ledger record schema (version 2).
+"""Ledger record schema (version 8).
 
 A run ledger is a JSONL file: one self-describing record per line.
 Every record carries ``schema`` (this module's version) and ``kind``:
@@ -126,18 +126,41 @@ Schema v7 adds NO required keys — one optional round-record key
              by id across ``.p<k>``/``.job<j>`` shards;
              telemetry/critpath.py folds each DAG into per-bucket
              critical-path seconds.
+
+Schema v8 adds NO required keys — two optional round-record keys (the
+one span model, telemetry/core.py):
+
+``timeline`` — the round's host spans, one ``[name, t0, t1, parent,
+             thread]`` entry each, in the order they opened:
+             ``clock.tick`` seconds (``t1`` None for a span still open
+             when the record was written), ``parent`` the index in
+             this list of the span open on the same thread when this
+             one opened (None at the top), ``thread`` the thread's
+             name. At most ``TIMELINE_CAP`` entries a round; what did
+             not fit is counted in ``counters.timeline_dropped`` (the
+             spans' seconds still accumulate in ``spans``).
+             ``trace.host_timeline`` moves the entries onto a profiler
+             trace's clock by its ``fed_clock`` annotations.
+``hbm_reserved_peak_bytes`` — ``peak_bytes_reserved`` of device 0
+             beside ``hbm_peak_bytes`` (``peak_bytes_in_use``): on the
+             TPU the round programs' temporaries are reserved, not
+             allocated, so the chip's peak is the sum. None
+             off-accelerator.
 """
 
 from __future__ import annotations
 
 from commefficient_tpu.telemetry import clock
 
-LEDGER_SCHEMA_VERSION = 7
+LEDGER_SCHEMA_VERSION = 8
 
 # versions validate_record accepts: v1 (pre-probe), v2 (pre-trace),
-# v3 (pre-fleet), v4 (pre-DP), v5 (pre-SLO) and v6 (pre-causal)
-# ledgers stay readable by the report tooling
-READABLE_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7)
+# v3 (pre-fleet), v4 (pre-DP), v5 (pre-SLO), v6 (pre-causal) and v7
+# (pre-timeline) ledgers stay readable by the report tooling
+READABLE_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8)
+
+# most timeline entries one round record keeps
+TIMELINE_CAP = 256
 
 # device_time keys whose values are nested dicts (v4); every other
 # bucket value must be numeric
@@ -212,6 +235,8 @@ def make_round_record(round_index: int) -> dict:
         "dp_delta": None,
         "dp_sigma": None,
         "slo": None,
+        "timeline": [],
+        "hbm_reserved_peak_bytes": None,
     })
     return rec
 
@@ -271,6 +296,31 @@ def _validate_causal(causal) -> list:
     return problems
 
 
+def _validate_timeline(timeline) -> list:
+    """Problems with an optional v8 ``timeline`` (validated only when
+    present)."""
+    if not isinstance(timeline, list):
+        return ["timeline is not a list"]
+    if len(timeline) > TIMELINE_CAP:
+        return [f"timeline holds more than {TIMELINE_CAP} entries"]
+    problems = []
+    for i, entry in enumerate(timeline):
+        if not isinstance(entry, (list, tuple)) or len(entry) != 5:
+            problems.append("timeline entry is not [name, t0, t1, "
+                            "parent, thread]")
+            continue
+        name, t0, t1, parent, thread = entry
+        if not isinstance(name, str) or not isinstance(thread, str):
+            problems.append("timeline name/thread is not a string")
+        if not isinstance(t0, (int, float)) or not (
+                t1 is None or isinstance(t1, (int, float))):
+            problems.append("timeline t0/t1 is non-numeric")
+        if parent is not None and not (isinstance(parent, int)
+                                       and 0 <= parent < i):
+            problems.append("timeline parent is not an earlier index")
+    return problems
+
+
 def validate_record(rec) -> list:
     """Schema check: a list of problem strings, empty when valid."""
     problems = []
@@ -314,6 +364,11 @@ def validate_record(rec) -> list:
             problems.append("slo is not a dict")
         if "causal" in rec:                # optional (v7): validate
             problems.extend(_validate_causal(rec["causal"]))
+        if "timeline" in rec:              # optional (v8): validate
+            problems.extend(_validate_timeline(rec["timeline"]))
+        v = rec.get("hbm_reserved_peak_bytes")    # optional (v8)
+        if v is not None and not isinstance(v, (int, float)):
+            problems.append("hbm_reserved_peak_bytes is non-numeric")
         dt = rec.get("device_time")
         if dt is not None:
             if not isinstance(dt, dict):

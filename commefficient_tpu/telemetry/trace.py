@@ -5,15 +5,26 @@ the gap to the device timeline. Two halves:
 
 **Markers** — while a ``trace_window`` is open, ``FedModel`` brackets
 each round in a ``jax.profiler.StepTraceAnnotation`` (name
-``fed_round``, ``step_num`` = the ledger round index) and the
-device-relevant phases (h2d / round_dispatch / server) in
-``TraceAnnotation``s. The round annotation is opened at
-``begin_round`` and closed at the NEXT round's begin — mirroring the
-ledger record lifecycle, so the server step (dispatched after
+``fed_round``, ``step_num`` = the ledger round index), and every
+``Telemetry.span(name)`` (core.py) that is the outermost one open on
+the thread that opened the window (the round loop's: ``sampler``,
+``client_pass``, ``server_pass``) opens a ``fed_phase::<name>``
+``TraceAnnotation`` through ``phase`` below, so no instant of the
+trace lies under two phases; the spans nested in those, and other
+threads', are placed by the clock below. The round annotation is
+opened at ``begin_round`` and closed at the NEXT round's begin —
+mirroring the ledger record lifecycle, so the server step (dispatched after
 ``_call_train`` returns) lands inside its own round's window. State is
 module-level (one live FedModel per process, like
 ``fed_model._CURRENT_MODEL``); every call is a single flag check when
 no trace is active, so the round hot loop pays nothing.
+
+**One clock** — ``set_tracing`` writes a zero-length annotation
+``fed_clock::<clock.tick() in ns>`` when a window opens and when it
+closes. ``annotation ts - tick`` is the offset from the host spans'
+clock to the trace's, so ``host_timeline`` places every ``timeline``
+entry of the round records (also those of a thread or a round that
+carries no annotation) on the device timeline.
 
 **Parser** — ``jax.profiler.stop_trace`` writes a Chrome trace-event
 dump (``plugins/profile/<ts>/<host>.trace.json.gz``): ``ph:"X"``
@@ -49,9 +60,18 @@ import gzip
 import json
 import os
 import re
+import threading
+
+from commefficient_tpu.telemetry import clock
 
 ROUND_MARKER = "fed_round"
 PHASE_PREFIX = "fed_phase"
+CLOCK_PREFIX = "fed_clock"
+
+#: lines of a device process whose events are not operations: the
+#: TPU's ``Steps`` line repeats the step annotation (events named
+#: ``fed_round``) over each step's device activity
+NOT_OPERATIONS = ("Steps",)
 
 #: substrings (lowercase) classifying a device-lane event
 COLLECTIVE_TOKENS = (
@@ -67,7 +87,10 @@ TRANSFER_TOKENS = (
 # one live FedModel per process (fed_model._CURRENT_MODEL) -> one
 # module-level marker state; "ann" is the currently-open round
 # StepTraceAnnotation, closed at the next begin or at window exit
-_STATE = {"tracing": False, "ann": None, "round": None}
+_STATE = {"tracing": False, "ann": None, "round": None,
+          # the thread that opened the window, and how many of its
+          # spans are open as ``_Phase``
+          "thread": None, "depth": 0}
 
 
 def tracing() -> bool:
@@ -75,12 +98,23 @@ def tracing() -> bool:
 
 
 def set_tracing(on: bool):
-    """Flipped by ``profiler.trace_window`` enter/exit. Turning
-    tracing off force-closes any open round marker first, so its end
-    timestamp lands inside the trace."""
+    """Flipped by ``profiler.trace_window`` enter/exit, between
+    ``start_trace`` and ``stop_trace``. Turning tracing off
+    force-closes any open round marker first, so its end timestamp
+    lands inside the trace. Either way one ``fed_clock`` annotation
+    ties ``clock.tick`` to the trace's clock."""
     if not on:
         end_round_marker()
     _STATE["tracing"] = bool(on)
+    _STATE["thread"] = threading.get_ident() if on else None
+    _clock_mark()
+
+
+def _clock_mark():
+    import jax
+    name = f"{CLOCK_PREFIX}::{int(clock.tick() * 1e9)}"
+    with jax.profiler.TraceAnnotation(name):
+        pass
 
 
 def begin_round_marker(round_index: int):
@@ -105,6 +139,7 @@ def end_round_marker():
 
 
 class _NullPhase:
+    """Shared, allocation-free no-op context manager."""
     __slots__ = ()
 
     def __enter__(self):
@@ -114,17 +149,46 @@ class _NullPhase:
         return False
 
 
-_NULL_PHASE = _NullPhase()
+#: the one no-op span of the package (``core.NULL_SPAN`` is this object)
+NULL_PHASE = _NullPhase()
+
+
+class _Phase:
+    """``fed_phase::<name>`` for the outermost span open on the round
+    loop's thread; a span opened inside another adds no annotation.
+    One level, one thread: whoever credits a stretch of the device's
+    time to the phase over it (the benchmark's ``idle_gaps``) counts
+    it once, and every other span is in the records' ``timeline``."""
+    __slots__ = ("_name", "_ann")
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        _STATE["depth"] += 1
+        self._ann = None
+        if _STATE["depth"] == 1:
+            import jax
+            self._ann = jax.profiler.TraceAnnotation(
+                f"{PHASE_PREFIX}::{self._name}")
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        _STATE["depth"] -= 1
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 def phase(name: str):
-    """Context manager: a ``TraceAnnotation`` named
-    ``fed_phase::<name>`` when tracing, the shared no-op otherwise.
-    Used alongside (not instead of) the telemetry host spans."""
-    if not _STATE["tracing"]:
-        return _NULL_PHASE
-    import jax
-    return jax.profiler.TraceAnnotation(f"{PHASE_PREFIX}::{name}")
+    """Context manager: the ``fed_phase::<name>`` annotation (of the
+    outermost span open) when tracing and on the thread that opened
+    the window, the shared no-op otherwise. What ``Telemetry.span``
+    opens; nothing else calls it."""
+    if not _STATE["tracing"] or threading.get_ident() != _STATE["thread"]:
+        return NULL_PHASE
+    return _Phase(name)
 
 
 # --- trace file discovery + loading ------------------------------------
@@ -184,7 +248,9 @@ def lane_devices(events):
 
     TPU/GPU xplanes expose one ``/device:<KIND>:<N>`` process per
     device — every thread under it belongs to that device, so the id
-    is the process-name suffix (``TPU:0``). The CPU backend runs each
+    is the process-name suffix (``TPU:0``), except the ``Steps`` line
+    (``NOT_OPERATIONS``): counted as operations its step-long events
+    make a device look busy all the time. The CPU backend runs each
     virtual device on a ``tf_XLA*`` runtime thread; each such thread
     is its own lane, labelled ``cpu:<n>`` by the trailing integer of
     the thread name (stable across a run, unlike raw tids)."""
@@ -199,7 +265,8 @@ def lane_devices(events):
         pname = procs.get(key[0], "")
         tname = threads.get(key, "")
         if pname.startswith("/device:"):
-            out[key] = pname[len("/device:"):]
+            if tname not in NOT_OPERATIONS:
+                out[key] = pname[len("/device:"):]
         elif tname.startswith("tf_XLA"):
             m = re.search(r"(\d+)$", tname)
             out[key] = "cpu:%s" % (m.group(1) if m else key[1])
@@ -286,10 +353,15 @@ def round_windows(events):
     """[(round_index, ts_us, end_us), ...] from the ``fed_round``
     StepTraceAnnotations, in timeline order. Each window is the
     annotation's own extent (begin_round -> next begin_round /
-    trace-window exit)."""
+    trace-window exit), taken from host threads only: a TPU device
+    process repeats the marker on its ``Steps`` line with the
+    device's busy extent in place of the round's."""
+    procs, _ = _lane_names(events)
     wins = []
     for e in events:
         if e.get("ph") != "X" or e.get("name") != ROUND_MARKER:
+            continue
+        if procs.get(e.get("pid"), "").startswith("/device:"):
             continue
         args = e.get("args") or {}
         step = args.get("step_num", args.get("round"))
@@ -522,3 +594,41 @@ def attribute_logdir(logdir: str) -> dict:
     if path is None:
         return {}
     return attribute_rounds(load_trace_events(path))
+
+
+# --- host spans on the trace's clock -----------------------------------
+
+
+def clock_offset_us(events):
+    """Microseconds to add to ``clock.tick() * 1e6`` to get a trace
+    ``ts``, from the ``fed_clock::<tick ns>`` annotations (the mean of
+    those the trace holds); None when it holds none."""
+    offs = []
+    for e in events:
+        name = e.get("name", "")
+        if e.get("ph") == "X" and name.startswith(CLOCK_PREFIX + "::"):
+            tick_ns = int(name[len(CLOCK_PREFIX) + 2:])
+            offs.append(float(e["ts"]) - tick_ns / 1e3)
+    return sum(offs) / len(offs) if offs else None
+
+
+def host_timeline(events, records):
+    """The round records' ``timeline`` entries in trace time:
+    ``[{"round", "name", "ts", "end", "parent", "thread"}, ...]``
+    with ``ts``/``end`` in the trace's microseconds, sorted by start;
+    ``parent`` indexes the same round's timeline. Entries still open
+    when their record was written (no end) are left out. Empty when
+    the trace carries no ``fed_clock`` annotation."""
+    off = clock_offset_us(events)
+    if off is None:
+        return []
+    out = []
+    for rec in records:
+        for name, t0, t1, parent, thread in rec.get("timeline") or ():
+            if t1 is None:
+                continue
+            out.append({"round": rec.get("round"), "name": name,
+                        "ts": t0 * 1e6 + off, "end": t1 * 1e6 + off,
+                        "parent": parent, "thread": thread})
+    out.sort(key=lambda s: s["ts"])
+    return out

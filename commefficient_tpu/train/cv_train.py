@@ -29,7 +29,7 @@ from commefficient_tpu.data import transforms as T
 from commefficient_tpu.models import get_model
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
                                        TrainRun, drain_rounds)
-from commefficient_tpu.telemetry import clock
+from commefficient_tpu.telemetry import clock, setup_span
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
                                      TSVLogger, Timer, steps_per_epoch)
@@ -362,6 +362,7 @@ def train(model, opt, lr_scheduler, train_loader, val_loader, args,
     return results
 
 
+@setup_span("data_build")
 def get_data_loaders(args: Config):
     """(reference cv_train.py:254-287)"""
     name = args.dataset_name
@@ -540,6 +541,8 @@ def run(argv=None) -> TrainRun:
                      compute_loss_val=loss_val,
                      padded_batch_size=train_loader.B,
                      stats_fn=stats_fn, init_model_state=init_stats)
+    # the loader's spans go onto this model's round records
+    train_loader.telemetry = model.telemetry
     if hasattr(train_loader, "peek_next_client_ids"):
         # host client store: the loader's one-round lookahead feeds
         # the prefetch thread (no-op under --clientstore device)

@@ -33,6 +33,7 @@ from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            token_nll)
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
                                        TrainRun, drain_rounds)
+from commefficient_tpu.telemetry import setup_span
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
                                      Timer, steps_per_epoch)
@@ -351,6 +352,7 @@ def build_model_and_tokenizer(args: Config):
     return module, params, tokenizer
 
 
+@setup_span("data_build")
 def get_data_loaders(args: Config, tokenizer):
     """(reference gpt2_train.py:315-355)"""
     if args.do_test and not os.path.exists(
@@ -440,6 +442,8 @@ def run(argv=None) -> TrainRun:
                          compute_loss_val=make_compute_loss_val(module,
                                                                 args),
                          padded_batch_size=train_loader.B)
+    # the loader's spans go onto this model's round records
+    train_loader.telemetry = model.telemetry
     if hasattr(model, "attach_participant_feed") \
             and hasattr(train_loader, "peek_next_client_ids"):
         # host client store: one-round lookahead feeds the prefetcher

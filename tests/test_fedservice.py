@@ -29,8 +29,9 @@ W, B, DIM = 8, 2, 256
 
 #: wall-clock / host-load fields that legitimately differ between a
 #: solo run and a daemon-interleaved one; everything else must match
-NONDET_KEYS = ("ts", "spans", "counters", "device_time",
-               "host_rss_peak_bytes", "hbm_peak_bytes")
+NONDET_KEYS = ("ts", "spans", "counters", "timeline", "device_time",
+               "host_rss_peak_bytes", "hbm_peak_bytes",
+               "hbm_reserved_peak_bytes")
 
 
 def _loss(params, batch, cfg):

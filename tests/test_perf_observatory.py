@@ -669,7 +669,7 @@ class TestProfileIntegration:
         assert all(not validate_record(r) for r in recs)
         rounds = [r for r in recs if r["kind"] == "round"]
         assert len(rounds) == 5
-        assert all(r["schema"] == 7 for r in rounds)
+        assert all(r["schema"] == 8 for r in rounds)
 
         traced = [r for r in rounds if r.get("device_time")]
         assert [r["round"] for r in traced] == [1, 2, 3, 4]

@@ -165,9 +165,9 @@ def test_schema_v4_device_time_round_trip(tmp_path):
     from commefficient_tpu.telemetry.record import (
         READABLE_SCHEMA_VERSIONS, make_round_record)
 
-    assert READABLE_SCHEMA_VERSIONS == (1, 2, 3, 4, 5, 6, 7)
+    assert READABLE_SCHEMA_VERSIONS == (1, 2, 3, 4, 5, 6, 7, 8)
     rec = make_round_record(0)
-    assert rec["schema"] == 7 and rec["device_time"] is None
+    assert rec["schema"] == 8 and rec["device_time"] is None
     assert rec["slo"] is None  # v6: the SLO stamp, None unless armed
     assert "causal" not in rec  # v7: OPTIONAL — absent unless traced
     assert validate_record(rec) == []
