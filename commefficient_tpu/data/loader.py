@@ -15,12 +15,15 @@ incomplete-round skipping, epoch length); subclasses provide only
 The train loaders open up the trainer's ``sampler`` span (the wait in
 ``next(loader)``): spans ``data.sample`` (advancing the sampler),
 ``data.index``, ``data.submit``, ``data.pop_alloc``, ``data.pop_wait``
-(the native ring: only the C++ plane is behind it), ``data.ring_open``
-/ ``data.ring_close`` (the ring made and torn down at every epoch's
-ends, native/__init__.py), ``data.collate``, and the counter
+(the native ring: only the C++ plane and its copy into a recycled
+buffer are behind it), ``data.ring_open`` / ``data.ring_close`` (once a
+loader: the ring is made at the first ``__iter__`` and kept until
+``close()``, native/__init__.py), ``data.collate``, and the counters
 ``data.epoch_start`` (1 on the ``next()`` that entered a fresh
-``__iter__``). A loader is built before the run's ``Telemetry``: who
-builds both hands it over (``loader.telemetry = model.telemetry``);
+``__iter__``) and ``data.buffer_reused`` / ``data.buffer_fresh`` (per
+pop of the native ring: whether the round landed in memory the process
+had touched before). A loader is built before the run's ``Telemetry``:
+who builds both hands it over (``loader.telemetry = model.telemetry``);
 left unset, each epoch looks it up once, on the consumer's thread, in
 ``telemetry.current()``.
 """
@@ -133,6 +136,11 @@ class _RoundLoaderBase:
     def collate(self, round_spec) -> dict:
         raise NotImplementedError
 
+    def close(self):
+        """Release what the loader keeps between epochs (the native
+        loader's ring and threads; nothing here). Idempotent; a closed
+        loader can be iterated again."""
+
     def __len__(self):
         from commefficient_tpu.utils import steps_per_epoch
         return steps_per_epoch(self.sampler.local_batch_size,
@@ -179,10 +187,26 @@ class NativeFedLoader(_RoundLoaderBase):
     output matches FedLoader bit-for-bit — tested in
     tests/test_native_dataplane.py.
 
+    One ring for the loader's life: the ``native.Prefetcher`` (its
+    ``depth`` rounds of output and its worker threads) is made at the
+    first ``__iter__`` and reused by every later one, and popped rounds
+    land in its recycled buffers, so the round loop faults in no fresh
+    memory. An epoch still drains at its end, with no read-ahead across
+    the boundary: the sampler's RNG stream and its epoch-boundary
+    checkpoint contract (data/fed_sampler.py) are as with a ring an
+    epoch, and so is every batch, bit for bit. An epoch abandoned
+    mid-way (the generator closed or collected) empties the ring; a
+    new ``__iter__`` retires an earlier unfinished one, whose next
+    ``next()`` raises. ``close()`` destroys the ring and joins its
+    threads.
+
     Raises RuntimeError when the toolchain/transform/dataset don't
     support the native path — use :func:`make_fed_loader` for the
     auto-fallback.
     """
+
+    _ring = None    # the loader's native.Prefetcher, once made
+    _epoch = None   # the unfinished __iter__ that owns the ring
 
     def __init__(self, dataset, sampler,
                  max_batch_size: Optional[int] = None,
@@ -225,13 +249,27 @@ class NativeFedLoader(_RoundLoaderBase):
             idx[i, : len(rows)] = rows
         return ids, idx
 
-    def __iter__(self):
+    def _open_ring(self, tel):
+        """The loader's ring, made on first use; one that an earlier
+        unfinished ``__iter__`` still owns is taken from it, emptied."""
         from commefficient_tpu import native
 
+        if self._ring is None:
+            self._ring = native.Prefetcher(
+                self.plane, self.depth, self.n_threads, telemetry=tel)
+        else:
+            self._ring.telemetry = tel
+            if self._epoch is not None:
+                self._ring.reset()
+        return self._ring
+
+    def __iter__(self):
         tel = self._epoch_telemetry()
-        with native.Prefetcher(self.plane, self.depth, self.n_threads,
-                               telemetry=tel) as pf:
-            pending: list = []
+        pf = self._open_ring(tel)
+        mine = self._epoch = object()
+        pending: list = []
+        drained = False
+        try:
             for round_spec in self._round_specs(tel):
                 with tel.span("data.index"):
                     ids, idx = self._spec_to_indices(round_spec)
@@ -240,14 +278,43 @@ class NativeFedLoader(_RoundLoaderBase):
                 pending.append(ids)
                 if len(pending) > self.depth:
                     yield self._pop(pf, pending)
+                    self._check_owner(mine)
             while pending:
                 yield self._pop(pf, pending)
+                self._check_owner(mine)
+            drained = True
+        finally:
+            if self._epoch is mine:
+                self._epoch = None
+                if not drained:     # abandoned, or a pop raised
+                    pf.reset()
+
+    def _check_owner(self, mine):
+        """Where an epoch resumes, before it touches sampler or ring."""
+        if self._epoch is not mine:
+            raise RuntimeError(
+                "this epoch was retired: a later __iter__ (or close()) "
+                "took the loader's ring")
 
     def _pop(self, pf, pending):
         ids = pending.pop(0)
         x, y, m = pf.pop()
         return self._apply_dropout(
             {"client_ids": ids, "x": x, "y": y, "mask": m})
+
+    def close(self):
+        """Destroy the ring and join its threads. Idempotent; a later
+        ``__iter__`` makes a new ring."""
+        self._epoch = None
+        ring, self._ring = self._ring, None
+        if ring is not None:
+            ring.close()
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:   # best-effort: interpreter shutdown
+            pass
 
 
 def make_fed_loader(dataset, sampler, max_batch_size=None, seed=0,

@@ -7,6 +7,25 @@ when no toolchain is available: callers must check :func:`available`.
 
 Counterpart of the reference's native data plumbing (multiprocessing
 queues + torchvision C++ transform kernels, SURVEY.md §2.9).
+
+A :class:`Prefetcher` allocates its round memory once. The ring and its
+worker threads live until ``close()``; an epoch that ends or is
+abandoned leaves the ring empty (``reset()``), not destroyed: making a
+ring first-touches ``depth`` rounds of output, and on the benchmark's
+host fresh anonymous pages cost 1.09 ms/MB on whichever thread touches
+them. For the same reason ``pop()`` copies the slot into a recycled
+buffer, not a fresh ``np.empty``. The reuse rule: a buffer goes out
+again only when nothing but the pool refers to it, read off the
+reference counts of the arrays that own the memory at pop time. Views
+and sub-views hold their owner (numpy collapses ``base`` chains onto
+it, so a finalizer on a handed-out view would fire too early), PJRT
+holds the array until an asynchronous host-to-device copy completes,
+and on the CPU backend ``jnp.asarray`` may alias it for the device
+array's whole life: each of them is a reference, and the buffer waits.
+With none free the pool allocates (and keeps) a fresh one, so it never
+blocks. The ring's own slots are not handed out without a copy: a slot
+has to be released by a call, and no call can be made safe against
+those aliases without a knob.
 """
 
 from __future__ import annotations
@@ -15,6 +34,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -82,6 +102,8 @@ def _lib():
         lib.cet_ring_pop.restype = ctypes.c_int64
         lib.cet_ring_oob.argtypes = [ctypes.c_void_p]
         lib.cet_ring_oob.restype = ctypes.c_longlong
+        lib.cet_ring_reset.argtypes = [ctypes.c_void_p]
+        lib.cet_ring_reset.restype = None
         lib.cet_ring_destroy.argtypes = [ctypes.c_void_p]
         lib.cet_ring_destroy.restype = None
         _lib_handle = lib
@@ -174,17 +196,25 @@ class NativeDataplane:
 class Prefetcher:
     """Bounded ring of pre-assembled rounds, filled by C++ worker
     threads; pops arrive strictly in submission order (deterministic
-    regardless of thread scheduling)."""
+    regardless of thread scheduling). One thread submits, pops and
+    resets. Popped rounds land in recycled buffers (module docstring):
+    hold a batch for as long as you like, it is not written again
+    while anything refers to it."""
+
+    #: free buffers kept beyond the ones the consumer still holds
+    _POOL_RESERVE = 2
 
     def __init__(self, plane: NativeDataplane, depth: int = 4,
                  n_threads: int = 2, telemetry=None):
-        """``telemetry``: the caller's span recorder (the loader's);
-        None records nothing."""
+        """``telemetry``: the caller's span recorder (the loader sets
+        the attribute again at each epoch); None records nothing."""
         if telemetry is None:
             from commefficient_tpu.telemetry import NULL_TELEMETRY
             telemetry = NULL_TELEMETRY
         self.plane = plane
-        self._tel = telemetry
+        self.telemetry = telemetry
+        self._pool: list = []       # every (x, y, m) this ring handed out
+        self._pool_only = None      # _refs() of a buffer only the pool holds
         # allocates and zero-fills ``depth`` rounds of output
         with telemetry.span("data.ring_open"):
             self._handle = plane._lib.cet_ring_create(
@@ -192,17 +222,41 @@ class Prefetcher:
         assert self._handle
 
     def submit(self, indices: np.ndarray, seed: int):
-        with self._tel.span("data.submit"):
+        with self.telemetry.span("data.submit"):
             idx = np.ascontiguousarray(indices, dtype=np.int64)
             assert idx.shape == (self.plane.slots, self.plane.B)
             self.plane._lib.cet_ring_submit(
                 self._handle, _ptr(idx, ctypes.c_int64),
                 ctypes.c_uint64(seed & (2**64 - 1)))
 
+    @staticmethod
+    def _refs(bufs) -> int:
+        return max(map(sys.getrefcount, bufs))
+
+    def _take_buffers(self):
+        """(x, y, m) to pop into: the first pooled triple that only the
+        pool refers to, else a fresh one. Free triples beyond the
+        reserve are let go, so the pool is what the consumer holds
+        plus ``_POOL_RESERVE``."""
+        held, free = [], []
+        for bufs in self._pool:
+            (free if self._refs(bufs) == self._pool_only
+             else held).append(bufs)
+        self._pool = held + free[:1 + self._POOL_RESERVE]
+        if free:
+            self.telemetry.count("data.buffer_reused")
+            return free[0]
+        bufs = self.plane._alloc_out()
+        self._pool.append(bufs)
+        if self._pool_only is None:
+            self._pool_only = self._refs(bufs)
+        self.telemetry.count("data.buffer_fresh")
+        return bufs
+
     def pop(self):
-        tel = self._tel
+        tel = self.telemetry
         with tel.span("data.pop_alloc"):
-            x, y, m = self.plane._alloc_out()
+            x, y, m = self._take_buffers()
         # the wait for the C++ plane, and nothing else
         with tel.span("data.pop_wait"):
             seq = self.plane._lib.cet_ring_pop(
@@ -215,11 +269,19 @@ class Prefetcher:
                 f"{oob} out-of-range indices submitted to the ring")
         return x, y, m
 
+    def reset(self):
+        """Empty the ring where an epoch stopped: queued specs are
+        discarded, slots being filled are waited for and freed, the
+        sequence starts again."""
+        if self._handle:
+            self.plane._lib.cet_ring_reset(self._handle)
+
     def close(self):
         if self._handle:
-            with self._tel.span("data.ring_close"):
+            with self.telemetry.span("data.ring_close"):
                 self.plane._lib.cet_ring_destroy(self._handle)
             self._handle = None
+            self._pool = []
 
     def __enter__(self):
         return self

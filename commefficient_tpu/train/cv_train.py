@@ -650,6 +650,8 @@ def run(argv=None) -> TrainRun:
                                   getattr(model, "model_state", None),
                                   tpath)
             print(f"saved torch state_dict to {tpath}")
+    # the native loader's ring and worker threads end with the run
+    train_loader.close()
     return TrainRun(results, model, opt, train_loader)
 
 

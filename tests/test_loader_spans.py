@@ -122,7 +122,8 @@ def test_fed_loader_spans_and_counters(bind):
 
 @pytest.mark.skipif(not native.available(), reason="no native toolchain")
 def test_native_loader_spans_and_counters():
-    recs, firsts = _drive(_cv_loader(NativeFedLoader, depth=DEPTH))
+    loader = _cv_loader(NativeFedLoader, depth=DEPTH)
+    recs, firsts = _drive(loader)
     assert firsts == [0, 8]
     for first in firsts:
         rec = recs[first]
@@ -135,9 +136,20 @@ def test_native_loader_spans_and_counters():
             by.setdefault(e[0], []).append(e)
         assert len(by["data.index"]) == DEPTH + 1 == len(by["data.submit"])
         assert len(by["data.pop_alloc"]) == 1 == len(by["data.pop_wait"])
-        assert len(by["data.ring_open"]) == 1       # a ring an epoch
+        # one ring a loader: made in its first record, kept after
+        assert len(by.get("data.ring_open", [])) == (first == 0)
         assert all(e[4] == "MainThread" for e in kids)
-    assert "data.ring_close" in recs[firsts[1]]["spans"]
+    assert sum(_count(rec, "data.ring_open") for rec in recs.values()) == 1
+    assert not any("data.ring_close" in rec["spans"]
+                   for rec in recs.values())
+    # every pop says where its round landed; _drive's ``batch`` holds
+    # one round while the next is popped, so two buffers take turns
+    pops = {r: (rec["counters"].get("data.buffer_fresh", 0),
+                rec["counters"].get("data.buffer_reused", 0))
+            for r, rec in recs.items()}
+    assert pops[0] == (1, 0) == pops[1] and pops[2] == (0, 1) == pops[8]
+    assert sum(f for f, _ in pops.values()) == 2
+    assert sum(u for _, u in pops.values()) == 14
     # steady state: one in, one out; the epoch's end drains the ring
     assert _count(recs[1], "data.index") == 1 == _count(recs[1],
                                                         "data.pop_wait")
@@ -150,6 +162,17 @@ def test_native_loader_spans_and_counters():
     covered = sum(e[2] - e[1] for e in kids)
     assert 0.5 * rec["spans"]["sampler"] <= covered \
         <= rec["spans"]["sampler"]
+    # the ring goes at close(), and only there
+    sink = ListSink()
+    loader.telemetry = tel = Telemetry([sink])
+    tel.begin_round(0)
+    next(iter(loader))
+    loader.close()
+    tel.set_round_bytes(0, 0.0, 0.0)
+    tel.close()
+    rec = sink.records[0]
+    assert _count(rec, "data.ring_close") == 1
+    assert _count(rec, "data.ring_open") == 0       # still the first ring
 
 
 @pytest.mark.parametrize("depth", [1, 3])

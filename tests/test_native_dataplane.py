@@ -5,6 +5,12 @@ output must be a member of the enumerable crop/flip candidate set;
 prefetch-ring pops must equal one-shot assembly in submission order.
 Skipped wholesale when no toolchain is present."""
 
+import contextlib
+import gc
+import os
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -196,7 +202,6 @@ def test_library_is_keyed_by_its_source(tmp_path, monkeypatch):
     the file name carries the source's hash (mtimes mean nothing in a
     copied tree, and _build/ is git-ignored but survives on disk)."""
     import hashlib
-    import os
 
     from commefficient_tpu import native as native_mod
     with open(native_mod._SRC, "rb") as f:
@@ -211,3 +216,333 @@ def test_library_is_keyed_by_its_source(tmp_path, monkeypatch):
     monkeypatch.setattr(native_mod, "_BUILD_DIR", str(tmp_path))
     other = native_mod._compile()
     assert other is not None and tag not in other
+
+
+# ---- one ring for the loader's life, rounds in recycled buffers -------------
+
+AUG = Compose([ToFloat(), RandomCrop(32, 2), RandomHorizontalFlip(),
+               Normalize(MEAN, STD)])
+PLAIN = Compose([ToFloat(), Normalize(MEAN, STD)])
+ROUNDS = 8          # _dataset: 64 images, 2 clients x 4 a round
+KEYS = ("client_ids", "x", "y", "mask")
+
+
+def _within(seconds, fn):
+    """``fn()`` on a thread of its own with a time limit: a ring out of
+    step would block in ``cet_ring_pop`` for ever."""
+    box = {}
+
+    def run():
+        try:
+            box["value"] = fn()
+        except BaseException as e:      # re-raised below
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still running after {seconds} s"
+    if "error" in box:
+        raise box["error"]
+    return box["value"]
+
+
+def _copy(batch):
+    return {k: np.array(batch[k]) for k in KEYS}
+
+
+def _same(got, want):
+    for k in KEYS:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+@pytest.fixture
+def rings(monkeypatch):
+    """The rings made while the test runs."""
+    made = []
+
+    class Counted(native.Prefetcher):
+        def __init__(self, *a, **kw):
+            made.append(self)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(native, "Prefetcher", Counted)
+    return made
+
+
+def _ring_per_epoch(tf, seed, takes):
+    """What the loader gave when it made a ring at every ``__iter__``:
+    a loader rebuilt per epoch over one sampler, the round counter
+    carried over; ``takes[e]`` rounds are taken of epoch ``e`` (None:
+    all of it) before it is dropped."""
+    ds = _dataset(tf)
+    sampler = _sampler(ds, seed=5)
+    counter, epochs = 0, []
+    for take in takes:
+        loader = NativeFedLoader(ds, sampler, seed=seed)
+        loader._round_counter = counter
+        it = iter(loader)
+        got = []
+        for batch in it:
+            got.append(_copy(batch))
+            if take is not None and len(got) == take:
+                break
+        it.close()
+        counter = loader._round_counter
+        loader.close()
+        epochs.append(got)
+    return epochs
+
+
+@pytest.mark.parametrize("tf", [AUG, PLAIN], ids=["aug", "plain"])
+def test_three_epochs_through_one_ring_are_bit_identical(tf, rings):
+    def body():
+        ds = _dataset(tf)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), seed=11)
+        got = [[_copy(b) for b in loader] for _ in range(3)]
+        assert len(rings) == 1
+        loader.close()
+        # (1) the one-shot assembly of the same indices and seeds
+        ds2 = _dataset(tf)
+        twin = NativeFedLoader(ds2, _sampler(ds2, seed=5), seed=11)
+        r = 0
+        for epoch in got:
+            specs = [s for s in twin.sampler if len(s) >= twin.W]
+            assert len(specs) == len(epoch) == ROUNDS
+            for spec, batch in zip(specs, epoch):
+                ids, idx = twin._spec_to_indices(spec)
+                x, y, m = twin.plane.assemble(idx, 11 + r)
+                _same(batch, {"client_ids": ids, "x": x, "y": y,
+                              "mask": m})
+                r += 1
+        # (2) a ring an epoch
+        for mine, theirs in zip(got, _ring_per_epoch(tf, 11, [None] * 3)):
+            assert len(mine) == len(theirs)
+            for a, b in zip(mine, theirs):
+                _same(a, b)
+    _within(60, body)
+
+
+def test_held_batches_are_never_written_again():
+    import jax.numpy as jnp
+
+    def body():
+        ds = _dataset(AUG)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), seed=3,
+                                 depth=2)
+        held, copies, on_device = [], [], []
+        for _ in range(2):
+            for i, batch in enumerate(loader):
+                copies.append(_copy(batch))
+                if i % 3 == 0:
+                    # only a sub-view survives: it holds the owner
+                    held.append({k: batch[k][:1] for k in KEYS})
+                elif i % 3 == 1:
+                    # may alias the numpy memory for the array's life
+                    on_device.append((len(copies) - 1,
+                                      jnp.asarray(batch["x"])))
+                    held.append(None)
+                else:
+                    held.append(batch)
+                del batch
+        assert len(copies) == 2 * ROUNDS
+        list(loader)        # a third epoch pops over whatever was freed
+        for h, c in zip(held, copies):
+            if h is not None:
+                for k in KEYS:
+                    np.testing.assert_array_equal(h[k], c[k][:len(h[k])])
+        for i, dev in on_device:
+            np.testing.assert_array_equal(np.asarray(dev), copies[i]["x"])
+        loader.close()
+    _within(60, body)
+
+
+class _Counts:
+    """A recorder that keeps the per-pop buffer counters in order."""
+
+    def __init__(self):
+        self.pops = []
+
+    def count(self, name, n=1):
+        if name.startswith("data.buffer_"):
+            self.pops.append(name)
+
+    def span(self, name):
+        return contextlib.nullcontext()
+
+
+@pytest.mark.parametrize("hold", [False, True], ids=["dropped", "held"])
+def test_buffer_counters_say_whether_a_pop_reused_memory(hold):
+    def body():
+        ds = _dataset(PLAIN)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5))
+        loader.telemetry = tel = _Counts()
+        kept = []
+        for _ in range(2):
+            for batch in loader:        # the loop variable holds one
+                if hold:
+                    kept.append(batch)
+        assert len(tel.pops) == 2 * ROUNDS
+        if hold:
+            assert set(tel.pops) == {"data.buffer_fresh"}
+            assert len(loader._ring._pool) == 2 * ROUNDS
+        else:
+            # the second pop finds the first batch still in the loop
+            # variable; from the third on nothing fresh is needed
+            assert tel.pops[0] == "data.buffer_fresh"
+            assert set(tel.pops[2:]) == {"data.buffer_reused"}
+            assert tel.pops.count("data.buffer_fresh") <= 2
+            assert len(loader._ring._pool) <= 2
+        del kept, batch
+        # once let go, all but the reserve is given back
+        next(iter(loader))
+        assert len(loader._ring._pool) <= 1 + native.Prefetcher._POOL_RESERVE
+        loader.close()
+    _within(60, body)
+
+
+@pytest.mark.parametrize("taken", [1, 5], ids=["after_1", "after_depth_plus_1"])
+def test_an_abandoned_epoch_leaves_the_ring_in_step(taken, rings):
+    def body():
+        ds = _dataset(AUG)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), seed=7,
+                                 depth=4)
+        assert taken in (1, loader.depth + 1)
+        got = []
+        for take in (taken, None, taken, None):
+            it = iter(loader)
+            epoch = []
+            for batch in it:
+                epoch.append(_copy(batch))
+                if len(epoch) == take:
+                    break
+            del it, batch           # collected: the generator is closed
+            got.append(epoch)
+        assert [len(e) for e in got] == [taken, ROUNDS, taken, ROUNDS]
+        assert len(rings) == 1
+        loader.close()
+        want = _ring_per_epoch(AUG, 7, (taken, None, taken, None))
+        for mine, theirs in zip(got, want):
+            assert len(mine) == len(theirs)
+            for a, b in zip(mine, theirs):
+                _same(a, b)
+    _within(60, body)
+
+
+def test_a_new_iter_retires_an_unfinished_one(rings):
+    def body():
+        ds = _dataset(PLAIN)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5))
+        old = iter(loader)
+        next(old)
+        new = iter(loader)
+        first = _copy(next(new))
+        with pytest.raises(RuntimeError, match="retired"):
+            next(old)
+        del old                     # its clean-up must not touch the ring
+        rest = [_copy(b) for b in new]
+        assert len(rest) == ROUNDS - 1 and len(rings) == 1
+        loader.close()
+        want = _ring_per_epoch(PLAIN, 0, (1, None))[1]
+        for a, b in zip([first] + rest, want):
+            _same(a, b)
+    _within(60, body)
+
+
+def _ring_threads(want=None):
+    """Live threads named ``cet-ring``; with ``want``, read again for
+    up to 2 s until it is that many (a joined thread's /proc entry may
+    outlive the join by a moment)."""
+    deadline = time.monotonic() + 2.0
+    while True:
+        n = _count_ring_threads()
+        if want is None or n == want or time.monotonic() > deadline:
+            return n
+        time.sleep(0.01)
+
+
+def _count_ring_threads():
+    names = []
+    for tid in os.listdir("/proc/self/task"):
+        try:
+            with open(f"/proc/self/task/{tid}/comm") as f:
+                names.append(f.read().strip())
+        except OSError:     # the thread ended meanwhile
+            pass
+    return names.count("cet-ring")
+
+
+@pytest.mark.skipif(not os.path.isdir("/proc/self/task"),
+                    reason="needs /proc")
+def test_close_joins_the_threads_and_a_closed_loader_reopens(rings):
+    def body():
+        gc.collect()        # rings that earlier tests left to the collector
+        before = _ring_threads()
+        ds = _dataset(PLAIN)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), n_threads=3)
+        loader.close()      # nothing made yet
+        assert len(rings) == 0
+        a = [_copy(b) for b in loader]
+        assert _ring_threads() == before + 3 and len(rings) == 1
+        loader.close()
+        loader.close()
+        assert _ring_threads(before) == before
+        it = iter(loader)
+        next(it)
+        assert len(rings) == 2 and _ring_threads() == before + 3
+        loader.close()      # mid-epoch: the epoch is retired with the ring
+        assert _ring_threads(before) == before
+        with pytest.raises(RuntimeError, match="retired"):
+            next(it)
+        assert len(a) == ROUNDS
+    _within(60, body)
+
+
+def test_a_failed_pop_does_not_poison_the_next_epoch():
+    def body():
+        ds = _dataset(PLAIN)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5))
+        good = loader._spec_to_indices
+
+        def bad(round_spec):
+            ids, idx = good(round_spec)
+            idx[0, 0] = 10 ** 6
+            return ids, idx
+
+        loader._spec_to_indices = bad
+        with pytest.raises(IndexError):
+            list(loader)
+        loader._spec_to_indices = good
+        assert len(list(loader)) == ROUNDS
+        loader.close()
+    _within(60, body)
+
+
+def test_ring_reset_discards_what_was_submitted():
+    images = np.random.RandomState(0).randint(
+        0, 256, (64, 16, 16, 3)).astype(np.uint8)
+    targets = np.arange(64, dtype=np.int32) % 7
+    plane = native.NativeDataplane(images, targets, slots=3, B=5,
+                                   mean=MEAN, std=STD, crop_pad=2,
+                                   do_flip=True)
+    rng = np.random.RandomState(1)
+    specs = [rng.randint(-1, 64, (3, 5)).astype(np.int64)
+             for _ in range(20)]
+
+    def body():
+        with native.Prefetcher(plane, depth=3, n_threads=2) as pf:
+            pf.reset()                      # an empty ring: nothing to do
+            for popped in (0, 1, 2, 5):
+                # 2 * depth queued at most: a submit never blocks here
+                for i, s in enumerate(specs[:6]):
+                    pf.submit(s, i)
+                for _ in range(popped):
+                    pf.pop()
+                pf.reset()
+                for i, s in enumerate(specs[10:14]):
+                    pf.submit(s, 50 + i)
+                for i, s in enumerate(specs[10:14]):
+                    want = plane.assemble(s, 50 + i)
+                    for a, b in zip(pf.pop(), want):
+                        np.testing.assert_array_equal(a, b)
+    _within(60, body)
