@@ -28,6 +28,7 @@ FED_DATASETS = {
     "ImageNet": 1000,
     "PERSONA": -1,
     "Synthetic": 10,
+    "TOKENS": -1,  # per-client token streams (data/fed_tokens.py)
 }
 
 # natural client counts when --num_clients is omitted

@@ -37,7 +37,8 @@ import numpy as np
 from commefficient_tpu import telemetry
 
 __all__ = ["FedLoader", "ValLoader", "PersonaFedLoader",
-           "PersonaValLoader", "NativeFedLoader", "make_fed_loader"]
+           "PersonaValLoader", "TokenFedLoader", "TokenValLoader",
+           "NativeFedLoader", "make_fed_loader"]
 
 
 class _RoundLoaderBase:
@@ -337,10 +338,8 @@ def make_fed_loader(dataset, sampler, max_batch_size=None, seed=0,
                      dropout_prob=dropout_prob, dropout_seed=seed)
 
 
-class PersonaFedLoader(_RoundLoaderBase):
-    """PersonaChat rounds: adds the double-heads arrays
-    input_ids/token_type_ids/lm_labels (W, B, N, T), mc_token_ids
-    (W, B, N), mc_labels (W, B).
+class _PrefetchedRoundLoader(_RoundLoaderBase):
+    """Language-model rounds, collated in Python.
 
     ``prefetch_depth`` > 1 runs tokenization/collation on ONE
     background thread, up to that many rounds ahead of the consumer —
@@ -352,15 +351,15 @@ class PersonaFedLoader(_RoundLoaderBase):
     epoch end — are deterministic per seed (tested in
     tests/test_gpt2.py TestPersonaPrefetch)."""
 
-    def __init__(self, dataset, sampler, num_candidates: int,
-                 max_seq_len: int, pad_id: int = 0,
+    _thread_name = "persona-prefetch"
+
+    def __init__(self, dataset, sampler,
                  max_batch_size: Optional[int] = None,
                  dropout_prob: float = 0.0, dropout_seed: int = 0,
                  prefetch_depth: int = 2):
         super().__init__(dataset, sampler, max_batch_size,
                          dropout_prob=dropout_prob,
                          dropout_seed=dropout_seed)
-        self.N, self.T, self.pad_id = num_candidates, max_seq_len, pad_id
         self.prefetch_depth = prefetch_depth
 
     def __iter__(self) -> Iterator[dict]:
@@ -401,7 +400,7 @@ class PersonaFedLoader(_RoundLoaderBase):
             put_or_stop(("done", None))
 
         t = threading.Thread(target=produce, daemon=True,
-                             name="persona-prefetch")
+                             name=self._thread_name)
         t.start()
         try:
             while True:
@@ -424,6 +423,24 @@ class PersonaFedLoader(_RoundLoaderBase):
                 except queue.Empty:
                     break
             t.join(timeout=5.0)
+
+
+
+class PersonaFedLoader(_PrefetchedRoundLoader):
+    """PersonaChat rounds: adds the double-heads arrays
+    input_ids/token_type_ids/lm_labels (W, B, N, T), mc_token_ids
+    (W, B, N), mc_labels (W, B)."""
+
+    def __init__(self, dataset, sampler, num_candidates: int,
+                 max_seq_len: int, pad_id: int = 0,
+                 max_batch_size: Optional[int] = None,
+                 dropout_prob: float = 0.0, dropout_seed: int = 0,
+                 prefetch_depth: int = 2):
+        super().__init__(dataset, sampler, max_batch_size,
+                         dropout_prob=dropout_prob,
+                         dropout_seed=dropout_seed,
+                         prefetch_depth=prefetch_depth)
+        self.N, self.T, self.pad_id = num_candidates, max_seq_len, pad_id
 
     def collate(self, round_spec) -> dict:
         from commefficient_tpu.data.fed_persona import persona_collate
@@ -449,6 +466,26 @@ class PersonaFedLoader(_RoundLoaderBase):
             batch["mask"][i, :n] = 1.0
         batch["client_ids"] = ids
         return batch
+
+
+class TokenFedLoader(_PrefetchedRoundLoader):
+    """Causal-LM rounds over per-client token streams
+    (data/fed_tokens.py): ``input_ids`` (W, B, T) i32, ``mask`` (W, B).
+    Every position of a real row is a token: streams are packed."""
+
+    _thread_name = "tokens-prefetch"
+
+    def collate(self, round_spec) -> dict:
+        W, B = self.W, self.B
+        ids = np.zeros((W, B, self.dataset.seq_len), np.int32)
+        mask = np.zeros((W, B), np.float32)
+        cids = np.zeros((W,), np.int32)
+        for i, (cid, idxs) in enumerate(round_spec):
+            cids[i] = cid
+            rows = np.asarray(idxs[:B], np.int64)
+            ids[i, :len(rows)] = self.dataset.sequences(rows, cid)
+            mask[i, :len(rows)] = 1.0
+        return {"client_ids": cids, "input_ids": ids, "mask": mask}
 
 
 class _ShardedValBase:
@@ -531,3 +568,17 @@ class PersonaValLoader(_ShardedValBase):
                     batch[k][s, :n] = arrs[k]
                 batch["mask"][s, :n] = 1.0
             yield batch
+
+
+class TokenValLoader(_ShardedValBase):
+    """Held-out token streams as (S, B, T) shards."""
+
+    def __iter__(self):
+        T = int(self.dataset.seq_len)
+        for idxs in self._shard_indices():
+            ids = np.zeros((self.S * self.B, T), np.int32)
+            mask = np.zeros((self.S * self.B,), np.float32)
+            ids[:len(idxs)] = self.dataset.sequences(idxs)
+            mask[:len(idxs)] = 1.0
+            yield {"input_ids": ids.reshape(self.S, self.B, T),
+                   "mask": mask.reshape(self.S, self.B)}

@@ -39,7 +39,8 @@ def _ensure_loaded():
     # users never pay for flax imports
     import importlib
     import importlib.util
-    for mod in ("resnet9", "fixup_resnet9", "resnet18", "resnets", "gpt2"):
+    for mod in ("resnet9", "fixup_resnet9", "resnet18", "resnets", "gpt2",
+                "joyai"):
         name = f"commefficient_tpu.models.{mod}"
         # skip modules not yet written, but let real import errors
         # inside existing ones propagate

@@ -1,0 +1,512 @@
+"""One chip's share of a JoyAI-LLM-Flash layer stack, in flax: MLA
+(latent attention), a leading dense SwiGLU layer, sigmoid-routed expert
+layers with a shared expert, a depth-1 multi-token-prediction module,
+untied embedding and head over a vocabulary slice.
+
+The block is DeepSeek-V3's (arXiv:2412.19437) as JoyAI-LLM-Flash's
+``config.json`` sizes it; the equations are restated in
+``models/joyai_reference.py``, the plain float32 reference this module
+is tested against. What is TPU-shaped here:
+
+- **The expert layer is the expert-parallel layer on one chip.** It is
+  told which experts it holds (``n_held_experts`` from
+  ``expert_offset``), routes over all ``n_router_experts`` in float32,
+  and computes its own experts' part: the (token, expert) assignments
+  that land here are sorted by expert and taken a buffer of ``tokens``
+  rows at a time (static: a round program has no dynamic shape): a
+  gather, three ragged products (``jax.lax.ragged_dot``: XLA's tiled
+  TPU kernel, whose cost follows the rows, not rows x experts) and a
+  scatter-add under the gates. With the experts of one chip of 32 a
+  token lands here 0.25 times in expectation, so one pass at a quarter
+  full is the rule; a routing that sends the average token to more than
+  one held expert takes a second pass (a loop of dynamic length), so no
+  assignment is ever left out (``moe.dropped`` counts what the passes
+  did not reach: 0). No exchange, and nothing stands in for the absent
+  chips.
+- **Under the clients ``vmap``** (``core/rounds.py make_local_loss``)
+  ``ragged_dot`` has no batching rule for an unbatched weight, and a
+  batched one would copy the experts per client: ``routed_experts``
+  carries its own VJP and runs once per client
+  (``jax.custom_batching.sequential_vmap``), which is also what lets
+  its loop have a length of its own per client; the weights stay shared
+  and their gradient is summed by the transformation as for any layer.
+- bf16 compute on float32 parameters as ``models/gpt2.py``: norms,
+  RoPE, softmax, router scores and selection in float32.
+
+Scopes (``PERF.md`` section 3): ``mla_attn``, ``moe_route`` (scores,
+top-k, dispatch order and gather), ``moe_experts`` (the ragged
+products), ``moe_combine`` (gates, scatter-add, shared expert add),
+``mtp``; the heads' ``lm_head`` is ``lm_nll_sums_chunked``'s. Inside
+``routed_experts`` the gathers are ``moe_route`` and the scatter-adds
+``moe_combine``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.custom_batching import sequential_vmap
+
+from commefficient_tpu.models import register_model
+
+#: a client's routing counts, which ``causal_lm_loss`` returns beside
+#: the loss and ``train/gpt2_train.py`` turns into the round's ``moe.*``
+#: counters: (token, expert) assignments to experts held here over all
+#: expert layers; the fullest (layer, expert)'s; the mean over (layer,
+#: expert); assignments no pass of ``routed_experts`` reached (0)
+MOE_STATS = ("assignments_here", "load_max", "load_mean", "dropped")
+
+
+@dataclasses.dataclass(frozen=True)
+class JoyAIConfig:
+    vocab_size: int = 129280          # rows held of embedding and head
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40       # dense + expert layers, no MTP
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32     # heads held
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 768
+    n_router_experts: int = 256       # the router's published width
+    n_held_experts: int = 256         # experts whose weights are here
+    expert_offset: int = 0            # id of the first of them
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    num_nextn_predict_layers: int = 1
+    mtp_loss_weight: float = 0.3
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 32e6
+    initializer_range: float = 0.02
+    dtype: Any = jnp.float32
+    remat: bool = False
+
+    @staticmethod
+    def tiny() -> "JoyAIConfig":
+        """Test-scale: every mechanism present, nothing wide."""
+        return JoyAIConfig(
+            vocab_size=96, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            intermediate_size=48, moe_intermediate_size=16,
+            n_router_experts=64, n_held_experts=4, expert_offset=8,
+            num_experts_per_tok=4)
+
+    @staticmethod
+    def from_hf(blob: dict) -> "JoyAIConfig":
+        """From a ``config.json`` of the cut: the published keys, with
+        ``n_routed_experts`` the experts held, ``router_experts`` the
+        router's width (default: the same) and ``expert_offset``."""
+        fields = {f.name for f in dataclasses.fields(JoyAIConfig)}
+        kw = {k: v for k, v in blob.items() if k in fields}
+        held = int(blob.get("n_routed_experts", 256))
+        kw.update(n_held_experts=held,
+                  n_router_experts=int(blob.get("router_experts", held)))
+        kw.pop("dtype", None)
+        return JoyAIConfig(**kw)
+
+    def reference_spec(self) -> dict:
+        """The same sizes under the keys ``joyai_reference`` reads."""
+        spec = {f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self)
+                if f.name not in ("dtype", "remat", "n_router_experts",
+                                  "n_held_experts")}
+        spec.update(n_routed_experts=self.n_held_experts,
+                    router_experts=self.n_router_experts)
+        return spec
+
+
+# --- the held experts' products -------------------------------------------
+
+def _ragged(x, w, sizes):
+    """(M, K) rows sorted by group, (G, K, N) float32, (G,) -> (M, N)
+    float32, computed in ``x``'s dtype. Rows past the groups are zero on
+    the CPU and whatever the buffer held on the TPU: mask them."""
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _ragged_outer(x, dy, sizes):
+    """(M, K), (M, N), (G,) -> (G, K, N) float32: each group's x^T dy."""
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return jax.lax.ragged_dot_general(
+        x, dy, sizes, dims, preferred_element_type=jnp.float32)
+
+
+def _pass(p, x, token, gate, load):
+    """Pass ``p`` of the sorted assignments: rows [pN, (p+1)N). Returns
+    the rows' tokens, gates, validity, each expert's share of the rows
+    and the gathered inputs."""
+    N = x.shape[0]
+    with jax.named_scope("moe_route"):
+        lo = p * N
+        rows = jax.lax.dynamic_slice_in_dim(token, lo, N)
+        g = jax.lax.dynamic_slice_in_dim(gate, lo, N)
+        ends = jnp.cumsum(load)
+        sizes = (jnp.clip(ends, lo, lo + N)
+                 - jnp.clip(ends - load, lo, lo + N)).astype(jnp.int32)
+        valid = ((lo + jnp.arange(N)) < ends[-1])[:, None]
+        xg = x[rows]
+    return rows, g, valid, sizes, xg
+
+
+def _expert_ffn(xg, valid, sizes, wg, wu, wd):
+    # the TPU kernel leaves the rows past the groups unwritten: every
+    # ragged product is masked before anything reads it
+    a = jnp.where(valid, _ragged(xg, wg, sizes), 0.0)
+    b = jnp.where(valid, _ragged(xg, wu, sizes), 0.0)
+    h = (jax.nn.silu(a) * b).astype(xg.dtype)
+    return a, b, h, jnp.where(valid, _ragged(h, wd, sizes), 0.0)
+
+
+def _zeros(shape, load):
+    """float32 zeros to carry through a pass loop: derived from the
+    client's ``load`` and not ``jnp.zeros``, so that inside a
+    ``shard_map`` over clients they vary over the mesh axis as the
+    loop's results do (the scan carry-type check; cf. models/gpt2.py
+    ``lm_nll_sums_chunked``)."""
+    return jnp.zeros(shape, jnp.float32) \
+        + (load[0] * 0).astype(jnp.float32)
+
+
+def _passes(load, N):
+    return (jnp.sum(load) + N - 1) // N
+
+
+@sequential_vmap
+def _routed_fwd(x, token, gate, load, wg, wu, wd):
+    N, C = x.shape
+
+    def body(p, y):
+        rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
+        with jax.named_scope("moe_experts"):
+            o = _expert_ffn(xg, valid, sizes, wg, wu, wd)[3]
+        with jax.named_scope("moe_combine"):
+            return y.at[rows].add(o * g[:, None])
+
+    return jax.lax.fori_loop(0, _passes(load, N), body,
+                             _zeros((N, C), load))
+
+
+@sequential_vmap
+def _routed_bwd(x, token, gate, load, wg, wu, wd, dy):
+    N, C = x.shape
+    dt = x.dtype
+
+    def body(p, carry):
+        dx, dgate, dwg, dwu, dwd = carry
+        rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
+        with jax.named_scope("moe_experts"):
+            a, b, h, o = _expert_ffn(xg, valid, sizes, wg, wu, wd)
+        with jax.named_scope("moe_combine"):
+            dyg = jnp.where(valid, dy[rows], 0.0)
+            dg = jnp.sum(dyg * o, axis=-1)
+            do = (dyg * g[:, None]).astype(dt)
+        with jax.named_scope("moe_experts"):
+            dh = jnp.where(
+                valid, _ragged(do, jnp.swapaxes(wd, 1, 2), sizes), 0.0)
+            sa = jax.nn.sigmoid(a)
+            da = (dh * b * sa * (1.0 + a * (1.0 - sa))).astype(dt)
+            db = (dh * a * sa).astype(dt)
+            dxg = jnp.where(
+                valid, _ragged(da, jnp.swapaxes(wg, 1, 2), sizes)
+                + _ragged(db, jnp.swapaxes(wu, 1, 2), sizes), 0.0)
+            dwg = dwg + _ragged_outer(xg, da, sizes)
+            dwu = dwu + _ragged_outer(xg, db, sizes)
+            dwd = dwd + _ragged_outer(h, do, sizes)
+        with jax.named_scope("moe_route"):
+            dx = dx.at[rows].add(dxg)
+            dgate = jax.lax.dynamic_update_slice_in_dim(
+                dgate, dg, p * N, axis=0)
+        return dx, dgate, dwg, dwu, dwd
+
+    dx, dgate, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, _passes(load, N), body,
+        tuple(_zeros(a.shape, load) for a in (x, gate, wg, wu, wd)))
+    return dx.astype(dt), dgate, dwg, dwu, dwd
+
+
+@jax.custom_vjp
+def routed_experts(x, token, gate, load, wg, wu, wd):
+    """What the experts held here add to each token, float32 (N, C).
+
+    ``x`` (N, C) in the compute dtype; ``token`` / ``gate`` (A_max,):
+    the token and the gate of every (token, expert) assignment, those
+    to held experts first and sorted by expert; ``load`` (E,): how many
+    each held expert has; ``wg``, ``wu`` (E, C, F), ``wd`` (E, F, C)
+    float32. The assignments are taken N rows a pass, as many passes
+    as the load needs (one, unless the average token picks more than
+    one expert held here), each pass three ragged products: every
+    assignment is computed whatever the routing, at a cost that
+    follows the load. Carries its own VJP (no reverse mode runs
+    through a loop of dynamic length) and recomputes the pass's
+    activations there. Under ``vmap`` it runs once per batch element
+    with the weights shared, and their gradient is summed over the
+    batch in float32."""
+    return _routed_fwd(x, token, gate, load, wg, wu, wd)
+
+
+def _routed_vjp_fwd(*args):
+    return _routed_fwd(*args), args
+
+
+def _routed_vjp_bwd(res, dy):
+    dx, dgate, dwg, dwu, dwd = _routed_bwd(*res, dy)
+    return dx, None, dgate, None, dwg, dwu, dwd
+
+
+routed_experts.defvjp(_routed_vjp_fwd, _routed_vjp_bwd)
+
+
+# --- layers ---------------------------------------------------------------
+
+def _init(cfg):
+    return nn.initializers.normal(stddev=cfg.initializer_range)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+
+
+def rope(x, theta):
+    """Rotate the interleaved pairs of the last axis; x: (S, T, ..., D),
+    float32."""
+    T, D = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    ang = ang.reshape((1, T) + (1,) * (x.ndim - 3) + (D // 2,))
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
+                      x1 * jnp.sin(ang) + x2 * jnp.cos(ang)],
+                     axis=-1).reshape(x.shape)
+
+
+class _Weights(nn.Module):
+    """Declares matrices under the reference's names; no ``Dense``:
+    there are no biases, and several are used as stacks."""
+    cfg: JoyAIConfig
+
+    def mat(self, name, shape, std=None):
+        init = _init(self.cfg) if std is None \
+            else nn.initializers.normal(stddev=std)
+        return self.param(name, init, shape)
+
+
+class MLA(_Weights):
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        S, T, C = x.shape
+        H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim)
+        dv, r = cfg.v_head_dim, cfg.kv_lora_rank
+        q_a = self.mat("q_a", (C, cfg.q_lora_rank))
+        cq = RMSNorm(cfg.rms_norm_eps, name="q_norm")(x @ q_a.astype(dt))
+        q_b = self.mat("q_b", (cfg.q_lora_rank, H * (dn + dr)))
+        kv_a = self.mat("kv_a", (C, r + dr))
+        kv = x @ kv_a.astype(dt)
+        ckv = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(kv[..., :r])
+        kv_b = self.mat("kv_b", (r, H * (dn + dv)))
+        o = self.mat("o", (H * dv, C))
+        qh = (cq.astype(dt) @ q_b.astype(dt)).reshape(S, T, H, dn + dr)
+        kvh = (ckv.astype(dt) @ kv_b.astype(dt)).reshape(S, T, H, dn + dv)
+        with jax.named_scope("mla_attn"):
+            qr = rope(qh[..., dn:].astype(jnp.float32), cfg.rope_theta)
+            kr = rope(kv[..., r:].astype(jnp.float32), cfg.rope_theta)
+            att = (jnp.einsum("sthd,suhd->shtu", qh[..., :dn],
+                              kvh[..., :dn],
+                              preferred_element_type=jnp.float32)
+                   + jnp.einsum("sthd,sud->shtu", qr.astype(dt),
+                                kr.astype(dt),
+                                preferred_element_type=jnp.float32)) \
+                * float((dn + dr) ** -0.5)
+            causal = jnp.tril(jnp.ones((T, T), bool))
+            att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
+            out = jnp.einsum("shtu,suhd->sthd", att.astype(dt),
+                             kvh[..., dn:])
+        return out.reshape(S, T, H * dv) @ o.astype(dt)
+
+
+class SwiGLU(_Weights):
+    width: int = 0
+
+    @nn.compact
+    def __call__(self, x):
+        dt, C = self.cfg.dtype, x.shape[-1]
+        gate = self.mat("gate", (C, self.width))
+        up = self.mat("up", (C, self.width))
+        down = self.mat("down", (self.width, C))
+        return (jax.nn.silu(x @ gate.astype(dt)) * (x @ up.astype(dt))) \
+            @ down.astype(dt)
+
+
+class _Experts(_Weights):
+    @nn.compact
+    def __call__(self):
+        cfg = self.cfg
+        E, C, F = (cfg.n_held_experts, cfg.hidden_size,
+                   cfg.moe_intermediate_size)
+        return (self.mat("gate", (E, C, F)), self.mat("up", (E, C, F)),
+                self.mat("down", (E, F, C)))
+
+
+class ExpertLayer(_Weights):
+    """The expert layer of one chip: ``(y, stats)`` with ``stats`` =
+    float32 (assignments here, the fullest expert's, dropped)."""
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
+        N, C = x.shape
+        E, k = cfg.n_held_experts, cfg.num_experts_per_tok
+        router = self.mat("router", (C, cfg.n_router_experts))
+        bias = self.mat("router_bias", (cfg.n_router_experts,))
+        gate_w, up_w, down_w = _Experts(cfg, name="experts")()
+        with jax.named_scope("moe_route"):
+            s = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST))
+            _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
+            sel = jnp.take_along_axis(s, top, axis=-1)
+            if cfg.norm_topk_prob:
+                sel = sel / (jnp.sum(sel, -1, keepdims=True) + 1e-20)
+            g = cfg.routed_scaling_factor * sel               # (N, k)
+            self.sow("intermediates", "top", top)
+            # every assignment, those to experts held here first and
+            # sorted by expert
+            local = (top - cfg.expert_offset).reshape(-1)     # (N*k,)
+            key = jnp.where((local >= 0) & (local < E), local, E)
+            order = jnp.argsort(key, stable=True)
+            load = jnp.sum(jax.nn.one_hot(key, E + 1, dtype=jnp.int32),
+                           axis=0)[:E]                        # (E,)
+        routed = routed_experts(x.astype(dt), order // k,
+                                g.reshape(-1)[order], load,
+                                gate_w, up_w, down_w)
+        shared = SwiGLU(cfg, cfg.moe_intermediate_size
+                        * cfg.n_shared_experts, name="shared")(x)
+        with jax.named_scope("moe_combine"):
+            y = (routed + shared.astype(jnp.float32)).astype(dt)
+        total = jnp.sum(load)
+        done = jnp.minimum(total, _passes(load, N) * N)
+        stats = jnp.stack([total, jnp.max(load),
+                           total - done]).astype(jnp.float32)
+        return y.reshape(shape), stats
+
+
+class Block(nn.Module):
+    cfg: JoyAIConfig
+    moe: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        x = x + MLA(cfg, name="attn")(
+            RMSNorm(cfg.rms_norm_eps, name="attn_norm")(x).astype(dt))
+        h = RMSNorm(cfg.rms_norm_eps, name="ffn_norm")(x).astype(dt)
+        if not self.moe:
+            return x + SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h), \
+                jnp.zeros((3,), jnp.float32)
+        y, stats = ExpertLayer(cfg, name="moe")(h)
+        return x + y, stats
+
+
+class MTPModule(_Weights):
+    """Depth-1 multi-token prediction: position t joins the trunk's
+    h_t with the embedding of x_{t+1}; its hidden predicts x_{t+2}."""
+
+    @nn.compact
+    def __call__(self, h, emb_next, block_cls):
+        cfg, dt = self.cfg, self.cfg.dtype
+        C = cfg.hidden_size
+        eh = self.mat("eh_proj", (2 * C, C))
+        x = jnp.concatenate(
+            [RMSNorm(cfg.rms_norm_eps, name="hnorm")(h),
+             RMSNorm(cfg.rms_norm_eps, name="enorm")(emb_next)],
+            axis=-1).astype(dt) @ eh.astype(dt)
+        x, stats = block_cls(cfg, moe=True, name="block")(x)
+        return RMSNorm(cfg.rms_norm_eps, name="norm")(x), stats
+
+
+@register_model("JoyAIFlashLM")
+class JoyAIFlashLM(nn.Module):
+    """(S, T) token ids -> (final hidden (S, T, C) float32, MTP hidden
+    or None, head weight (V, C), the expert layers' (assignments here,
+    fullest expert's load, dropped) folded over layers).
+    The heads are applied by the loss in token chunks
+    (``models/gpt2.py lm_nll_sums_chunked``), so no (tokens, vocab)
+    logits tensor exists."""
+    cfg: JoyAIConfig = JoyAIConfig()
+
+    @nn.compact
+    def __call__(self, input_ids):
+        cfg, dt = self.cfg, self.cfg.dtype
+        embed = self.param("embed", _init(cfg),
+                           (cfg.vocab_size, cfg.hidden_size))
+        head = self.param("lm_head", _init(cfg),
+                          (cfg.vocab_size, cfg.hidden_size))
+        block_cls = nn.remat(Block) if cfg.remat else Block
+        h = embed[input_ids].astype(dt)
+        stats = jnp.zeros((3,), jnp.float32)
+        for i in range(cfg.num_hidden_layers):
+            h, s = block_cls(cfg, moe=i >= cfg.first_k_dense_replace,
+                             name=f"layer_{i}")(h)
+            stats = _fold(stats, s)
+        final = RMSNorm(cfg.rms_norm_eps, name="norm")(h)
+        mtp = None
+        for i in range(cfg.num_nextn_predict_layers):
+            # the sequence's last position has no next token: it wraps
+            # to the first one, and the loss leaves it out (causal
+            # attention: no other position sees it)
+            with jax.named_scope("mtp"):
+                nxt = embed[jnp.roll(input_ids, -1, axis=1)].astype(dt)
+                mtp, s = MTPModule(cfg, name=f"mtp_{i}")(h, nxt, block_cls)
+            stats = _fold(stats, s)
+        return final, mtp, head, stats
+
+
+def _fold(total, layer):
+    """Sum assignments and drops over layers, keep the fullest expert."""
+    return jnp.stack([total[0] + layer[0], jnp.maximum(total[1], layer[1]),
+                      total[2] + layer[2]])
+
+
+def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
+    """Per-sequence loss (main head's mean NLL + ``mtp_loss_weight`` x
+    the MTP head's) and the ``MOE_STATS`` scalars."""
+    from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
+    cfg = module.cfg
+    final, mtp, head, stats = module.apply({"params": params}, input_ids)
+    sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
+                                 cfg.dtype, ignore_index=-1,
+                                 tokens_per_chunk=tokens_per_chunk)
+    loss = sn / jnp.maximum(sv, 1.0)
+    if mtp is not None:
+        with jax.named_scope("mtp"):
+            mn, mv = lm_nll_sums_chunked(
+                mtp[:, :-2], head, input_ids[:, 2:], cfg.dtype,
+                ignore_index=-1, tokens_per_chunk=tokens_per_chunk)
+        loss = loss + cfg.mtp_loss_weight * mn / jnp.maximum(mv, 1.0)
+    expert_layers = (cfg.num_hidden_layers - cfg.first_k_dense_replace
+                     + cfg.num_nextn_predict_layers)
+    mean = stats[0] / max(expert_layers * cfg.n_held_experts, 1)
+    return loss, (stats[0], stats[1], mean, stats[2])

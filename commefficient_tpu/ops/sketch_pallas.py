@@ -82,12 +82,15 @@ def supported(d: int, c: int, r: int) -> bool:
     scheduler handles the flagship r=5, c=2^19 case (10.5 MB table) on
     v5e. Geometries pushing right up to the limit may still OOM VMEM
     at compile — set backend="xla" explicitly there. The m bound keeps
-    the (r, m) rotation table within SMEM."""
+    the (r, m) rotation table within SMEM: (5, 718), d = 376M at
+    c = 2^19, compiles for and runs on the v5e (PERF.md section 6,
+    PR 27); past that, the XLA twin materialises (r, d) float32 and no
+    longer fits the chip, so a larger d needs the table blocked."""
     L = _pick_lanes(c)
     if L is None or 4 * r * c > _TABLE_VMEM_LIMIT:
         return False
     m = -(-d // c)
-    return r * m <= 2048
+    return r * m <= 4096
 
 
 def _sign_hash_chunk(t, sign_seed: np.uint32, c: int, S: int, L: int,

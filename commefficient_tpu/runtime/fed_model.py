@@ -803,6 +803,7 @@ class FedModel:
             probe_vals = (None if res.probes is None else
                           {k: float(_host(v))
                            for k, v in res.probes.items()})
+        self._note_metric_counters(ridx, metrics)
         if probe_vals is not None:
             # merge now (so eval-only callers still get them on the
             # ledger); the server pass completes the dict and runs the
@@ -847,6 +848,18 @@ class FedModel:
         tel.set_round_bytes(ridx, float(down.sum()), float(up.sum()))
         return metrics + [down, up]
 
+    #: ((counter name, fold over clients), ...) for the loss's extra
+    #: results, in their order after the loss itself: what a model
+    #: counts inside the round program (an expert layer's loads) lands
+    #: on the round record as counters. Set by the trainer.
+    metric_counters = ()
+
+    def _note_metric_counters(self, ridx, metrics):
+        if self.metric_counters:
+            self.telemetry.set_round_counters(ridx, {
+                name: float(fold(m)) for (name, fold), m
+                in zip(self.metric_counters, metrics[1:])})
+
     def flush(self, force=True):
         """Materialise buffered pipelined rounds, replaying the
         deferred accounting ops in dispatch order. Returns the list of
@@ -880,9 +893,11 @@ class FedModel:
                     self._finish_probes(op[3], vals)
                 down, up = self._account_bytes(op[1], op[2],
                                                cfg=op[4])
+                metrics = next(rounds)
+                self._note_metric_counters(op[3], metrics)
                 self.telemetry.set_round_bytes(
                     op[3], float(down.sum()), float(up.sum()))
-                results.append(next(rounds) + [down, up])
+                results.append(metrics + [down, up])
             else:
                 self._apply_note(op[1])
         return results

@@ -297,10 +297,13 @@ class Telemetry:
         round. No-op when disabled."""
         if not self._sinks:
             return None
-        self._close_current()
         rec = make_round_record(index)
         self._records[index] = rec
-        self._current = rec
+        # the new record is current before the old one is finished
+        # (memory statistics, emission): a span another thread opens
+        # meanwhile (a loader's producer, woken by the pop that
+        # preceded this call) lands on it and is not dropped
+        self._close_current(rec)
         mark = self._compile_mark = dict(_COMPILE)
         if not self._seen_round:
             # what compiled before this run's first round (set-up):
@@ -314,8 +317,8 @@ class Telemetry:
             self.causal.begin_round(index)
         return rec
 
-    def _close_current(self):
-        rec, self._current = self._current, None
+    def _close_current(self, successor=None):
+        rec, self._current = self._current, successor
         if rec is None:
             return
         rec["host_rss_peak_bytes"] = host_rss_peak_bytes()
@@ -370,6 +373,14 @@ class Telemetry:
         if self._current is not None:
             c = self._current["counters"]
             c[name] = c.get(name, 0) + n
+
+    def set_round_counters(self, index: int, counters: dict):
+        """Set counters on round ``index``'s record from values the
+        round's program returned (``FedModel.metric_counters``).
+        Arrives with the round's metrics, before ``set_round_bytes``."""
+        rec = self._records.get(index)
+        if rec is not None:
+            rec["counters"].update(counters)
 
     def set_round_bytes(self, index: int, downlink, uplink):
         """Attach the round's FedModel accounting totals. Arrives at

@@ -26,7 +26,9 @@ from commefficient_tpu.data.fed_persona import (FedPERSONA,
                                                 generate_synthetic_personachat)
 from commefficient_tpu.data.fed_sampler import FedSampler
 from commefficient_tpu.data.loader import (PersonaFedLoader,
-                                           PersonaValLoader)
+                                           PersonaValLoader,
+                                           TokenFedLoader,
+                                           TokenValLoader)
 from commefficient_tpu.data.tokenizer import (SPECIAL_TOKENS,
                                               load_tokenizer)
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
@@ -39,6 +41,31 @@ from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
                                      Timer, steps_per_epoch)
 
 MAX_SEQ_LEN = 256  # static pad length (persona sequences are short)
+
+#: ``--model`` names this trainer builds; any other value (the shared
+#: parser's default is a CV model) means GPT2DoubleHeads, as before
+#: the flag was read here
+CAUSAL_LMS = ("JoyAIFlashLM",)
+
+
+#: how ``FedModel`` folds the causal loss's per-client routing counts
+#: (``models/joyai.py MOE_STATS``, in that order) into the round
+#: record's ``moe.*`` counters
+MOE_COUNTERS = (("moe.assignments_here", np.sum), ("moe.load_max", np.max),
+                ("moe.load_mean", np.mean), ("moe.dropped", np.sum))
+
+
+def is_causal_lm(args) -> bool:
+    """A causal LM on packed token streams (``--model JoyAIFlashLM
+    --dataset_name TOKENS``) instead of double heads on PersonaChat;
+    the two flags go together."""
+    causal = args.model in CAUSAL_LMS
+    if causal != (args.dataset_name == "TOKENS"):
+        raise ValueError(
+            f"--model {args.model} and --dataset_name "
+            f"{args.dataset_name or 'PERSONA'} do not go together: "
+            f"{CAUSAL_LMS} train on TOKENS, GPT2DoubleHeads on PERSONA")
+    return causal
 
 
 def _lm_nll_sums(module, params, batch, tokens_per_chunk=0,
@@ -86,6 +113,26 @@ def _resolve_fused(args, module):
 def _token_nll(logits, labels, ignore_index=-1):
     """token_nll with the persona loaders' label padding default."""
     return token_nll(logits, labels, ignore_index)
+
+
+def make_causal_loss(module, args, train=True):
+    """A causal LM's loss of a client batch ``{input_ids (B, T), mask
+    (B,)}``: the masked mean over sequences of ``causal_lm_loss`` (main
+    head + lambda x MTP head, heads chunked as GPT-2's). Training
+    returns the client's routing counts beside it (``MOE_STATS``: the
+    round's ``moe.*`` counters); validation the shape ``run_batches``
+    reads, with no multiple-choice task to score (accuracy 0)."""
+    from commefficient_tpu.models.joyai import causal_lm_loss
+
+    def compute_loss(params, batch, cfg):
+        losses, stats = causal_lm_loss(
+            module, params, batch["input_ids"],
+            getattr(args, "tokens_per_chunk", 0) or 1024)
+        m = batch["mask"]
+        loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return loss, (tuple(stats) if train else (jnp.zeros(()),))
+
+    return compute_loss
 
 
 def make_compute_loss_train(module, args):
@@ -282,11 +329,41 @@ def train_gpt2(model, opt, lr_scheduler, train_loader, val_loader,
     return results
 
 
+def build_causal_lm(args: Config):
+    """``--model JoyAIFlashLM``: the architecture is ``config.json`` in
+    ``--model_checkpoint`` (the published keys, cut to the chip's share
+    as ``JoyAIConfig.from_hf`` reads them), or the tiny preset under
+    ``--test``. Random weights: no checkpoint format is read yet."""
+    import dataclasses
+    import json
+
+    from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlashLM
+    cfg_json = os.path.join(args.model_checkpoint, "config.json")
+    if os.path.exists(cfg_json):
+        with open(cfg_json) as f:
+            cfg = JoyAIConfig.from_hf(json.load(f))
+    elif args.do_test:
+        cfg = JoyAIConfig.tiny()
+    else:
+        raise FileNotFoundError(
+            f"--model {args.model} needs {cfg_json} (or --test)")
+    cfg = dataclasses.replace(
+        cfg, dtype=jnp.bfloat16 if args.do_bf16 else jnp.float32,
+        remat=bool(args.do_remat))
+    module = JoyAIFlashLM(cfg)
+    # model-init stream, not noise  # audit: allow(noise-confinement)
+    params = module.init(jax.random.PRNGKey(args.seed),
+                         jnp.zeros((1, 8), jnp.int32))["params"]
+    return module, params
+
+
 def build_model_and_tokenizer(args: Config):
     import dataclasses
 
     import json
 
+    if is_causal_lm(args):
+        return build_causal_lm(args) + (None,)
     tokenizer = load_tokenizer(args.model_checkpoint)
     tokenizer.add_special_tokens(SPECIAL_TOKENS)
     cfg_json = os.path.join(args.model_checkpoint, "config.json") \
@@ -352,9 +429,33 @@ def build_model_and_tokenizer(args: Config):
     return module, params, tokenizer
 
 
+def _token_loaders(args: Config):
+    """``--dataset_name TOKENS``: per-client token streams
+    (data/fed_tokens.py), dealt out by the same sampler."""
+    from commefficient_tpu.data.fed_tokens import (
+        FedTokens, generate_synthetic_tokens)
+    if args.do_test and not os.path.exists(
+            os.path.join(args.dataset_dir, "stats.json")):
+        generate_synthetic_tokens(args.dataset_dir, seed=args.seed)
+    train_ds = FedTokens(args.dataset_dir, train=True,
+                         num_clients=args.num_clients)
+    val_ds = FedTokens(args.dataset_dir, train=False)
+    sampler = FedSampler(train_ds, args.num_workers,
+                         args.local_batch_size, seed=args.seed)
+    train_loader = TokenFedLoader(
+        train_ds, sampler, dropout_prob=args.dropout_prob,
+        dropout_seed=args.seed)
+    val_loader = TokenValLoader(
+        val_ds, args.valid_batch_size,
+        shards_per_step=max(1, args.num_workers))
+    return train_loader, val_loader, train_ds
+
+
 @setup_span("data_build")
 def get_data_loaders(args: Config, tokenizer):
     """(reference gpt2_train.py:315-355)"""
+    if args.dataset_name == "TOKENS":
+        return _token_loaders(args)
     if args.do_test and not os.path.exists(
             os.path.join(args.dataset_dir,
                          "personachat_self_original.json")):
@@ -412,7 +513,8 @@ def run(argv=None) -> TrainRun:
         maybe_initialize_multihost_cli
     maybe_initialize_multihost_cli(args)
     np.random.seed(args.seed)
-    args.num_results_train = 1
+    causal = is_causal_lm(args)
+    args.num_results_train = 1 + (len(MOE_COUNTERS) if causal else 0)
 
     if args.do_test:
         # pre-run CLI override: no round program exists yet for a
@@ -428,7 +530,17 @@ def run(argv=None) -> TrainRun:
     if args.num_clients is None:
         args.num_clients = int(train_ds.num_clients)
 
-    if args.seq_devices > 1:
+    if causal:
+        if args.seq_devices > 1:
+            raise ValueError("--seq_devices > 1 shards GPT-2's attention "
+                             f"only (core/rounds_sp.py), not {args.model}")
+        model = FedModel(module, params,
+                         make_causal_loss(module, args), args,
+                         compute_loss_val=make_causal_loss(
+                             module, args, train=False),
+                         padded_batch_size=train_loader.B)
+        model.metric_counters = MOE_COUNTERS
+    elif args.seq_devices > 1:
         from commefficient_tpu.runtime.fed_model_sp import (
             SeqParallelFedModel)
         model = SeqParallelFedModel(
@@ -519,7 +631,8 @@ def run(argv=None) -> TrainRun:
         # saved HF-style into the run's logdir (skipped after a NaN
         # abort — diverged weights are not a final model)
         model.save_pretrained(logdir, hf_format=args.do_hf_export)
-        tokenizer.save_pretrained(logdir)
+        if tokenizer is not None:
+            tokenizer.save_pretrained(logdir)
         print(f"saved model + tokenizer to {logdir}"
               + (" (HF torch format)" if args.do_hf_export else ""))
     return TrainRun(results, model, opt, train_loader)

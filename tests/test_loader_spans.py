@@ -53,6 +53,18 @@ def _persona_loader(root, depth):
         2, 64, 0, prefetch_depth=depth)
 
 
+def _token_loader(root, depth):
+    from commefficient_tpu.data.fed_tokens import (
+        FedTokens, generate_synthetic_tokens)
+    from commefficient_tpu.data.loader import TokenFedLoader
+    generate_synthetic_tokens(root, num_clients=6, stream_len=128,
+                              seq_len=32)
+    ds = FedTokens(root)
+    return TokenFedLoader(
+        ds, FedSampler(ds, num_workers=2, local_batch_size=2, seed=3),
+        prefetch_depth=depth)
+
+
 @pytest.fixture(autouse=True)
 def no_live_telemetry(monkeypatch):
     """``current()`` as a fresh process has it: models that earlier
@@ -176,8 +188,11 @@ def test_native_loader_spans_and_counters():
 
 
 @pytest.mark.parametrize("depth", [1, 3])
-def test_persona_loader_spans_and_counters(tmp_path, depth):
-    recs, firsts = _drive(_persona_loader(str(tmp_path), depth))
+@pytest.mark.parametrize("make, thread", [
+    (_persona_loader, "persona-prefetch"),
+    (_token_loader, "tokens-prefetch")], ids=["persona", "tokens"])
+def test_persona_loader_spans_and_counters(tmp_path, depth, make, thread):
+    recs, firsts = _drive(make(str(tmp_path), depth))
     assert len(firsts) == 2 and firsts[1] > 2
     threads = {e[4] for rec in recs.values() for e in rec["timeline"]
                if e[0] == "data.collate"}
@@ -191,7 +206,7 @@ def test_persona_loader_spans_and_counters(tmp_path, depth):
     else:
         # the producer thread collates: its spans are on the timeline
         # under its own name, with no parent on the consumer's stack
-        assert threads == {"persona-prefetch"}
+        assert threads == {thread}
         collate = [e for rec in recs.values() for e in rec["timeline"]
                    if e[0] == "data.collate"]
         assert all(e[3] is None for e in collate)

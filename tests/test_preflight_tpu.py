@@ -25,6 +25,7 @@ from commefficient_tpu.parallel.mesh import (client_sharding, make_mesh,
                                              replicated)
 
 D_RESNET9, D_GPT2 = 6_584_000, 124_439_808   # flagship grad sizes
+D_JOYAI = 376_091_904   # the joyai-llm-flash-ep32 cut (718 chunks of COLS)
 COLS, ROWS, K = 524288, 5, 50000
 
 
@@ -110,9 +111,13 @@ def test_other_sketch_branches_lower_on_four_devices(pallas, devices, kw,
     assert client >= 1 and server == 3
 
 
-@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2])
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI])
 @pytest.mark.parametrize("rot_lanes", [0, 1024])
 def test_sketch_kernels_lower_at_flagship_geometry(d, rot_lanes):
+    from commefficient_tpu.ops.sketch_pallas import supported
+    # "auto" must pick the kernels at every benchmark geometry: their
+    # XLA twin materialises (r, d) float32, 7.5 GB at the largest
+    assert supported(d, COLS, ROWS)
     cs = CountSketch(d=d, c=COLS, r=ROWS, seed=7, backend="pallas",
                      rot_lanes=rot_lanes)
     assert tpu_kernels(cs.sketch, sds((d,))) == 1
@@ -130,7 +135,7 @@ def test_quantized_emit_lowers_fused_for_int8_unfused_for_fp8():
                            sds((D_RESNET9,))) == 1
 
 
-@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2])
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI])
 def test_take_mask_kernel_lowers(d):
     from commefficient_tpu.ops.topk import threshold_topk_mask_1d
     assert tpu_kernels(lambda sq: threshold_topk_mask_1d(sq, K),

@@ -141,6 +141,31 @@ def test_a_span_straddling_begin_round_is_no_parent_of_the_next_round():
     assert all(validate_record(r) == [] for r in (r0, r1))
 
 
+
+def test_a_span_opened_while_a_round_closes_lands_on_the_next(monkeypatch):
+    """``begin_round`` finishes the old record (memory statistics,
+    emission) with the new one already current: a loader's producer
+    thread that wakes just then (the pop preceded the call) records its
+    ``data.collate`` on the new round, not nowhere."""
+    from commefficient_tpu.telemetry import core
+    sink = ListSink()
+    tel = Telemetry([sink])
+
+    def meanwhile():
+        with tel.span("data.collate"):
+            pass
+        return 0
+
+    monkeypatch.setattr(core, "host_rss_peak_bytes", meanwhile)
+    tel.begin_round(0)
+    tel.set_round_bytes(0, 0.0, 0.0)
+    tel.begin_round(1)
+    tel.set_round_bytes(1, 0.0, 0.0)
+    tel.close()
+    recs = {r["round"]: r for r in sink.records}
+    assert "data.collate" not in recs[0]["spans"]
+    assert [e[0] for e in recs[1]["timeline"]].count("data.collate") == 1
+
 @pytest.mark.parametrize("version", READABLE_SCHEMA_VERSIONS)
 def test_every_schema_version_still_validates(version):
     rec = make_round_record(3)
