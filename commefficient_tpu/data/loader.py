@@ -26,19 +26,165 @@ had touched before). A loader is built before the run's ``Telemetry``:
 who builds both hands it over (``loader.telemetry = model.telemetry``);
 left unset, each epoch looks it up once, on the consumer's thread, in
 ``telemetry.current()``.
+
+**Read-ahead.** Where the consuming model's placement is known
+(``loader.placement``, handed over like the recorder, or
+``staging.current()``: data/staging.py), an epoch's batches are made on
+a thread of the loader's own (``_ReadAhead``): while the round loop
+waits for round r, that thread advances the sampler, indexes, submits,
+pops and drops out round r+1 and then places it on the device (span
+``data.stage``), so ``next(loader)`` hands over a batch that is already
+resident or in flight. The spans above then run on that thread, with no
+parent, but for the ``data.sample`` that opens an epoch (the sampler's
+``__iter__`` and its first advance: the consumer's thread, which could
+only wait meanwhile); the consumer's own wait, for the hand-over, is a
+``data.pop_wait`` under ``sampler``. One round ahead (``prefetch_depth``
+rounds for the language-model loaders, whose thread existed before),
+never across an epoch's end: the thread is started by the ``next()``
+that enters an epoch and ends with it. Batches, their order and every
+RNG stream are those of the loader without read-ahead; a round the
+thread has made and not handed over is one more round *in flight*, as
+the native ring's ``depth`` are: drawn from the sampler, and lost to a
+mid-epoch checkpoint, which continues bit-exactly from the round after
+them (``settle()`` keeps such a checkpoint from reading the sampler
+while the thread advances it). With no placement the CV loaders make
+their batches on the consumer's thread, as before.
 """
 
 from __future__ import annotations
 
+import collections
+import threading
 from typing import Iterator, Optional
 
 import numpy as np
 
 from commefficient_tpu import telemetry
+from commefficient_tpu.data import staging
 
 __all__ = ["FedLoader", "ValLoader", "PersonaFedLoader",
            "PersonaValLoader", "TokenFedLoader", "TokenValLoader",
            "NativeFedLoader", "make_fed_loader"]
+
+
+class _ReadAhead:
+    """One epoch's batches, made by a thread of the loader's at most
+    ``ahead`` rounds before the consumer asks for them.
+
+    ``batches`` is the epoch's generator, not yet started: it is
+    advanced and closed on the thread alone, so what it owns (the
+    sampler's iterator, the native ring) keeps one owner at a time.
+    The thread makes a round only against a credit, and the consumer
+    gives one back with each batch it takes: the thread is never more
+    than ``ahead`` rounds in front, and what it has made always has
+    room. Its one wait is for a credit, and ``stop()`` ends that wait.
+    Each side waits in steps of ``_POLL`` seconds and looks, between
+    them, at ``stop`` and at whether the other side still lives."""
+
+    _POLL = 0.1
+    _JOIN = 60.0    # seconds a round in the making may take to end
+
+    def __init__(self, batches, ahead: int, name: str):
+        self._batches = batches
+        self._cv = threading.Condition()
+        self._credits = ahead
+        self._ready = collections.deque()   # (kind, value), oldest first
+        self._stopped = False
+        self._idle = False      # waiting for a credit, or ended
+        self._thread = threading.Thread(target=self._run, name=name,
+                                        daemon=True)
+        self._thread.start()
+
+    # --- the loader's thread -------------------------------------------
+
+    def _run(self):
+        it = self._batches
+        try:
+            while self._take_credit():
+                batch = next(it, None)
+                if batch is None:
+                    self._hand(("done", None))
+                    return
+                self._hand(("batch", batch))
+        except BaseException as e:  # raised from the consumer's next()
+            self._hand(("error", e))
+        finally:
+            try:
+                # here, not where the consumer stops: an unfinished
+                # native epoch empties its ring on the owning thread
+                it.close()
+            finally:
+                with self._cv:
+                    self._idle = True
+                    self._cv.notify_all()
+
+    def _take_credit(self) -> bool:
+        with self._cv:
+            self._idle = True
+            self._cv.notify_all()
+            while not self._credits and not self._stopped:
+                self._cv.wait(self._POLL)
+            if self._stopped:
+                return False
+            self._credits -= 1
+            self._idle = False
+            return True
+
+    def _hand(self, item):
+        with self._cv:
+            self._ready.append(item)
+            self._cv.notify_all()
+
+    # --- the consumer's thread -----------------------------------------
+
+    def take(self):
+        """The oldest ``(kind, value)`` the thread has made, waited for
+        if need be; the thread gets a credit for it."""
+        with self._cv:
+            while True:
+                if self._stopped:
+                    raise RuntimeError(
+                        "this epoch was retired: a later __iter__ (or "
+                        "close()) took the loader")
+                if self._ready:
+                    break
+                if not self._thread.is_alive():
+                    raise RuntimeError(
+                        "the loader's thread ended without a result")
+                self._cv.wait(self._POLL)
+            item = self._ready.popleft()
+            self._credits += 1
+            self._idle = False      # it has a round to make
+            self._cv.notify_all()
+            return item
+
+    def peek(self):
+        """The batch the next ``take()`` returns, if it is made."""
+        with self._cv:
+            head = self._ready[0] if self._ready else (None, None)
+        return head[1] if head[0] == "batch" else None
+
+    def settle(self):
+        """Wait until the thread is between rounds (or has ended). It
+        stays there: only ``take()`` hands it a credit."""
+        with self._cv:
+            while not self._idle and self._thread.is_alive():
+                self._cv.wait(self._POLL)
+
+    def stop(self):
+        """End the epoch wherever it is and join the thread: what it
+        owned is free again when this returns. Idempotent."""
+        with self._cv:
+            self._stopped = True
+            self._ready.clear()
+            self._cv.notify_all()
+        t = self._thread
+        if t is not threading.current_thread():
+            t.join(self._JOIN)
+            if t.is_alive():
+                raise RuntimeError(
+                    f"the loader's thread {t.name} did not end within "
+                    f"{self._JOIN:g} s of being stopped")
 
 
 class _RoundLoaderBase:
@@ -97,29 +243,100 @@ class _RoundLoaderBase:
     def _round_specs(self, tel):
         """The sampler's complete rounds (fewer than ``W`` clients:
         skipped), each advance of the sampler under a ``data.sample``
-        span."""
-        it = None
-        while True:
-            with tel.span("data.sample"):
-                if it is None:
-                    # a sampler's __iter__ may do an epoch's work
-                    # (FedSampler permutes every client's indices)
-                    it = iter(self.sampler)
-                round_spec = next(it, None)
-            if round_spec is None:
-                return
-            if len(round_spec) >= self.W:
-                yield round_spec
+        span. The epoch is opened here, on the caller's thread: a
+        sampler's ``__iter__`` may do an epoch's work (FedSampler
+        permutes every client's indices) while the consumer can only
+        wait, and on the chip that work ran a quarter slower on a
+        fresh thread (PERF.md section 6, PR 28). Returns the
+        generator of the rounds, to be advanced on any one thread."""
+        with tel.span("data.sample"):
+            it = iter(self.sampler)
+            first = next(it, None)
 
-    def _batches(self, tel) -> Iterator[dict]:
+        def rounds(round_spec=first):
+            while round_spec is not None:
+                if len(round_spec) >= self.W:
+                    yield round_spec
+                with tel.span("data.sample"):
+                    round_spec = next(it, None)
+        return rounds()
+
+    def _batches(self, tel, specs) -> Iterator[dict]:
         """One epoch's batches, on whichever thread iterates."""
-        for round_spec in self._round_specs(tel):
+        for round_spec in specs:
             with tel.span("data.collate"):
                 batch = self.collate(round_spec)
             yield self._apply_dropout(batch)
 
+    #: the consuming model's ``place_batch`` (data/staging.py); None:
+    #: ``staging.current()`` at each epoch
+    placement = None
+    _thread_name = "loader-stage"
+    _reader = None      # the unfinished epoch's _ReadAhead
+
+    def _host_ahead(self) -> int:
+        """Rounds the loader's thread runs ahead of the consumer where
+        nothing is placed; 0: no thread, the consumer makes them."""
+        return 0
+
+    def _staged_batches(self, tel, place, specs) -> Iterator[dict]:
+        """``_batches`` with each placed on the device as its maker's
+        last step (the model's own placement: data/staging.py)."""
+        for batch in self._batches(tel, specs):
+            if place is not None:
+                with tel.span("data.stage"):
+                    batch = staging.stage(batch, place)
+            yield batch
+
     def __iter__(self) -> Iterator[dict]:
-        yield from self._batches(self._epoch_telemetry())
+        self._retire()
+        tel = self._epoch_telemetry()
+        place = self.placement
+        if place is None:
+            place = staging.current()
+        # one round of device read-ahead wherever a batch can be
+        # placed: a constant, the same for every loader and device
+        ahead = max(self._host_ahead(), 0 if place is None else 1)
+        specs = self._round_specs(tel)
+        if not ahead:
+            yield from self._batches(tel, specs)
+            return
+        reader = self._reader = _ReadAhead(
+            self._staged_batches(tel, place, specs), ahead,
+            self._thread_name)
+        try:
+            while True:
+                with tel.span("data.pop_wait"):
+                    kind, val = reader.take()
+                if kind == "batch":
+                    yield val
+                elif kind == "error":
+                    raise val
+                else:
+                    break
+        finally:
+            # the epoch ended, was abandoned (NaN abort, the generator
+            # closed or collected) or was retired: the thread goes with
+            # it, and cannot race a later epoch over sampler or ring
+            reader.stop()
+            if self._reader is reader:
+                self._reader = None
+
+    def _retire(self):
+        """Stop and join the thread of an earlier unfinished epoch,
+        whose next ``next()`` raises."""
+        reader, self._reader = self._reader, None
+        if reader is not None:
+            reader.stop()
+
+    def settle(self):
+        """Return once the loader's thread is between rounds: the
+        sampler, the dropout stream and the loader's counters are then
+        whole, and stay so until the next ``next()`` (a mid-epoch
+        checkpoint reads them: runtime/checkpoint.py)."""
+        reader = self._reader
+        if reader is not None:
+            reader.settle()
 
     def peek_next_client_ids(self):
         """Next round's participant ids one round ahead (the
@@ -127,7 +344,12 @@ class _RoundLoaderBase:
         the sampler can't see ahead or the peeked round is incomplete
         (it would be skipped above) — the consumer then falls back to
         a synchronous gather, so a miss costs latency, never
-        correctness."""
+        correctness. With a thread reading ahead the sampler is past
+        that round: the answer is the made batch's, or None."""
+        reader = self._reader
+        if reader is not None:
+            batch = reader.peek()
+            return None if batch is None else batch["client_ids"]
         peek = getattr(self.sampler, "peek_next_client_ids", None)
         ids = peek() if peek is not None else None
         if ids is None or len(ids) < self.W:
@@ -138,9 +360,10 @@ class _RoundLoaderBase:
         raise NotImplementedError
 
     def close(self):
-        """Release what the loader keeps between epochs (the native
-        loader's ring and threads; nothing here). Idempotent; a closed
-        loader can be iterated again."""
+        """Release what the loader keeps between epochs (an unfinished
+        epoch's thread; the native loader's ring and its threads).
+        Idempotent; a closed loader can be iterated again."""
+        self._retire()
 
     def __len__(self):
         from commefficient_tpu.utils import steps_per_epoch
@@ -195,11 +418,18 @@ class NativeFedLoader(_RoundLoaderBase):
     memory. An epoch still drains at its end, with no read-ahead across
     the boundary: the sampler's RNG stream and its epoch-boundary
     checkpoint contract (data/fed_sampler.py) are as with a ring an
-    epoch, and so is every batch, bit for bit. An epoch abandoned
-    mid-way (the generator closed or collected) empties the ring; a
-    new ``__iter__`` retires an earlier unfinished one, whose next
-    ``next()`` raises. ``close()`` destroys the ring and joins its
-    threads.
+    epoch, and so is every batch, bit for bit.
+
+    One owner at a time: the thread that iterates ``_batches`` submits
+    to the ring, pops it and resets it. Where the batches are placed
+    ahead (module docstring) that is the loader's own thread, for the
+    epoch, and the consumer's otherwise. An epoch abandoned mid-way
+    (the generator closed or collected) empties the ring, on the thread
+    that owns it; a new ``__iter__`` retires an earlier unfinished one
+    (its thread stopped and joined first), whose next ``next()``
+    raises. ``close()`` does the same, then destroys the ring and joins
+    its workers. The pool of recycled buffers holds one round more
+    where a round is staged: the batch whose copy is in flight.
 
     Raises RuntimeError when the toolchain/transform/dataset don't
     support the native path — use :func:`make_fed_loader` for the
@@ -264,14 +494,13 @@ class NativeFedLoader(_RoundLoaderBase):
                 self._ring.reset()
         return self._ring
 
-    def __iter__(self):
-        tel = self._epoch_telemetry()
+    def _batches(self, tel, specs):
         pf = self._open_ring(tel)
         mine = self._epoch = object()
         pending: list = []
         drained = False
         try:
-            for round_spec in self._round_specs(tel):
+            for round_spec in specs:
                 with tel.span("data.index"):
                     ids, idx = self._spec_to_indices(round_spec)
                 pf.submit(idx, self.seed + self._round_counter)
@@ -306,6 +535,7 @@ class NativeFedLoader(_RoundLoaderBase):
     def close(self):
         """Destroy the ring and join its threads. Idempotent; a later
         ``__iter__`` makes a new ring."""
+        super().close()     # an unfinished epoch's thread first
         self._epoch = None
         ring, self._ring = self._ring, None
         if ring is not None:
@@ -349,7 +579,11 @@ class _PrefetchedRoundLoader(_RoundLoaderBase):
     dataset ``_rng`` personality shuffles, dropout) byte-identical to
     the synchronous path, so batches — and checkpointed RNG state at
     epoch end — are deterministic per seed (tested in
-    tests/test_gpt2.py TestPersonaPrefetch)."""
+    tests/test_gpt2.py TestPersonaPrefetch). It is the thread every
+    round loader has (``_ReadAhead``): placing a round on the device
+    is its last step for that round, not a second thread behind it,
+    and at ``prefetch_depth`` <= 1 it runs one round ahead where there
+    is a placement and not at all where there is none."""
 
     _thread_name = "persona-prefetch"
 
@@ -362,68 +596,8 @@ class _PrefetchedRoundLoader(_RoundLoaderBase):
                          dropout_seed=dropout_seed)
         self.prefetch_depth = prefetch_depth
 
-    def __iter__(self) -> Iterator[dict]:
-        if self.prefetch_depth <= 1:
-            yield from super().__iter__()
-            return
-        tel = self._epoch_telemetry()
-        import queue
-        import threading
-
-        q: "queue.Queue" = queue.Queue(maxsize=self.prefetch_depth)
-        stop = threading.Event()
-
-        def put_or_stop(item) -> bool:
-            # every producer put is stop-aware and bounded: an
-            # abandoning consumer (finally-drain racing a concurrent
-            # put) can never leave this thread blocked past the 5s
-            # join holding dataset/sampler references
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def produce():
-            try:
-                # the synchronous path's own iterator: skip-guard,
-                # collate and dropout stay defined in ONE place
-                for batch in self._batches(tel):
-                    if stop.is_set() or not put_or_stop(("batch",
-                                                         batch)):
-                        return
-            except BaseException as e:  # surface in the consumer
-                put_or_stop(("error", e))
-                return
-            put_or_stop(("done", None))
-
-        t = threading.Thread(target=produce, daemon=True,
-                             name=self._thread_name)
-        t.start()
-        try:
-            while True:
-                with tel.span("data.pop_wait"):
-                    kind, val = q.get()
-                if kind == "batch":
-                    yield val
-                elif kind == "error":
-                    raise val
-                else:
-                    break
-        finally:
-            # consumer abandoned mid-epoch (NaN abort): unblock and
-            # retire the producer so it can't race a later epoch's
-            # iteration of the same sampler
-            stop.set()
-            while not q.empty():
-                try:
-                    q.get_nowait()
-                except queue.Empty:
-                    break
-            t.join(timeout=5.0)
-
+    def _host_ahead(self) -> int:
+        return self.prefetch_depth if self.prefetch_depth > 1 else 0
 
 
 class PersonaFedLoader(_PrefetchedRoundLoader):

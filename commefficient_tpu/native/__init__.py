@@ -196,10 +196,16 @@ class NativeDataplane:
 class Prefetcher:
     """Bounded ring of pre-assembled rounds, filled by C++ worker
     threads; pops arrive strictly in submission order (deterministic
-    regardless of thread scheduling). One thread submits, pops and
-    resets. Popped rounds land in recycled buffers (module docstring):
-    hold a batch for as long as you like, it is not written again
-    while anything refers to it."""
+    regardless of thread scheduling). One thread at a time submits,
+    pops and resets: no call here is made safe against another. Under
+    ``NativeFedLoader`` that is, for the length of an epoch, the thread
+    that iterates the epoch (the loader's own where it places batches
+    ahead, else the consumer's), and whoever stops the epoch joins
+    that thread before it resets or closes the ring. Popped rounds land
+    in recycled buffers (module docstring): hold a batch for as long as
+    you like, it is not written again while anything refers to it; a
+    batch staged on the device a round ahead is one more such holder,
+    so the pool then keeps one buffer more."""
 
     #: free buffers kept beyond the ones the consumer still holds
     _POOL_RESERVE = 2

@@ -350,6 +350,15 @@ def save_checkpoint(path: str, model, opt, scheduler=None,
         meta["privacy"] = acc.state_dict()
     if scheduler is not None:
         meta["scheduler_step"] = int(scheduler._step)
+    settle = getattr(loader, "settle", None)
+    if settle is not None:
+        # a loader that makes its batches on a thread of its own
+        # (data/loader.py) finishes the round it is making first: the
+        # sampler, the dropout stream and the round counter read below
+        # are then whole. Rounds it has made and not yet handed over
+        # are in flight, like the native ring's: a mid-epoch resume
+        # continues, bit-exactly, with the round after them
+        settle()
     if sampler is not None and hasattr(sampler.rng, "get_state"):
         state = sampler.rng.get_state()
         meta["sampler_rng"] = [state[0], None, int(state[2]),

@@ -48,6 +48,7 @@ from commefficient_tpu.clientstore import (HostClientStore,
                                            resolve_clientstore,
                                            shard_range, state_fields)
 from commefficient_tpu.config import Config, NATURAL_NUM_CLIENTS
+from commefficient_tpu.data import staging
 from commefficient_tpu.core.rounds import (ClientStates,
                                            build_client_round,
                                            build_server_round,
@@ -439,6 +440,15 @@ class FedModel:
             plan=round_plan(args))
 
         _CURRENT_MODEL = self
+        # what a loader places each round's batch with, one round
+        # ahead (data/staging.py): handed over by the trainer, found in
+        # staging.current() by a loader handed nothing. None under
+        # --async_buffer_size: every batch is folded into another
+        # before its round, and its copy would be made for nothing
+        self.placement = (self.place_batch
+                          if self._async_driver is None else None)
+        if self.placement is not None:
+            staging.publish(self.placement)
 
     # --- reference API surface ------------------------------------------
 
@@ -454,6 +464,8 @@ class FedModel:
         device barrier, plus host client-store teardown (prefetch
         thread join, final write-back, spill-file removal)."""
         trace.end_round_marker()
+        staging.withdraw(self.place_batch)
+        self.placement = None
         # audit: allow(host-sync) — the shutdown barrier IS the sync
         jax.block_until_ready(self.ps_weights)
         if self._prefetcher is not None:
@@ -656,6 +668,21 @@ class FedModel:
         with tel.span("client_pass"):
             return self._client_pass(batch, ridx)
 
+    def place_batch(self, batch):
+        """A round's host batch on this model's mesh: ``(the (W, ...)
+        arrays with the client axis sharded, the client ids
+        replicated)``, both possibly still in flight. The one placement
+        of a train round: ``_client_pass`` runs it where the batch
+        comes without a copy, and a loader runs it a round ahead, on
+        its own thread (data/staging.py)."""
+        dev_batch = shard_batch(self.mesh, jax.tree_util.tree_map(
+            jnp.asarray, {k: v for k, v in batch.items()
+                          if k != "client_ids"}))
+        ids = jax.device_put(
+            jnp.asarray(np.asarray(batch["client_ids"]), jnp.int32),
+            replicated(self.mesh))
+        return dev_batch, ids
+
     def _client_pass(self, batch, ridx):
         args = self.args
         tel = self.telemetry
@@ -677,13 +704,21 @@ class FedModel:
             with tel.span("async_fold"):
                 batch, staleness = self._async_driver.step(batch)
         ids_np = np.asarray(batch["client_ids"])
-        dev_batch = {k: v for k, v in batch.items()
-                     if k != "client_ids"}
-        with tel.span("h2d"):
-            dev_batch = shard_batch(self.mesh, jax.tree_util.tree_map(
-                jnp.asarray, dev_batch))
-            ids = jax.device_put(jnp.asarray(ids_np, jnp.int32),
-                                 replicated(self.mesh))
+        # the copy the loader's thread made of this very batch a round
+        # ago (resident, or landing while the previous round ran): no
+        # copy is issued here and the program need not wait for one. A
+        # batch that anything rebuilt since the loader (the fold above,
+        # a chaos wrapper, mixup, ``dict(batch)``) carries none, nor
+        # does one staged by another model's placement or with a field
+        # replaced: those are placed here, as every batch used to be
+        placed = staging.staged_copy(batch, self.place_batch)
+        if placed is None:
+            tel.count("h2d.inline")
+            with tel.span("h2d"):
+                placed = self.place_batch(batch)
+        else:
+            tel.count("h2d.staged")
+        dev_batch, ids = placed
 
         rng = jax.random.fold_in(self._rng, self.round_index)
         cs_in = self.client_states

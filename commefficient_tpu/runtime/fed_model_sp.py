@@ -75,6 +75,11 @@ class SeqParallelFedModel(FedModel):
         # this subclass's _call_train accounts synchronously; keep the
         # base pipeline machinery off so the op ordering stays valid
         self.pipeline_depth = 1
+        # its rounds take their batch on the sequence mesh, placed by
+        # _client_pass below: a loader has nothing to place ahead
+        from commefficient_tpu.data import staging
+        staging.withdraw(self.place_batch)
+        self.placement = None
 
         sp_cfg = dataclasses.replace(gpt2_cfg,
                                      seq_impl=args.seq_impl)

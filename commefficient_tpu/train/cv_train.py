@@ -541,8 +541,10 @@ def run(argv=None) -> TrainRun:
                      compute_loss_val=loss_val,
                      padded_batch_size=train_loader.B,
                      stats_fn=stats_fn, init_model_state=init_stats)
-    # the loader's spans go onto this model's round records
+    # the loader's spans go onto this model's round records, and its
+    # thread places each round's batch with this model's placement
     train_loader.telemetry = model.telemetry
+    train_loader.placement = model.placement
     if hasattr(train_loader, "peek_next_client_ids"):
         # host client store: the loader's one-round lookahead feeds
         # the prefetch thread (no-op under --clientstore device)

@@ -554,8 +554,10 @@ def run(argv=None) -> TrainRun:
                          compute_loss_val=make_compute_loss_val(module,
                                                                 args),
                          padded_batch_size=train_loader.B)
-    # the loader's spans go onto this model's round records
+    # the loader's spans go onto this model's round records, and its
+    # thread places each round's batch with this model's placement
     train_loader.telemetry = model.telemetry
+    train_loader.placement = model.placement
     if hasattr(model, "attach_participant_feed") \
             and hasattr(train_loader, "peek_next_client_ids"):
         # host client store: one-round lookahead feeds the prefetcher

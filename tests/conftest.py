@@ -25,6 +25,17 @@ def devices():
     return devs
 
 
+@pytest.fixture(autouse=True)
+def no_model_left_live(monkeypatch):
+    """``staging.current()`` as a fresh process has it: a model that an
+    earlier test of this worker built and never finalized does not
+    count, so no loader places its batches with a stranger's mesh (and
+    which thread makes a loader's batches does not depend on what ran
+    before)."""
+    from commefficient_tpu.data import staging
+    monkeypatch.setattr(staging, "_LIVE", [])
+
+
 @pytest.fixture(scope="session")
 def package_parse():
     """One timed cold flowlint run (parse + both lint tiers) on the
