@@ -5,7 +5,7 @@
   waivers; pinned identical by tests/test_flowlint.py).
 * :data:`FLOW_CHECKERS` — the whole-program checkers that need the
   call graph / symbol table: trace-purity, prng-keys,
-  wire-dtype-crossing, lock-confinement, causal-confinement.
+  wire-dtype-crossing, lock-confinement.
 
 ``scripts/audit.py`` runs both tiers and gates them through the same
 baseline; ``# audit: allow(<rule>)`` waivers work identically for
@@ -17,9 +17,6 @@ from commefficient_tpu.analysis.checkers.legacy import (  # noqa: F401
     HOST_HOT_PATH,
     LEGACY_RULES,
     LEGACY_RULES_BY_NAME,
-)
-from commefficient_tpu.analysis.checkers.causal import (
-    CHECKER as CAUSAL_CONFINEMENT,
 )
 from commefficient_tpu.analysis.checkers.locks import (
     CHECKER as LOCK_CONFINEMENT,
@@ -39,7 +36,6 @@ FLOW_CHECKERS = [
     PRNG_KEYS,
     WIRE_DTYPE_CROSSING,
     LOCK_CONFINEMENT,
-    CAUSAL_CONFINEMENT,
 ]
 
 FLOW_CHECKERS_BY_NAME = {c.name: c for c in FLOW_CHECKERS}

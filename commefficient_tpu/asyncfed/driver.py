@@ -18,7 +18,6 @@ compute is replayed at fold time).
 
 from __future__ import annotations
 
-import contextlib
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -39,10 +38,6 @@ class AsyncRoundDriver:
         self.queue = ArrivalQueue()
         self._arrival: Optional[ArrivalProcess] = None
         self._stamp = stamp  # (ids, issue_round) -> None
-        # optional CausalTracer (--causal_trace), attached by
-        # FedModel: cohort_issue / arrival_dequeue spans nest under
-        # the enclosing async_fold telemetry span
-        self.causal = None
         self._fold = 0
         self.issued_total = 0
         self.folded_total = 0
@@ -71,21 +66,14 @@ class AsyncRoundDriver:
             delays = np.zeros((W,), np.int64)
         if self._stamp is not None:
             self._stamp(ids, now)
-        causal = self.causal
-        ctx = (causal.span("cohort_issue") if causal is not None
-               else contextlib.nullcontext())
-        with ctx:
-            for i in range(W):
-                self.queue.push(now + int(delays[i]), {
-                    "issue": now,
-                    "slot": {k: np.asarray(v)[i] for k, v in
-                             batch.items()},
-                })
-            self.issued_total += W
-        ctx = (causal.span("arrival_dequeue") if causal is not None
-               else contextlib.nullcontext())
-        with ctx:
-            arrived = self.queue.pop_arrived(now, self.k)
+        for i in range(W):
+            self.queue.push(now + int(delays[i]), {
+                "issue": now,
+                "slot": {k: np.asarray(v)[i] for k, v in
+                         batch.items()},
+            })
+        self.issued_total += W
+        arrived = self.queue.pop_arrived(now, self.k)
         self.folded_total += len(arrived)
         fold_batch = self._assemble(arrived, batch)
         staleness = np.zeros((self.num_workers,), np.float32)

@@ -194,9 +194,6 @@ class Config:
     # them there.
     approx_topk: bool = False
     approx_recall: float = 0.95  # recall target for --approx_topk
-    # rounds the host may run ahead of the device before materialising
-    # metrics/accounting (1 = synchronous, reference-faithful timing)
-    pipeline_depth: int = 1
     # multi-host pod launch (jax.distributed): when set, the trainers
     # call initialize_multihost(coordinator_address, num_processes,
     # process_id) before building the mesh — one process per host,
@@ -443,14 +440,6 @@ class Config:
     # where postmortem bundles land (stamped into the run registry
     # when --runs_dir is known)
     postmortem_dir: str = "runs/postmortems"
-    # causal round tracing (telemetry/causal.py): record the round's
-    # span DAG with deterministic ids and stamp it on the round
-    # record (optional schema-v7 "causal" key) for the critical-path
-    # explainer (telemetry/critpath.py). Off (default): no tracer is
-    # constructed, no ledger field appears, and the compiled program
-    # is bit-identical. Entirely host-side; hash-excluded like the
-    # other observability taps.
-    causal_trace: bool = False
     # per-job SLO targets (telemetry/slo.py) — each 0 leaves that
     # objective un-armed; any nonzero target arms the SLO engine,
     # which merges slo_burn_* probes into the round record and stamps
@@ -552,8 +541,6 @@ class Config:
                 "immediately)"
         assert 0.0 < self.approx_recall <= 1.0, \
             "--approx_recall must be in (0, 1]"
-        assert self.pipeline_depth >= 1, \
-            "--pipeline_depth must be >= 1"
         assert self.tokens_per_chunk >= 0, \
             "--tokens_per_chunk must be >= 0 (0 = auto)"
         assert self.fused_ce in ("auto", "on", "off"), \
@@ -784,15 +771,10 @@ class Config:
         if self.async_buffer_size > 0:
             # the buffered fold weights the round's per-client
             # transmits by staleness; the chunked scan only ever
-            # holds a running sum, and the async driver *is* the
-            # round-overlap mechanism, so the pipelined dispatch
-            # queue stays at depth 1
+            # holds a running sum
             assert self.client_chunk == 0, \
                 "--async_buffer_size needs the full per-client " \
                 "transmit stack; incompatible with --client_chunk"
-            assert self.pipeline_depth == 1, \
-                "--async_buffer_size overlaps rounds via the " \
-                "arrival buffer; incompatible with --pipeline_depth"
         return self
 
     @property
@@ -1006,7 +988,6 @@ def build_parser(default_lr: Optional[float] = None,
     parser.add_argument("--compute_dtype", type=str, default="float32")
     parser.add_argument("--approx_topk", action="store_true")
     parser.add_argument("--approx_recall", type=float, default=0.95)
-    parser.add_argument("--pipeline_depth", type=int, default=1)
     parser.add_argument("--classes_per_client", type=int, default=1)
     parser.add_argument("--synthetic_per_class", type=int, default=64)
     parser.add_argument("--synthetic_separation", type=float,
@@ -1204,14 +1185,6 @@ def build_parser(default_lr: Optional[float] = None,
     parser.add_argument("--postmortem_dir", type=str,
                         default="runs/postmortems",
                         help="directory postmortem bundles land in")
-    parser.add_argument("--causal_trace", action="store_true",
-                        dest="causal_trace",
-                        help="causal round tracing: record the "
-                        "round's span DAG (deterministic ids) onto "
-                        "round records for the critical-path "
-                        "explainer (telemetry_report.py --critpath); "
-                        "host-side only, off keeps the build "
-                        "bit-identical")
     parser.add_argument("--slo_round_p95", type=float, default=0.0,
                         help="SLO round-latency objective: a round "
                         "slower than this many seconds is a "

@@ -164,7 +164,6 @@ def round_plan(cfg: Config) -> dict:
         "upload_floats_per_client": int(cfg.upload_floats_per_client),
         "fused_grad": fused_grad_eligible(cfg),
         "robust_agg": getattr(cfg, "robust_agg", "none"),
-        "pipeline_depth": int(getattr(cfg, "pipeline_depth", 1)),
         "client_chunk": int(getattr(cfg, "client_chunk", 0)),
         "overlap_depth": int(getattr(cfg, "overlap_depth", 1)),
         "clientstore": getattr(cfg, "clientstore", "device"),

@@ -27,10 +27,9 @@ Client state: the SP round is *stateless* per client (uncompressed /
 sketch modes only — no local momentum, no local error feedback), so
 the host-resident client store (clientstore/) never applies here;
 ``--clientstore host`` composes with the 1-D engine's stateful modes
-(local_topk, fedavg) and FedModel raises if combined with
-``pipeline_depth > 1`` rather than silently degrading. If stateful
-modes are ever added to this path, the dense_rows participant-row
-contract in core/rounds.py build_client_round is the template.
+(local_topk, fedavg). If stateful modes are ever added to this path,
+the dense_rows participant-row contract in core/rounds.py
+build_client_round is the template.
 """
 
 from __future__ import annotations

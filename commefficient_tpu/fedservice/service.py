@@ -40,16 +40,12 @@ from commefficient_tpu.parallel.mesh import (carve_submeshes,
 from commefficient_tpu.runtime.checkpoint import (RoundAutosaver,
                                                   load_checkpoint,
                                                   save_checkpoint)
-from commefficient_tpu.telemetry import (build_telemetry, clock,
+from commefficient_tpu.telemetry import (build_telemetry,
                                          job_ledger_path,
                                          recover_ledger_shards)
 from commefficient_tpu.telemetry import registry
 from commefficient_tpu.telemetry.alarms import (AlarmEngine,
                                                 DivergenceAbort)
-from commefficient_tpu.telemetry.causal import (SEQ_ADMIT, SEQ_GRANT,
-                                                SEQ_ROOT,
-                                                build_causal_tracer,
-                                                span_id, trace_id)
 from commefficient_tpu.telemetry.live import attach_live_plane
 from commefficient_tpu.telemetry.slo import build_slo_engine
 
@@ -84,10 +80,6 @@ class _Job:
         self.starved_ticks = 0
         self.done = False
         self.final_state = None
-        # --causal_trace bookkeeping: monotonic instant the job last
-        # became runnable (admission / previous grant) — the begin of
-        # its next round's sched_grant span
-        self.wait_since = None
 
     def backlog(self) -> int:
         return max(0, int(self.spec.rounds) - self.rounds_done)
@@ -150,14 +142,6 @@ class FedService:
         # service-level SLO engine (starvation objective, typically):
         # observed once per scheduler tick; None with no target set
         self._slo = build_slo_engine(cfg)
-        # causal tracer (--causal_trace on the service cfg): tick
-        # records carry the daemon's own span DAGs, and admission /
-        # scheduler-grant spans are stamped INTO each tenant's round
-        # trace by deterministic id (they ride the next tick record
-        # with a trace override; ledger_merge stitches them)
-        self.telemetry.set_causal_tracer(
-            build_causal_tracer(cfg, job="service"))
-        self._causal = self.telemetry.causal
 
     # ------------------------------------------------------------ admission
 
@@ -198,8 +182,6 @@ class FedService:
         except AdmissionError:
             self._count_rejection()
             raise
-        admit_b = clock.tick()
-
         burning = self.slo_burning_jobs()
         if burning:
             # admission flag, not refusal: a tenant burning its error
@@ -237,9 +219,6 @@ class FedService:
                 and not getattr(spec.cfg, "flightrec_rounds", 0):
             plane["flightrec_rounds"] = self.cfg.flightrec_rounds
             plane["postmortem_dir"] = self.cfg.postmortem_dir
-        if getattr(self.cfg, "causal_trace", False) \
-                and not getattr(spec.cfg, "causal_trace", False):
-            plane["causal_trace"] = True
         cfg = dataclasses.replace(spec.cfg, ledger=shard, **plane)
         job = _Job(spec, index, cfg, mesh, devices)
         job.model, job.opt = spec.builder(cfg, mesh)
@@ -251,15 +230,6 @@ class FedService:
         with self._lock:
             self._jobs.append(job)
             self._by_id[str(spec.job_id)] = job
-        job.wait_since = clock.tick()
-        if self._causal is not None:
-            # the tenant's round-0 trace gets the admission span;
-            # parent=None makes it a root anchor (it precedes the
-            # round root in time and may sit on another clock)
-            self._causal.add_event(
-                "admission", admit_b, job.wait_since,
-                trace=trace_id(index, 0),
-                sid=span_id(index, 0, SEQ_ADMIT), parent=None)
         if self.runs_dir:
             registry.write_manifest(
                 self.runs_dir, args=cfg, ledger=shard,
@@ -394,32 +364,11 @@ class FedService:
         if batch is None:
             self._finish(job)
             return
-        if self._causal is not None:
-            # grant span: runnable-since -> now, stitched into the
-            # tenant's round trace by deterministic id (parent is the
-            # tenant's round root — minted by the tenant, never by
-            # us). Emitted only for rounds that actually run.
-            now = clock.tick()
-            r = job.rounds_done
-            self._causal.add_event(
-                "sched_grant",
-                job.wait_since if job.wait_since is not None else now,
-                now, trace=trace_id(job.index, r),
-                sid=span_id(job.index, r, SEQ_GRANT),
-                parent=span_id(job.index, r, SEQ_ROOT))
         job.model(batch)
         job.opt.step()
         job.rounds_done += 1
         if job.autosaver is not None:
-            if job.model.telemetry.causal is not None:
-                # round r's record is still current: the checkpoint
-                # lands in its flush bucket. Off-path untouched so a
-                # service-driven ledger stays byte-identical to solo.
-                with job.model.telemetry.span("checkpoint"):
-                    job.autosaver(0)
-            else:
-                job.autosaver(0)
-        job.wait_since = clock.tick()
+            job.autosaver(0)
         if job.rounds_done >= int(job.spec.rounds):
             self._finish(job)
 

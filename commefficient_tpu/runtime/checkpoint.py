@@ -228,12 +228,6 @@ def save_checkpoint(path: str, model, opt, scheduler=None,
     remaining rounds bit-exactly instead of restarting the epoch.
     Epoch-boundary saves must NOT set it: their exhausted iterator
     state would make the resumed epoch yield zero rounds."""
-    if getattr(model, "_inflight", None):
-        # flushing here would drop the flushed rounds' metrics and
-        # desync the trainer's pending queue — the caller must drain
-        raise RuntimeError("checkpoint requested with pipelined rounds "
-                           "inflight; drain with model.flush(force="
-                           "True) (the trainers do this at epoch end)")
     # _host, not device_get: on a multi-process mesh the per-client
     # state rows are sharded across processes and not fully addressable
     # — process_allgather (a collective every process must reach)
@@ -775,14 +769,11 @@ class RoundAutosaver:
     """``--checkpoint_every_rounds`` round-cadence autosave.
 
     Called from the trainers' round loop after every completed round.
-    Saves a ``mid_epoch`` checkpoint at the configured cadence —
-    skipping rounds whose pipelined dispatches are still inflight
-    (forcing a drain on the hot path would serialise the pipeline;
-    the next eligible round retries) — then retains up to
-    ``--checkpoint_keep`` round-stamped history snapshots via
-    hardlinks to the just-written archive (zero copy cost; falls
-    back to a copy on link-hostile filesystems) and prunes the
-    oldest beyond the budget. A SIGTERM at any point leaves either
+    Saves a ``mid_epoch`` checkpoint at the configured cadence, then
+    retains up to ``--checkpoint_keep`` round-stamped history
+    snapshots via hardlinks to the just-written archive (zero copy
+    cost; falls back to a copy on link-hostile filesystems) and prunes
+    the oldest beyond the budget. A SIGTERM at any point leaves either
     the previous or the new checkpoint intact — never a torn one
     (the save itself is tmp+rename atomic)."""
 
@@ -804,8 +795,6 @@ class RoundAutosaver:
             return
         r = int(self.model.round_index)
         if r <= 0 or r % self.every or r == self._last_saved:
-            return
-        if getattr(self.model, "_inflight", None):
             return
         save_checkpoint(self.path, self.model, self.opt,
                         self.scheduler, self.sampler, epoch=int(epoch),

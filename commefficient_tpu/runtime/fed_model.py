@@ -186,11 +186,6 @@ class FedModel:
         self._store_pending = None
         self._prefetch_after_writeback = False
         if self.clientstore == "host":
-            if int(getattr(args, "pipeline_depth", 1)) > 1:
-                raise ValueError(
-                    "--clientstore host requires --pipeline_depth 1: "
-                    "round N's write-back must land before round "
-                    "N+1's gather reads the store")
             fields = state_fields(
                 args, init_weights=(np.asarray(flat)
                                     if getattr(args, "do_topk_down",
@@ -344,17 +339,6 @@ class FedModel:
         self._repeat_count = 0
         self._bitmap_bits = 0
 
-        # --pipeline_depth > 1: rounds are dispatched without waiting
-        # for their metrics/accounting; the host runs ahead of the
-        # device by up to `depth` rounds and materialises in batches
-        # via flush() (per-round math is unchanged — only when results
-        # cross to the host changes)
-        self.pipeline_depth = max(1, int(getattr(args,
-                                                 "pipeline_depth", 1)))
-        self._inflight = []   # per round: device metric arrays
-        self._oplog = []      # ordered ("account", ids, mask, ridx) /
-        #                       ("note", support) deferred host ops
-
         # round-ledger telemetry (commefficient_tpu/telemetry): spans
         # around each host-side round stage, byte totals unified with
         # the accounting above, memory/compile watermarks. Disabled
@@ -364,13 +348,11 @@ class FedModel:
         # telemetry finds it there, while this is the one live model
         set_current(self.telemetry)
         # probe bookkeeping: _probe_host holds materialised client-
-        # pass values until the server pass completes the round's dict
-        # (sync path); _probe_log holds DEVICE scalars for pipelined
-        # rounds, materialised at flush replay. The alarm engine is
-        # None with probes off; it evaluates even without sinks, so
-        # --on_divergence abort works ledgerless.
+        # pass values until the server pass completes the round's
+        # dict. The alarm engine is None with probes off; it evaluates
+        # even without sinks, so --on_divergence abort works
+        # ledgerless.
         self._probe_host = {}
-        self._probe_log = {}
         self._prev_residual = None
         from commefficient_tpu.telemetry.alarms import build_alarm_engine
         self.alarm_engine = build_alarm_engine(args, self.telemetry)
@@ -381,9 +363,8 @@ class FedModel:
                 self.alarm_engine.check_device_time
         # --dp sketch: the run's RDP accountant (privacy/). Charged
         # once per DISPATCHED round — the round program releases the
-        # noised table whether or not its metrics ever materialise —
-        # so pipelined rounds spend budget in dispatch order too. Its
-        # cumulative ε lands on the schema-v5 ledger keys and feeds
+        # noised table whether or not its metrics ever materialise.
+        # Its cumulative ε lands on the schema-v5 ledger keys and feeds
         # the privacy_budget_exhausted alarm. None with --dp off.
         self._accountant = build_accountant(args)
         # roofline cost model (analysis/cost.py), computed lazily at
@@ -414,21 +395,9 @@ class FedModel:
             self.telemetry, args, labels=labels,
             runs_dir="runs" if ledger else "")
         # per-run SLO engine (telemetry/slo.py): None unless a target
-        # is set; observed once per synchronous round in step()
+        # is set; observed once per round in step()
         from commefficient_tpu.telemetry.slo import build_slo_engine
         self._slo = build_slo_engine(args)
-        # causal round tracer (telemetry/causal.py): None unless
-        # --causal_trace — every telemetry span then also records a
-        # causal frame, and the asyncfed driver adds cohort-issue /
-        # arrival-dequeue spans through the same tracer. The job
-        # index keys the deterministic trace ids, so daemon-side
-        # grant spans stitch in by id across the process boundary.
-        from commefficient_tpu.telemetry.causal import \
-            build_causal_tracer
-        self.telemetry.set_causal_tracer(
-            build_causal_tracer(args, job=job))
-        if self._async_driver is not None:
-            self._async_driver.causal = self.telemetry.causal
         self.telemetry.emit_meta(
             num_clients=num_clients,
             num_devices=int(np.prod(self.mesh.devices.shape)),
@@ -479,17 +448,14 @@ class FedModel:
 
     def interrupted(self):
         """Crash-safety cleanup after a mid-round SIGTERM/exception:
-        discard every partially-dispatched round's host-side state so
+        discard the partially-dispatched round's host-side state so
         ``finalize()`` (device barrier, store teardown, telemetry
         close) runs cleanly. Server state and residuals are left
         untouched — the last round-cadence autosave is the consistent
-        restore point, and dropping the in-flight rounds keeps both
-        the ledger and the client store free of rounds the checkpoint
+        restore point, and dropping the unfinished round keeps both
+        the ledger and the client store free of a round the checkpoint
         never saw (a half-written-back round would desync store rows
         from the checkpointed server state)."""
-        self._inflight = []
-        self._oplog = []
-        self._probe_log = {}
         self._probe_host = {}
         self.pending_aggregated = None
         self.pending_client_ids = None
@@ -689,13 +655,8 @@ class FedModel:
         eng = self.alarm_engine
         step_t0 = (clock.tick()
                    if eng is not None and eng.step_time_ratio > 0
-                   and self.pipeline_depth <= 1 else None)
-        # SLO latency samples need a wall clock on every synchronous
-        # round (pipelined dispatch times measure the host, not the
-        # round — same exclusion as step_time_regression)
-        slo_t0 = (clock.tick()
-                  if self._slo is not None and self.pipeline_depth <= 1
-                  else None)
+                   else None)
+        slo_t0 = clock.tick() if self._slo is not None else None
         staleness = None
         if self._async_driver is not None:
             # issue the sampled cohort into the arrival queue, then
@@ -821,18 +782,6 @@ class FedModel:
                                         0.9 * ra + 0.1 * s, ra),
                 self.model_state, new_stats)
 
-        if self.pipeline_depth > 1:
-            # bytes for this round attach at flush() replay — the
-            # ledger record stays buffered (round order preserved)
-            # until then; probe scalars stay DEVICE arrays in
-            # _probe_log (no sync) and materialise at the same replay
-            self._oplog.append(("account", ids_np,
-                                np.asarray(batch["mask"]), ridx,
-                                var.cfg))
-            self._inflight.append(list(res.metrics))
-            if res.probes is not None:
-                self._probe_log.setdefault(ridx, {}).update(res.probes)
-            return None
         with tel.span("metrics_host"):
             metrics = [_host(m) for m in res.metrics]
             probe_vals = (None if res.probes is None else
@@ -895,48 +844,6 @@ class FedModel:
                 name: float(fold(m)) for (name, fold), m
                 in zip(self.metric_counters, metrics[1:])})
 
-    def flush(self, force=True):
-        """Materialise buffered pipelined rounds, replaying the
-        deferred accounting ops in dispatch order. Returns the list of
-        per-round outputs in the same format a synchronous
-        ``model(batch)`` call returns; empty until ``pipeline_depth``
-        rounds are buffered unless ``force``."""
-        if self.pipeline_depth <= 1 or not self._inflight:
-            return []
-        if not force and len(self._inflight) < self.pipeline_depth:
-            return []
-        # the pipelined path's big blocking sync: every buffered
-        # round's metrics materialise here, so ledger-attribute it
-        # like the synchronous path does (the span lands on the
-        # current record — the flush boundary — which is where the
-        # wall-clock actually goes)
-        with self.telemetry.span("metrics_host"):
-            rounds = iter([[_host(m) for m in ms]
-                           for ms in self._inflight])
-        self._inflight = []
-        oplog, self._oplog = self._oplog, []
-        results = []
-        for op in oplog:
-            if op[0] == "account":
-                # probes must land on the record BEFORE its bytes:
-                # set_round_bytes makes the record emission-eligible
-                pd = self._probe_log.pop(op[3], None)
-                if pd is not None:
-                    with self.telemetry.span("metrics_host"):
-                        vals = {k: float(_host(v))
-                                for k, v in pd.items()}
-                    self._finish_probes(op[3], vals)
-                down, up = self._account_bytes(op[1], op[2],
-                                               cfg=op[4])
-                metrics = next(rounds)
-                self._note_metric_counters(op[3], metrics)
-                self.telemetry.set_round_bytes(
-                    op[3], float(down.sum()), float(up.sum()))
-                results.append(metrics + [down, up])
-            else:
-                self._apply_note(op[1])
-        return results
-
     def _charge_privacy(self, ridx: int, cfg, staleness=None,
                         mask=None):
         """Charge round ``ridx``'s DP release to the accountant and
@@ -988,7 +895,7 @@ class FedModel:
                                                   sigma=sigma)})
 
     def _observe_slo(self, ridx: int, round_s: float, astats=None):
-        """One SLO observation per synchronous round: latency is the
+        """One SLO observation per round: latency is the
         dispatch-through-metrics wall time, staleness comes from the
         async driver's round stats, ε from the accountant's
         post-charge curve. The returned burn probes ride the ledger
@@ -1011,8 +918,8 @@ class FedModel:
         """Complete round ``ridx``'s probe dict host-side: fold in any
         stashed client-pass values, derive the residual growth ratio
         from the previous round's residual norm (rounds are finished
-        in dispatch order on both the sync and flush-replay paths, so
-        the ratio is always consecutive-round), merge onto the ledger
+        in dispatch order, so the ratio is always consecutive-round),
+        merge onto the ledger
         record, and evaluate the alarm rules — which may raise
         DivergenceAbort under ``--on_divergence abort``."""
         full = self._probe_host.pop(ridx, {})
@@ -1028,9 +935,9 @@ class FedModel:
             self.alarm_engine.check(ridx, full)
         if self._autopilot is not None:
             # between-rounds knob control: one observation per finished
-            # round, in dispatch order on both the sync and
-            # flush-replay paths — the controller (and so its manifest
-            # trajectory) sees exactly the probe stream the run saw
+            # round, in dispatch order — the controller (and so its
+            # manifest trajectory) sees exactly the probe stream the
+            # run saw
             new_key = self._autopilot.observe(ridx, full)
             if new_key is not None:
                 self._switch_variant(new_key)
@@ -1146,7 +1053,7 @@ class FedModel:
         dense-f32 or delta-coded per --downlink_encoding. ``cfg`` is
         the config the round was DISPATCHED under (the dispatch-time
         round variant's) so autopilot knob moves reprice exactly from
-        the round that first used them, even on pipelined replay."""
+        the round that first used them."""
         if cfg is None:
             cfg = self.args
         download_bytes = np.zeros(self.num_clients)
@@ -1198,8 +1105,7 @@ class FedModel:
         return [out[:, i] for i in range(out.shape[1])] + [counts]
 
     def note_update(self, support=None):
-        """Record the server update's support for download accounting
-        (deferred to flush() when pipelining).
+        """Record the server update's support for download accounting.
 
         ``support`` forms:
         - ((k,) indices, (k,) values): sparse support of the weight
@@ -1216,12 +1122,6 @@ class FedModel:
           selections; 1/32 the transfer of the dense form);
         - a dense update array: host-side ``!= 0`` compare (legacy
           form, kept for direct callers)."""
-        if self.pipeline_depth > 1:
-            self._oplog.append(("note", support))
-            return
-        self._apply_note(support)
-
-    def _apply_note(self, support):
         self._update_round += 1
         r = self._update_round
         if len(self._round_counts) < r + 2:
@@ -1270,17 +1170,6 @@ class FedModel:
         self._bitmap_bits = prev_n
         self._prev_support_idx = (None if idx is None
                                   else np.asarray(idx, np.int64))
-
-
-def drain_rounds(model, pending, process, force):
-    """Trainer-side pipeline drain: pop ``model.flush()`` results in
-    dispatch order, pairing each with its queued dispatch-time context
-    tuple from ``pending``. Returns False as soon as ``process`` does
-    (divergence abort)."""
-    for metrics in model.flush(force=force):
-        if not process(metrics, *pending.pop(0)):
-            return False
-    return True
 
 
 class FedOptimizer:
@@ -1465,15 +1354,10 @@ class FedOptimizer:
             # the round this server pass belongs to (round_index was
             # already advanced by _call_train)
             sridx = m.round_index - 1
-            if m.pipeline_depth > 1:
-                # stay on device: values cross at flush replay, in
-                # round order, together with the client-pass probes
-                m._probe_log.setdefault(sridx, {}).update(sprobes)
-            else:
-                with m.telemetry.span("metrics_host"):
-                    svals = {k: float(_host(v))
-                             for k, v in sprobes.items()}
-                m._finish_probes(sridx, svals)
+            with m.telemetry.span("metrics_host"):
+                svals = {k: float(_host(v))
+                         for k, v in sprobes.items()}
+            m._finish_probes(sridx, svals)
 
     def zero_grad(self):
         raise NotImplementedError(

@@ -72,9 +72,6 @@ class SeqParallelFedModel(FedModel):
         super().__init__(module, params, compute_loss, args,
                          compute_loss_val=compute_loss_val,
                          padded_batch_size=padded_batch_size)
-        # this subclass's _call_train accounts synchronously; keep the
-        # base pipeline machinery off so the op ordering stays valid
-        self.pipeline_depth = 1
         # its rounds take their batch on the sequence mesh, placed by
         # _client_pass below: a loader has nothing to place ahead
         from commefficient_tpu.data import staging

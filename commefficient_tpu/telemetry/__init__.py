@@ -13,9 +13,6 @@ logdir naming and must not cycle through this package import.
 """
 
 from commefficient_tpu.telemetry import clock, trace
-from commefficient_tpu.telemetry.causal import (CausalTracer,
-                                                assemble_traces,
-                                                build_causal_tracer)
 from commefficient_tpu.telemetry.core import (NULL_TELEMETRY, Telemetry,
                                               build_telemetry, current,
                                               hbm_peak_bytes,
@@ -23,9 +20,6 @@ from commefficient_tpu.telemetry.core import (NULL_TELEMETRY, Telemetry,
                                               host_rss_peak_bytes,
                                               set_current, setup_span,
                                               setup_spans)
-from commefficient_tpu.telemetry.critpath import (critical_path,
-                                                  critpath_diff,
-                                                  median_buckets)
 from commefficient_tpu.telemetry.record import (LEDGER_SCHEMA_VERSION,
                                                 make_bench_record,
                                                 make_meta_record,
@@ -84,10 +78,4 @@ __all__ = [
     "SLOEngine",
     "SLOSpec",
     "build_slo_engine",
-    "CausalTracer",
-    "assemble_traces",
-    "build_causal_tracer",
-    "critical_path",
-    "critpath_diff",
-    "median_buckets",
 ]

@@ -25,10 +25,7 @@ round instead of silently training on garbage. Three rules:
                        *performance* alarm, not an algorithmic one:
                        it catches the slow bleed (fragmentation, a
                        background compile storm, thermal throttle)
-                       that end-of-run means average away. Evaluated
-                       on synchronous rounds only — pipelined
-                       dispatch times measure the host, not the
-                       round.
+                       that end-of-run means average away.
 ``byzantine_suspect`` — a per-client transmit-norm outlier:
                        ``client_norm_max`` above
                        ``--alarm_byzantine_ratio`` x

@@ -5,15 +5,14 @@ One ``Telemetry`` instance observes one run.  The hot-path contract:
 - **disabled** (no sinks): ``begin_round`` is a single truthiness
   check, ``span()`` returns one shared no-op context manager, and
   ``count()`` returns immediately — no per-round allocation, nothing
-  retained.  ``bench.py`` with telemetry off must stay within 1% of
-  the recorded baseline, and the whole disabled path is a handful of
-  attribute loads per round.
+  retained: the whole disabled path is a handful of attribute loads
+  per round.
 - **enabled**: ``begin_round`` opens a round record; ``span(name)``
   accumulates wall-time into it; ``count(name)`` bumps a counter.
   Records are emitted to every sink in round order once they are (a)
   no longer the current round and (b) carry their uplink/downlink
-  bytes (``set_round_bytes`` — deferred under ``--pipeline_depth``
-  until the trainer drains).  ``close()`` flushes whatever remains.
+  bytes (``set_round_bytes``, at the end of the round's client
+  pass).  ``close()`` flushes whatever remains.
 
 The span model (one for the whole program): besides the accumulated
 seconds in ``rec["spans"][name]``, every span is one entry
@@ -40,8 +39,7 @@ Round lifecycle (mirrors runtime/fed_model.py):
 
     begin_round(r)        # top of FedModel._call_train
       span("h2d") ...     # client pass spans
-      set_round_bytes(r)  # sync path: end of _call_train;
-                          # pipelined: FedModel.flush replay
+      set_round_bytes(r)  # end of _call_train
       span("server") ...  # FedOptimizer.step (record still current)
     begin_round(r+1)      # closes r -> watermark snapshot -> emit
 
@@ -67,13 +65,12 @@ NULL_SPAN = trace.NULL_PHASE
 
 
 class _Span:
-    __slots__ = ("_tel", "_rec", "_name", "_entry", "_causal", "_ann")
+    __slots__ = ("_tel", "_rec", "_name", "_entry", "_ann")
 
     def __init__(self, tel, rec, name):
         self._tel = tel
         self._rec = rec
         self._name = name
-        self._causal = tel.causal
 
     def __enter__(self):
         tel, rec, name = self._tel, self._rec, self._name
@@ -86,16 +83,9 @@ class _Span:
         entry = self._entry = [name, clock.tick(), None, parent,
                                threading.current_thread().name]
         stack.append((rec, tel._enter_timeline(rec, entry)))
-        if self._causal is not None:
-            # open AFTER t0 so the causal frame nests inside the
-            # accumulated span second-for-second; nesting (driver
-            # spans inside async_fold) comes from the tracer's stack
-            self._causal.open(name)
         return self
 
     def __exit__(self, *exc):
-        if self._causal is not None:
-            self._causal.close_span()
         entry = self._entry
         entry[2] = t1 = clock.tick()
         self._ann.__exit__(None, None, None)
@@ -253,11 +243,6 @@ class Telemetry:
         # collective-skew check so trace-derived skew can escalate
         # like any other alarm rule
         self.on_device_time = None
-        # optional CausalTracer (--causal_trace): every _Span also
-        # opens/closes a causal frame, and closing a round stamps its
-        # span DAG onto the record as the optional v7 ``causal`` key.
-        # None (the default) keeps the hot path byte-identical.
-        self.causal = None
         # per-thread stack of the spans open on that thread, as
         # (record, index in its timeline): a span's parent
         self._open = threading.local()
@@ -276,11 +261,6 @@ class Telemetry:
         once the run's logdir exists)."""
         self._sinks.append(sink)
         _ensure_compile_listener()
-
-    def set_causal_tracer(self, tracer):
-        """Attach a CausalTracer (or None to detach). Only meaningful
-        on an enabled Telemetry — causal stamps ride round records."""
-        self.causal = tracer if self._sinks else None
 
     def emit(self, rec):
         for sink in self._sinks:
@@ -313,8 +293,6 @@ class Telemetry:
             c["compile_events_before"] = mark["events"]
             c["compile_secs_before"] = round(mark["secs"], 6)
             c["compile_cache_hits_before"] = mark["cache_hits"]
-        if self.causal is not None:
-            self.causal.begin_round(index)
         return rec
 
     def _close_current(self, successor=None):
@@ -330,10 +308,6 @@ class Telemetry:
         c["compile_secs"] = round(_COMPILE["secs"] - mark["secs"], 6)
         c["compile_cache_hits"] = (_COMPILE["cache_hits"]
                                    - mark["cache_hits"])
-        if self.causal is not None:
-            stamp = self.causal.end_round()
-            if stamp is not None:
-                rec["causal"] = stamp
         self._closed_rounds.add(rec["round"])
         self._drain()
 
@@ -384,8 +358,7 @@ class Telemetry:
 
     def set_round_bytes(self, index: int, downlink, uplink):
         """Attach the round's FedModel accounting totals. Arrives at
-        the end of the client pass (synchronous) or at flush replay
-        (``--pipeline_depth`` > 1)."""
+        the end of the client pass."""
         rec = self._records.get(index)
         if rec is None:
             return
@@ -420,9 +393,8 @@ class Telemetry:
         """Merge algorithm-probe values onto round ``index``'s record
         (schema v2). Client-pass probes land inside ``metrics_host``;
         server-pass probes merge during ``FedOptimizer.step`` while
-        the record is still current; pipelined rounds merge at flush
-        replay — all strictly before the record can emit (emission
-        waits on ``set_round_bytes``, which arrives last)."""
+        the record is still current — all strictly before the record
+        can emit."""
         rec = self._records.get(index)
         if rec is None or not probes:
             return

@@ -241,20 +241,6 @@ class LiveMetricsSink:
         eps = rec.get("dp_epsilon")
         if eps is not None:
             reg.gauge_set(PREFIX + "dp_epsilon", float(eps), labels)
-        causal = rec.get("causal")
-        if isinstance(causal, dict):
-            # --causal_trace runs export the round's critical-path
-            # bucket attribution (seconds per bucket); fedwatch
-            # derives its "crit" dominant-bucket column from these
-            from commefficient_tpu.telemetry.critpath import \
-                critical_path
-            crit = critical_path(causal, rec.get("device_time"))
-            if crit is not None:
-                for b, s in crit["buckets"].items():
-                    if s > 0:
-                        reg.gauge_set(PREFIX + "critpath_seconds",
-                                      float(s),
-                                      dict(labels, bucket=str(b)))
         for alarm in rec.get("alarms") or []:
             reg.counter_add(
                 PREFIX + "alarms_total", 1,

@@ -109,23 +109,9 @@ conventions:
              the run registry's ``postmortem``/``postmortem_reason``
              manifest keys are the lineage stamp.
 
-Schema v7 adds NO required keys — one optional round-record key
-(causal round tracing, telemetry/causal.py):
-
-``causal`` — absent unless the run set ``--causal_trace`` (absent,
-             not None: the off path must add zero ledger fields),
-             else {"trace", "job", "round", "wall", "spans"} where
-             ``spans`` is the round's span DAG — dicts with
-             deterministic ``id``, ``parent`` (None for the round
-             root), ``name``, critical-path ``bucket``, monotonic
-             ``b``/``e`` seconds, and an optional ``trace`` override
-             for spans a process records into ANOTHER trace (the
-             fedservice daemon's ``sched_grant`` riding its own tick
-             record but belonging to the tenant's round trace).
-             ``scripts/ledger_merge.py`` reassembles per-trace DAGs
-             by id across ``.p<k>``/``.job<j>`` shards;
-             telemetry/critpath.py folds each DAG into per-bucket
-             critical-path seconds.
+Schema v7 added one optional round-record key, ``causal``, that
+nothing writes any more; a ledger that carries it still validates, the
+key unread.
 
 Schema v8 adds NO required keys — two optional round-record keys (the
 one span model, telemetry/core.py):
@@ -199,13 +185,6 @@ ROUND_V6_KEYS = (
     "slo",                                 # None without an SLO engine
 )
 
-# v7 adds no required keys: ``causal`` is optional (present only
-# under --causal_trace) so the off path adds zero ledger fields
-ROUND_V7_KEYS = ()
-
-# keys every span dict inside a causal stamp must carry
-CAUSAL_SPAN_KEYS = ("id", "parent", "name", "bucket", "b", "e")
-
 
 def _base(kind: str) -> dict:
     return {"schema": LEDGER_SCHEMA_VERSION, "kind": kind,
@@ -260,40 +239,6 @@ def make_summary_record(**fields) -> dict:
     rec = _base("summary")
     rec.update(fields)
     return rec
-
-
-def _validate_causal(causal) -> list:
-    """Problems with an optional v7 ``causal`` stamp (the key is
-    validated only when present — absence is the off-mode contract)."""
-    if not isinstance(causal, dict):
-        return ["causal is not a dict"]
-    problems = []
-    if not isinstance(causal.get("trace"), str):
-        problems.append("causal.trace is not a string")
-    if not isinstance(causal.get("round"), int):
-        problems.append("causal.round is not an int")
-    if not isinstance(causal.get("wall"), (int, float)):
-        problems.append("causal.wall is non-numeric")
-    spans = causal.get("spans")
-    if not isinstance(spans, list):
-        return problems + ["causal.spans is not a list"]
-    for span in spans:
-        if not isinstance(span, dict):
-            problems.append("causal span is not a dict")
-            continue
-        for key in CAUSAL_SPAN_KEYS:
-            if key not in span:
-                problems.append(f"causal span missing {key!r}")
-        for key in ("id", "name", "bucket"):
-            if key in span and not isinstance(span[key], str):
-                problems.append(f"causal span {key} is not a string")
-        if span.get("parent") is not None \
-                and not isinstance(span.get("parent"), str):
-            problems.append("causal span parent is not str-or-None")
-        for key in ("b", "e"):
-            if key in span and not isinstance(span[key], (int, float)):
-                problems.append(f"causal span {key} is non-numeric")
-    return problems
 
 
 def _validate_timeline(timeline) -> list:
@@ -362,8 +307,6 @@ def validate_record(rec) -> list:
         slo = rec.get("slo")
         if slo is not None and not isinstance(slo, dict):
             problems.append("slo is not a dict")
-        if "causal" in rec:                # optional (v7): validate
-            problems.extend(_validate_causal(rec["causal"]))
         if "timeline" in rec:              # optional (v8): validate
             problems.extend(_validate_timeline(rec["timeline"]))
         v = rec.get("hbm_reserved_peak_bytes")    # optional (v8)

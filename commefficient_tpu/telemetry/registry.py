@@ -34,7 +34,7 @@ MANIFEST_PREFIX = "run_"
 #: with different ledger paths are the SAME configuration)
 _HASH_EXCLUDE = ("ledger", "telemetry_console", "use_tensorboard",
                  "do_profile", "clientstore_dir", "live_port",
-                 "flightrec_rounds", "postmortem_dir", "causal_trace")
+                 "flightrec_rounds", "postmortem_dir")
 
 
 def config_dict(args) -> dict:

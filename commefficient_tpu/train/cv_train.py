@@ -28,7 +28,7 @@ from commefficient_tpu.data import (FedLoader, FedSampler, ValLoader,
 from commefficient_tpu.data import transforms as T
 from commefficient_tpu.models import get_model
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
-                                       TrainRun, drain_rounds)
+                                       TrainRun)
 from commefficient_tpu.telemetry import clock, setup_span
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
@@ -179,8 +179,7 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
                 round_hook=None, epoch=0):
     """(reference cv_train.py:171-252). ``round_hook(epoch)`` runs
     after every completed round (round-cadence autosave,
-    runtime/checkpoint.py RoundAutosaver; it skips itself while
-    pipelined rounds are still in flight)."""
+    runtime/checkpoint.py RoundAutosaver)."""
     if training:
         model.train(True)
         losses, accs = [], []
@@ -189,9 +188,8 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
         spe = len(loader)
         max_batches = max(1, int(spe * epoch_fraction))
         state = {"t0": clock.wall()}
-        pending = []
 
-        def process(metrics, i, w, lr):
+        def process(metrics, i, w):
             loss, acc, download, upload = (metrics[0], metrics[1],
                                            metrics[-2], metrics[-1])
             download_total[:] += download
@@ -206,12 +204,11 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
             accs.append(float(np.sum(acc * w) / w.sum()))
             if args.dataset_name == "EMNIST":
                 # per-round progress line (reference cv_train.py:
-                # 233-237); lr captured at dispatch time so pipelined
-                # drains report each round's own LR (Time becomes
-                # burst-shaped under pipelining — inherent)
+                # 233-237)
                 print("LR: {:0.5f}, Loss: {:0.5f}, Acc: {:0.5f}, "
                       "Time: {:0.2f}".format(
-                          lr, losses[-1], accs[-1],
+                          float(opt.param_groups[0]["lr"]),
+                          losses[-1], accs[-1],
                           clock.wall() - state["t0"]))
                 state["t0"] = clock.wall()
             if not math.isfinite(losses[-1]) or \
@@ -249,22 +246,12 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
                 metrics = model(batch)
                 opt.step()
                 w = np.asarray(batch["mask"]).sum(axis=1)
-                lr_now = float(opt.param_groups[0]["lr"])
-                if metrics is None:
-                    # pipelined (--pipeline_depth > 1): results arrive
-                    # in batches; the device runs ahead of this loop
-                    pending.append((i, w, lr_now))
-                    if not drain_rounds(model, pending, process,
-                                        force=False):
-                        return None
-                elif not process(metrics, i, w, lr_now):
+                if not process(metrics, i, w):
                     return None
                 if round_hook is not None:
                     round_hook(epoch)
                 if args.do_test:
                     break
-            if not drain_rounds(model, pending, process, force=True):
-                return None
         except DivergenceAbort as e:
             # --on_divergence abort: a probe alarm fired (alarms are
             # already flagged on the round's ledger record, which

@@ -34,7 +34,7 @@ from commefficient_tpu.data.tokenizer import (SPECIAL_TOKENS,
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            token_nll)
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
-                                       TrainRun, drain_rounds)
+                                       TrainRun)
 from commefficient_tpu.telemetry import setup_span
 from commefficient_tpu.telemetry.alarms import DivergenceAbort
 from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
@@ -209,7 +209,6 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
     if training:
         model.train(True)
         losses = []
-        pending = []
 
         def process(metrics, i, w):
             # sample-count weighting: see cv_train.run_batches;
@@ -239,19 +238,12 @@ def run_batches(model, opt, lr_scheduler, loader, args, training,
                 metrics = model(batch)
                 opt.step()
                 w = np.asarray(batch["mask"]).sum(axis=1)
-                if metrics is None:  # --pipeline_depth > 1
-                    pending.append((i, w))
-                    if not drain_rounds(model, pending, process,
-                                        force=False):
-                        return None
-                elif not process(metrics, i, w):
+                if not process(metrics, i, w):
                     return None
                 if round_hook is not None:
                     round_hook(epoch)
                 if args.do_test:
                     break
-            if not drain_rounds(model, pending, process, force=True):
-                return None
         except DivergenceAbort as e:
             # alarm engine (--on_divergence abort): the offending
             # round is already ledger-flagged; tel.close() in

@@ -68,7 +68,7 @@ def common_flags(args):
         "--num_workers", "100",
         "--num_epochs", str(args.epochs),
         "--lr_scale", str(args.lr_scale), "--pivot_epoch", "5",
-        "--bf16", "--pipeline_depth", "4",
+        "--bf16",
         "--seed", str(args.seed),
     ]
     return flags

@@ -3,9 +3,9 @@
 
 Polls a live-plane exporter (``--live_port``'s ``/metrics``) and
 renders one refreshing per-job table — rounds done, round-latency
-p95, wire bytes, backlog, staleness, ε spend, SLO burn rate, the
-dominant critical-path bucket (--causal_trace runs), alarm fires —
-so an operator watches the pod instead of tailing J ledger shards.
+p95, wire bytes, backlog, staleness, ε spend, SLO burn rate, alarm
+fires — so an operator watches the pod instead of tailing J ledger
+shards.
 Falls back to tailing the ledger shards directly (``--ledger``) when
 the daemon has no exporter armed.
 
@@ -109,21 +109,8 @@ def job_table(samples):
             row["eps"] = val
         elif name == "commeff_slo_burn":
             row["burn"] = max(row.get("burn", 0.0), val)
-        elif name == "commeff_critpath_seconds":
-            row.setdefault("critpath", {})[
-                labels.get("bucket", "?")] = val
         elif name == "commeff_alarms_total":
             row["alarms"] = row.get("alarms", 0.0) + val
-    for row in jobs.values():
-        cp = row.pop("critpath", None)
-        if cp:
-            # last traced round's per-bucket critical-path gauges:
-            # the buckets sum to the round wall, so the max bucket's
-            # share IS the dominant attribution
-            total = sum(cp.values())
-            b, s = max(cp.items(), key=lambda kv: kv[1])
-            if total > 0:
-                row["crit"] = f"{b} {100 * s / total:.0f}%"
     return jobs
 
 
@@ -132,7 +119,7 @@ COLS = (("job", "job", ""), ("rounds", "rounds", ""),
         ("up", "up", "mib"), ("down", "down", "mib"),
         ("backlog", "backlog", ""), ("stale", "stale", ""),
         ("eps", "eps", ""), ("burn", "burn", ""),
-        ("crit", "crit", ""), ("alarms", "alarms", ""))
+        ("alarms", "alarms", ""))
 
 
 def render_table(jobs) -> str:
@@ -147,31 +134,6 @@ def render_table(jobs) -> str:
              for r in rows]
     lines.insert(1, "-" * len(lines[0]))
     return "\n".join(lines)
-
-
-def _crit_cell(causal, device_time=None):
-    """Dominant critical-path bucket cell ("h2d 62%") for a ledger
-    record's causal stamp. The only non-stdlib touch in this script
-    — degrades to None when the package isn't importable so the
-    console stays usable standalone."""
-    import os
-    try:
-        try:
-            from commefficient_tpu.telemetry.critpath import (
-                critical_path, dominant_bucket)
-        except ImportError:
-            # run as `python scripts/fedwatch.py` next to the repo:
-            # the checkout root isn't on sys.path yet
-            sys.path.insert(0, os.path.join(os.path.dirname(
-                os.path.abspath(__file__)), ".."))
-            from commefficient_tpu.telemetry.critpath import (
-                critical_path, dominant_bucket)
-        dom = dominant_bucket(critical_path(causal, device_time))
-    except Exception:
-        return None
-    if dom is None:
-        return None
-    return f"{dom[0]} {100 * dom[1]:.0f}%"
 
 
 def ledger_table(path):
@@ -217,11 +179,6 @@ def ledger_table(path):
                 row["burn"] = probes["slo_burn_max"]
             if rec.get("dp_epsilon") is not None:
                 row["eps"] = rec["dp_epsilon"]
-            causal = rec.get("causal")
-            if isinstance(causal, dict):
-                crit = _crit_cell(causal, rec.get("device_time"))
-                if crit:
-                    row["crit"] = crit
         if lats:
             lats.sort()
             row["p95_s"] = lats[min(len(lats) - 1,

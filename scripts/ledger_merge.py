@@ -35,14 +35,6 @@ sub-shards per job — which are discovered per job shard and joined
 on round id WITHIN the job (same rules as the top-level process
 merge) before the job stream is appended.
 
-Causal stitching (--causal_trace runs, schema v7): joined round
-records union their ``causal`` spans across process shards (dedup by
-deterministic span id), and after the merge the per-trace span DAGs
-are reassembled (telemetry/causal.py ``assemble_traces``) — the
-summary reports stitched trace/span counts and warns on any orphan
-span (a parent id no shard supplied), which is how a torn shard or a
-missing tenant trace shows up.
-
 ``scripts/telemetry_report.py`` renders merged ledgers with a
 per-shard summary block. Pure host-side JSON work: no jax import.
 """
@@ -58,7 +50,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from commefficient_tpu.telemetry.causal import assemble_traces  # noqa: E402
 from commefficient_tpu.telemetry.record import validate_record  # noqa: E402
 
 MERGED_SUFFIX = ".merged.jsonl"
@@ -143,29 +134,6 @@ def _host_gap_s(rec):
     return None
 
 
-def _merge_causal(rec, shards: dict):
-    """Union causal spans across process shards onto the (already
-    copied) canonical round record's stamp, dedup'd by deterministic
-    span id — each process carries the spans only IT observed; the
-    joined record carries the round's whole DAG."""
-    stamps = [rec.get("causal")]
-    stamps += [sh.get("causal") for _, sh in sorted(shards.items())]
-    stamps = [s for s in stamps if isinstance(s, dict)]
-    if not stamps:
-        return
-    merged = dict(stamps[0])
-    seen, spans = set(), []
-    for stamp in stamps:
-        for span in stamp.get("spans") or ():
-            sid = span.get("id")
-            if sid in seen:
-                continue
-            seen.add(sid)
-            spans.append(span)
-    merged["spans"] = spans
-    rec["causal"] = merged
-
-
 def _shard_view(rec) -> dict:
     view = {}
     for key in SHARD_VIEW_KEYS:
@@ -208,7 +176,6 @@ def merge_ledgers(canonical_records, shard_records: dict) -> tuple:
         rec = dict(rec)
         rec["shards"] = {pk: _shard_view(sh)
                          for pk, sh in sorted(shards.items())}
-        _merge_causal(rec, shards)
         gaps = {}
         hg0 = _host_gap_s(rec)
         if hg0 is not None:
@@ -290,17 +257,6 @@ def main(argv=None) -> int:
         for rec in merged:
             json.dump(rec, f, separators=(",", ":"))
             f.write("\n")
-    traces = assemble_traces(merged)
-    if traces:
-        n_spans = sum(len(t["spans"]) for t in traces.values())
-        n_orphans = sum(len(t["orphans"]) for t in traces.values())
-        print(f"causal: {len(traces)} trace(s), {n_spans} span(s) "
-              f"stitched, {n_orphans} orphan(s)")
-        for tid, t in sorted(traces.items()):
-            if t["orphans"]:
-                print(f"WARNING causal trace {tid}: orphan span(s) "
-                      f"{t['orphans']} (parent id missing from "
-                      "every shard)", file=sys.stderr)
     print(f"{args.ledger} + shards p{stats['shards']} "
           f"+ jobs {job_stats['jobs']}: "
           f"{stats['joined_rounds']} round(s) joined, "

@@ -23,14 +23,6 @@
         two-tier lint run — waived/new/fixed counts per rule, the
         "did this branch move the static-analysis needle" view
 
-    python scripts/telemetry_report.py runs/a.jsonl --critpath
-        critical-path explainer for a --causal_trace ledger
-        (schema v7 ``causal`` stamps): per-round critical-path
-        bucket attribution, the aggregate bucket shares, and a
-        top-K slowest-rounds explainer diffed against the typical
-        (per-bucket median) round — the "why is this round slow"
-        view
-
 Schema-v3 ledgers additionally render the trace-derived device-time
 breakdown (compute / collective / transfer / host-gap per round) and
 the roofline expectation next to the host-span percentiles. Schema-v4
@@ -886,99 +878,6 @@ def runs_dir_report(runs_dir: str, as_json: bool) -> int:
     return 0
 
 
-def critpath_report(records, as_json: bool, top_k: int = 5) -> int:
-    """Critical-path explainer over a --causal_trace ledger: fold
-    each round's causal span DAG into per-bucket seconds
-    (telemetry/critpath.py), then render per-round attributions, the
-    aggregate bucket shares, and the top-K slowest rounds each
-    diffed against the per-bucket median round."""
-    from commefficient_tpu.telemetry.causal import BUCKETS
-    from commefficient_tpu.telemetry.critpath import (critical_path,
-                                                      critpath_diff,
-                                                      dominant_bucket,
-                                                      median_buckets)
-    crits = []
-    for r in records:
-        if r.get("kind") != "round" \
-                or not isinstance(r.get("causal"), dict):
-            continue
-        c = critical_path(r["causal"], r.get("device_time"))
-        if c is not None:
-            if isinstance(r.get("job"), int):
-                c["job"] = r["job"]
-            crits.append(c)
-    if not crits:
-        print("no causal data in this ledger — pre-v7 records, or "
-              "the run did not set --causal_trace")
-        return 1
-    base = median_buckets(crits)
-    wall_total = sum(c["wall"] for c in crits)
-    shares = {b: sum(c["buckets"][b] for c in crits) for b in BUCKETS}
-    slowest = sorted(crits, key=lambda c: c["wall"],
-                     reverse=True)[:top_k]
-    if as_json:
-        print(json.dumps({
-            "rounds": crits, "median_buckets": base,
-            "aggregate": {"wall_s": wall_total, "buckets": shares},
-            "slowest": [{"crit": c,
-                         "diff_vs_median": critpath_diff(c, base)}
-                        for c in slowest]}))
-        return 0
-    lines = [f"== critical path ({len(crits)} traced round(s)) =="]
-    for c in crits:
-        dom = dominant_bucket(c)
-        job = f" job {c['job']}" if "job" in c else ""
-        top = ", ".join(
-            f"{b} {1e3 * s:.3f} ms"
-            for b, s in sorted(c["buckets"].items(),
-                               key=lambda kv: kv[1],
-                               reverse=True)[:3] if s > 0)
-        head = (f"{dom[0]} {100 * dom[1]:.0f}%"
-                if dom else "idle")
-        lines.append(f"  round {c['round']}{job}: wall "
-                     f"{1e3 * c['wall']:.3f} ms, {head} ({top})")
-    lines.append("  aggregate bucket shares:")
-    for b in BUCKETS:
-        s = shares[b]
-        if s <= 0:
-            continue
-        pct = 100 * s / wall_total if wall_total else 0.0
-        lines.append(f"    {b:18} {s:10.4f} s  {pct:5.1f}%")
-    lines.append(f"  slowest {len(slowest)} round(s) vs the "
-                 "median round:")
-    for c in slowest:
-        d = critpath_diff(c, base)
-        grew = [r for r in d["rows"] if r["delta_s"] > 0][:2]
-        why = "; ".join(
-            f"{r['bucket']} +{1e3 * r['delta_s']:.3f} ms"
-            + (f" ({r['ratio']:.1f}x)" if r["ratio"] else "")
-            for r in grew) or "no bucket above the median"
-        job = f" job {c['job']}" if "job" in c else ""
-        lines.append(f"    round {c['round']}{job}: wall "
-                     f"{1e3 * c['wall']:.3f} ms vs median "
-                     f"{1e3 * d['base_wall']:.3f} ms — {why}")
-    print("\n".join(lines))
-    return 0
-
-
-def render_critpath_diff(diff) -> str:
-    """Text block for a bundle's attached critical-path diff (the
-    flight recorder computes it at alarm-dump time)."""
-    lines = [f"  critical-path diff: round {diff.get('round')} wall "
-             f"{1e3 * diff['wall']:.3f} ms vs rolling-median "
-             f"{1e3 * diff['base_wall']:.3f} ms"]
-    for row in diff.get("rows") or []:
-        if not row.get("cur_s") and not row.get("median_s"):
-            continue
-        ratio = (f", {row['ratio']:.2f}x"
-                 if row.get("ratio") else "")
-        lines.append(
-            f"    {row['bucket']:18} {1e3 * row['cur_s']:9.3f} ms "
-            f"vs {1e3 * row['median_s']:9.3f} ms median "
-            f"(delta {1e3 * row['delta_s']:+9.3f} ms{ratio})")
-    return "\n".join(lines)
-
-
 def postmortem_report(path: str, as_json: bool) -> int:
     """Render a flight-recorder bundle: the incident header (reason,
     rule, labels, lineage), the recent compile/alarm event queue, and
@@ -1009,15 +908,9 @@ def postmortem_report(path: str, as_json: bool) -> int:
     lines.append(f"  config: {bundle.get('config_hash', '')[:12]}"
                  + (f", manifest {bundle['manifest']}"
                     if bundle.get("manifest") else ""))
-    ctx = dict(bundle.get("context") or {})
-    critdiff = ctx.pop("critpath_diff", None)
+    ctx = bundle.get("context")
     if ctx:
         lines.append("  context: " + json.dumps(ctx, sort_keys=True))
-    if isinstance(critdiff, dict):
-        lines.append(render_critpath_diff(critdiff))
-    elif not any(isinstance(r.get("causal"), dict) for r in rounds):
-        lines.append("  critical path: no causal data (pre-v7 "
-                     "bundle, or the run did not set --causal_trace)")
     lines.append(f"  ring: {len(rounds)} of last "
                  f"{bundle.get('ring_rounds')} round(s) retained")
     for ev in bundle.get("events") or []:
@@ -1113,11 +1006,6 @@ def main(argv=None):
                     help="findings diff: committed audit baseline vs "
                          "a fresh two-tier lint run (new/fixed/"
                          "waived counts per rule)")
-    ap.add_argument("--critpath", action="store_true",
-                    help="critical-path explainer: per-round and "
-                         "aggregate bucket shares plus the top-K "
-                         "slowest rounds diffed against the median "
-                         "round (needs a --causal_trace ledger)")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable output")
     args = ap.parse_args(argv)
@@ -1134,9 +1022,6 @@ def main(argv=None):
     records, problems = load_ledger(args.ledger)
     for p in problems:
         print(f"WARNING {args.ledger}: {p}", file=sys.stderr)
-    if args.critpath:
-        # job records stay in: the explainer attributes per tenant
-        return critpath_report(records, args.json)
     # fedservice runs: job records summarize per-tenant, not into the
     # service's own (fairness) stream
     jobs = job_summaries(records, args.ledger)

@@ -386,112 +386,3 @@ class TestLedgerMatchesBruteForce:
         for rec, (down_total, up_total) in zip(rounds, returned):
             assert rec["downlink_bytes"] == down_total
             assert rec["uplink_bytes"] == up_total
-
-
-class TestPipelinedFlush:
-    """Multi-round pipeline replay: interleaved account/note ops and
-    pending alignment across several rounds of a real FedModel, vs a
-    synchronous twin (the --test CLI path only ever runs one round per
-    epoch, so the replay machinery is exercised here)."""
-
-    def _run(self, depth, n_rounds=7, seed=3):
-        import flax.linen as nn
-
-        class Lin(nn.Module):
-            @nn.compact
-            def __call__(self, x):
-                return nn.Dense(4, use_bias=False)(x)
-
-        module = Lin()
-        params = module.init(jax.random.PRNGKey(0),
-                             jnp.zeros((1, 3)))["params"]
-        args = Config(mode="true_topk", error_type="virtual", k=3,
-                      local_momentum=0.0, virtual_momentum=0.9,
-                      num_workers=2, local_batch_size=2,
-                      num_clients=5, dataset_name="CIFAR10", seed=0,
-                      pipeline_depth=depth)
-
-        def loss(p, batch, cfg):
-            pred = module.apply({"params": p}, batch["x"])
-            per = jnp.sum((pred - batch["y"][..., None]) ** 2, -1)
-            n = jnp.maximum(jnp.sum(batch["mask"]), 1.0)
-            return jnp.sum(per * batch["mask"]) / n, ()
-
-        from commefficient_tpu.runtime import (FedOptimizer,
-                                               drain_rounds)
-        model = FedModel(module, params, loss, args)
-        opt = FedOptimizer([{"lr": 0.1}], args)
-        rng = np.random.RandomState(seed)
-        outputs = []
-
-        def process(metrics, i):
-            outputs.append((i, [np.asarray(m) for m in metrics]))
-            return True
-
-        pending = []
-        for i in range(n_rounds):
-            batch = {
-                "x": rng.randn(2, 2, 3).astype(np.float32),
-                "y": rng.randn(2, 2).astype(np.float32),
-                "mask": np.ones((2, 2), np.float32),
-                "client_ids": rng.choice(5, 2,
-                                         replace=False).astype(np.int32),
-            }
-            out = model(batch)
-            opt.step()
-            if out is None:
-                pending.append((i,))
-                assert drain_rounds(model, pending, process,
-                                    force=False)
-            else:
-                process(out, i)
-        assert drain_rounds(model, pending, process, force=True)
-        assert not pending
-        return outputs, np.asarray(model.ps_weights)
-
-    def test_depth3_matches_sync(self):
-        sync, w_sync = self._run(depth=1)
-        piped, w_piped = self._run(depth=3)
-        assert [i for i, _ in sync] == [i for i, _ in piped]
-        np.testing.assert_array_equal(w_sync, w_piped)
-        for (i, ms), (j, mp) in zip(sync, piped):
-            for a, b in zip(ms, mp):
-                np.testing.assert_array_equal(a, b)
-
-    def test_checkpoint_refuses_inflight(self, tmp_path):
-        import pytest as _pytest
-
-        from commefficient_tpu.runtime.checkpoint import save_checkpoint
-        # a model with one round inflight at depth 2
-        import flax.linen as nn
-
-        class Lin(nn.Module):
-            @nn.compact
-            def __call__(self, x):
-                return nn.Dense(4, use_bias=False)(x)
-
-        module = Lin()
-        params = module.init(jax.random.PRNGKey(0),
-                             jnp.zeros((1, 3)))["params"]
-        args = Config(mode="uncompressed", error_type="none",
-                      local_momentum=0.0, num_workers=2,
-                      local_batch_size=2, num_clients=5,
-                      dataset_name="CIFAR10", seed=0,
-                      pipeline_depth=2)
-
-        def loss(p, batch, cfg):
-            return jnp.float32(0.0), ()
-
-        from commefficient_tpu.runtime import FedOptimizer
-        model = FedModel(module, params, loss, args)
-        opt = FedOptimizer([{"lr": 0.1}], args)
-        batch = {"x": np.zeros((2, 2, 3), np.float32),
-                 "y": np.zeros((2, 2), np.float32),
-                 "mask": np.ones((2, 2), np.float32),
-                 "client_ids": np.array([0, 1], np.int32)}
-        assert model(batch) is None
-        opt.step()
-        with _pytest.raises(RuntimeError, match="inflight"):
-            save_checkpoint(str(tmp_path / "c.npz"), model, opt)
-        model.flush(force=True)
-        save_checkpoint(str(tmp_path / "c.npz"), model, opt)  # now ok

@@ -158,11 +158,6 @@ def test_host_bit_identical_to_device(mode_kw):
     assert mh.client_store is None and mh._prefetcher is None
 
 
-def test_host_requires_unpipelined_rounds():
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        _build(_cfg("host", pipeline_depth=2))
-
-
 # ----------------------------------------------------------------------
 # checkpoint/resume through the store
 

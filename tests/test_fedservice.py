@@ -131,6 +131,53 @@ class TestDeterminism:
             assert len(shard) == R
             assert shard == ref, f"job {j} ledger diverged"
 
+    def test_tenant_ledgers_validate_and_name_no_tracer(self, tmp_path):
+        """Two tenants with a round-cadence autosave each: the daemon's
+        ledger and both shards hold schema-8 records that validate, a
+        span is a timeline entry on every one of them, and neither the
+        daemon nor a tenant carries a second span recorder."""
+        from commefficient_tpu.telemetry.record import (
+            LEDGER_SCHEMA_VERSION, validate_record)
+        R = 3
+        led = str(tmp_path / "svc.jsonl")
+        svc = FedService(_svc_cfg(led))
+        bs = [_batches(7, R), _batches(9, R)]
+        for j, (name, seed) in enumerate((("a", 3), ("b", 4))):
+            cfg = _job_cfg(seed, checkpoint_every_rounds=1,
+                           checkpoint_path=str(tmp_path / f"ck{j}"))
+            svc.admit(JobSpec(name, cfg, _builder,
+                              lambda r, j=j: bs[j][r], rounds=R))
+        assert not hasattr(svc, "_causal")
+        assert not hasattr(svc.telemetry, "causal")
+        for job in svc._jobs:
+            assert not hasattr(job, "wait_since")
+            assert not hasattr(job.model.telemetry, "causal")
+        svc.run()
+        svc.close()
+        for j in range(2):
+            assert os.path.exists(
+                os.path.join(str(tmp_path / f"ck{j}"),
+                             f"ckpt_job{j}.npz"))
+        for path, n_rounds in ((led, None),
+                               (f"{led}.job0.jsonl", R),
+                               (f"{led}.job1.jsonl", R)):
+            recs = [json.loads(line) for line in open(path)]
+            rounds = [r for r in recs if r.get("kind") == "round"]
+            assert rounds and n_rounds in (None, len(rounds))
+            for rec in recs:
+                assert rec["schema"] == LEDGER_SCHEMA_VERSION == 8
+                assert validate_record(rec) == [], rec
+                assert "causal" not in rec
+            for rec in rounds:
+                assert isinstance(rec["timeline"], list)
+                assert set(rec["spans"]) == {e[0]
+                                             for e in rec["timeline"]}
+        shard = [json.loads(line) for line in open(f"{led}.job0.jsonl")]
+        names = {e[0] for r in shard if r.get("kind") == "round"
+                 for e in r["timeline"]}
+        assert {"client_pass", "server_pass"} <= names
+        assert "checkpoint" not in names
+
     def test_single_job_daemon_parity(self, tmp_path):
         """The J=1 daemon adds zero noise — the reason j1 keeps the
         bare perf-gate key."""

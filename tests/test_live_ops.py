@@ -538,6 +538,45 @@ def test_report_renders_postmortem(tmp_path, capsys):
     assert blob["summary"]["rounds"] == 3
 
 
+def test_latency_alarm_bundle_carries_the_rounds_timeline(tmp_path,
+                                                         capsys):
+    """What a ``step_time_regression`` post-mortem has to explain a
+    slow round with: the ring's round records, each with its
+    ``timeline`` of spans, and no second account of the same time."""
+    fr = FlightRecorder(Config(), 4, out_dir=str(tmp_path / "pm"))
+    tel = Telemetry([fr])
+    for r in range(6):
+        tel.begin_round(r)
+        with tel.span("client_pass"):
+            with tel.span("metrics_host"):
+                pass
+        with tel.span("server_pass"):
+            pass
+        if r == 5:
+            tel.flag_alarm(r, {"rule": "step_time_regression",
+                               "round": r, "value": 10.0,
+                               "threshold": 2.0, "rolling_median": 1.0})
+        tel.set_round_bytes(r, 8.0, 4.0)
+    tel.close()
+    bundle, problems = load_postmortem(fr.last_bundle)
+    assert problems == []
+    assert bundle["rule"] == "step_time_regression"
+    assert set(bundle["context"]) == {"alarms", "round"}
+    assert [r["round"] for r in bundle["rounds"]] == [2, 3, 4, 5]
+    for rec in bundle["rounds"]:
+        assert [(e[0], e[3]) for e in rec["timeline"]] == [
+            ("client_pass", None), ("metrics_host", 0),
+            ("server_pass", None)]
+        assert all(e[1] <= e[2] for e in rec["timeline"])
+        assert "causal" not in rec
+    report = _load_script("telemetry_report")
+    assert report.main(["--postmortem", fr.last_bundle]) == 0
+    text = capsys.readouterr().out
+    assert "incident: alarm rule=step_time_regression" in text
+    assert "4 of last 4 round(s)" in text
+    assert "critical" not in text and "causal" not in text
+
+
 # --- shard recovery at daemon restart ----------------------------------
 
 

@@ -1084,6 +1084,41 @@ class TestLedgerShards:
         metas = [r for r in merged if r.get("kind") == "meta"]
         assert len(metas) == 1 and "process" not in metas[0]
 
+    def test_merge_of_shards_with_old_causal_stamps_does_not_fail(
+            self, tmp_path, capsys):
+        """A v7 ledger written while ``--causal_trace`` existed still
+        merges: each record keeps the stamp it came with, unread, and
+        the summary says nothing of traces."""
+        lm = _load_script("ledger_merge")
+        ledger, shard = self._write_shard_fixture(tmp_path)
+        for path, job in ((ledger, "solo"), (shard, "solo")):
+            recs = [json.loads(l) for l in open(path)]
+            for rec in recs:
+                if rec.get("kind") == "round":
+                    r = rec["round"]
+                    rec["schema"] = 7
+                    rec["causal"] = {
+                        "trace": f"j{job}.r{r}", "job": None,
+                        "round": r, "wall": 0.1,
+                        "spans": [{"id": f"j{job}.r{r}.s9",
+                                   "parent": f"j{job}.r{r}.s0",
+                                   "name": "h2d", "bucket": "h2d",
+                                   "b": 0.0, "e": 0.05}]}
+            with open(path, "w") as f:
+                f.writelines(json.dumps(rec) + "\n" for rec in recs)
+        assert lm.main([ledger]) == 0
+        out = capsys.readouterr()
+        assert "2 round(s) joined" in out.out
+        assert "causal" not in out.out and "WARNING" not in out.err
+        merged = [json.loads(l) for l in open(ledger + ".merged.jsonl")]
+        rounds = [r for r in merged if r.get("kind") == "round"]
+        assert [r["round"] for r in rounds] == [0, 1, 2]
+        for rec in rounds:
+            assert validate_record(rec) == []
+            # the canonical record's own stamp, not a union of shards'
+            assert len(rec["causal"]["spans"]) == 1
+        assert "causal" not in rounds[0]["shards"]["p1"]
+
     def test_merge_without_shards_is_an_error(self, tmp_path):
         lm = _load_script("ledger_merge")
         ledger = str(tmp_path / "solo.jsonl")

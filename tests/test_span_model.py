@@ -177,6 +177,22 @@ def test_every_schema_version_still_validates(version):
         assert validate_record(rec) == []
 
 
+def test_a_v7_record_with_a_causal_stamp_still_validates():
+    """An old ledger's round record: schema 7, no timeline, and the
+    ``causal`` key that nothing writes or reads any more, whatever it
+    holds."""
+    rec = make_round_record(3)
+    rec["schema"] = 7
+    del rec["timeline"], rec["hbm_reserved_peak_bytes"]
+    rec["causal"] = {
+        "trace": "jsolo.r3", "job": None, "round": 3, "wall": 0.5,
+        "spans": [{"id": "jsolo.r3.s0", "parent": None, "name": "round",
+                   "bucket": "host_other", "b": 0.0, "e": 0.5}]}
+    assert validate_record(rec) == []
+    rec["causal"] = "torn"
+    assert validate_record(rec) == []
+
+
 @pytest.mark.parametrize("timeline,problem", [
     ("x", "not a list"),
     ([["a", 0.0, 1.0, None]], "not [name"),
@@ -191,6 +207,92 @@ def test_malformed_timelines_are_reported(timeline, problem):
     rec["timeline"] = [["a", 0.0, None, None, "t"],      # still open
                        ["b", 0.1, 0.2, 0, "t"]]
     assert validate_record(rec) == []
+
+
+@pytest.mark.parametrize("extra", [
+    pytest.param(["--num_devices", "1"], id="one-device"),
+    pytest.param(["--num_devices", "4"], id="mesh4"),
+    pytest.param(["--num_devices", "1", "--async_buffer_size", "2"],
+                 id="async-buffer"),
+])
+def test_top_level_spans_and_the_uncovered_gap_partition_the_round(
+        extra, tmp_path, monkeypatch):
+    """A real 4-round CV run. On the round loop's thread the parentless
+    spans of a record follow one another between its ``begin_round`` and
+    the next, so their durations plus the gaps between them are the
+    round's period: what no top-level span covers is
+    ``runtime.uncovered_ms``, and nothing is counted twice."""
+    import json
+
+    from commefficient_tpu.asyncfed.driver import AsyncRoundDriver
+    from commefficient_tpu.telemetry import clock
+    from commefficient_tpu.train import cv_train
+
+    bounds, folds = [], []
+    real_begin, real_close = Telemetry.begin_round, Telemetry.close
+    real_step = AsyncRoundDriver.step
+
+    def begin_round(self, index):
+        if self.enabled:
+            bounds.append(clock.tick())
+        return real_begin(self, index)
+
+    def close(self):
+        if self.enabled and len(bounds) == 4:
+            bounds.append(clock.tick())
+        return real_close(self)
+
+    def step(self, batch):
+        t0 = clock.tick()
+        out = real_step(self, batch)
+        folds.append((t0, clock.tick()))
+        return out
+
+    monkeypatch.setattr(Telemetry, "begin_round", begin_round)
+    monkeypatch.setattr(Telemetry, "close", close)
+    monkeypatch.setattr(AsyncRoundDriver, "step", step)
+    ledger = str(tmp_path / "run.jsonl")
+    cv_train.main([
+        "--test", "--dataset_name", "Synthetic", "--mode", "sketch",
+        "--error_type", "virtual", "--local_momentum", "0",
+        "--virtual_momentum", "0.9", "--num_clients", "20",
+        "--num_workers", "4", "--local_batch_size", "4",
+        "--num_epochs", "4", "--lr_scale", "0.1", "--pivot_epoch", "1",
+        "--seed", "5", "--ledger", ledger, *extra])
+    with open(ledger) as f:
+        records = [json.loads(line) for line in f]
+    rounds = [r for r in records if r["kind"] == "round"]
+    assert [r["round"] for r in rounds] == [0, 1, 2, 3]
+    assert len(bounds) == 5
+    assert len(folds) == (4 if "--async_buffer_size" in extra else 0)
+    for rec, begin, end in zip(rounds, bounds, bounds[1:]):
+        assert validate_record(rec) == []
+        top = [e for e in rec["timeline"]
+               if e[3] is None and e[4] == "MainThread"]
+        names = [e[0] for e in top]
+        assert names[:2] == ["client_pass", "server_pass"]
+        # then the validation pass's wait and the next batch's fetch
+        assert set(names[2:]) <= {"metrics_host", "sampler"}
+        covered, gaps, at = 0.0, 0.0, begin
+        for _, t0, t1, _, _ in top:
+            assert at <= t0 <= t1       # in order, none overlapping
+            gaps += t0 - at
+            covered += t1 - t0
+            at = t1
+        assert at <= end
+        gaps += end - at
+        assert covered + gaps == pytest.approx(end - begin, abs=1e-9)
+        assert covered > 0.0 and gaps < end - begin
+        if folds:
+            # the one span the buffered path adds, under client_pass,
+            # over the cohort's issue and the arrivals' dequeue
+            i_cp, _ = _entry(rec, "client_pass")
+            _, fold = _entry(rec, "async_fold")
+            assert fold[3] == i_cp
+            s0, s1 = folds[rec["round"]]
+            assert fold[1] <= s0 <= s1 <= fold[2]
+        else:
+            assert "async_fold" not in rec["spans"]
 
 
 def test_fed_clock_places_a_span_on_its_own_annotation(tmp_path):

@@ -169,7 +169,7 @@ def test_schema_v4_device_time_round_trip(tmp_path):
     rec = make_round_record(0)
     assert rec["schema"] == 8 and rec["device_time"] is None
     assert rec["slo"] is None  # v6: the SLO stamp, None unless armed
-    assert "causal" not in rec  # v7: OPTIONAL — absent unless traced
+    assert "causal" not in rec  # v7's key: nothing writes it any more
     assert validate_record(rec) == []
 
     rec["device_time"] = {"window_s": 0.01, "busy_s": 0.004,

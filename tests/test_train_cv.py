@@ -548,28 +548,6 @@ class TestModelConfigs:
         assert get_model_config("ResNet9") is None
 
 
-class TestPipelinedRounds:
-    def test_pipeline_depth_identical_results(self):
-        """--pipeline_depth only changes WHEN results cross to the
-        host: every epoch metric, including the byte-accounting
-        totals, must match the synchronous run exactly."""
-        base = [
-            "--test", "--dataset_name", "Synthetic",
-            "--mode", "sketch", "--error_type", "virtual",
-            "--local_momentum", "0", "--virtual_momentum", "0.9",
-            "--num_clients", "10", "--num_workers", "2",
-            "--local_batch_size", "4", "--num_epochs", "2",
-            "--lr_scale", "0.1", "--pivot_epoch", "1", "--seed", "5",
-        ]
-        sync = cv_train.main(base)
-        piped = cv_train.main(base + ["--pipeline_depth", "4"])
-        assert len(sync) == len(piped) == 2
-        for rs, rp in zip(sync, piped):
-            for key in ("train_loss", "train_acc", "test_acc",
-                        "down (MiB)", "up (MiB)"):
-                assert rs[key] == rp[key], key
-
-
 class TestDeterminism:
     def test_same_seed_identical_training(self):
         """Two identical runs (same seed) must produce bit-identical
