@@ -238,6 +238,9 @@ def save_checkpoint(path: str, model, opt, scheduler=None,
         # host client store: land any round still awaiting write-back
         # so the store snapshot below is complete
         model._store_writeback()
+    # the last server update's support may still be pending: the
+    # accounting state saved below is the state with it applied
+    model.settle_update()
 
     # checkpoint save is a deliberate full sync OFF the round hot
     # path (epoch cadence): materialising state here is the point,
@@ -621,6 +624,9 @@ def load_checkpoint(path: str, model, opt, scheduler=None,
             np.asarray(z["ss_Vvelocity"]), np.asarray(z["ss_Verror"]),
             sharding=server_state_sharding(
                 model.mesh, tuple(model.args.transmit_shape)))
+        # an update this model had pending belongs to the state the
+        # archive replaces: applied first, then overwritten
+        model.settle_update()
         model.last_updated = np.asarray(z["last_updated"])
         model.client_last_seen = np.asarray(z["client_last_seen"])
         if getattr(model, "model_state", None) is not None:

@@ -27,6 +27,16 @@ downlink width (``accounting.py``; dense f32, or ``--downlink_encoding
 delta``'s (idx, val) pairs + repeat bitmap). Identical to the
 reference's count except for exact value-reversion collisions
 (measure-zero) and without the deque's staleness clamp approximation.
+The support of server update r is settled on the host one dispatch
+late: ``opt.step()`` starts its copy and leaves it in one pending slot
+(``defer_update``), and the next ``model(batch)`` applies it
+(``note_update``) right after it has dispatched client program r+1,
+so the unpacking runs while the device does and server r -> client
+r+1 are enqueued back to back. Only round r+1's byte accounting reads
+it, after that round's metrics; whatever else reads or writes the
+accounting state (a checkpoint, ``finalize``, a second ``opt.step()``,
+a direct ``note_update``) settles the slot first (``settle_update``),
+so the state anyone sees is the state settling at once would give.
 Uploads bill at the wire dtype: ``--sketch_dtype int8`` tables cost
 r x c bytes + r f32 row scales, not 4 x r x c.
 """
@@ -338,6 +348,12 @@ class FedModel:
             0, np.int64)
         self._repeat_count = 0
         self._bitmap_bits = 0
+        # the one server update whose support is not applied to the
+        # state above yet, as ``(support,)`` (``None`` is a support:
+        # the dense update), and whether something other than the next
+        # client pass settled the last one (defer_update/settle_update)
+        self._pending_update: Optional[tuple] = None
+        self._settled_early = False
 
         # round-ledger telemetry (commefficient_tpu/telemetry): spans
         # around each host-side round stage, byte totals unified with
@@ -435,6 +451,7 @@ class FedModel:
         trace.end_round_marker()
         staging.withdraw(self.place_batch)
         self.placement = None
+        self.settle_update()
         # audit: allow(host-sync) — the shutdown barrier IS the sync
         jax.block_until_ready(self.ps_weights)
         if self._prefetcher is not None:
@@ -760,9 +777,13 @@ class FedModel:
                 # so take() would synchronously re-gather every repeat
                 # participant's row next round. Defer the submit to
                 # step(), right after the write-back lands — the
-                # background gather then overlaps the downlink
-                # delta-encode bookkeeping (note_update /
-                # _note_delta_support) instead of being undone by it.
+                # background gather then overlaps whatever the trainer
+                # does between opt.step() and the next model(batch)
+                # (its own bookkeeping, the next batch's fetch) instead
+                # of being undone by the write-back. The support's
+                # bookkeeping (note_update / _note_delta_support) no
+                # longer runs in that stretch: it follows the next
+                # dispatch, under the device.
                 self._prefetch_after_writeback = True
             else:
                 self._submit_prefetch()
@@ -782,6 +803,7 @@ class FedModel:
                                         0.9 * ra + 0.1 * s, ra),
                 self.model_state, new_stats)
 
+        self._settle_after_dispatch()
         with tel.span("metrics_host"):
             metrics = [_host(m) for m in res.metrics]
             probe_vals = (None if res.probes is None else
@@ -1056,6 +1078,7 @@ class FedModel:
         the round that first used them."""
         if cfg is None:
             cfg = self.args
+        self.settle_update()
         download_bytes = np.zeros(self.num_clients)
         suffix = np.cumsum(self._round_counts[::-1])[::-1]
         q = self.client_last_seen[ids_np] + 2
@@ -1104,6 +1127,44 @@ class FedModel:
             batch["mask"].shape[0], -1).sum(axis=1)
         return [out[:, i] for i in range(out.shape[1])] + [counts]
 
+    def defer_update(self, support):
+        """Leave the server update's ``support`` (a ``note_update``
+        form) in the pending slot, its copy to the host started and
+        not waited for. At most one update is ever pending: one still
+        there is settled first."""
+        self.settle_update()
+        for leaf in jax.tree_util.tree_leaves(support):
+            if isinstance(leaf, jax.Array):
+                leaf.copy_to_host_async()
+        self._pending_update = (support,)
+
+    def settle_update(self) -> bool:
+        """Apply the pending update's support, if there is one: what
+        everything that reads or writes the accounting state
+        (``last_updated``, ``_round_counts``, ``_update_round``, the
+        delta bookkeeping) calls first. True if there was one."""
+        if self._pending_update is None:
+            return False
+        (support,), self._pending_update = self._pending_update, None
+        with self.telemetry.span("note_update"):
+            self.note_update(support)
+        self._settled_early = True
+        return True
+
+    def _settle_after_dispatch(self):
+        """The client pass's settlement, right after it dispatched its
+        program: the support's host work runs while the device does.
+        Counts, on this round's record, whether the previous update was
+        settled here (``account.deferred``) or by something earlier (a
+        checkpoint, a direct call: ``account.inline``); a round with no
+        update before it counts neither."""
+        early = self._settled_early
+        if self.settle_update():
+            self.telemetry.count("account.deferred")
+        elif early:
+            self.telemetry.count("account.inline")
+        self._settled_early = False
+
     def note_update(self, support=None):
         """Record the server update's support for download accounting.
 
@@ -1122,6 +1183,7 @@ class FedModel:
           selections; 1/32 the transfer of the dense form);
         - a dense update array: host-side ``!= 0`` compare (legacy
           form, kept for direct callers)."""
+        self.settle_update()    # updates apply in the order they came
         self._update_round += 1
         r = self._update_round
         if len(self._round_counts) < r + 2:
@@ -1327,29 +1389,31 @@ class FedOptimizer:
             # --overlap_depth > 1: the gather staged here sees the
             # post-write-back row versions, so next round's take() is
             # patch-free while the worker thread hides the gather
-            # under the delta-encode host work below
+            # under the host work between this step and the next
+            # round's gather
             m._prefetch_after_writeback = False
             m._submit_prefetch()
-        with m.telemetry.span("note_update"):
-            if support is None:
-                # dense-update modes. fedavg/momentum updates touch every
-                # coordinate; the exceptions that don't: a zero scalar LR
-                # (nothing moved) and local_topk (even with virtual
-                # momentum the update's support is only the union of past
-                # top-k selections, ~W*k coords early on — the reference
-                # value-compares weight_update != 0, so marking all
-                # grad_size coords would overcount download bytes)
-                lr_np = np.asarray(lr)
-                if (self.args.mode != "fedavg" and lr_np.ndim == 0
-                        and float(lr_np) == 0):
-                    support = (np.zeros(0, np.int64), np.zeros(0))
-                elif self.args.mode in ("local_topk", "fedavg") \
-                        or lr_np.ndim > 0:
-                    # != 0 compare, packed ON DEVICE: shipping the dense
-                    # f32 update to the host costs 4*d bytes of D2H per
-                    # round — the bitmap is 1/32 of that
-                    support = {"bitmap": jnp.packbits(update != 0)}
-            m.note_update(support)
+        if support is None:
+            # dense-update modes. fedavg/momentum updates touch every
+            # coordinate; the exceptions that don't: a zero scalar LR
+            # (nothing moved) and local_topk (even with virtual
+            # momentum the update's support is only the union of past
+            # top-k selections, ~W*k coords early on — the reference
+            # value-compares weight_update != 0, so marking all
+            # grad_size coords would overcount download bytes)
+            vector_lr = np.ndim(lr) > 0
+            if (self.args.mode != "fedavg" and not vector_lr
+                    and float(lr) == 0):
+                support = (np.zeros(0, np.int64), np.zeros(0))
+            elif self.args.mode in ("local_topk", "fedavg") \
+                    or vector_lr:
+                # != 0 compare, packed ON DEVICE: shipping the dense
+                # f32 update to the host costs 4*d bytes of D2H per
+                # round — the bitmap is 1/32 of that
+                support = {"bitmap": jnp.packbits(update != 0)}
+        # not waited for here: the next client pass applies it once its
+        # own program is dispatched (FedModel.defer_update)
+        m.defer_update(support)
         if sprobes is not None:
             # the round this server pass belongs to (round_index was
             # already advanced by _call_train)

@@ -283,6 +283,22 @@ def test_top_level_spans_and_the_uncovered_gap_partition_the_round(
         gaps += end - at
         assert covered + gaps == pytest.approx(end - begin, abs=1e-9)
         assert covered > 0.0 and gaps < end - begin
+        # the previous server update's support is settled inside the
+        # client pass, after its dispatch and before the wait for the
+        # round's metrics (PR 30): no child of server_pass, and never
+        # a top-level span (``names`` above), so the device's wait for
+        # the next dispatch is not behind it
+        i_cp, _ = _entry(rec, "client_pass")
+        notes = [e for e in rec["timeline"] if e[0] == "note_update"]
+        assert len(notes) == (0 if rec["round"] == 0 else 1)
+        for note in notes:
+            assert note[3] == i_cp
+            _, disp = _entry(rec, "round_dispatch")
+            _, wait = _entry(rec, "metrics_host")
+            assert disp[3] == wait[3] == i_cp
+            assert disp[2] <= note[1] <= note[2] <= wait[1]
+        assert rec["counters"].get("account.deferred", 0) == len(notes)
+        assert "account.inline" not in rec["counters"]
         if folds:
             # the one span the buffered path adds, under client_pass,
             # over the cohort's issue and the arrivals' dequeue
