@@ -29,12 +29,12 @@ it to ``CountSketch(backend="xla")``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.flatten_util import ravel_pytree
 
 _M1, _M2 = 0x85EBCA6B, 0xC2B2AE35
 
@@ -92,11 +92,10 @@ def _chunk_signs(spec: SketchSpec, t):
     return 1.0 - 2.0 * bit.astype(jnp.float32)
 
 
-def sketch(spec: SketchSpec, vec):
-    """(d,) -> (r, c) table: sign, rotate each chunk, add the chunks."""
-    vp = jnp.pad(vec.astype(jnp.float32),
-                 (0, spec.m * spec.c - spec.d)).reshape(spec.m, spec.c)
-    rots = jnp.asarray(spec.rotations())
+def _add_chunks(spec: SketchSpec, table, chunks, rots, ts):
+    """``table`` plus the chunks' share of the sketch: sign and rotate
+    each (c,) row of ``chunks``, add them in order. ``rots`` (n, r) and
+    ``ts`` (n,) are the chunks' rotations and numbers."""
 
     def body(acc, inp):
         x, rot_t, t = inp
@@ -104,15 +103,12 @@ def sketch(spec: SketchSpec, vec):
         rows = [jnp.roll(sx[row], rot_t[row]) for row in range(spec.r)]
         return acc + jnp.stack(rows), None
 
-    table, _ = jax.lax.scan(
-        body, jnp.zeros((spec.r, spec.c), jnp.float32),
-        (vp, rots, jnp.arange(spec.m, dtype=jnp.uint32)))
-    return table
+    return jax.lax.scan(body, table, (chunks, rots, ts))[0]
 
 
-def estimates(spec: SketchSpec, table):
-    """(r, c) table -> (d,) median-of-rows estimates."""
-    rots = jnp.asarray(spec.rotations())
+def _estimate_chunks(spec: SketchSpec, table, rots, ts):
+    """(n, c) median-of-rows estimates of the chunks numbered ``ts``
+    (n,), whose rotations are ``rots`` (n, r)."""
 
     def body(_, inp):
         rot_t, t = inp
@@ -121,8 +117,23 @@ def estimates(spec: SketchSpec, table):
                 for row in range(spec.r)]
         return None, jnp.median(jnp.stack(rows), axis=0)
 
-    _, est = jax.lax.scan(
-        body, None, (rots, jnp.arange(spec.m, dtype=jnp.uint32)))
+    return jax.lax.scan(body, None, (rots, ts))[1]
+
+
+def sketch(spec: SketchSpec, vec):
+    """(d,) -> (r, c) table: sign, rotate each chunk, add the chunks."""
+    vp = jnp.pad(vec.astype(jnp.float32),
+                 (0, spec.m * spec.c - spec.d)).reshape(spec.m, spec.c)
+    return _add_chunks(
+        spec, jnp.zeros((spec.r, spec.c), jnp.float32), vp,
+        jnp.asarray(spec.rotations()),
+        jnp.arange(spec.m, dtype=jnp.uint32))
+
+
+def estimates(spec: SketchSpec, table):
+    """(r, c) table -> (d,) median-of-rows estimates."""
+    est = _estimate_chunks(spec, table, jnp.asarray(spec.rotations()),
+                           jnp.arange(spec.m, dtype=jnp.uint32))
     return est.reshape(-1)[: spec.d]
 
 
@@ -170,12 +181,123 @@ def quantizer(name):
 
 # --- the three steps ------------------------------------------------------
 
+#: coordinates in a block of chunks: what the server step holds on the
+#: device at once beside the tables (64 MB), whatever d is
+BLOCK_COORDS = 1 << 24
+
+
 def _client_blocks(batch, block):
     W = int(np.shape(batch["mask"])[0])
     block = max(b for b in range(1, min(block, W) + 1) if W % b == 0)
     for s in range(0, W, block):
         yield {k: jnp.asarray(np.asarray(v)[s:s + block])
                for k, v in batch.items()}
+
+
+def ravel_host(tree):
+    """The tree's leaves as one float32 numpy vector, in
+    ``ravel_pytree``'s order; nothing is put on a device."""
+    return np.concatenate([np.asarray(x, np.float32).reshape(-1)
+                           for x in jax.tree_util.tree_leaves(tree)])
+
+
+def _read_back(tree, out, spans):
+    """The device tree's leaves into the host vector ``out``, leaf i at
+    ``spans[i]``."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    for x in leaves:
+        x.copy_to_host_async()
+    for x, (a, b) in zip(leaves, spans):
+        out[a:b] = np.asarray(x).reshape(-1)
+
+
+def client_step(ref, spec_model, q):
+    """The jitted ``(w, g, client block) -> (g + the block's gradient of
+    sum_i n_i * loss_i, the block's losses)``. ``w`` and ``g`` are trees
+    and ``g`` is donated, so each leaf's gradient is added where the
+    accumulator lies: the step holds d twice on the device, beside one
+    block's activations and the leaf gradients in flight."""
+
+    def block_sum(w, cb):
+        def one(b):
+            loss = ref.client_loss(w, b, spec_model, q)
+            n = jnp.sum(b["mask"])
+            return jnp.where(n > 0, loss * n, 0.0), loss
+        weighted, losses = jax.vmap(one)(cb)
+        return jnp.sum(weighted), losses
+
+    def step(w, g, cb):
+        (_, losses), gb = jax.value_and_grad(block_sum, has_aux=True)(w, cb)
+        return jax.tree_util.tree_map(jnp.add, g, gb), losses
+
+    return jax.jit(step, donate_argnums=1)
+
+
+def _blocks(sk: SketchSpec):
+    """(chunks in a block, blocks): the server step's unit of work."""
+    nb = min(sk.m, max(1, BLOCK_COORDS // sk.c))
+    return nb, -(-sk.m // nb)
+
+
+def _block_rotations(sk: SketchSpec) -> np.ndarray:
+    """(blocks, chunks in a block, r) rotations; the chunks past the
+    last are all zeros, as is what they are fed."""
+    nb, nblocks = _blocks(sk)
+    rots = np.zeros((nblocks * nb, sk.r), np.int32)
+    rots[: sk.m] = sk.rotations()
+    return rots.reshape(nblocks, nb, sk.r)
+
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+def _add_block(sk, table, chunks, rots, t0):
+    ts = t0 + jnp.arange(chunks.shape[0], dtype=jnp.uint32)
+    return _add_chunks(sk, table, chunks, rots, ts)
+
+
+def _select(sk: SketchSpec, v, k):
+    """The k largest ``|estimates(v)|`` as (indices, estimates), in
+    ``jax.lax.top_k``'s order over all d (larger first, the lower index
+    first among equals), with one block of estimates on the device at a
+    time: each block's own top k, merged into the k kept so far. The
+    kept ones lie at lower indices than the block's and both lists are
+    in that order, so the merge, which prefers the earlier position
+    among equals, keeps it. Exact."""
+    nb, nblocks = _blocks(sk)
+    span = nb * sk.c
+    if nblocks * span >= 2 ** 31:
+        raise ValueError(f"d = {sk.d}: the selection's indices are int32")
+
+    def body(kept, inp):
+        mag, idx, val = kept
+        rot_b, b = inp
+        est = _estimate_chunks(
+            sk, v, rot_b, b * jnp.uint32(nb)
+            + jnp.arange(nb, dtype=jnp.uint32)).reshape(-1)
+        base = b.astype(jnp.int32) * span
+        inside = base + jnp.arange(span, dtype=jnp.int32) < sk.d
+        bmag, at = jax.lax.top_k(
+            jnp.where(inside, jnp.abs(est), -1.0), min(k, span))
+        mag, pick = jax.lax.top_k(jnp.concatenate([mag, bmag]), k)
+        return (mag, jnp.concatenate([idx, base + at])[pick],
+                jnp.concatenate([val, est[at]])[pick]), None
+
+    start = (jnp.full((k,), -1.0), jnp.zeros((k,), jnp.int32),
+             jnp.zeros((k,), jnp.float32))
+    (_, idx, val), _ = jax.lax.scan(
+        body, start, (jnp.asarray(_block_rotations(sk)),
+                      jnp.arange(nblocks, dtype=jnp.uint32)))
+    return idx, val
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _server(sk, k, rho, u, v, table):
+    """Momentum and error feedback in table space, the selection, and
+    the selected coordinates' buckets zeroed in both tables."""
+    u = rho * u + table
+    v = v + u
+    idx, vals = _select(sk, v, k)
+    keep = sketch_sparse(sk, idx, vals) == 0
+    return jnp.where(keep, u, 0.0), jnp.where(keep, v, 0.0), idx, vals
 
 
 def follow(ref, spec_model, params, batches, lrs, hyper,
@@ -186,56 +308,75 @@ def follow(ref, spec_model, params, batches, lrs, hyper,
     one client's masked-mean loss. ``hyper``: k, rho (virtual momentum),
     weight_decay. Returns per-round per-client losses, the first
     round's table, and the flat weight change after the last round.
+
+    The flat vectors (the weights at the start and now, the round's
+    gradient) are the host's, in numpy. The device holds d twice: the
+    weights as the tree ``client_loss`` takes, and the tree the
+    clients' gradients are added into, which is read back once a round.
+    The server step works in table space on blocks of chunks: the
+    sketch is fed from the host, the estimates are made and selected
+    from block by block, and the k selected coordinates step the host's
+    weights, whose changed leaves are put on the device again.
     """
     q = quantizer(precision)
-    flat0, unravel = ravel_pytree(params)
-    flat0 = flat0.astype(jnp.float32)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    shapes = [np.shape(x) for x in leaves]
+    sizes = [int(np.prod(s)) for s in shapes]
+    ends = np.cumsum(sizes)
+    spans = list(zip(ends - sizes, ends))
+    flat0 = ravel_host(leaves)
+    del leaves, params
+    flat = flat0.copy()
+    if flat.size != sk.d:
+        raise ValueError(f"{flat.size} weights for a sketch of d = {sk.d}")
     k = min(int(hyper["k"]), sk.d)
+    rho = float(hyper["rho"])
+    decay = np.float32(hyper["weight_decay"] / hyper["num_workers"])
     block = int(getattr(ref, "CLIENTS_PER_BLOCK", 1))
+    nb, nblocks = _blocks(sk)
+    span = nb * sk.c
+    rots = _block_rotations(sk)
 
-    def block_sum(flat, cb):
-        def one(b):
-            loss = ref.client_loss(unravel(flat), b, spec_model, q)
-            n = jnp.sum(b["mask"])
-            return jnp.where(n > 0, loss * n, 0.0), loss
-        weighted, losses = jax.vmap(one)(cb)
-        return jnp.sum(weighted), losses
+    def put(i):
+        (a, b), shape = spans[i], shapes[i]
+        return jax.device_put(flat[a:b].reshape(shape))
 
-    grad_block = jax.jit(jax.value_and_grad(block_sum, has_aux=True))
-
-    @jax.jit
-    def server(flat, u, v, g, lr):
-        table = sketch(sk, g)
-        u = hyper["rho"] * u + table
-        v = v + u
-        est = estimates(sk, v)
-        _, idx = jax.lax.top_k(jnp.abs(est), k)
-        vals = est[idx]
-        keep = sketch_sparse(sk, idx, vals) == 0
-        u = jnp.where(keep, u, 0.0)
-        v = jnp.where(keep, v, 0.0)
-        return flat.at[idx].add(-lr * vals), u, v, table
-
+    step = client_step(ref, spec_model, q)
+    zeros = jax.jit(lambda: treedef.unflatten(
+        [jnp.zeros(s, jnp.float32) for s in shapes]))
+    w = [put(i) for i in range(len(shapes))]
+    grad = np.empty_like(flat)
     out = {"losses": [], "table0": None}
-    flat = flat0
     u = v = jnp.zeros((sk.r, sk.c), jnp.float32)
     with jax.default_matmul_precision("highest"):
         for t, batch in enumerate(batches):
-            total = float(np.asarray(batch["mask"]).sum())
-            g = jnp.zeros_like(flat)
-            losses = []
+            total = np.float32(max(float(np.asarray(batch["mask"]).sum()),
+                                   1.0))
+            g, losses = zeros(), []
             for cb in _client_blocks(batch, block):
-                (_, ls), gb = grad_block(flat, cb)
-                g = g + gb
-                losses.append(np.asarray(ls))
-            g = g / max(total, 1.0) + (
-                hyper["weight_decay"] / hyper["num_workers"]) * flat
-            flat, u, v, table = server(flat, u, v, g,
-                                       jnp.float32(lrs[t]))
-            out["losses"].append(np.concatenate(losses))
+                g, ls = step(treedef.unflatten(w), g, cb)
+                losses.append(ls)
+            _read_back(g, grad, spans)
+            del g
+            table = jnp.zeros((sk.r, sk.c), jnp.float32)
+            for i in range(nblocks):
+                a, b = i * span, min((i + 1) * span, sk.d)
+                chunks = np.zeros((span,), np.float32)
+                chunks[: b - a] = grad[a:b] / total + decay * flat[a:b]
+                table = _add_block(sk, table, chunks.reshape(nb, sk.c),
+                                   rots[i], np.uint32(i * nb))
             if t == 0:
                 out["table0"] = np.asarray(table)
-    out["delta"] = np.asarray(flat - flat0)
+            u, v, idx, vals = _server(sk, k, rho, u, v, table)
+            idx, vals = np.asarray(idx), np.asarray(vals)
+            flat[idx] += -np.float32(lrs[t]) * vals
+            if t + 1 < len(batches):
+                for i in np.unique(np.searchsorted(ends, idx, side="right")):
+                    w[i] = put(i)
+            out["losses"].append(np.concatenate(
+                [np.asarray(ls) for ls in losses]))
+    flat -= flat0
+    out["delta"] = flat
     return out
 
 

@@ -89,6 +89,29 @@ def applies(entry, cell_name):
     return "workloads" not in entry or cell_name in entry["workloads"]
 
 
+def device_use(devices):
+    """The arrays this process has alive on its devices, and the
+    allocator's ``bytes_in_use`` where the backend reports it."""
+    import jax
+    live = jax.live_arrays()
+    return {"arrays": len(live), "array_bytes": sum(x.nbytes for x in live),
+            "bytes_in_use": max((d.memory_stats() or {}).get(
+                "bytes_in_use", 0) for d in devices)}
+
+
+def release(devices):
+    """Delete every array this process has alive on its devices and let
+    go of every compiled program; what is in use after that."""
+    import gc
+
+    import jax
+    for x in jax.live_arrays():
+        x.delete()
+    gc.collect()
+    jax.clear_caches()
+    return device_use(devices)
+
+
 def main(argv=None, fault=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -134,7 +157,6 @@ def main(argv=None, fault=None):
     from benchmark.lib import fetchsgd_ref as fr
     from benchmark.lib.peaks import peaks_of
     from jax import monitoring
-    from jax.flatten_util import ravel_pytree
     from commefficient_tpu.telemetry import trace as markers
 
     # programs built (or fetched from the persistent cache) so far; a
@@ -185,9 +207,9 @@ def main(argv=None, fault=None):
         for i in range(WARMUP_ROUNDS):
             one_round(keep=True)
             if i == 0:
-                kept["table0"] = np.asarray(run.last_aggregate)
+                kept["table0"] = np.array(run.last_aggregate)
         jax.block_until_ready(model.ps_weights)
-        kept["w_after"] = np.asarray(model.ps_weights)
+        kept["w_after"] = np.array(model.ps_weights)
         run.last_aggregate = None
         setup_s = time.perf_counter() - _T0
 
@@ -239,8 +261,6 @@ def main(argv=None, fault=None):
         # rounds up to the mark, outside the window, where it was short
         while len(rounds) < cell["mark_round"]:
             one_round()
-        if sink is not None:
-            tel.close()
         step = max(1, len(rounds) // 12)
         print("loss by round:", ", ".join(
             f"{i}: {sum(r['loss'] for r in rounds[i:i + step]) / len(rounds[i:i + step]):.4f}"
@@ -249,18 +269,36 @@ def main(argv=None, fault=None):
               f"clients in {win['t_close'] - t_start:.3f} s; "
               f"{len(rounds)} rounds in all; set-up {setup_s:.2f} s")
 
-        # ---- correct: against the plain float32 reference --------------
+        # ---- the program leaves the chip --------------------------------
+        # What the comparison reads (``kept``) is the host's already.
+        # The rest is taken from the live program now; then its loader's
+        # thread (which stages round r+1 on the device) and the model
+        # are shut down as the trainer does, every array the process has
+        # on the device is deleted, and the program's objects and
+        # compiled programs are let go. ``run`` keeps ``ref_spec`` and
+        # ``args``, host values, which is all the readers ask it for.
         t_check = time.perf_counter()
         sk = fr.SketchSpec(**run.sketch_spec())
-        params0 = run.make_params()
+        hyper = run.hyper()
+        eng = None if a.rehearse else run.engagement()
+        run.loader.close()
+        model.finalize()
+        held = device_use(devices[:n_dev])
+        run.model = run.opt = run.lr_scheduler = run.loader = None
+        model = tel = feed = None
+        print("released:", json.dumps(
+            {"before": held, "after": release(devices[:n_dev])}))
+
+        # ---- correct: against the plain float32 reference --------------
+        params0 = jax.tree_util.tree_map(np.asarray, run.make_params())
         leaf_sizes = [int(np.prod(x.shape))
                       for x in jax.tree_util.tree_leaves(params0)]
-        flat0 = np.asarray(ravel_pytree(params0)[0], np.float32)
         observed = {"losses": kept["losses"], "table0": kept["table0"],
-                    "delta": kept["w_after"] - flat0}
+                    "delta": kept.pop("w_after")}
+        observed["delta"] -= fr.ravel_host(params0)
         follow = dict(ref=ref, spec_model=run.ref_spec, params=params0,
                       batches=kept["batches"], lrs=kept["lrs"],
-                      hyper=run.hyper(), sk=sk)
+                      hyper=hyper, sk=sk)
         want = fr.follow(**follow)
         nums = fr.numbers(observed, want, leaf_sizes)
         print("check detail:", json.dumps(fr.detail(observed, want)))
@@ -269,8 +307,7 @@ def main(argv=None, fault=None):
             print(f"correct: {name} = {value:.6g} (limit {limit:g}) "
                   f"{'ok' if ok else 'FAILS'}")
         correct = all(ok for *_, ok in rows)
-        if not a.rehearse:
-            eng = run.engagement()
+        if eng is not None:
             print("engagement:", json.dumps(eng))
             engaged = (eng["sketch_backend"] == "pallas"
                        and eng["client_custom_calls"] >= 1
