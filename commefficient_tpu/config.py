@@ -879,7 +879,13 @@ def build_parser(default_lr: Optional[float] = None,
         from commefficient_tpu import models
         model_names = models.model_names()
     parser.add_argument("--model", default="ResNet9",
-                        choices=model_names or None)
+                        choices=model_names or None,
+                        help="cv_train: a CV model; gpt2_train: "
+                        "GPT2DoubleHeads on PERSONA (any other value), or "
+                        "a causal LM on --dataset_name TOKENS: "
+                        "JoyAIFlashLM, NemotronHLM (architecture from "
+                        "config.json in --model_checkpoint, whose "
+                        "model_type must be the model's)")
     parser.add_argument("--finetune", action="store_true", dest="do_finetune")
     parser.add_argument("--checkpoint", action="store_true",
                         dest="do_checkpoint")

@@ -8,28 +8,13 @@ The block is DeepSeek-V3's (arXiv:2412.19437) as JoyAI-LLM-Flash's
 ``models/joyai_reference.py``, the plain float32 reference this module
 is tested against. What is TPU-shaped here:
 
-- **The expert layer is the expert-parallel layer on one chip.** It is
-  told which experts it holds (``n_held_experts`` from
-  ``expert_offset``), routes over all ``n_router_experts`` in float32,
-  and computes its own experts' part: the (token, expert) assignments
-  that land here are sorted by expert and taken a buffer of ``tokens``
-  rows at a time (static: a round program has no dynamic shape): a
-  gather, three ragged products (``jax.lax.ragged_dot``: XLA's tiled
-  TPU kernel, whose cost follows the rows, not rows x experts) and a
-  scatter-add under the gates. With the experts of one chip of 32 a
-  token lands here 0.25 times in expectation, so one pass at a quarter
-  full is the rule; a routing that sends the average token to more than
-  one held expert takes a second pass (a loop of dynamic length), so no
-  assignment is ever left out (``moe.dropped`` counts what the passes
-  did not reach: 0). No exchange, and nothing stands in for the absent
-  chips.
-- **Under the clients ``vmap``** (``core/rounds.py make_local_loss``)
-  ``ragged_dot`` has no batching rule for an unbatched weight, and a
-  batched one would copy the experts per client: ``routed_experts``
-  carries its own VJP and runs once per client
-  (``jax.custom_batching.sequential_vmap``), which is also what lets
-  its loop have a length of its own per client; the weights stay shared
-  and their gradient is summed by the transformation as for any layer.
+- **The expert layer is the expert-parallel layer on one chip**
+  (``models/moe.py``, shared with ``models/nemotron_h.py``): it is told
+  which experts it holds (``n_held_experts`` from ``expert_offset``),
+  routes over all ``n_router_experts`` in float32 and computes its own
+  experts' part with ``routed_experts``, three ragged products a pass.
+  With the experts of one chip of 32 a token lands here 0.25 times in
+  expectation, so one pass at a quarter full is the rule.
 - bf16 compute on float32 parameters as ``models/gpt2.py``: norms,
   RoPE, softmax, router scores and selection in float32.
 
@@ -49,16 +34,13 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.custom_batching import sequential_vmap
 
 from commefficient_tpu.models import register_model
-
-#: a client's routing counts, which ``causal_lm_loss`` returns beside
-#: the loss and ``train/gpt2_train.py`` turns into the round's ``moe.*``
-#: counters: (token, expert) assignments to experts held here over all
-#: expert layers; the fullest (layer, expert)'s; the mean over (layer,
-#: expert); assignments no pass of ``routed_experts`` reached (0)
-MOE_STATS = ("assignments_here", "load_max", "load_mean", "dropped")
+from commefficient_tpu.models.norms import RMSNorm
+from commefficient_tpu.models.moe import MOE_COUNTERS as COUNTERS  # noqa: F401
+from commefficient_tpu.models.moe import MOE_STATS  # noqa: F401
+from commefficient_tpu.models.moe import (dispatch, fold_stats, layer_stats,
+                                          route, routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,165 +107,10 @@ class JoyAIConfig:
         return spec
 
 
-# --- the held experts' products -------------------------------------------
-
-def _ragged(x, w, sizes):
-    """(M, K) rows sorted by group, (G, K, N) float32, (G,) -> (M, N)
-    float32, computed in ``x``'s dtype. Rows past the groups are zero on
-    the CPU and whatever the buffer held on the TPU: mask them."""
-    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
-                              preferred_element_type=jnp.float32)
-
-
-def _ragged_outer(x, dy, sizes):
-    """(M, K), (M, N), (G,) -> (G, K, N) float32: each group's x^T dy."""
-    dims = jax.lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-    return jax.lax.ragged_dot_general(
-        x, dy, sizes, dims, preferred_element_type=jnp.float32)
-
-
-def _pass(p, x, token, gate, load):
-    """Pass ``p`` of the sorted assignments: rows [pN, (p+1)N). Returns
-    the rows' tokens, gates, validity, each expert's share of the rows
-    and the gathered inputs."""
-    N = x.shape[0]
-    with jax.named_scope("moe_route"):
-        lo = p * N
-        rows = jax.lax.dynamic_slice_in_dim(token, lo, N)
-        g = jax.lax.dynamic_slice_in_dim(gate, lo, N)
-        ends = jnp.cumsum(load)
-        sizes = (jnp.clip(ends, lo, lo + N)
-                 - jnp.clip(ends - load, lo, lo + N)).astype(jnp.int32)
-        valid = ((lo + jnp.arange(N)) < ends[-1])[:, None]
-        xg = x[rows]
-    return rows, g, valid, sizes, xg
-
-
-def _expert_ffn(xg, valid, sizes, wg, wu, wd):
-    # the TPU kernel leaves the rows past the groups unwritten: every
-    # ragged product is masked before anything reads it
-    a = jnp.where(valid, _ragged(xg, wg, sizes), 0.0)
-    b = jnp.where(valid, _ragged(xg, wu, sizes), 0.0)
-    h = (jax.nn.silu(a) * b).astype(xg.dtype)
-    return a, b, h, jnp.where(valid, _ragged(h, wd, sizes), 0.0)
-
-
-def _zeros(shape, load):
-    """float32 zeros to carry through a pass loop: derived from the
-    client's ``load`` and not ``jnp.zeros``, so that inside a
-    ``shard_map`` over clients they vary over the mesh axis as the
-    loop's results do (the scan carry-type check; cf. models/gpt2.py
-    ``lm_nll_sums_chunked``)."""
-    return jnp.zeros(shape, jnp.float32) \
-        + (load[0] * 0).astype(jnp.float32)
-
-
-def _passes(load, N):
-    return (jnp.sum(load) + N - 1) // N
-
-
-@sequential_vmap
-def _routed_fwd(x, token, gate, load, wg, wu, wd):
-    N, C = x.shape
-
-    def body(p, y):
-        rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
-        with jax.named_scope("moe_experts"):
-            o = _expert_ffn(xg, valid, sizes, wg, wu, wd)[3]
-        with jax.named_scope("moe_combine"):
-            return y.at[rows].add(o * g[:, None])
-
-    return jax.lax.fori_loop(0, _passes(load, N), body,
-                             _zeros((N, C), load))
-
-
-@sequential_vmap
-def _routed_bwd(x, token, gate, load, wg, wu, wd, dy):
-    N, C = x.shape
-    dt = x.dtype
-
-    def body(p, carry):
-        dx, dgate, dwg, dwu, dwd = carry
-        rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
-        with jax.named_scope("moe_experts"):
-            a, b, h, o = _expert_ffn(xg, valid, sizes, wg, wu, wd)
-        with jax.named_scope("moe_combine"):
-            dyg = jnp.where(valid, dy[rows], 0.0)
-            dg = jnp.sum(dyg * o, axis=-1)
-            do = (dyg * g[:, None]).astype(dt)
-        with jax.named_scope("moe_experts"):
-            dh = jnp.where(
-                valid, _ragged(do, jnp.swapaxes(wd, 1, 2), sizes), 0.0)
-            sa = jax.nn.sigmoid(a)
-            da = (dh * b * sa * (1.0 + a * (1.0 - sa))).astype(dt)
-            db = (dh * a * sa).astype(dt)
-            dxg = jnp.where(
-                valid, _ragged(da, jnp.swapaxes(wg, 1, 2), sizes)
-                + _ragged(db, jnp.swapaxes(wu, 1, 2), sizes), 0.0)
-            dwg = dwg + _ragged_outer(xg, da, sizes)
-            dwu = dwu + _ragged_outer(xg, db, sizes)
-            dwd = dwd + _ragged_outer(h, do, sizes)
-        with jax.named_scope("moe_route"):
-            dx = dx.at[rows].add(dxg)
-            dgate = jax.lax.dynamic_update_slice_in_dim(
-                dgate, dg, p * N, axis=0)
-        return dx, dgate, dwg, dwu, dwd
-
-    dx, dgate, dwg, dwu, dwd = jax.lax.fori_loop(
-        0, _passes(load, N), body,
-        tuple(_zeros(a.shape, load) for a in (x, gate, wg, wu, wd)))
-    return dx.astype(dt), dgate, dwg, dwu, dwd
-
-
-@jax.custom_vjp
-def routed_experts(x, token, gate, load, wg, wu, wd):
-    """What the experts held here add to each token, float32 (N, C).
-
-    ``x`` (N, C) in the compute dtype; ``token`` / ``gate`` (A_max,):
-    the token and the gate of every (token, expert) assignment, those
-    to held experts first and sorted by expert; ``load`` (E,): how many
-    each held expert has; ``wg``, ``wu`` (E, C, F), ``wd`` (E, F, C)
-    float32. The assignments are taken N rows a pass, as many passes
-    as the load needs (one, unless the average token picks more than
-    one expert held here), each pass three ragged products: every
-    assignment is computed whatever the routing, at a cost that
-    follows the load. Carries its own VJP (no reverse mode runs
-    through a loop of dynamic length) and recomputes the pass's
-    activations there. Under ``vmap`` it runs once per batch element
-    with the weights shared, and their gradient is summed over the
-    batch in float32."""
-    return _routed_fwd(x, token, gate, load, wg, wu, wd)
-
-
-def _routed_vjp_fwd(*args):
-    return _routed_fwd(*args), args
-
-
-def _routed_vjp_bwd(res, dy):
-    dx, dgate, dwg, dwu, dwd = _routed_bwd(*res, dy)
-    return dx, None, dgate, None, dwg, dwu, dwd
-
-
-routed_experts.defvjp(_routed_vjp_fwd, _routed_vjp_bwd)
-
-
 # --- layers ---------------------------------------------------------------
 
 def _init(cfg):
     return nn.initializers.normal(stddev=cfg.initializer_range)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x = x.astype(jnp.float32)
-        return x * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
 def rope(x, theta):
@@ -383,33 +210,17 @@ class ExpertLayer(_Weights):
         bias = self.mat("router_bias", (cfg.n_router_experts,))
         gate_w, up_w, down_w = _Experts(cfg, name="experts")()
         with jax.named_scope("moe_route"):
-            s = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST))
-            _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
-            sel = jnp.take_along_axis(s, top, axis=-1)
-            if cfg.norm_topk_prob:
-                sel = sel / (jnp.sum(sel, -1, keepdims=True) + 1e-20)
-            g = cfg.routed_scaling_factor * sel               # (N, k)
+            top, g = route(x, router, bias, k, cfg.routed_scaling_factor,
+                           cfg.norm_topk_prob)                # (N, k)
             self.sow("intermediates", "top", top)
-            # every assignment, those to experts held here first and
-            # sorted by expert
-            local = (top - cfg.expert_offset).reshape(-1)     # (N*k,)
-            key = jnp.where((local >= 0) & (local < E), local, E)
-            order = jnp.argsort(key, stable=True)
-            load = jnp.sum(jax.nn.one_hot(key, E + 1, dtype=jnp.int32),
-                           axis=0)[:E]                        # (E,)
-        routed = routed_experts(x.astype(dt), order // k,
-                                g.reshape(-1)[order], load,
-                                gate_w, up_w, down_w)
+            token, gate, load = dispatch(top, g, cfg.expert_offset, E)
+        routed = routed_experts(x.astype(dt), token, gate, load,
+                                (gate_w, up_w, down_w), "swiglu")
         shared = SwiGLU(cfg, cfg.moe_intermediate_size
                         * cfg.n_shared_experts, name="shared")(x)
         with jax.named_scope("moe_combine"):
             y = (routed + shared.astype(jnp.float32)).astype(dt)
-        total = jnp.sum(load)
-        done = jnp.minimum(total, _passes(load, N) * N)
-        stats = jnp.stack([total, jnp.max(load),
-                           total - done]).astype(jnp.float32)
+        stats = layer_stats(load, N)
         return y.reshape(shape), stats
 
 
@@ -457,6 +268,10 @@ class JoyAIFlashLM(nn.Module):
     logits tensor exists."""
     cfg: JoyAIConfig = JoyAIConfig()
 
+    #: ``config.json``'s ``model_type`` and its reader, for the trainer
+    model_type = "joyai_llm_flash"
+    config_class = JoyAIConfig
+
     @nn.compact
     def __call__(self, input_ids):
         cfg, dt = self.cfg, self.cfg.dtype
@@ -470,7 +285,7 @@ class JoyAIFlashLM(nn.Module):
         for i in range(cfg.num_hidden_layers):
             h, s = block_cls(cfg, moe=i >= cfg.first_k_dense_replace,
                              name=f"layer_{i}")(h)
-            stats = _fold(stats, s)
+            stats = fold_stats(stats, s)
         final = RMSNorm(cfg.rms_norm_eps, name="norm")(h)
         mtp = None
         for i in range(cfg.num_nextn_predict_layers):
@@ -480,14 +295,8 @@ class JoyAIFlashLM(nn.Module):
             with jax.named_scope("mtp"):
                 nxt = embed[jnp.roll(input_ids, -1, axis=1)].astype(dt)
                 mtp, s = MTPModule(cfg, name=f"mtp_{i}")(h, nxt, block_cls)
-            stats = _fold(stats, s)
+            stats = fold_stats(stats, s)
         return final, mtp, head, stats
-
-
-def _fold(total, layer):
-    """Sum assignments and drops over layers, keep the fullest expert."""
-    return jnp.stack([total[0] + layer[0], jnp.maximum(total[1], layer[1]),
-                      total[2] + layer[2]])
 
 
 def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):

@@ -1,0 +1,429 @@
+"""One chip's share of a Nemotron-H layer stack (``model_type``
+``nemotron_h``: NVIDIA-Nemotron-3-Super's ``config.json``), in flax:
+blocks that are one mixer each, laid out by a pattern string, ``M`` a
+Mamba-2 state-space mixer, ``*`` grouped-query attention with no
+positional embedding, ``E`` a LatentMoE feed-forward part; untied
+embedding and head over a vocabulary slice.
+
+    block:   x += mixer(RMSNorm(x)),  eps 1e-5; final RMSNorm
+    M:       [z | xBC | dt] = x W_in;  xBC = silu(conv4(xBC) + b)
+             [x | B | C] = xBC;  delta = softplus(dt + dt_bias)
+             S_t = exp(delta_t A) S_{t-1} + delta_t x_t B_t^T   (a head)
+             y_t = S_t C_t + D x_t;   A = -exp(A_log)
+             out = W_out GroupRMSNorm(y * silu(z))
+    *:       softmax_causal(q k^T / sqrt(128)) v, the query heads of a
+             group sharing its key/value head
+    E:       s = sigmoid(x W_r) over all the router's experts; the 22
+             largest of s + b; g = 5 s / sum of the chosen s
+             u = x W_down;  y = W_up sum_e g_e W2_e relu(W1_e u)^2
+                              + W2_s relu(W1_s x)^2
+
+The equations are restated, with the recurrence taken one position at
+a time, in ``benchmark/reference/nemotron3-super-ep64-tp8.py``, the
+plain float32 reference this module is tested against
+(``tests/test_nemotron_h.py``). What is TPU-shaped here:
+
+- **The recurrence is computed by chunks** (SSD, Dao & Gu
+  arXiv:2405.21060, chunks of ``chunk_size`` positions): inside a chunk
+  the masked product ``(C B^T * L) X`` with ``L = exp(segsum(delta A))``,
+  three matrix products a head on (chunk, chunk) tiles; between chunks
+  the state, carried by a ``lax.scan`` over the chunks. It is
+  differentiated by plain reverse mode under the clients ``vmap``
+  (``core/rounds.py make_local_loss``) and ``--remat``. delta, A, the
+  cumulative sums and the exponentials are float32; the products take
+  operands in the compute dtype and accumulate in float32.
+- **The share.** ``mamba_num_heads`` heads in ``n_groups`` groups,
+  ``num_attention_heads`` query and ``num_key_value_heads`` key/value
+  heads, ``n_held_experts`` experts from ``expert_offset`` and
+  ``vocab_size`` rows are what this chip holds of a layer; every width,
+  the router's outputs and its picks a token are the published ones. A
+  group's norm, B and C need the group's heads together, so a Mamba
+  share is whole groups. The expert layer is ``models/moe.py``'s, the
+  code ``models/joyai.py`` routes with. No exchange, and nothing stands
+  in for the absent chips.
+
+Scopes (``PERF.md`` section 3): ``ssm_mixer`` (the whole Mamba-2
+mixer) > ``ssm_scan`` (decay sums, the in-chunk products, the state
+scan; conv, gate-norm and projections outside it); ``gqa_attn``
+(scores, softmax, value product); ``moe_route``, ``moe_experts`` (the
+latent projections inside it), ``moe_combine``; the head's ``lm_head``
+is ``lm_nll_sums_chunked``'s.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from commefficient_tpu.models import register_model
+from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS, dispatch,
+                                          fold_stats, layer_stats, route,
+                                          routed_experts)
+from commefficient_tpu.models.norms import RMSNorm
+
+#: a client's counts, which ``causal_lm_loss`` returns beside the loss:
+#: ``models/moe.py``'s four, and the chunks its Mamba-2 mixers scanned
+#: (sequences x chunks a sequence x ``M`` layers)
+STATS = MOE_STATS + ("ssm_chunks",)
+
+#: how ``FedModel`` folds them into the round record's counters
+COUNTERS = MOE_COUNTERS + (("ssm.chunks", np.sum),)
+
+#: the 88 published layers
+PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*E"
+                     "MEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072          # rows held of embedding and head
+    hidden_size: int = 4096
+    hybrid_override_pattern: str = PUBLISHED_PATTERN
+    mamba_num_heads: int = 128        # heads held
+    mamba_head_dim: int = 64
+    n_groups: int = 8                 # groups held (whole ones)
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    num_attention_heads: int = 32     # query heads held
+    num_key_value_heads: int = 2      # key/value heads held
+    head_dim: int = 128
+    n_router_experts: int = 512       # the router's published width
+    n_held_experts: int = 512         # experts whose weights are here
+    expert_offset: int = 0            # id of the first of them
+    num_experts_per_tok: int = 22
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 5.0
+    layer_norm_epsilon: float = 1e-5
+    initializer_range: float = 0.02
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 0.0001
+    dtype: Any = jnp.float32
+    remat: bool = False
+
+    @staticmethod
+    def tiny() -> "NemotronHConfig":
+        """Test-scale: one whole period, every mechanism, nothing wide."""
+        return NemotronHConfig(
+            vocab_size=96, hidden_size=32,
+            hybrid_override_pattern="MEMEMEMEM*E", mamba_num_heads=2,
+            mamba_head_dim=8, n_groups=1, ssm_state_size=8, chunk_size=8,
+            num_attention_heads=2, num_key_value_heads=1, head_dim=8,
+            n_router_experts=64, n_held_experts=4, expert_offset=8,
+            num_experts_per_tok=6, moe_intermediate_size=24,
+            moe_latent_size=16, moe_shared_expert_intermediate_size=40)
+
+    @staticmethod
+    def from_hf(blob: dict) -> "NemotronHConfig":
+        """From a ``config.json`` of the cut: the published keys, with
+        ``n_routed_experts`` the experts held, ``router_experts`` the
+        router's width (default: the same) and ``expert_offset``."""
+        fields = {f.name for f in dataclasses.fields(NemotronHConfig)}
+        kw = {k: v for k, v in blob.items() if k in fields}
+        held = int(blob.get("n_routed_experts", 512))
+        kw.update(n_held_experts=held,
+                  n_router_experts=int(blob.get("router_experts", held)))
+        kw.pop("dtype", None)
+        cfg = NemotronHConfig(**kw)
+        layers = blob.get("num_hidden_layers",
+                          len(cfg.hybrid_override_pattern))
+        if layers != len(cfg.hybrid_override_pattern):
+            raise ValueError(
+                f"num_hidden_layers {layers} is not the length of "
+                f"hybrid_override_pattern {cfg.hybrid_override_pattern!r}")
+        return cfg
+
+    def reference_spec(self) -> dict:
+        """The same sizes under the keys the plain reference reads."""
+        spec = {f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self)
+                if f.name not in ("dtype", "remat", "n_router_experts",
+                                  "n_held_experts")}
+        spec.update(n_routed_experts=self.n_held_experts,
+                    router_experts=self.n_router_experts,
+                    num_hidden_layers=len(self.hybrid_override_pattern))
+        return spec
+
+    def count(self, kind: str) -> int:
+        return self.hybrid_override_pattern.count(kind)
+
+
+# --- the Mamba-2 recurrence, by chunks --------------------------------------
+
+def ssd_chunked(x, delta, A, B, C, chunk, dtype):
+    """``y_t = S_t C_t`` with ``S_t = exp(delta_t A) S_{t-1} + delta_t
+    x_t B_t^T`` and ``S_{-1} = 0``, a head at a time, by chunks.
+
+    ``x`` (S, T, H, P); ``delta`` (S, T, H) float32; ``A`` (H,) float32,
+    negative; ``B``, ``C`` (S, T, G, N), the H / G heads of a group
+    sharing them. Returns ((S, T, H, P) float32, chunks scanned). T is
+    padded to whole chunks with delta = 0, which leaves the state as it
+    is and adds nothing. Heads are a batch axis and (chunk, chunk),
+    (chunk, P), (chunk, N) the tiles, so every product is the MXU's."""
+    S, T, H, P = x.shape
+    G, N = B.shape[-2:]
+    Q, hg = int(chunk), H // G
+    nc = -(-T // Q)
+    pad = nc * Q - T
+
+    def chunks(v):                      # (S, T, n, ...) -> (S, nc, n, Q, ...)
+        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        return jnp.moveaxis(v.reshape((S, nc, Q) + v.shape[2:]), 2, 3)
+
+    a = chunks(delta * A)                                  # (S, nc, H, Q)
+    xd = chunks((x.astype(jnp.float32) * delta[..., None]).astype(dtype))
+    xd = xd.reshape(S, nc, G, hg, Q, P)
+    Bc, Cc = chunks(B.astype(dtype)), chunks(C.astype(dtype))
+    cs = jnp.cumsum(a, axis=-1)                            # float32
+    # in a chunk: L[t, u] = exp(sum_{u < v <= t} a_v) for u <= t
+    seg = cs[..., :, None] - cs[..., None, :]
+    L = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg, -jnp.inf))
+    cb = jnp.einsum("scgtn,scgun->scgtu", Cc, Bc,
+                    preferred_element_type=jnp.float32)
+    m = (cb[:, :, :, None] * L.reshape(S, nc, G, hg, Q, Q)).astype(dtype)
+    y = jnp.einsum("scghtu,scghup->scghtp", m, xd,
+                   preferred_element_type=jnp.float32)
+    # what a chunk adds to the state by its end, and its whole decay
+    to_end = jnp.exp(cs[..., -1:] - cs).reshape(S, nc, G, hg, Q, 1)
+    added = jnp.einsum("scgun,scghup->scghpn", Bc,
+                       (xd * to_end).astype(dtype),
+                       preferred_element_type=jnp.float32)
+    decay = jnp.exp(cs[..., -1]).reshape(S, nc, G, hg)
+
+    def step(state, inp):
+        add, dec = inp
+        return state * dec[..., None, None] + add, state
+
+    _, entering = jax.lax.scan(
+        step, added[:, 0] * 0.0,
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(decay, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)           # (S, nc, G, hg, P, N)
+    # what the state a chunk entered with gives each of its positions
+    y = y + jnp.einsum("scgtn,scghpn->scghtp", Cc, entering.astype(dtype),
+                       preferred_element_type=jnp.float32) \
+        * jnp.exp(cs).reshape(S, nc, G, hg, Q, 1)
+    y = jnp.moveaxis(y.reshape(S, nc, H, Q, P), 2, 3)
+    return y.reshape(S, nc * Q, H, P)[:, :T], S * nc
+
+
+# --- layers ---------------------------------------------------------------
+
+class _Weights(nn.Module):
+    """Declares matrices under the reference's names; no ``Dense``:
+    there are no biases, and several are used as stacks."""
+    cfg: NemotronHConfig
+
+    def mat(self, name, shape):
+        return self.param(name, nn.initializers.normal(
+            stddev=self.cfg.initializer_range), shape)
+
+
+def _dt_bias_init(cfg):
+    """Mamba-2's: delta = exp(U(log min, log max)) floored, through the
+    inverse of softplus."""
+    def init(key, shape):
+        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+        d = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, lo, hi)), cfg.time_step_floor)
+        return d + jnp.log(-jnp.expm1(-d))
+    return init
+
+
+def _uniform(lo, hi, fn=lambda v: v):
+    return lambda key, shape: fn(jax.random.uniform(
+        key, shape, jnp.float32, lo, hi))
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+class Mamba2Mixer(_Weights):
+    """``(y, chunks scanned)``."""
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        S, T, C = x.shape
+        H, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
+        N, K = cfg.ssm_state_size, cfg.conv_kernel
+        inner, bc = H * P, G * N
+        in_proj = self.mat("in_proj", (C, 2 * inner + 2 * bc + H))
+        bound = K ** -0.5           # the conv keeps PyTorch's default
+        conv_w = self.param("conv_w", _uniform(-bound, bound),
+                            (K, inner + 2 * bc))
+        conv_b = self.param("conv_b", _uniform(-bound, bound),
+                            (inner + 2 * bc,))
+        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (H,))
+        a_log = self.param("A_log", _uniform(1.0, 16.0, jnp.log), (H,))
+        skip = self.param("D", nn.initializers.ones, (H,))
+        scale = self.param("gate_norm", nn.initializers.ones, (inner,))
+        out_proj = self.mat("out_proj", (inner, C))
+        with jax.named_scope("ssm_mixer"):
+            zxd = x @ in_proj.astype(dt)
+            z = zxd[..., :inner].astype(jnp.float32)
+            xbc = zxd[..., inner:2 * inner + 2 * bc].astype(jnp.float32)
+            delta = jax.nn.softplus(
+                zxd[..., 2 * inner + 2 * bc:].astype(jnp.float32) + dt_bias)
+            # causal depthwise conv: position t sees t-K+1 .. t
+            xp = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            xbc = jax.nn.silu(sum(conv_w[k] * xp[:, k:k + T]
+                                  for k in range(K)) + conv_b)
+            xs = xbc[..., :inner].reshape(S, T, H, P)
+            with jax.named_scope("ssm_scan"):
+                y, n = ssd_chunked(
+                    xs, delta, -jnp.exp(a_log),
+                    xbc[..., inner:inner + bc].reshape(S, T, G, N),
+                    xbc[..., inner + bc:].reshape(S, T, G, N),
+                    cfg.chunk_size, dt)
+            y = (y + skip[:, None] * xs).reshape(S, T, inner)
+            # gate first, then one norm a group
+            g = (y * jax.nn.silu(z)).reshape(S, T, G, inner // G)
+            g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                                  + cfg.layer_norm_epsilon)
+            out = (g.reshape(S, T, inner) * scale).astype(dt) \
+                @ out_proj.astype(dt)
+        return out, n
+
+
+class GQAttention(_Weights):
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        S, T, C = x.shape
+        Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                      cfg.head_dim)
+        wq, wk = self.mat("q", (C, Hq * D)), self.mat("k", (C, Hkv * D))
+        wv, wo = self.mat("v", (C, Hkv * D)), self.mat("o", (Hq * D, C))
+        q = (x @ wq.astype(dt)).reshape(S, T, Hkv, Hq // Hkv, D)
+        k = (x @ wk.astype(dt)).reshape(S, T, Hkv, D)
+        v = (x @ wv.astype(dt)).reshape(S, T, Hkv, D)
+        with jax.named_scope("gqa_attn"):
+            att = jnp.einsum("stgqd,sugd->sgqtu", q, k,
+                             preferred_element_type=jnp.float32) \
+                * float(D ** -0.5)
+            causal = jnp.tril(jnp.ones((T, T), bool))
+            att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
+            out = jnp.einsum("sgqtu,sugd->stgqd", att.astype(dt), v)
+        return out.reshape(S, T, Hq * D) @ wo.astype(dt)
+
+
+class _Pair(_Weights):
+    """``w1`` and ``w2`` of a squared-ReLU feed-forward part, or of a
+    stack of them."""
+    shapes: Any = ()
+
+    @nn.compact
+    def __call__(self):
+        return self.mat("w1", self.shapes[0]), self.mat("w2", self.shapes[1])
+
+
+class LatentMoE(_Weights):
+    """The expert layer of one chip: ``(y, stats)`` with ``stats`` =
+    float32 (assignments here, the fullest expert's, dropped)."""
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
+        N, C = x.shape
+        E, Z = cfg.n_held_experts, cfg.moe_latent_size
+        F, Fs = (cfg.moe_intermediate_size,
+                 cfg.moe_shared_expert_intermediate_size)
+        router = self.mat("router", (C, cfg.n_router_experts))
+        bias = self.mat("router_bias", (cfg.n_router_experts,))
+        down, up = self.mat("latent_down", (C, Z)), self.mat("latent_up",
+                                                             (Z, C))
+        w1, w2 = _Pair(cfg, ((E, Z, F), (E, F, Z)), name="experts")()
+        s1, s2 = _Pair(cfg, ((C, Fs), (Fs, C)), name="shared")()
+        with jax.named_scope("moe_route"):
+            top, g = route(x, router, bias, cfg.num_experts_per_tok,
+                           cfg.routed_scaling_factor, cfg.norm_topk_prob)
+            self.sow("intermediates", "top", top)
+            token, gate, load = dispatch(top, g, cfg.expert_offset, E)
+        with jax.named_scope("moe_experts"):
+            u = x @ down.astype(dt)
+        routed = routed_experts(u, token, gate, load, (w1, w2), "relu2")
+        with jax.named_scope("moe_experts"):
+            routed = routed.astype(dt) @ up.astype(dt)
+        shared = _relu2(x @ s1.astype(dt)) @ s2.astype(dt)
+        with jax.named_scope("moe_combine"):
+            y = routed + shared
+        return y.reshape(shape), layer_stats(load, N)
+
+
+class Block(nn.Module):
+    """``(x + mixer(norm(x)), the expert layer's (assignments here,
+    fullest expert's, dropped), chunks scanned)``."""
+    cfg: NemotronHConfig
+    kind: str = "M"
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        h = RMSNorm(cfg.layer_norm_epsilon, name="norm")(x).astype(dt)
+        moe, chunks = jnp.zeros((3,), jnp.float32), 0
+        if self.kind == "M":
+            y, chunks = Mamba2Mixer(cfg, name="mixer")(h)
+        elif self.kind == "*":
+            y = GQAttention(cfg, name="mixer")(h)
+        elif self.kind == "E":
+            y, moe = LatentMoE(cfg, name="mixer")(h)
+        else:
+            raise ValueError(f"no mixer for pattern character {self.kind!r}")
+        return x + y, moe, chunks
+
+
+@register_model("NemotronHLM")
+class NemotronHLM(nn.Module):
+    """(S, T) token ids -> (final hidden (S, T, C) float32, head weight
+    (V, C), the expert layers' (assignments here, fullest expert's
+    load, dropped) folded over layers, chunks scanned). The head is applied
+    by the loss in token chunks (``models/gpt2.py
+    lm_nll_sums_chunked``), so no (tokens, vocab) logits tensor
+    exists."""
+    cfg: NemotronHConfig = NemotronHConfig()
+
+    #: ``config.json``'s ``model_type`` and its reader, for the trainer
+    model_type = "nemotron_h"
+    config_class = NemotronHConfig
+
+    @nn.compact
+    def __call__(self, input_ids):
+        cfg, dt = self.cfg, self.cfg.dtype
+        init = nn.initializers.normal(stddev=cfg.initializer_range)
+        embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size))
+        head = self.param("lm_head", init,
+                          (cfg.vocab_size, cfg.hidden_size))
+        block_cls = nn.remat(Block) if cfg.remat else Block
+        h = embed[input_ids].astype(dt)
+        stats, chunks = jnp.zeros((3,), jnp.float32), 0
+        for i, kind in enumerate(cfg.hybrid_override_pattern):
+            h, s, n = block_cls(cfg, kind, name=f"layer_{i}")(h)
+            stats, chunks = fold_stats(stats, s), chunks + n
+        return (RMSNorm(cfg.layer_norm_epsilon, name="norm")(h), head,
+                stats, jnp.float32(chunks))
+
+
+def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
+    """Per-sequence mean next-token NLL and the ``STATS`` scalars."""
+    from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
+    cfg = module.cfg
+    final, head, stats, chunks = module.apply({"params": params}, input_ids)
+    sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
+                                 cfg.dtype, ignore_index=-1,
+                                 tokens_per_chunk=tokens_per_chunk)
+    mean = stats[0] / max(cfg.count("E") * cfg.n_held_experts, 1)
+    return sn / jnp.maximum(sv, 1.0), (stats[0], stats[1], mean, stats[2],
+                                       chunks)

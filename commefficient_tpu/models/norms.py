@@ -92,3 +92,16 @@ class BatchStatNorm(nn.Module):
                 ra_var.value = var * (n / max(n - 1.0, 1.0))
         inv = (scale * jax.lax.rsqrt(var + self.epsilon)).astype(x.dtype)
         return x * inv + (bias - mean * inv).astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    """Root-mean-square norm over the last axis with a learned scale,
+    in float32 whatever the input's dtype (the causal LMs' norm)."""
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale

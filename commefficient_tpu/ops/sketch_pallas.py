@@ -81,16 +81,44 @@ def supported(d: int, c: int, r: int) -> bool:
     per-chunk value arrays through the median network, but Mosaic's
     scheduler handles the flagship r=5, c=2^19 case (10.5 MB table) on
     v5e. Geometries pushing right up to the limit may still OOM VMEM
-    at compile — set backend="xla" explicitly there. The m bound keeps
-    the (r, m) rotation table within SMEM: (5, 718), d = 376M at
-    c = 2^19, compiles for and runs on the v5e (PERF.md section 6,
-    PR 27); past that, the XLA twin materialises (r, d) float32 and no
-    longer fits the chip, so a larger d needs the table blocked."""
+    at compile — set backend="xla" explicitly there. The number of
+    chunks m no longer bounds it: up to ``_ROT_WHOLE`` entries the
+    (r, m) rotation table lies whole in SMEM, as it always has, and
+    past that it passes through SMEM ``_ROT_BLOCK`` chunks at a time
+    (``_rot_operand``), still inside one kernel call ((5, 1337),
+    d = 701M at c = 2^19: PERF.md section 6, PR 32). What bounds d
+    now is the kernels' index arithmetic, padded d < 2^31 (the
+    estimates kernel's ``valid`` mask compares int32 positions), and
+    the chip's memory for the vector itself."""
     L = _pick_lanes(c)
     if L is None or 4 * r * c > _TABLE_VMEM_LIMIT:
         return False
-    m = -(-d // c)
-    return r * m <= 4096
+    return -(-d // c) * c < 2 ** 31
+
+
+#: (r, m) rotation tables of at most this many entries lie whole in
+#: SMEM (what every geometry up to PR 31 ran with, bit for bit)
+_ROT_WHOLE = 4096
+#: past that, the chunks of rotations one SMEM block holds
+_ROT_BLOCK = 512
+
+
+def _rot_operand(rot, r: int, m: int):
+    """The (r, m) rotation table as the kernels' first operand:
+    ``(operand, its BlockSpec, read)`` with ``read(ref, row, t)`` the
+    rotation of ``row`` at grid step ``t``. A small table is one
+    unblocked SMEM operand. A larger one is padded to whole blocks of
+    ``_ROT_BLOCK`` chunks and block ``t // _ROT_BLOCK`` is the one in
+    SMEM at step ``t`` (the pipeline fetches the next as the steps
+    reach it), so SMEM holds the same few KB whatever m is."""
+    rot = rot.astype(jnp.int32)
+    if r * m <= _ROT_WHOLE:
+        return (rot, pl.BlockSpec(memory_space=pltpu.SMEM),
+                lambda ref, row, t: ref[row, t])
+    rot = jnp.pad(rot, ((0, 0), (0, (-m) % _ROT_BLOCK)))
+    spec = pl.BlockSpec((r, _ROT_BLOCK), lambda t: (0, t // _ROT_BLOCK),
+                        memory_space=pltpu.SMEM)
+    return rot, spec, lambda ref, row, t: ref[row, t % _ROT_BLOCK]
 
 
 def _sign_hash_chunk(t, sign_seed: np.uint32, c: int, S: int, L: int,
@@ -235,6 +263,7 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
     seed = np.uint32(sign_seed)
     sublane = rot_step > 0 and rot_step % L == 0
     packed = sgn is not None
+    rot, rot_spec, rot_at = _rot_operand(rot, r, m)
 
     def kernel(rot_ref, v_ref, *refs):
         (sgn_ref, out_ref) = refs if packed else (None, refs[0])
@@ -257,20 +286,19 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
         for row in range(r):
             signed = _apply_flip(chunk, flips[row])
             if sublane:
-                rolled = pltpu.roll(signed, rot_ref[row, t] // L,
+                rolled = pltpu.roll(signed, rot_at(rot_ref, row, t) // L,
                                     axis=0)
             else:
-                rolled = _roll1d(signed, rot_ref[row, t], S, L, lane)
+                rolled = _roll1d(signed, rot_at(rot_ref, row, t), S, L, lane)
             sl = slice(row * S, (row + 1) * S)
             out_ref[sl, :] = out_ref[sl, :] + rolled
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
+        rot_spec,
         pl.BlockSpec((S, L), lambda t: (t, 0),
                      memory_space=pltpu.VMEM),
     ]
-    operands = [rot.astype(jnp.int32),
-                vp.astype(jnp.float32).reshape(m * S, L)]
+    operands = [rot, vp.astype(jnp.float32).reshape(m * S, L)]
     if packed:
         in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
                                      memory_space=pltpu.VMEM))
@@ -333,6 +361,7 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
         # the one-mix hash carries 16 sign bits — absolute rows of a
         # chunked call must stay inside them
         assert row_offset + r <= 16, (row_offset, r)
+    rot, rot_spec, rot_at = _rot_operand(rot, r, m)
 
     def kernel(rot_ref, v_ref, *refs):
         if packed:
@@ -353,10 +382,10 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
         for row in range(r):
             signed = _apply_flip(chunk, flips[row])
             if sublane:
-                rolled = pltpu.roll(signed, rot_ref[row, t] // L,
+                rolled = pltpu.roll(signed, rot_at(rot_ref, row, t) // L,
                                     axis=0)
             else:
-                rolled = _roll1d(signed, rot_ref[row, t], S, L, lane)
+                rolled = _roll1d(signed, rot_at(rot_ref, row, t), S, L, lane)
             sl = slice(row * S, (row + 1) * S)
             acc_ref[sl, :] = acc_ref[sl, :] + rolled
 
@@ -374,12 +403,11 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
                 rm_ref[row, :] = jnp.full((L,), rm, jnp.float32)
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
+        rot_spec,
         pl.BlockSpec((S, L), lambda t: (t, 0),
                      memory_space=pltpu.VMEM),
     ]
-    operands = [rot.astype(jnp.int32),
-                vp.astype(jnp.float32).reshape(m * S, L)]
+    operands = [rot, vp.astype(jnp.float32).reshape(m * S, L)]
     if packed:
         in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
                                      memory_space=pltpu.VMEM))
@@ -422,6 +450,7 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
     seed = np.uint32(sign_seed)
     sublane = rot_step > 0 and rot_step % L == 0
     packed = sgn is not None
+    rot, rot_spec, rot_at = _rot_operand(rot, r, m)
 
     def kernel(rot_ref, tab_ref, *refs):
         (sgn_ref, out_ref) = refs if packed else (None, refs[0])
@@ -433,7 +462,7 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
         vals = []
         for row in range(r):
             trow = tab_ref[row * S:(row + 1) * S, :]
-            o = rot_ref[row, t]
+            o = rot_at(rot_ref, row, t)
             back = (jnp.int32(c) - o) % jnp.int32(c)
             if sublane:
                 unrolled = pltpu.roll(trow, back // L, axis=0)
@@ -452,13 +481,12 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
         out_ref[:] = med.reshape(c)
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
+        rot_spec,
         # table resident in VMEM across all chunk steps
         pl.BlockSpec((r * S, L), lambda t: (0, 0),
                      memory_space=pltpu.VMEM),
     ]
-    operands = [rot.astype(jnp.int32),
-                table.astype(jnp.float32).reshape(r * S, L)]
+    operands = [rot, table.astype(jnp.float32).reshape(r * S, L)]
     if packed:
         in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
                                      memory_space=pltpu.VMEM))

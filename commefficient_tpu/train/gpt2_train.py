@@ -33,6 +33,8 @@ from commefficient_tpu.data.tokenizer import (SPECIAL_TOKENS,
                                               load_tokenizer)
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            token_nll)
+# JoyAI's counters, under the name its benchmark builder reads them by
+from commefficient_tpu.models.moe import MOE_COUNTERS  # noqa: F401
 from commefficient_tpu.runtime import (FedModel, FedOptimizer, LambdaLR,
                                        TrainRun)
 from commefficient_tpu.telemetry import setup_span
@@ -42,17 +44,12 @@ from commefficient_tpu.utils import (PiecewiseLinear, TableLogger,
 
 MAX_SEQ_LEN = 256  # static pad length (persona sequences are short)
 
-#: ``--model`` names this trainer builds; any other value (the shared
-#: parser's default is a CV model) means GPT2DoubleHeads, as before
-#: the flag was read here
-CAUSAL_LMS = ("JoyAIFlashLM",)
-
-
-#: how ``FedModel`` folds the causal loss's per-client routing counts
-#: (``models/joyai.py MOE_STATS``, in that order) into the round
-#: record's ``moe.*`` counters
-MOE_COUNTERS = (("moe.assignments_here", np.sum), ("moe.load_max", np.max),
-                ("moe.load_mean", np.mean), ("moe.dropped", np.sum))
+#: ``--model`` names of the causal LMs this trainer builds (each a
+#: module file of ``models/`` that brings the flax module with its
+#: ``model_type`` and ``config_class``, ``causal_lm_loss`` and
+#: ``COUNTERS``); any other value (the shared parser's default is a CV
+#: model) means GPT2DoubleHeads, as before the flag was read here
+CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM")
 
 
 def is_causal_lm(args) -> bool:
@@ -66,6 +63,14 @@ def is_causal_lm(args) -> bool:
             f"{args.dataset_name or 'PERSONA'} do not go together: "
             f"{CAUSAL_LMS} train on TOKENS, GPT2DoubleHeads on PERSONA")
     return causal
+
+
+def causal_lm_file(model: str):
+    """The module file of ``models/`` that brings ``--model``."""
+    import importlib
+
+    from commefficient_tpu.models import get_model
+    return importlib.import_module(get_model(model).__module__)
 
 
 def _lm_nll_sums(module, params, batch, tokens_per_chunk=0,
@@ -117,12 +122,13 @@ def _token_nll(logits, labels, ignore_index=-1):
 
 def make_causal_loss(module, args, train=True):
     """A causal LM's loss of a client batch ``{input_ids (B, T), mask
-    (B,)}``: the masked mean over sequences of ``causal_lm_loss`` (main
-    head + lambda x MTP head, heads chunked as GPT-2's). Training
-    returns the client's routing counts beside it (``MOE_STATS``: the
-    round's ``moe.*`` counters); validation the shape ``run_batches``
-    reads, with no multiple-choice task to score (accuracy 0)."""
-    from commefficient_tpu.models.joyai import causal_lm_loss
+    (B,)}``: the masked mean over sequences of its file's
+    ``causal_lm_loss`` (heads chunked as GPT-2's). Training returns the
+    client's counts beside it (the file's ``COUNTERS``: the round's
+    ``moe.*`` / ``ssm.*`` counters); validation the shape
+    ``run_batches`` reads, with no multiple-choice task to score
+    (accuracy 0)."""
+    causal_lm_loss = causal_lm_file(args.model).causal_lm_loss
 
     def compute_loss(params, batch, cfg):
         losses, stats = causal_lm_loss(
@@ -322,27 +328,36 @@ def train_gpt2(model, opt, lr_scheduler, train_loader, val_loader,
 
 
 def build_causal_lm(args: Config):
-    """``--model JoyAIFlashLM``: the architecture is ``config.json`` in
+    """``--model`` of ``CAUSAL_LMS``: the module is the one registered
+    under that name, the architecture is ``config.json`` in
     ``--model_checkpoint`` (the published keys, cut to the chip's share
-    as ``JoyAIConfig.from_hf`` reads them), or the tiny preset under
+    as the module's ``config_class.from_hf`` reads them; its
+    ``model_type`` has to be the module's), or the tiny preset under
     ``--test``. Random weights: no checkpoint format is read yet."""
     import dataclasses
     import json
 
-    from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlashLM
+    from commefficient_tpu.models import get_model
+    module_cls = get_model(args.model)
     cfg_json = os.path.join(args.model_checkpoint, "config.json")
     if os.path.exists(cfg_json):
         with open(cfg_json) as f:
-            cfg = JoyAIConfig.from_hf(json.load(f))
+            blob = json.load(f)
+        if blob.get("model_type") != module_cls.model_type:
+            raise ValueError(
+                f"--model {args.model} builds model_type "
+                f"{module_cls.model_type!r}, but {cfg_json} has model_type "
+                f"{blob.get('model_type')!r}")
+        cfg = module_cls.config_class.from_hf(blob)
     elif args.do_test:
-        cfg = JoyAIConfig.tiny()
+        cfg = module_cls.config_class.tiny()
     else:
         raise FileNotFoundError(
             f"--model {args.model} needs {cfg_json} (or --test)")
     cfg = dataclasses.replace(
         cfg, dtype=jnp.bfloat16 if args.do_bf16 else jnp.float32,
         remat=bool(args.do_remat))
-    module = JoyAIFlashLM(cfg)
+    module = module_cls(cfg)
     # model-init stream, not noise  # audit: allow(noise-confinement)
     params = module.init(jax.random.PRNGKey(args.seed),
                          jnp.zeros((1, 8), jnp.int32))["params"]
@@ -506,7 +521,8 @@ def run(argv=None) -> TrainRun:
     maybe_initialize_multihost_cli(args)
     np.random.seed(args.seed)
     causal = is_causal_lm(args)
-    args.num_results_train = 1 + (len(MOE_COUNTERS) if causal else 0)
+    counters = causal_lm_file(args.model).COUNTERS if causal else ()
+    args.num_results_train = 1 + len(counters)
 
     if args.do_test:
         # pre-run CLI override: no round program exists yet for a
@@ -531,7 +547,7 @@ def run(argv=None) -> TrainRun:
                          compute_loss_val=make_causal_loss(
                              module, args, train=False),
                          padded_batch_size=train_loader.B)
-        model.metric_counters = MOE_COUNTERS
+        model.metric_counters = counters
     elif args.seq_devices > 1:
         from commefficient_tpu.runtime.fed_model_sp import (
             SeqParallelFedModel)

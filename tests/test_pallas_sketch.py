@@ -213,3 +213,39 @@ def test_r17_per_row_mix_path():
     np.testing.assert_allclose(np.asarray(tx), np.asarray(tp),
                                rtol=1e-6, atol=1e-5)
     assert jnp.array_equal(xla.estimates(tx), pal.estimates(tx))
+
+
+# (d, c, r) with r * m on both sides of ``_ROT_WHOLE``: 819 chunks
+# (4,095 rotations, whole in SMEM) and 1,100 (5,500: three SMEM blocks,
+# the last a part of one)
+ROT_GEOMS = [(819 * 128 - 7, 128, 5), (1100 * 128 - 7, 128, 5)]
+
+
+@pytest.mark.parametrize("d,c,r", ROT_GEOMS)
+def test_rotation_table_whole_and_blocked_match_the_xla_twin(d, c, r):
+    """Past ``_ROT_WHOLE`` the rotation table goes through SMEM a block
+    at a time, inside one kernel call: sketch, quantised sketch and
+    estimates equal the XLA twin's on both sides of the old limit."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    m = -(-d // c)
+    assert (r * m > sp._ROT_WHOLE) == (m == 1100) and supported(d, c, r)
+    xla, pal = _pair(d, c, r)
+    v = jnp.asarray(np.random.RandomState(8).randn(d).astype(np.float32))
+    tx = xla.sketch(v)
+    np.testing.assert_allclose(np.asarray(tx), np.asarray(pal.sketch(v)),
+                               rtol=1e-6, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(xla.estimates(tx)),
+                                  np.asarray(pal.estimates(tx)))
+    (qx, sx), (qp, s_p) = (cs.sketch_quantized(v, "int8")
+                           for cs in (xla, pal))
+    np.testing.assert_allclose(np.asarray(sx), np.asarray(s_p), rtol=1e-6)
+    # a bucket on a rounding boundary may land one step apart
+    assert np.max(np.abs(np.asarray(qx, np.int32)
+                         - np.asarray(qp, np.int32))) <= 1
+
+
+def test_supported_is_bounded_by_index_arithmetic_not_chunks():
+    c = 524288
+    assert supported(700_903_424, c, 5)        # r * m = 6,685
+    assert supported(2 ** 31 - c, c, 5)
+    assert not supported(2 ** 31 - c + 1, c, 5)  # padded d = 2^31

@@ -26,6 +26,7 @@ from commefficient_tpu.parallel.mesh import (client_sharding, make_mesh,
 
 D_RESNET9, D_GPT2 = 6_584_000, 124_439_808   # flagship grad sizes
 D_JOYAI = 376_091_904   # the joyai-llm-flash-ep32 cut (718 chunks of COLS)
+D_NEMOTRON = 700_865_520  # nemotron3-super-ep64-tp8: r * m = 6,685
 COLS, ROWS, K = 524288, 5, 50000
 
 
@@ -111,7 +112,7 @@ def test_other_sketch_branches_lower_on_four_devices(pallas, devices, kw,
     assert client >= 1 and server == 3
 
 
-@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI])
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI, D_NEMOTRON])
 @pytest.mark.parametrize("rot_lanes", [0, 1024])
 def test_sketch_kernels_lower_at_flagship_geometry(d, rot_lanes):
     from commefficient_tpu.ops.sketch_pallas import supported
@@ -135,7 +136,7 @@ def test_quantized_emit_lowers_fused_for_int8_unfused_for_fp8():
                            sds((D_RESNET9,))) == 1
 
 
-@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI])
+@pytest.mark.parametrize("d", [D_RESNET9, D_GPT2, D_JOYAI, D_NEMOTRON])
 def test_take_mask_kernel_lowers(d):
     from commefficient_tpu.ops.topk import threshold_topk_mask_1d
     assert tpu_kernels(lambda sq: threshold_topk_mask_1d(sq, K),
