@@ -224,6 +224,20 @@ def args2sketch(cfg: Config) -> Optional[CountSketch]:
                        rot_lanes=resolve_rot_lanes(cfg))
 
 
+def server_select_form(cfg: Config, mesh=None):
+    """``(form, candidates)`` of the selection the server round built
+    from ``cfg`` on ``mesh`` makes (``CountSketch.select_form``; the
+    model-sharded round's distributed select looks at every estimate);
+    None outside sketch mode, whose server rounds recover nothing."""
+    sketch = args2sketch(cfg)
+    if sketch is None:
+        return None
+    from commefficient_tpu.parallel.mesh import model_axis_size
+    if model_axis_size(mesh) > 1:
+        return "flat", sketch.d
+    return sketch.select_form(cfg.k)
+
+
 def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                        padded_batch_size: int,
                        mesh=None, stats_fn: Callable = None,

@@ -587,11 +587,13 @@ class CountSketch:
                 idx = jnp.minimum(idx, self.d - 1)
         else:
             if use_threshold_select(k, self.d, False):
-                # exact selection without the full sort: at GPT-2's
-                # d=124M lax.top_k costs 461.9 ms vs 103.2 ms for the
-                # threshold + hierarchical extraction (BENCHMARKS.md)
-                idx = threshold_topk_indices(
-                    jax.lax.square(est), k)
+                # exact selection without the full sort, and where the
+                # k candidate blocks are a small part of d without
+                # looking at all of d either (``select_form``); the
+                # squares are taken of the gathered candidates, not
+                # written out over d
+                idx = threshold_topk_indices(est, k,
+                                             key=jax.lax.square)
             else:
                 _, idx = jax.lax.top_k(jax.lax.square(est), k)
             oob = None
@@ -622,8 +624,8 @@ class CountSketch:
 
     def unsketch_dense_mask(self, table: jax.Array, k: int):
         """Exact dense unsketch without the top-k sort: the
-        threshold-select mask (ops/topk.py, 32 streaming count passes)
-        keeps the k largest-magnitude estimates via a ``where`` — no
+        threshold-select mask (ops/topk.py: an 8-pass nibble search,
+        then the take-mask kernel) keeps the k largest-magnitude estimates via a ``where`` — no
         sort, no index gather/scatter. Returns ``(dense, mask)``;
         use where the consumer never needs the (k,) index form (the
         dense-regime server step; download accounting takes the
@@ -641,12 +643,34 @@ class CountSketch:
         """Dense-regime exact recovery via the threshold mask: wins
         once d is large enough that lax.top_k lowers to an expensive
         full sort (~13 ms extra per round at ResNet9's d=6.6M,
-        BENCHMARKS.md). Approximate recovery (approx_topk) stays on
-        the index path — approx_max_k is cheaper than the 32 count
-        passes; and the sparse-resketch regime needs indices anyway."""
+        BENCHMARKS.md; the mask's `select` scope reads 1.18 ms there,
+        PERF.md section 5). Approximate recovery (approx_topk) stays
+        on the index path — approx_max_k is cheaper than the nibble
+        search's count passes; and the sparse-resketch regime needs
+        indices anyway."""
         from commefficient_tpu.ops.topk import use_threshold_select
         return (use_threshold_select(k, self.d, self.approx_topk)
                 and not self.prefer_sparse_resketch(k))
+
+    def select_form(self, k: int):
+        """``(form, candidates)`` of the selection a server round's
+        recovery of ``k`` coordinates makes over this sketch's
+        estimates, from the shapes alone: ``("blocked", k·block)``
+        where ``unsketch`` reaches ``threshold_topk_indices`` and the
+        two-level form engages there (ops/topk.py ``select_block``, on
+        the padded length the selection sees), else ``("flat", d)``:
+        the threshold mask, ``lax.top_k`` or ``approx_max_k`` over
+        every estimate. The round records' ``select.*`` counters."""
+        from commefficient_tpu.ops.topk import (select_block,
+                                                use_threshold_select)
+        k = min(k, self.d)
+        if (not self.approx_topk
+                and use_threshold_select(k, self.d, False)
+                and not self.prefer_threshold_unsketch(k)):
+            block = select_block(self._padded_d, k)
+            if block:
+                return "blocked", k * block
+        return "flat", self.d
 
     def sketch_sparse(self, idx: jax.Array,
                       vals: jax.Array) -> jax.Array:

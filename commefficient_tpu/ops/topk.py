@@ -15,12 +15,16 @@ import jax.numpy as jnp
 # Row size at/above which exact selection leaves lax.top_k (a full
 # sort at large d on TPU). Current routing: DENSE selections use the
 # threshold MASK + where (~3x at d = 6.6M, k = 50k on v5e); 1-D exact
-# INDEX selection (unsketch recovery) uses the mask + hierarchical
-# extraction (461.9 -> 103.2 ms at d = 124M — a naive jnp.nonzero
-# compaction would be a d-sized scatter and lose to the sort, the
-# blocked-cumsum extraction does not). Only batched index selections
-# and approx_max_k requests remain on the XLA primitives. Numbers:
-# BENCHMARKS.md, runs/exact_select.log.
+# INDEX selection (unsketch recovery) uses ``threshold_topk_indices``:
+# the mask + hierarchical extraction (a naive jnp.nonzero compaction
+# would be a d-sized scatter and lose to the sort, the blocked-cumsum
+# extraction does not), run over all of d (flat) or, where the k
+# candidate blocks are a small part of d, over those alone (two-level,
+# PR 33). On one v5e chip at k = 50,000, the selection and the gather
+# of its values alone (scripts/select_probe.py, my chip run, PR 33):
+# flat 34.9 / 87.0 / 157.2 ms and two-level 15.9 / 19.6 / 22.5 ms at
+# d = 124M / 376M / 701M. Only batched index selections and
+# approx_max_k requests remain on the XLA primitives.
 _THRESHOLD_SELECT_MIN_D = 1 << 20
 _approx_override_logged = False
 
@@ -261,19 +265,16 @@ def _threshold_topk_idx(sq: jax.Array, k: int) -> jax.Array:
         take.shape[:-1] + (k,))
 
 
-def threshold_topk_indices(sq: jax.Array, k: int,
-                           block: int = 1024) -> jax.Array:
-    """Exact top-k INDICES (ascending) of non-negative 1-D ``sq``
-    without sorting and without a d-sized scatter: the threshold mask
-    (32 streaming count passes) followed by hierarchical compaction —
-    blockwise cumsums locate each output slot's block (searchsorted
-    over block totals) and its column (argmax over the gathered block
-    cumsum row). O(d) streaming + O(k·block) gather work, vs
-    lax.top_k's full sort: 461.9 -> 103.2 ms at d = 124M, k = 50k on
-    v5e — the selection behind exact unsketch recovery at GPT-2
-    scale (BENCHMARKS.md, runs/exact_select.log). Same selected set
-    as lax.top_k, including the lowest-index tie-break."""
-    assert sq.ndim == 1, "hierarchical extraction is 1-D"
+def _flat_topk_indices(sq: jax.Array, k: int,
+                       block: int = 1024) -> jax.Array:
+    """``threshold_topk_indices``' flat form: the threshold mask over
+    all of non-negative 1-D ``sq`` (an 8-pass nibble search for the
+    k-th largest key, then the take-mask kernel) followed by
+    hierarchical compaction of its k set bits — blockwise cumsums
+    locate each output slot's block (searchsorted over block totals)
+    and its column (argmax over the gathered block cumsum row). O(d)
+    streaming (about 28 d-sized passes: PERF.md section 6, PR 33) +
+    O(k·block) gather work, no sort and no d-sized scatter."""
     d = sq.shape[0]
     take = threshold_topk_mask_1d(sq, k)  # exactly k set bits
     pad = (-d) % block
@@ -287,6 +288,87 @@ def threshold_topk_indices(sq: jax.Array, k: int,
     rows = intra[b]  # (k, block) gather
     col = jnp.argmax(rows > j[:, None], axis=1).astype(jnp.int32)
     return b * block + col
+
+
+# The two-level exact selection (PR 33). The flat form streams d-sized
+# arrays about 28 times to find k of d numbers; where the k candidate
+# blocks are a small part of d, the block maxima say which k blocks can
+# hold a winner and the flat form runs over k·block candidates instead.
+# The block is 128: a row of 128 is the 1-D array's own tile on the
+# TPU, so the (d/128, 128) view costs nothing, and d/b + k·b, the flat
+# work that is left, is least at b = sqrt(d/k) = 50 … 118 for the
+# benchmark's cells; 256 / 512 / 1024 read 28.0 / 27.6 / 34.6 ms where
+# 128 reads 22.5 at d = 701M (scripts/select_probe.py, my chip run,
+# PR 33: PERF.md section 6). The ratio: a flat index selection costs
+# about 4.5 ms + 0.24 ms a million coordinates, the two-level form
+# two small ones + one read of d (4.6 ms at 701M), which crosses near
+# d = 6·k·128.
+_SELECT_BLOCK = 128
+_SELECT_BLOCKED_MIN_RATIO = 8
+
+
+def select_block(d: int, k: int) -> int:
+    """The ONE rule for which form ``threshold_topk_indices`` takes, a
+    function of the shapes alone: the candidate block size of the
+    two-level form where its k·block candidates are at most a
+    ``_SELECT_BLOCKED_MIN_RATIO``-th of d, else 0 (the flat form)."""
+    b = _SELECT_BLOCK
+    return b if d >= _SELECT_BLOCKED_MIN_RATIO * k * b else 0
+
+
+def threshold_topk_indices(sq: jax.Array, k: int, block: int = 1024,
+                           *, key=None,
+                           coarse: int = None) -> jax.Array:
+    """Exact top-k INDICES (ascending) of 1-D ``sq`` by its
+    non-negative keys, without sorting and without a d-sized scatter.
+    Same selected set as lax.top_k, including the lowest-index
+    tie-break.
+
+    ``key``: elementwise map from ``sq``'s values to the non-negative
+    keys that are compared (``jax.lax.square`` for estimates); None:
+    ``sq`` holds the keys. Keys are compared as the uint32 bit
+    patterns of float32, so ±inf and NaN are ordered as the flat form
+    always ordered them.
+
+    Two forms, chosen from (d, k) by ``select_block``: flat
+    (``_flat_topk_indices`` over all of d), or
+
+    two-level, where d >= 8·k·128: (1) each contiguous block of 128
+    coordinates gives its largest key, one streamed read of ``sq``;
+    (2) the flat form over the d/128 block maxima picks the k blocks
+    that can hold a winner (ascending, ties to the lowest block);
+    (3) those k rows are gathered from ``sq`` itself (``key`` is
+    applied after the gather: no d-sized keyed copy is ever written)
+    and the flat form runs over their k·128 candidates, which are in
+    ascending global order; (4) positions map back to coordinates.
+    Why it is the same selection: with t the k-th largest key and t_lo
+    the k-th largest block maximum, t >= t_lo; every key > t sits in a
+    block whose maximum is > t_lo or among the lowest-indexed blocks
+    whose maximum equals it, and so do the lowest-indexed ties at t
+    that the rule takes (ISSUE 33's argument; tests/test_ops.py pins
+    it, ties in excluded blocks included).
+
+    ``coarse`` forces a form whatever the shapes (tests, the probe):
+    a block size, or 0 for flat; a forced block still falls back to
+    flat where there are not more than k blocks."""
+    assert sq.ndim == 1, "hierarchical extraction is 1-D"
+    d = sq.shape[0]
+    keyed = (lambda x: x) if key is None else key
+    b = select_block(d, k) if coarse is None else coarse
+    if not b or -(-d // b) <= k:
+        return _flat_topk_indices(keyed(sq), k, block)
+    # rows of b (128: the 1-D array's own tiles on the TPU, so the view
+    # costs nothing). A zero tail: key 0 is the smallest there is and
+    # the tail holds the highest indices, so it changes no maximum and
+    # no pad slot is ever taken (k blocks hold at least k real
+    # coordinates)
+    x = jnp.pad(sq, (0, (-d) % b)).reshape(-1, b)
+    block_max = jnp.max(jax.lax.bitcast_convert_type(
+        keyed(x).astype(jnp.float32), jnp.uint32), axis=1)
+    cand = _flat_topk_indices(
+        jax.lax.bitcast_convert_type(block_max, jnp.float32), k)
+    pos = _flat_topk_indices(keyed(x[cand]).reshape(-1), k)
+    return cand[pos // b] * b + pos % b
 
 
 def _select_idx(vec: jax.Array, k: int, approx: bool,

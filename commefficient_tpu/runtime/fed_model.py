@@ -62,7 +62,8 @@ from commefficient_tpu.data import staging
 from commefficient_tpu.core.rounds import (ClientStates,
                                            build_client_round,
                                            build_server_round,
-                                           build_val_fn, round_plan)
+                                           build_val_fn, round_plan,
+                                           server_select_form)
 from commefficient_tpu.core.server import ServerState
 from commefficient_tpu.privacy import build_accountant, noise_stream
 from commefficient_tpu.telemetry import build_telemetry, clock, trace
@@ -1284,6 +1285,7 @@ class FedOptimizer:
             build_server_round(self.args, probes=self._probes,
                                mesh=mesh),
             donate_argnums=(0, 1))
+        self._select_form = server_select_form(self.args, mesh)
         # legacy --do_dp server-mode noise stream: the seed+1 root key
         # comes from privacy/ (the one module allowed raw jax.random
         # noise — analysis/lint.py noise-confinement)
@@ -1359,6 +1361,13 @@ class FedOptimizer:
                     sharding=server_state_sharding(self._mesh, geom))
                 self._server_geom = geom
             server_fn = svar.server_fn
+        # which selection the program about to run was built with
+        # (engagement, as h2d.staged / account.deferred: no metric)
+        form = (self._select_form if svar is None
+                else server_select_form(svar.cfg, self._mesh))
+        if form is not None:
+            m.telemetry.count("select." + form[0])
+            m.telemetry.count("select.candidates", form[1])
         sfirst = svar is not None and "server" not in svar.compiled
         cmark = compile_mark() if sfirst else None
         with m.telemetry.span("server"):

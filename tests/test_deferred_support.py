@@ -95,6 +95,82 @@ def test_deferred_equals_settled_at_once(mode, num_devices, encoding):
     assert sum(float(d.sum()) for d, _, _ in late[1:]) > 0
 
 
+@pytest.mark.parametrize("form", ["blocked", "flat"])
+def test_select_counters_on_every_record(form, monkeypatch):
+    """A sketch run in the sparse-resketch regime (d > 90·r·k, the
+    cells' server path): every round record says which form of the
+    exact selection its server program was built with, and over how
+    many candidates; the program traced is that form."""
+    import importlib
+
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from commefficient_tpu.config import Config
+    from commefficient_tpu.runtime import FedModel, FedOptimizer
+    from test_round_contract import B, CLIENTS, W
+    topk_mod = importlib.import_module("commefficient_tpu.ops.topk")
+
+    class Lin(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(64, use_bias=False)(x)
+
+    d, k, block = 32 * 64, 2, 8
+    masks = []
+    real = topk_mod.threshold_topk_mask_1d
+    monkeypatch.setattr(
+        topk_mod, "threshold_topk_mask_1d",
+        lambda sq, k, **kw: (masks.append(sq.shape[0]), real(sq, k, **kw))[1])
+    if form == "blocked":
+        # the cells' regime at a toy size: the threshold select from
+        # d = 1 on, blocks of 8
+        monkeypatch.setattr(topk_mod, "_THRESHOLD_SELECT_MIN_D", 1)
+        monkeypatch.setattr(topk_mod, "_SELECT_BLOCK", block)
+    module = Lin()
+    params = module.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 32)))["params"]
+    # a seed a form: the sketch is a static argument of the jitted
+    # unsketch, and an equal one would be served the other form's trace
+    args = Config(num_workers=W, num_clients=CLIENTS, num_devices=1,
+                  dataset_name="CIFAR10", local_batch_size=B,
+                  seed=11 + (form == "flat"),
+                  **dict(MODES["sketch"], num_rows=1, num_cols=64, k=k))
+
+    def loss(p, batch, cfg):
+        pred = module.apply({"params": p}, batch["x"])
+        per = jnp.sum((pred - batch["y"][..., None]) ** 2, -1)
+        n = jnp.maximum(jnp.sum(batch["mask"]), 1.0)
+        l = jnp.sum(per * batch["mask"]) / n
+        return l, (l * 0.0 + 1.0,)
+
+    model = FedModel(module, params, loss, args, padded_batch_size=B)
+    opt = FedOptimizer([{"lr": 0.1}], args)
+    assert args.grad_size == d > 90 * args.num_rows * k
+    sink = ListSink()
+    model.telemetry.add_sink(sink)
+    rng = np.random.RandomState(5)
+    for _ in range(4):
+        model({"x": rng.randn(W, B, 32).astype(np.float32),
+               "y": rng.randn(W, B).astype(np.float32),
+               "mask": np.ones((W, B), np.float32),
+               "client_ids": rng.choice(CLIENTS, W, replace=False)
+               .astype(np.int32)})
+        opt.step()
+    model.finalize()
+    want = {"blocked": {"select.blocked": 1,
+                        "select.candidates": k * block},
+            "flat": {"select.flat": 1, "select.candidates": d}}[form]
+    assert len(sink.records) == 4
+    for rec in sink.records:
+        assert {n: v for n, v in rec["counters"].items()
+                if n.startswith("select.")} == want
+    # one mask over the block maxima, one over the k blocks; the flat
+    # form at this d is lax.top_k
+    assert masks == ([d // block, k * block] if form == "blocked" else [])
+
+
 @pytest.mark.parametrize("mode", sorted(MODES))
 def test_checkpoint_with_the_slot_full_continues_the_same(mode, tmp_path):
     some = batches(7)

@@ -159,6 +159,174 @@ class TestThresholdSelect:
         assert np.all(np.abs(out[nz]) >= thresh)
 
 
+def _two_level_cases():
+    """name -> (values, k, two_level): inputs on which the two-level
+    form of threshold_topk_indices has to pick what lax.top_k picks,
+    as a function of the block size."""
+    def gaussian(b, rng):
+        return rng.randn(200 * b).astype(np.float32), 37, True
+
+    def heavy_ties(b, rng):
+        return rng.randint(0, 4, 300 * b).astype(np.float32), 61, True
+
+    def few_nonzeros(b, rng):
+        # fewer than k nonzeros and a ragged last block: the zero ties
+        # fall to the lowest indices, never into the padded tail
+        d, k = 100 * b + b // 2 + 1, 30
+        x = np.zeros(d, np.float32)
+        x[rng.choice(d, k // 3, replace=False)] = rng.randn(k // 3)
+        return x, k, True
+
+    def constant(b, rng):
+        return np.full(48 * b, 0.5, np.float32), 11, True
+
+    def ties_in_excluded_blocks(b, rng):
+        # one block above the threshold, thirty blocks whose maximum IS
+        # the threshold, several ties in each: only the lowest-indexed
+        # of them become candidates, the others hold ties that must
+        # not be picked
+        x = np.zeros(40 * b, np.float32).reshape(40, b)
+        for j in range(2, 32):
+            x[j, rng.choice(b, rng.randint(1, 4), replace=False)] = 1.0
+        x[35, 0], x[35, b - 1] = 2.0, -1.0
+        return x.reshape(-1), 9, True
+
+    def ragged_d(b, rng):
+        return rng.randn(33 * b + 5).astype(np.float32), 7, True
+
+    def k_not_below_blocks(b, rng):
+        # as many blocks as k or fewer: every block would be a
+        # candidate, the flat form runs
+        return rng.randn(16 * b).astype(np.float32), 16, False
+
+    def infinities(b, rng):
+        x = rng.randn(40 * b).astype(np.float32)
+        x[[3, 5 * b + 1, 39 * b]] = np.inf, -np.inf, np.inf
+        return x, 2, True
+
+    return [gaussian, heavy_ties, few_nonzeros, constant,
+            ties_in_excluded_blocks, ragged_d, k_not_below_blocks,
+            infinities]
+
+
+@pytest.mark.parametrize("block", [8, 32, 128])
+@pytest.mark.parametrize("case", _two_level_cases(),
+                         ids=lambda f: f.__name__)
+def test_two_level_indices_match_lax_top_k(case, block, monkeypatch):
+    """threshold_topk_indices' two-level form, forced at small d: the
+    index ARRAY (the set, ascending) of lax.top_k over the squares,
+    ties included, from the values (``key=square``) and from the
+    squares themselves."""
+    import importlib
+    topk_mod = importlib.import_module("commefficient_tpu.ops.topk")
+    x, k, two_level = case(block, np.random.RandomState(block))
+    masks = []
+    real = topk_mod.threshold_topk_mask_1d
+    monkeypatch.setattr(
+        topk_mod, "threshold_topk_mask_1d",
+        lambda sq, k, **kw: (masks.append(sq.shape[0]), real(sq, k, **kw))[1])
+    sq = jnp.square(jnp.asarray(x))
+    want = np.sort(np.asarray(jax.lax.top_k(sq, k)[1]))
+    got = np.asarray(topk_mod.threshold_topk_indices(
+        jnp.asarray(x), k, key=jax.lax.square, coarse=block))
+    # the flat form makes one mask over d; the two-level form one over
+    # the block maxima and one over the k blocks' candidates
+    nb = -(-x.size // block)
+    assert masks == ([nb, k * block] if two_level else [x.size])
+    np.testing.assert_array_equal(got, want)
+    assert got.max() < x.size
+    np.testing.assert_array_equal(
+        np.asarray(topk_mod.threshold_topk_indices(sq, k, coarse=block)),
+        want)
+    np.testing.assert_array_equal(
+        np.asarray(topk_mod.threshold_topk_indices(sq, k, coarse=0)),
+        want)
+
+
+def test_unsketch_support_same_in_both_forms(monkeypatch):
+    """CountSketch.unsketch's support with the two-level form engaged
+    and with the flat form: identical idx and vals. The jitted method
+    would serve the second call from the first's trace, so the method
+    under the jit is called."""
+    import importlib
+    topk_mod = importlib.import_module("commefficient_tpu.ops.topk")
+    cs = CountSketch(d=5000, c=256, r=3)
+    table = jnp.asarray(
+        np.random.RandomState(8).randn(3, 256).astype(np.float32))
+    k = 16
+    monkeypatch.setattr(topk_mod, "_THRESHOLD_SELECT_MIN_D", 1)
+    monkeypatch.setattr(topk_mod, "_SELECT_BLOCK", 8)
+    got = {}
+    for form, ratio in (("blocked", 4), ("flat", 1 << 20)):
+        monkeypatch.setattr(topk_mod, "_SELECT_BLOCKED_MIN_RATIO", ratio)
+        assert bool(topk_mod.select_block(cs._padded_d, k)) \
+            == (form == "blocked")
+        dense, idx, vals = CountSketch.unsketch.__wrapped__(
+            cs, table, k, True, False)
+        assert dense is None
+        got[form] = np.asarray(idx), np.asarray(vals)
+    np.testing.assert_array_equal(got["blocked"][0], got["flat"][0])
+    np.testing.assert_array_equal(got["blocked"][1], got["flat"][1])
+    est = cs.estimates(table)
+    np.testing.assert_array_equal(
+        got["flat"][0],
+        np.sort(np.asarray(jax.lax.top_k(jnp.square(est), k)[1])))
+
+
+@pytest.mark.parametrize("d,k,form", [
+    (700_865_520, 50_000, "blocked"),     # the Nemotron cell
+    (376_091_904, 50_000, "blocked"),     # JoyAI
+    (124_444_417, 50_000, "blocked"),     # GPT-2
+    (60_000_000, 50_000, "blocked"),
+    (40_000_000, 50_000, "flat"),         # sparse regime, d < 8·k·128
+    (6_584_000, 50_000, "flat"),          # ResNet9: the dense mask
+    (100_000, 500, "flat"),               # lax.top_k
+])
+def test_select_form_is_a_function_of_the_shapes(d, k, form):
+    cs = CountSketch(d=d, c=524_288, r=5)
+    want = (form, k * 128 if form == "blocked" else d)
+    assert cs.select_form(k) == want
+    # nothing but (d, k): no backend, seed or rotation setting
+    assert CountSketch(d=d, c=524_288, r=5, seed=9, backend="xla",
+                       rot_lanes=1024).select_form(k) == want
+    assert CountSketch(d=d, c=524_288, r=5,
+                       approx_topk=True).select_form(k) == ("flat", d)
+
+
+def test_resnet9_server_round_never_reaches_the_index_select(
+        monkeypatch):
+    """ResNet9's geometry is in the dense regime (d < 90·r·k): its
+    sketched server update takes the threshold mask and is built
+    without ``threshold_topk_indices``, whose two forms it therefore
+    cannot tell apart."""
+    import importlib
+
+    from commefficient_tpu.config import Config
+    from commefficient_tpu.core.rounds import (args2sketch,
+                                               server_select_form)
+    from commefficient_tpu.core.server import ServerState, server_update
+    topk_mod = importlib.import_module("commefficient_tpu.ops.topk")
+
+    def never(*a, **kw):
+        raise AssertionError("the index select was traced")
+
+    monkeypatch.setattr(topk_mod, "threshold_topk_indices", never)
+    cfg = Config(mode="sketch", error_type="virtual", local_momentum=0.0,
+                 virtual_momentum=0.9, num_rows=5, num_cols=524_288,
+                 num_blocks=1, k=50_000, grad_size=6_584_000,
+                 num_workers=1, num_clients=1, dataset_name="CIFAR10")
+    sketch = args2sketch(cfg)
+    assert sketch.prefer_threshold_unsketch(cfg.k)
+    assert server_select_form(cfg) == ("flat", 6_584_000)
+    table = jax.ShapeDtypeStruct((5, 524_288), jnp.float32)
+    out = jax.eval_shape(
+        lambda g, v, e: server_update(cfg, g, ServerState(v, e), 0.1,
+                                      sketch, None),
+        table, table, table)
+    assert out.weight_update.shape == (6_584_000,)
+    assert set(out.support) == {"bitmap"}
+
+
 class TestClip:
     def test_noop_below_clip(self):
         v = jnp.array([0.3, 0.4])  # norm 0.5

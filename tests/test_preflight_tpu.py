@@ -143,6 +143,22 @@ def test_take_mask_kernel_lowers(d):
                        sds((d,))) == 1
 
 
+@pytest.mark.parametrize("d,masks", [
+    (D_GPT2, 2), (D_JOYAI, 2), (D_NEMOTRON, 2),     # two-level
+    (40_000_000, 1),                                # flat, sparse regime
+])
+def test_index_select_lowers_in_the_form_its_shapes_pick(d, masks):
+    """The exact index selection behind ``unsketch`` at the LM cells'
+    sizes: the two-level form holds two small take-mask kernels (over
+    the block maxima, over the k blocks' candidates), the flat form one
+    over all of d."""
+    from commefficient_tpu.ops.topk import threshold_topk_indices
+    pad = -(-d // COLS) * COLS
+    assert tpu_kernels(
+        lambda est: threshold_topk_indices(est, K, key=jax.lax.square),
+        sds((pad,))) == masks
+
+
 def test_flce_kernels_lower_at_gpt2_head(monkeypatch):
     from commefficient_tpu.ops import flce_pallas
     # the fused path asks the default backend; this is a cross-lowering
