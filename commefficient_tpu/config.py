@@ -883,7 +883,8 @@ def build_parser(default_lr: Optional[float] = None,
                         help="cv_train: a CV model; gpt2_train: "
                         "GPT2DoubleHeads on PERSONA (any other value), or "
                         "a causal LM on --dataset_name TOKENS: "
-                        "JoyAIFlashLM, NemotronHLM (architecture from "
+                        "JoyAIFlashLM, NemotronHLM, GraniteHybridLM "
+                        "(architecture from "
                         "config.json in --model_checkpoint, whose "
                         "model_type must be the model's)")
     parser.add_argument("--finetune", action="store_true", dest="do_finetune")

@@ -1,0 +1,339 @@
+"""The Mamba-2 state-space mixer and grouped-query attention that the
+hybrid language models share (``models/nemotron_h.py``,
+``models/granite_hybrid.py``), as ``models/moe.py`` is the expert layer
+two models route with. One code, parametrised by what differs between
+the models: attention's score scale.
+
+    M:  [z | xBC | dt] = x W_in;  xBC = silu(conv4(xBC) + b)
+        [x | B | C] = xBC;  delta = softplus(dt + dt_bias)
+        S_t = exp(delta_t A) S_{t-1} + delta_t x_t B_t^T   (a head)
+        y_t = S_t C_t + D x_t;   A = -exp(A_log)
+        out = W_out GroupRMSNorm(y * silu(z))
+    *:  softmax_causal(scale q k^T) v, the query heads of a group
+        sharing its key/value head; no positional embedding
+
+A configuration is any object with the fields the mixers read:
+``mamba_num_heads``, ``mamba_head_dim``, ``n_groups``,
+``ssm_state_size``, ``conv_kernel``, ``chunk_size``,
+``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
+``layer_norm_epsilon``, ``initializer_range``, ``time_step_min`` /
+``_max`` / ``_floor`` and ``dtype``.
+
+What is TPU-shaped here:
+
+- **The recurrence is computed by chunks** (SSD, Dao & Gu
+  arXiv:2405.21060, chunks of ``chunk_size`` positions): inside a chunk
+  the masked product ``(C B^T * L) X`` with ``L = exp(segsum(delta A))``,
+  three matrix products a head on (chunk, chunk) tiles; between chunks
+  the state, carried by a ``lax.scan`` over the chunks. It is
+  differentiated by plain reverse mode under the clients ``vmap``
+  (``core/rounds.py make_local_loss``) and ``--remat``. delta, A, the
+  cumulative sums and the exponentials are float32; the products take
+  operands in the compute dtype and accumulate in float32.
+- **What the scan holds is bounded.** The in-chunk decay ``L`` is
+  (sequences, chunks, heads, chunk, chunk) float32, and so are the
+  masked product and their cotangents. Where that is more than
+  ``SSD_DECAY_BYTES`` the heads are taken a block at a time (heads never
+  meet inside the recurrence), each block under ``jax.checkpoint``, so a
+  block's decay is all that is alive in either pass
+  (``ssd_head_block``). Up to that size the whole is computed at once,
+  as it always was. What it buys, end to end on the chip at 64 heads x
+  chunks of 256 under a 4-client ``vmap``: 5.81 against 5.74 clients/s
+  and 12.58 against 13.05 GB with all heads at once (PERF.md section 6,
+  PR 34); at 16 heads x 128 nothing, and nothing is blocked there.
+- **Attention does not materialise (heads, T, T) where that is large.**
+  Past ``ATTN_SCORE_BYTES`` of float32 scores the queries are taken a
+  block at a time against every key (``attn_query_block``): a block's
+  rows are whole, so its softmax is the plain one, in float32, and
+  nothing of the mathematics differs from the dense form; each block
+  under ``jax.checkpoint``. Up to that size the dense form is built.
+
+Both forms are chosen from the shapes alone: no flag, no environment
+variable.
+
+Scopes (``PERF.md`` section 3): ``ssm_mixer`` (the whole Mamba-2
+mixer) > ``ssm_scan`` (decay sums, the in-chunk products, the state
+scan; conv, gate-norm and projections outside it); ``gqa_attn``
+(scores, softmax, value product; the four projections outside it).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+#: the most float32 in-chunk decay (sequences x chunks x heads x chunk x
+#: chunk x 4 bytes) that ``ssd_chunked`` computes at once
+SSD_DECAY_BYTES = 1 << 25
+
+#: the most float32 scores (sequences x query heads x T x T x 4 bytes)
+#: that ``GQAttention`` materialises, and the most a block of queries'
+ATTN_SCORE_BYTES = 1 << 27
+ATTN_BLOCK_BYTES = 1 << 25
+
+
+# --- the Mamba-2 recurrence, by chunks --------------------------------------
+
+def ssd_head_block(S, T, H, G, chunk):
+    """Heads of a group that ``ssd_chunked`` takes at once: all of them
+    (``H // G``) where the (S, chunks, H, chunk, chunk) float32 decay is
+    within ``SSD_DECAY_BYTES``, else as many as keep a block's within
+    it."""
+    Q = int(chunk)
+    per_head = S * -(-T // Q) * Q * Q * 4
+    hg = H // G
+    if per_head * H <= SSD_DECAY_BYTES:
+        return hg
+    return max(1, min(hg, SSD_DECAY_BYTES // (per_head * G)))
+
+
+def _ssd_heads(x, delta, A, B, C, Q, dtype):
+    """``ssd_chunked`` of the heads it is given, all at once."""
+    S, T, H, P = x.shape
+    G, N = B.shape[-2:]
+    hg = H // G
+    nc = -(-T // Q)
+    pad = nc * Q - T
+
+    def chunks(v):                      # (S, T, n, ...) -> (S, nc, n, Q, ...)
+        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        return jnp.moveaxis(v.reshape((S, nc, Q) + v.shape[2:]), 2, 3)
+
+    a = chunks(delta * A)                                  # (S, nc, H, Q)
+    xd = chunks((x.astype(jnp.float32) * delta[..., None]).astype(dtype))
+    xd = xd.reshape(S, nc, G, hg, Q, P)
+    Bc, Cc = chunks(B.astype(dtype)), chunks(C.astype(dtype))
+    cs = jnp.cumsum(a, axis=-1)                            # float32
+    # in a chunk: L[t, u] = exp(sum_{u < v <= t} a_v) for u <= t
+    seg = cs[..., :, None] - cs[..., None, :]
+    L = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg, -jnp.inf))
+    cb = jnp.einsum("scgtn,scgun->scgtu", Cc, Bc,
+                    preferred_element_type=jnp.float32)
+    m = (cb[:, :, :, None] * L.reshape(S, nc, G, hg, Q, Q)).astype(dtype)
+    y = jnp.einsum("scghtu,scghup->scghtp", m, xd,
+                   preferred_element_type=jnp.float32)
+    # what a chunk adds to the state by its end, and its whole decay
+    to_end = jnp.exp(cs[..., -1:] - cs).reshape(S, nc, G, hg, Q, 1)
+    added = jnp.einsum("scgun,scghup->scghpn", Bc,
+                       (xd * to_end).astype(dtype),
+                       preferred_element_type=jnp.float32)
+    decay = jnp.exp(cs[..., -1]).reshape(S, nc, G, hg)
+
+    def step(state, inp):
+        add, dec = inp
+        return state * dec[..., None, None] + add, state
+
+    _, entering = jax.lax.scan(
+        step, added[:, 0] * 0.0,
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(decay, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)           # (S, nc, G, hg, P, N)
+    # what the state a chunk entered with gives each of its positions
+    y = y + jnp.einsum("scgtn,scghpn->scghtp", Cc, entering.astype(dtype),
+                       preferred_element_type=jnp.float32) \
+        * jnp.exp(cs).reshape(S, nc, G, hg, Q, 1)
+    y = jnp.moveaxis(y.reshape(S, nc, H, Q, P), 2, 3)
+    return y.reshape(S, nc * Q, H, P)[:, :T]
+
+
+def ssd_chunked(x, delta, A, B, C, chunk, dtype, head_block=None):
+    """``y_t = S_t C_t`` with ``S_t = exp(delta_t A) S_{t-1} + delta_t
+    x_t B_t^T`` and ``S_{-1} = 0``, a head at a time, by chunks.
+
+    ``x`` (S, T, H, P); ``delta`` (S, T, H) float32; ``A`` (H,) float32,
+    negative; ``B``, ``C`` (S, T, G, N), the H / G heads of a group
+    sharing them. Returns ((S, T, H, P) float32, chunks scanned). T is
+    padded to whole chunks with delta = 0, which leaves the state as it
+    is and adds nothing. Heads are a batch axis and (chunk, chunk),
+    (chunk, P), (chunk, N) the tiles, so every product is the MXU's.
+
+    ``head_block`` heads of a group are computed at a time (default:
+    ``ssd_head_block`` of the shapes). A group's heads that do not fill
+    the last block are padded with heads of delta = 0 and x = 0, which
+    give 0 and are cut off again."""
+    S, T, H, P = x.shape
+    G = B.shape[-2]
+    Q, hg = int(chunk), H // G
+    hb = int(head_block or ssd_head_block(S, T, H, G, Q))
+    n = S * -(-T // Q)
+    if hb >= hg:
+        return _ssd_heads(x, delta, A, B, C, Q, dtype), n
+    nb = -(-hg // hb)
+
+    def blocks(v):      # (a, b, H, *rest) -> (nb, a, b, G * hb, *rest)
+        lead, rest = v.shape[:2], v.shape[3:]
+        v = jnp.pad(v.reshape(lead + (G, hg) + rest),
+                    ((0, 0),) * 3 + ((0, nb * hb - hg),)
+                    + ((0, 0),) * len(rest))
+        v = jnp.moveaxis(v.reshape(lead + (G, nb, hb) + rest), 3, 0)
+        return v.reshape((nb,) + lead + (G * hb,) + rest)
+
+    @jax.checkpoint
+    def one(args):
+        xb, db, ab = args
+        return _ssd_heads(xb, db, ab[0, 0], B, C, Q, dtype)
+
+    # A is a slice of the flat weight vector. Left open, the compiler
+    # carries the (blocks, heads) shape back through the slice and
+    # reshapes all d weights to (d / heads a block, heads a block), whose
+    # tiles pad the short axis to 128: 8 x d floats at blocks of 16
+    A = jax.lax.optimization_barrier(A)
+    y = jax.lax.map(one, (blocks(x), blocks(delta), blocks(A[None, None])))
+    y = jnp.moveaxis(y.reshape(nb, S, T, G, hb, P), 0, 3)
+    return y.reshape(S, T, G, nb * hb, P)[:, :, :, :hg].reshape(
+        S, T, H, P), n
+
+
+# --- attention ------------------------------------------------------------
+
+def attn_query_block(S, T, Hq):
+    """Queries ``GQAttention`` takes at a time: all ``T`` (the dense
+    form) where the (S, Hq, T, T) float32 scores are within
+    ``ATTN_SCORE_BYTES``; else a multiple of 128 whose block of scores
+    is within ``ATTN_BLOCK_BYTES`` (at least 128)."""
+    if S * Hq * T * T * 4 <= ATTN_SCORE_BYTES:
+        return T
+    return int(max(1, ATTN_BLOCK_BYTES // (S * Hq * T * 4 * 128)) * 128)
+
+
+def gqa_attention(q, k, v, scale, query_block=None):
+    """Causal softmax attention of grouped query heads, exact: ``q``
+    (S, T, Hkv, Hq / Hkv, D), ``k`` / ``v`` (S, T, Hkv, D) ->
+    ((S, T, Hkv, Hq / Hkv, D) in q's dtype, whether the blocked form
+    was built); scores, softmax and its statistics float32, the value
+    product in q's dtype. ``query_block`` queries at a time (default:
+    ``attn_query_block`` of the shapes); with fewer than T the (heads,
+    T, T) scores never exist: each block of queries meets every key,
+    masks what lies ahead of it and takes the plain softmax of its
+    whole rows, under ``jax.checkpoint``."""
+    S, T, Hkv, g, D = q.shape
+    bq = int(query_block or attn_query_block(S, T, Hkv * g))
+
+    if bq >= T:                     # the dense form, as it always was
+        att = jnp.einsum("stgqd,sugd->sgqtu", q, k,
+                         preferred_element_type=jnp.float32) * scale
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
+        out = jnp.einsum("sgqtu,sugd->stgqd", att.astype(q.dtype), v)
+        return out, False
+
+    @jax.checkpoint
+    def rows(qb, first):
+        """The queries at positions first, first + 1, ..."""
+        att = jnp.einsum("stgqd,sugd->sgqtu", qb, k,
+                         preferred_element_type=jnp.float32) * scale
+        seen = (first + jnp.arange(bq))[:, None] >= jnp.arange(T)[None, :]
+        att = jax.nn.softmax(jnp.where(seen, att, -jnp.inf), axis=-1)
+        return jnp.einsum("sgqtu,sugd->stgqd", att.astype(qb.dtype), v)
+
+    nq = -(-T // bq)
+    qp = jnp.pad(q, ((0, 0), (0, nq * bq - T)) + ((0, 0),) * 3)
+    qp = jnp.moveaxis(qp.reshape(S, nq, bq, Hkv, g, D), 1, 0)
+    out = jax.lax.map(lambda a: rows(*a),
+                      (qp, jnp.arange(nq, dtype=jnp.int32) * bq))
+    out = jnp.moveaxis(out, 0, 1).reshape(S, nq * bq, Hkv, g, D)[:, :T]
+    return out, True
+
+
+# --- layers ---------------------------------------------------------------
+
+class Weights(nn.Module):
+    """Declares matrices under the reference's names; no ``Dense``:
+    there are no biases, and several are used as stacks."""
+    cfg: Any
+
+    def mat(self, name, shape):
+        return self.param(name, nn.initializers.normal(
+            stddev=self.cfg.initializer_range), shape)
+
+
+def _dt_bias_init(cfg):
+    """Mamba-2's: delta = exp(U(log min, log max)) floored, through the
+    inverse of softplus."""
+    def init(key, shape):
+        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+        d = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, lo, hi)), cfg.time_step_floor)
+        return d + jnp.log(-jnp.expm1(-d))
+    return init
+
+
+def _uniform(lo, hi, fn=lambda v: v):
+    return lambda key, shape: fn(jax.random.uniform(
+        key, shape, jnp.float32, lo, hi))
+
+
+class Mamba2Mixer(Weights):
+    """``(y, chunks scanned)``."""
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        S, T, C = x.shape
+        H, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
+        N, K = cfg.ssm_state_size, cfg.conv_kernel
+        inner, bc = H * P, G * N
+        in_proj = self.mat("in_proj", (C, 2 * inner + 2 * bc + H))
+        bound = K ** -0.5           # the conv keeps PyTorch's default
+        conv_w = self.param("conv_w", _uniform(-bound, bound),
+                            (K, inner + 2 * bc))
+        conv_b = self.param("conv_b", _uniform(-bound, bound),
+                            (inner + 2 * bc,))
+        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (H,))
+        a_log = self.param("A_log", _uniform(1.0, 16.0, jnp.log), (H,))
+        skip = self.param("D", nn.initializers.ones, (H,))
+        scale = self.param("gate_norm", nn.initializers.ones, (inner,))
+        out_proj = self.mat("out_proj", (inner, C))
+        with jax.named_scope("ssm_mixer"):
+            zxd = x @ in_proj.astype(dt)
+            z = zxd[..., :inner].astype(jnp.float32)
+            xbc = zxd[..., inner:2 * inner + 2 * bc].astype(jnp.float32)
+            delta = jax.nn.softplus(
+                zxd[..., 2 * inner + 2 * bc:].astype(jnp.float32) + dt_bias)
+            # causal depthwise conv: position t sees t-K+1 .. t
+            xp = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            xbc = jax.nn.silu(sum(conv_w[k] * xp[:, k:k + T]
+                                  for k in range(K)) + conv_b)
+            xs = xbc[..., :inner].reshape(S, T, H, P)
+            with jax.named_scope("ssm_scan"):
+                y, n = ssd_chunked(
+                    xs, delta, -jnp.exp(a_log),
+                    xbc[..., inner:inner + bc].reshape(S, T, G, N),
+                    xbc[..., inner + bc:].reshape(S, T, G, N),
+                    cfg.chunk_size, dt)
+            y = (y + skip[:, None] * xs).reshape(S, T, inner)
+            # gate first, then one norm a group
+            g = (y * jax.nn.silu(z)).reshape(S, T, G, inner // G)
+            g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                                  + cfg.layer_norm_epsilon)
+            out = (g.reshape(S, T, inner) * scale).astype(dt) \
+                @ out_proj.astype(dt)
+        return out, n
+
+
+class GQAttention(Weights):
+    """``scale`` multiplies the scores: ``head_dim ** -0.5`` where the
+    model states none. With ``with_form`` the result is ``(y, whether
+    the blocked form was built)``, for a model that counts it."""
+    scale: Optional[float] = None
+    with_form: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, dt = self.cfg, self.cfg.dtype
+        S, T, C = x.shape
+        Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                      cfg.head_dim)
+        wq, wk = self.mat("q", (C, Hq * D)), self.mat("k", (C, Hkv * D))
+        wv, wo = self.mat("v", (C, Hkv * D)), self.mat("o", (Hq * D, C))
+        q = (x @ wq.astype(dt)).reshape(S, T, Hkv, Hq // Hkv, D)
+        k = (x @ wk.astype(dt)).reshape(S, T, Hkv, D)
+        v = (x @ wv.astype(dt)).reshape(S, T, Hkv, D)
+        with jax.named_scope("gqa_attn"):
+            out, blocked = gqa_attention(q, k, v, float(
+                D ** -0.5 if self.scale is None else self.scale))
+        out = out.reshape(S, T, Hq * D) @ wo.astype(dt)
+        return (out, blocked) if self.with_form else out

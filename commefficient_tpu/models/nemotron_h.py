@@ -21,17 +21,11 @@ embedding and head over a vocabulary slice.
 The equations are restated, with the recurrence taken one position at
 a time, in ``benchmark/reference/nemotron3-super-ep64-tp8.py``, the
 plain float32 reference this module is tested against
-(``tests/test_nemotron_h.py``). What is TPU-shaped here:
+(``tests/test_nemotron_h.py``). The Mamba-2 mixer (the recurrence by
+chunks) and the attention are ``models/mixers.py``'s, the code
+``models/granite_hybrid.py`` builds from too; what is TPU-shaped in
+them is told there. Here:
 
-- **The recurrence is computed by chunks** (SSD, Dao & Gu
-  arXiv:2405.21060, chunks of ``chunk_size`` positions): inside a chunk
-  the masked product ``(C B^T * L) X`` with ``L = exp(segsum(delta A))``,
-  three matrix products a head on (chunk, chunk) tiles; between chunks
-  the state, carried by a ``lax.scan`` over the chunks. It is
-  differentiated by plain reverse mode under the clients ``vmap``
-  (``core/rounds.py make_local_loss``) and ``--remat``. delta, A, the
-  cumulative sums and the exponentials are float32; the products take
-  operands in the compute dtype and accumulate in float32.
 - **The share.** ``mamba_num_heads`` heads in ``n_groups`` groups,
   ``num_attention_heads`` query and ``num_key_value_heads`` key/value
   heads, ``n_held_experts`` experts from ``expert_offset`` and
@@ -42,10 +36,8 @@ plain float32 reference this module is tested against
   code ``models/joyai.py`` routes with. No exchange, and nothing stands
   in for the absent chips.
 
-Scopes (``PERF.md`` section 3): ``ssm_mixer`` (the whole Mamba-2
-mixer) > ``ssm_scan`` (decay sums, the in-chunk products, the state
-scan; conv, gate-norm and projections outside it); ``gqa_attn``
-(scores, softmax, value product); ``moe_route``, ``moe_experts`` (the
+Scopes (``PERF.md`` section 3): the mixers' ``ssm_mixer`` >
+``ssm_scan`` and ``gqa_attn``; ``moe_route``, ``moe_experts`` (the
 latent projections inside it), ``moe_combine``; the head's ``lm_head``
 is ``lm_nll_sums_chunked``'s.
 """
@@ -53,7 +45,6 @@ is ``lm_nll_sums_chunked``'s.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import flax.linen as nn
@@ -62,6 +53,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.models import register_model
+from commefficient_tpu.models.mixers import (GQAttention,  # noqa: F401
+                                             Mamba2Mixer, ssd_chunked)
+from commefficient_tpu.models.mixers import Weights as _Weights
 from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS, dispatch,
                                           fold_stats, layer_stats, route,
                                           routed_experts)
@@ -158,164 +152,12 @@ class NemotronHConfig:
         return self.hybrid_override_pattern.count(kind)
 
 
-# --- the Mamba-2 recurrence, by chunks --------------------------------------
-
-def ssd_chunked(x, delta, A, B, C, chunk, dtype):
-    """``y_t = S_t C_t`` with ``S_t = exp(delta_t A) S_{t-1} + delta_t
-    x_t B_t^T`` and ``S_{-1} = 0``, a head at a time, by chunks.
-
-    ``x`` (S, T, H, P); ``delta`` (S, T, H) float32; ``A`` (H,) float32,
-    negative; ``B``, ``C`` (S, T, G, N), the H / G heads of a group
-    sharing them. Returns ((S, T, H, P) float32, chunks scanned). T is
-    padded to whole chunks with delta = 0, which leaves the state as it
-    is and adds nothing. Heads are a batch axis and (chunk, chunk),
-    (chunk, P), (chunk, N) the tiles, so every product is the MXU's."""
-    S, T, H, P = x.shape
-    G, N = B.shape[-2:]
-    Q, hg = int(chunk), H // G
-    nc = -(-T // Q)
-    pad = nc * Q - T
-
-    def chunks(v):                      # (S, T, n, ...) -> (S, nc, n, Q, ...)
-        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-        return jnp.moveaxis(v.reshape((S, nc, Q) + v.shape[2:]), 2, 3)
-
-    a = chunks(delta * A)                                  # (S, nc, H, Q)
-    xd = chunks((x.astype(jnp.float32) * delta[..., None]).astype(dtype))
-    xd = xd.reshape(S, nc, G, hg, Q, P)
-    Bc, Cc = chunks(B.astype(dtype)), chunks(C.astype(dtype))
-    cs = jnp.cumsum(a, axis=-1)                            # float32
-    # in a chunk: L[t, u] = exp(sum_{u < v <= t} a_v) for u <= t
-    seg = cs[..., :, None] - cs[..., None, :]
-    L = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), seg, -jnp.inf))
-    cb = jnp.einsum("scgtn,scgun->scgtu", Cc, Bc,
-                    preferred_element_type=jnp.float32)
-    m = (cb[:, :, :, None] * L.reshape(S, nc, G, hg, Q, Q)).astype(dtype)
-    y = jnp.einsum("scghtu,scghup->scghtp", m, xd,
-                   preferred_element_type=jnp.float32)
-    # what a chunk adds to the state by its end, and its whole decay
-    to_end = jnp.exp(cs[..., -1:] - cs).reshape(S, nc, G, hg, Q, 1)
-    added = jnp.einsum("scgun,scghup->scghpn", Bc,
-                       (xd * to_end).astype(dtype),
-                       preferred_element_type=jnp.float32)
-    decay = jnp.exp(cs[..., -1]).reshape(S, nc, G, hg)
-
-    def step(state, inp):
-        add, dec = inp
-        return state * dec[..., None, None] + add, state
-
-    _, entering = jax.lax.scan(
-        step, added[:, 0] * 0.0,
-        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(decay, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1)           # (S, nc, G, hg, P, N)
-    # what the state a chunk entered with gives each of its positions
-    y = y + jnp.einsum("scgtn,scghpn->scghtp", Cc, entering.astype(dtype),
-                       preferred_element_type=jnp.float32) \
-        * jnp.exp(cs).reshape(S, nc, G, hg, Q, 1)
-    y = jnp.moveaxis(y.reshape(S, nc, H, Q, P), 2, 3)
-    return y.reshape(S, nc * Q, H, P)[:, :T], S * nc
-
-
 # --- layers ---------------------------------------------------------------
-
-class _Weights(nn.Module):
-    """Declares matrices under the reference's names; no ``Dense``:
-    there are no biases, and several are used as stacks."""
-    cfg: NemotronHConfig
-
-    def mat(self, name, shape):
-        return self.param(name, nn.initializers.normal(
-            stddev=self.cfg.initializer_range), shape)
-
-
-def _dt_bias_init(cfg):
-    """Mamba-2's: delta = exp(U(log min, log max)) floored, through the
-    inverse of softplus."""
-    def init(key, shape):
-        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-        d = jnp.maximum(jnp.exp(jax.random.uniform(
-            key, shape, jnp.float32, lo, hi)), cfg.time_step_floor)
-        return d + jnp.log(-jnp.expm1(-d))
-    return init
-
-
-def _uniform(lo, hi, fn=lambda v: v):
-    return lambda key, shape: fn(jax.random.uniform(
-        key, shape, jnp.float32, lo, hi))
-
+# (``Mamba2Mixer`` and ``GQAttention`` are imported; the scores keep the
+# default scale, 1 / sqrt(``head_dim``))
 
 def _relu2(x):
     return jnp.square(jax.nn.relu(x))
-
-
-class Mamba2Mixer(_Weights):
-    """``(y, chunks scanned)``."""
-
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.dtype
-        S, T, C = x.shape
-        H, P, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups
-        N, K = cfg.ssm_state_size, cfg.conv_kernel
-        inner, bc = H * P, G * N
-        in_proj = self.mat("in_proj", (C, 2 * inner + 2 * bc + H))
-        bound = K ** -0.5           # the conv keeps PyTorch's default
-        conv_w = self.param("conv_w", _uniform(-bound, bound),
-                            (K, inner + 2 * bc))
-        conv_b = self.param("conv_b", _uniform(-bound, bound),
-                            (inner + 2 * bc,))
-        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (H,))
-        a_log = self.param("A_log", _uniform(1.0, 16.0, jnp.log), (H,))
-        skip = self.param("D", nn.initializers.ones, (H,))
-        scale = self.param("gate_norm", nn.initializers.ones, (inner,))
-        out_proj = self.mat("out_proj", (inner, C))
-        with jax.named_scope("ssm_mixer"):
-            zxd = x @ in_proj.astype(dt)
-            z = zxd[..., :inner].astype(jnp.float32)
-            xbc = zxd[..., inner:2 * inner + 2 * bc].astype(jnp.float32)
-            delta = jax.nn.softplus(
-                zxd[..., 2 * inner + 2 * bc:].astype(jnp.float32) + dt_bias)
-            # causal depthwise conv: position t sees t-K+1 .. t
-            xp = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-            xbc = jax.nn.silu(sum(conv_w[k] * xp[:, k:k + T]
-                                  for k in range(K)) + conv_b)
-            xs = xbc[..., :inner].reshape(S, T, H, P)
-            with jax.named_scope("ssm_scan"):
-                y, n = ssd_chunked(
-                    xs, delta, -jnp.exp(a_log),
-                    xbc[..., inner:inner + bc].reshape(S, T, G, N),
-                    xbc[..., inner + bc:].reshape(S, T, G, N),
-                    cfg.chunk_size, dt)
-            y = (y + skip[:, None] * xs).reshape(S, T, inner)
-            # gate first, then one norm a group
-            g = (y * jax.nn.silu(z)).reshape(S, T, G, inner // G)
-            g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
-                                  + cfg.layer_norm_epsilon)
-            out = (g.reshape(S, T, inner) * scale).astype(dt) \
-                @ out_proj.astype(dt)
-        return out, n
-
-
-class GQAttention(_Weights):
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.dtype
-        S, T, C = x.shape
-        Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                      cfg.head_dim)
-        wq, wk = self.mat("q", (C, Hq * D)), self.mat("k", (C, Hkv * D))
-        wv, wo = self.mat("v", (C, Hkv * D)), self.mat("o", (Hq * D, C))
-        q = (x @ wq.astype(dt)).reshape(S, T, Hkv, Hq // Hkv, D)
-        k = (x @ wk.astype(dt)).reshape(S, T, Hkv, D)
-        v = (x @ wv.astype(dt)).reshape(S, T, Hkv, D)
-        with jax.named_scope("gqa_attn"):
-            att = jnp.einsum("stgqd,sugd->sgqtu", q, k,
-                             preferred_element_type=jnp.float32) \
-                * float(D ** -0.5)
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
-            out = jnp.einsum("sgqtu,sugd->stgqd", att.astype(dt), v)
-        return out.reshape(S, T, Hq * D) @ wo.astype(dt)
 
 
 class _Pair(_Weights):

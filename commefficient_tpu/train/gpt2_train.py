@@ -49,7 +49,7 @@ MAX_SEQ_LEN = 256  # static pad length (persona sequences are short)
 #: ``model_type`` and ``config_class``, ``causal_lm_loss`` and
 #: ``COUNTERS``); any other value (the shared parser's default is a CV
 #: model) means GPT2DoubleHeads, as before the flag was read here
-CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM")
+CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM", "GraniteHybridLM")
 
 
 def is_causal_lm(args) -> bool:

@@ -1,0 +1,164 @@
+#!/usr/bin/env python3
+"""By hand, on the chip: what do the two forms of the hybrid models'
+mixers (``models/mixers.py``) cost, forward and backward under the
+clients ``vmap``, at a cell's shapes?
+
+    python3 scripts/mixer_probe.py [--clients 4] [--seq 2048] [--reps 5]
+        [--attn 32,8,64] [--ssd 64,1,64,128,256] [--rehearse]
+
+Attention (``--attn`` query heads, key/value heads, head size): the
+dense form (the float32 (heads, T, T) scores written out), the blocked
+form at 128 / 256 / 512 queries a block (``gqa_attention``), and the
+library's Pallas flash kernel that ``models/gpt2.py --attn_impl flash``
+calls, with the key/value heads repeated to the query heads' count.
+The scan (``--ssd`` heads, groups, head size, state, chunk):
+``ssd_chunked`` with all the heads at once and with 8 / 16 / 32 a
+block. Each is jitted as ``jax.grad`` of a sum over a ``vmap`` over
+clients of the ``jax.checkpoint``-ed form on bf16 operands, compiled
+(its ``memory_analysis().temp_size_in_bytes`` printed: a form whose
+temporaries pass the chip is reported and skipped), run once, then
+timed over ``--reps`` calls. Every form's gradient is compared with
+the first's that ran. PR 34's step 1 (PERF.md section 6).
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--clients", type=int, default=4)
+    ap.add_argument("--seq", type=int, default=2048)
+    ap.add_argument("--attn", default="32,8,64")
+    ap.add_argument("--ssd", default="64,1,64,128,256")
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on whatever backend there is")
+    a = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from commefficient_tpu.models.mixers import gqa_attention, ssd_chunked
+
+    W, T = a.clients, a.seq
+    Hq, Hkv, D = map(int, a.attn.split(","))
+    H, G, P, N, Q = map(int, a.ssd.split(","))
+    if a.rehearse:
+        T, Hq, Hkv, D, H, P, N, Q = 256, 4, 2, 8, 8, 8, 8, 16
+    dt = jnp.bfloat16
+    tpu = jax.devices()[0].platform == "tpu"
+    limit = 15.5e9 if tpu else float("inf")
+
+    def measure(name, fn, args, first):
+        lowered = jax.jit(jax.grad(fn, argnums=tuple(range(len(args)))))
+        try:
+            exe = lowered.lower(*args).compile()
+        except Exception as e:  # noqa: BLE001  (a form the chip refuses)
+            print(json.dumps({"form": name, "failed": str(e)[:300]}))
+            return first
+        temp = exe.memory_analysis().temp_size_in_bytes
+        row = {"form": name, "temp_GB": temp / 1e9}
+        if temp > limit:
+            print(json.dumps(dict(row, skipped="temporaries pass the chip")))
+            return first
+        try:
+            got = jax.block_until_ready(exe(*args))
+        except Exception as e:  # noqa: BLE001
+            print(json.dumps(dict(row, failed=str(e)[:300])))
+            return first
+        times = []
+        for _ in range(a.reps):
+            t = time.perf_counter()
+            jax.block_until_ready(exe(*args))
+            times.append((time.perf_counter() - t) * 1e3)
+        if first is None:
+            first = got
+        else:
+            row["grad_rel_to_first"] = max(
+                float(jnp.linalg.norm((x - y).astype(jnp.float32))
+                      / jnp.linalg.norm(y.astype(jnp.float32)))
+                for x, y in zip(got, first))
+        print(json.dumps(dict(row, ms=statistics.median(times),
+                              ms_all=[round(t, 2) for t in times])),
+              flush=True)
+        return first
+
+    # --- attention ---------------------------------------------------------
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(k[0], (W, 1, T, Hkv, Hq // Hkv, D), dt)
+    kk = jax.random.normal(k[1], (W, 1, T, Hkv, D), dt)
+    v = jax.random.normal(k[2], (W, 1, T, Hkv, D), dt)
+    scale = 1.0 / D
+    print(json.dumps({"attention": {"clients": W, "T": T, "Hq": Hq,
+                                    "Hkv": Hkv, "D": D, "scale": scale}}))
+
+    def attn_loss(block):
+        one = jax.checkpoint(lambda q, k, v: gqa_attention(
+            q, k, v, scale, query_block=block)[0])
+        return lambda q, k, v: jnp.sum(jnp.sin(
+            jax.vmap(one)(q, k, v).astype(jnp.float32)))
+
+    def flash_loss(q, k, v):
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            BlockSizes, flash_attention)
+        b = next(x for x in (512, 256, 128) if T % x == 0)
+        blocks = BlockSizes(
+            block_q=b, block_k_major=b, block_k=b, block_b=1,
+            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
+            block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+
+        @jax.checkpoint
+        def one(q, k, v):               # a client: (1, T, Hkv, g, D)
+            g = q.shape[3]
+            qh = q.reshape(1, T, Hkv * g, D).transpose(0, 2, 1, 3)
+            kh = jnp.repeat(k, g, axis=2).transpose(0, 2, 1, 3)
+            vh = jnp.repeat(v, g, axis=2).transpose(0, 2, 1, 3)
+            out = flash_attention(qh, kh, vh, causal=True, sm_scale=scale,
+                                  block_sizes=blocks)
+            return out.transpose(0, 2, 1, 3).reshape(q.shape)
+        return jnp.sum(jnp.sin(jax.vmap(one)(q, k, v).astype(jnp.float32)))
+
+    first = None
+    for name, block in [("dense", T), ("blocked_128", 128),
+                        ("blocked_256", 256), ("blocked_512", 512)]:
+        if block <= T:
+            first = measure("attn." + name, attn_loss(block), (q, kk, v),
+                            first)
+    if tpu:
+        measure("attn.flash_pallas_kv_repeated", flash_loss, (q, kk, v),
+                first)
+
+    # --- the scan -----------------------------------------------------------
+    k = jax.random.split(jax.random.PRNGKey(1), 5)
+    x = jax.random.normal(k[0], (W, 1, T, H, P), dt)
+    delta = jax.nn.softplus(jax.random.normal(k[1], (W, 1, T, H)) - 3.0)
+    A = -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.5))
+    B = jax.random.normal(k[3], (W, 1, T, G, N), dt)
+    C = jax.random.normal(k[4], (W, 1, T, G, N), dt)
+    print(json.dumps({"scan": {"clients": W, "T": T, "H": H, "G": G, "P": P,
+                               "N": N, "chunk": Q}}))
+
+    def scan_loss(hb):
+        one = jax.checkpoint(lambda x, d, b, c: ssd_chunked(
+            x, d, A, b, c, Q, dt, head_block=hb)[0])
+        return lambda x, d, b, c: jnp.sum(jnp.sin(jax.vmap(one)(x, d, b, c)))
+
+    first = None
+    for hb in (H // G, 32, 16, 8):
+        if hb <= H // G:
+            name = "all_heads" if hb == H // G else f"blocks_of_{hb}"
+            first = measure("scan." + name, scan_loss(hb),
+                            (x, delta, B, C), first)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
