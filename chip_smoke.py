@@ -126,16 +126,16 @@ def sketch_kernels(d, rot_lanes):
     _report(f"estimates_pallas {tag}", tc, tr, f"bit-exact on {how}")
 
 
-def sketch_quant_kernel():
+def sketch_quant_kernel(d, rot_lanes):
     """Emit + quantize vs quantize_local of the same Pallas table:
     identical bytes (tests/test_quant.py). int8 is the fused kernel;
     fp8 is the sketch kernel plus XLA's quantize, chosen by code
     (CountSketch.sketch_quantized) — here it only has to run."""
     from commefficient_tpu.ops.quant import quantize_local
     from commefficient_tpu.ops.sketch import CountSketch
-    cs = CountSketch(d=D_RESNET9, c=COLS, r=ROWS, seed=7,
-                     backend="pallas")
-    v = jax.random.normal(jax.random.PRNGKey(2), (D_RESNET9,))
+    cs = CountSketch(d=d, c=COLS, r=ROWS, seed=7,
+                     backend="pallas", rot_lanes=rot_lanes)
+    v = jax.random.normal(jax.random.PRNGKey(2), (d,))
     for wire, fused in (("int8", "sketch_quant_pallas"),
                         ("fp8", "sketch_pallas + XLA quantize")):
         (qf, rmf), tc, tr = _timed(
@@ -145,7 +145,8 @@ def sketch_quant_kernel():
         assert qf.dtype.itemsize == 1, qf.dtype
         assert np.asarray(qf).tobytes() == np.asarray(qu).tobytes()
         np.testing.assert_array_equal(np.asarray(rmf), np.asarray(rmu))
-        _report(f"{fused} {wire} d={D_RESNET9}", tc, tr)
+        _report(f"{fused} {wire} d={d} rot_lanes={rot_lanes} "
+                f"({cs.rot_form})", tc, tr)
 
 
 def take_mask_kernel(d):
@@ -217,8 +218,11 @@ def kernel_legs():
         for rl in (0, 1024):
             leg(f"kernel sketch+estimates d={d} rot_lanes={rl}",
                 lambda d=d, rl=rl: sketch_kernels(d, rl))
-    leg("kernel sketch_quantized int8 (fused) + fp8 (unfused)",
-        sketch_quant_kernel)
+    # the rolled row loop, and the addressed one (GPT-2's 238 chunks)
+    for d, rl in ((D_RESNET9, 0), (D_GPT2, 1024)):
+        leg("kernel sketch_quantized int8 (fused) + fp8 (unfused) "
+            f"d={d} rot_lanes={rl}",
+            lambda d=d, rl=rl: sketch_quant_kernel(d, rl))
     for d in (D_RESNET9, D_GPT2):
         leg(f"kernel take_mask d={d}", lambda d=d: take_mask_kernel(d))
     leg("kernel flce fwd+bwd", flce_kernels)

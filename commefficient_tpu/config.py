@@ -240,8 +240,9 @@ class Config:
     # seeds, so the −44% kernel-pair / −8% flagship-round win is on by
     # default — core/rounds.py args2sketch); 0 everywhere else, since
     # quantized rotations pay their heavier collision tail for nothing
-    # without the Pallas sublane roll. 0 = force full granularity;
-    # >0 quantizes rotations to multiples of that lane width.
+    # without the Pallas kernels' addressed rotation. 0 = force full
+    # granularity; >0 quantizes rotations to multiples of that many
+    # elements.
     # Sketch tables/error state are not comparable across different
     # resolved values (different rotation streams) — a checkpoint
     # resumed under a different backend re-resolves -1, so pin an

@@ -98,9 +98,10 @@ def resolve_rot_lanes(cfg: Config) -> int:
     """Resolve ``--sketch_rot_lanes -1`` (auto, the default).
 
     Quantized rotations pay a heavier collision tail (rot_lanes/c for
-    same-lane-offset pairs instead of 1/c) and buy a single sublane
-    roll ONLY inside the Pallas TPU kernels — so auto engages 1024
-    exactly where that trade was measured to win with no quality cost:
+    same-lane-offset pairs instead of 1/c) and buy a rotation by
+    address (no roll at all) ONLY inside the Pallas TPU kernels — so
+    auto engages 1024 exactly where that trade was measured to win
+    with no quality cost:
     a TPU default backend at a Pallas-supported, lane-aligned,
     large-d geometry (−44% on the sketch/estimates kernel pair at
     d=124M, −8% on the flagship GPT-2 federated round; 24-epoch
@@ -113,13 +114,14 @@ def resolve_rot_lanes(cfg: Config) -> int:
     lanes = getattr(cfg, "sketch_rot_lanes", 0)
     if lanes >= 0:
         return lanes
-    from commefficient_tpu.ops.sketch_pallas import supported
+    from commefficient_tpu.ops.sketch_pallas import rotation_form, supported
     d, c, r = cfg.grad_size, cfg.num_cols, cfg.num_rows
-    # c % 1024 == 0 also implies _pick_lanes(c) == 1024 (it probes
-    # 1024 first), so the sublane fast path's rot_step % L == 0
-    # precondition holds whenever the modulus check passes
+    # 1,024 is one float32 vreg: auto quantizes exactly where the
+    # kernels then address the table by whole vregs (at rot_lanes 0
+    # they roll every chunk), and c leaves at least 8 such rotations
     if (d < (1 << 20) or not supported(d, c, r)
-            or c % _AUTO_ROT_LANES or c // _AUTO_ROT_LANES < 8):
+            or c // _AUTO_ROT_LANES < 8
+            or rotation_form(c, r, _AUTO_ROT_LANES) != "addressed"):
         return 0
     return _AUTO_ROT_LANES if jax.default_backend() == "tpu" else 0
 
@@ -236,6 +238,14 @@ def server_select_form(cfg: Config, mesh=None):
     if model_axis_size(mesh) > 1:
         return "flat", sketch.d
     return sketch.select_form(cfg.k)
+
+
+def sketch_rot_form(cfg: Config) -> Optional[str]:
+    """The form the sketch kernels of the rounds built from ``cfg``
+    apply a rotation in (``CountSketch.rot_form``); None outside
+    sketch mode."""
+    sketch = args2sketch(cfg)
+    return None if sketch is None else sketch.rot_form
 
 
 def build_client_round(cfg: Config, loss_fn: Optional[Callable],

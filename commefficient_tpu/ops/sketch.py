@@ -118,10 +118,14 @@ class CountSketch:
     # tables agree to ULP-level summation-order tolerance, recovery
     # from a given table is bit-exact.
     backend: str = "auto"
-    # > 0: quantize rotations to multiples of this lane width, so the
-    # Pallas kernels' per-(row, chunk) circular shift becomes a SINGLE
-    # sublane roll instead of the 5-op arbitrary-shift decomposition
-    # (the kernels are VPU-bound on rolls at large d). Collision
+    # > 0: quantize rotations to multiples of this many elements.
+    # At a multiple of 1,024 (one float32 vreg: 8 sublanes x 128
+    # lanes) on a chunk of whole (32, 128) tiles every rotation moves
+    # whole vregs, and the Pallas kernels apply it as a row offset into
+    # the VMEM-resident table (ops/sketch_pallas.py ``rotation_form``:
+    # "addressed") where they otherwise roll each chunk through the
+    # 5-op arbitrary-shift decomposition (the kernels were VPU-bound
+    # on rolls at large d). Collision
     # tradeoff: coords in chunks t != t' with equal lane offset
     # (j ≡ j' mod rot_lanes, a 1/rot_lanes fraction of pairs) collide
     # with probability rot_lanes/c instead of 1/c; all other cross-
@@ -261,10 +265,11 @@ class CountSketch:
     # --- sketching (accumulateVec) --------------------------------------
 
     def _check_rot_lanes_engage(self):
-        """rot_lanes only pays off when the kernels' roll collapses to
-        a sublane roll, i.e. rot_lanes is a multiple of the lane width
-        the kernel picks for this c. Otherwise the user eats the
-        heavier collision tail for zero speedup — warn once."""
+        """rot_lanes only pays off where the kernels address the table
+        instead of rolling the chunk: every rotation a whole number of
+        vregs on a chunk of whole tiles (``rot_form``). Otherwise the
+        user eats the heavier collision tail for zero speedup — warn
+        once."""
         if self.rot_lanes <= 0:
             return
         import logging
@@ -279,28 +284,28 @@ class CountSketch:
         if self.backend == "xla":
             self._warn_rot_lanes_no_pallas("xla")
             return
-        from commefficient_tpu.ops.sketch_pallas import _pick_lanes
-        L = _pick_lanes(self.c)
-        if L is not None and self.rot_lanes % L != 0:
+        from commefficient_tpu.ops.sketch_pallas import rotation_form
+        if rotation_form(self.c, self.r, self.rot_lanes) != "addressed":
             log.warning(
-                "rot_lanes=%d is not a multiple of the kernel lane "
-                "width %d for c=%d: rotations are quantized (heavier "
-                "collision tail) but the sublane fast path does NOT "
-                "engage — use rot_lanes=%d",
-                self.rot_lanes, L, self.c, L)
+                "rot_lanes=%d at c=%d, r=%d: rotations are quantized "
+                "(heavier collision tail) but they are not whole vregs "
+                "of whole (32, 128) tiles, so the kernels still roll "
+                "every chunk — use rot_lanes=1024 with c a multiple "
+                "of 4096", self.rot_lanes, self.c, self.r)
 
     def _warn_rot_lanes_no_pallas(self, resolved: str):
         """Quantized rotations pay their collision tail only to buy
-        the Pallas sublane roll; any non-pallas lowering (unsupported
-        geometry, non-TPU platform, explicit backend="xla") gains
-        nothing from them — warn once per instance."""
+        the Pallas kernels' addressed rotation; any non-pallas lowering
+        (unsupported geometry, non-TPU platform, explicit
+        backend="xla") gains nothing from them — warn once per
+        instance."""
         if getattr(self, "_rot_lanes_warned", False):
             return
         object.__setattr__(self, "_rot_lanes_warned", True)
         import logging
         logging.getLogger(__name__).warning(
-            "sketch_rot_lanes=%d with backend %r: the sublane fast "
-            "path only exists in the Pallas TPU kernels — rotations "
+            "sketch_rot_lanes=%d with backend %r: the addressed "
+            "rotation only exists in the Pallas TPU kernels — rotations "
             "are quantized (heavier collision tail) for zero speedup "
             "here; use rot_lanes=0", self.rot_lanes, resolved)
 
@@ -429,8 +434,8 @@ class CountSketch:
         # lowered for TPU). Its table still comes from the Pallas
         # sketch kernel below; only the quantize runs as XLA ops.
         if backend in ("pallas", "pallas_interpret") and wire == "int8":
-            from commefficient_tpu.ops.sketch_pallas import \
-                sketch_quant_pallas
+            from commefficient_tpu.ops.sketch_pallas import (
+                _pick_lanes, sketch_quant_pallas)
             assert v.shape == (self.d,), v.shape
             vp = jnp.pad(v.astype(jnp.float32),
                          (0, self._padded_d - self.d))
@@ -440,10 +445,15 @@ class CountSketch:
             rot = self._rotations()
             if rows is not None:
                 rot = rot[off:off + cnt]
+            # a row chunk takes the whole operator's rotation form (a
+            # smaller table could be addressed where the whole is not)
+            # so that it stays the same bits as the whole call's rows
+            lanes = (None if self.rot_form == "addressed"
+                     else _pick_lanes(self.c))
             return sketch_quant_pallas(
                 vp, jnp.asarray(rot), self.c, cnt,
                 int(sign_seed),
-                backend == "pallas_interpret",
+                backend == "pallas_interpret", lanes,
                 one_mix=self._one_mix_signs,
                 rot_step=self.rot_lanes, sgn=sgn,
                 row_offset=off)
@@ -671,6 +681,15 @@ class CountSketch:
             if block:
                 return "blocked", k * block
         return "flat", self.d
+
+    @property
+    def rot_form(self) -> str:
+        """``"addressed"`` or ``"rolled"``: the form the Pallas kernels
+        of this geometry apply a rotation in, from the shapes alone
+        (ops/sketch_pallas.py ``rotation_form``). The round records'
+        ``sketch.rot_*`` counters."""
+        from commefficient_tpu.ops.sketch_pallas import rotation_form
+        return rotation_form(self.c, self.r, self.rot_lanes)
 
     def sketch_sparse(self, idx: jax.Array,
                       vals: jax.Array) -> jax.Array:

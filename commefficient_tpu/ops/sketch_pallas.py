@@ -23,13 +23,24 @@ match to ULP-level tolerance (chunk summation order differs); recovery
 from a given table is bit-exact. Property-tested in
 tests/test_pallas_sketch.py.
 
-Rotation trick: a chunk of width c is viewed as a 2-D ``(S, L)`` tile
-(L a multiple of 128, so lane-aligned). A 1-D circular shift by
-``o = a·L + b`` decomposes into two sublane rolls (a, a+1), a lane roll
-(b) of each, and a lane-index select — all supported by Mosaic's
-``dynamic_rotate`` at any alignment, unlike a flat 1-D rotate of
-unaligned width. Requires ``c % 128 == 0`` (the auto backend falls back
-to XLA otherwise, e.g. for the reference's default c=500000).
+Rotation, two forms chosen from the shapes alone (``rotation_form``):
+
+- *addressed*: where every rotation is a multiple of 1,024 elements
+  (one float32 vreg, 8 sublanes x 128 lanes; ``CountSketch.rot_lanes``)
+  a chunk is viewed as ``(c/128, 128)`` and a rotation by 1024·j moves
+  no element inside its vreg, only which vreg row of the table it
+  meets: the kernels add into (read from) the VMEM-resident table at
+  row offset 8·j and roll nothing. The (·, 128) views of the 1-D
+  vector, sign stream and estimates are those arrays' own tiling, so no
+  d-sized relayout is paid around the kernels either.
+- *rolled*: anything else. A chunk is viewed as a 2-D ``(S, L)`` tile
+  (L a multiple of 128, so lane-aligned). A 1-D circular shift by
+  ``o = a·L + b`` decomposes into two sublane rolls (a, a+1), a lane
+  roll (b) of each, and a lane-index select — all supported by
+  Mosaic's ``dynamic_rotate`` at any alignment, unlike a flat 1-D
+  rotate of unaligned width. Requires ``c % 128 == 0`` (the auto
+  backend falls back to XLA otherwise, e.g. for the reference's
+  default c=500000).
 
 Reference provenance: this implements the same operator as the
 reference's external CUDA ``csvec`` library (fed_aggregator.py:466-469,
@@ -59,10 +70,20 @@ _TABLE_VMEM_LIMIT = 20 * 1024 * 1024
 _VMEM_CEILING = 64 * 1024 * 1024
 
 
-def _compiler_params(table_bytes: int):
-    # table resident + r per-chunk temp rows (~table again) + double-
-    # buffered chunk blocks + relayout scratch, with margin
-    want = min(_VMEM_CEILING, max(32 * 1024 * 1024, 3 * table_bytes))
+def _addressed_vmem(table_bytes: int) -> int:
+    """What the addressed form asks of VMEM: the table twice over in a
+    scratch (the doubled accumulator / the resident table), the sketch
+    kernel's output block in its two buffers besides, and the
+    double-buffered chunk blocks with margin."""
+    return 4 * table_bytes + 12 * 1024 * 1024
+
+
+def _compiler_params(table_bytes: int, addressed: bool = False):
+    # rolled: table resident + r per-chunk temp rows (~table again) +
+    # double-buffered chunk blocks + relayout scratch, with margin
+    want = (_addressed_vmem(table_bytes) if addressed
+            else 3 * table_bytes)
+    want = min(_VMEM_CEILING, max(32 * 1024 * 1024, want))
     return pltpu.CompilerParams(vmem_limit_bytes=want)
 
 
@@ -121,17 +142,26 @@ def _rot_operand(rot, r: int, m: int):
     return rot, spec, lambda ref, row, t: ref[row, t % _ROT_BLOCK]
 
 
+def _global_index(t, c: int, S: int, L: int, base=0):
+    """uint32 global coordinate of every element of an (S, L) tile that
+    starts ``base`` elements into chunk ``t`` (``base`` 0: the whole
+    chunk; the addressed row loop passes its block's offset)."""
+    s_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 0)
+    l_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 1)
+    g = t.astype(jnp.uint32) * jnp.uint32(c) + s_idx * jnp.uint32(L) + l_idx
+    if isinstance(base, int) and base == 0:
+        return g
+    return g + jnp.asarray(base).astype(jnp.uint32)
+
+
 def _sign_hash_chunk(t, sign_seed: np.uint32, c: int, S: int, L: int,
-                     r: int):
+                     r: int, base=0):
     """One-mix sign scheme (CountSketch._one_mix_signs, r <= 16): a
     single murmur mix of the global index per chunk element; row r's
     sign is bit 16+r. Hoisted out of the kernels' row loops — hashing
     was the dominant kernel cost at 1 mix per (row, coord)."""
     assert r <= 16
-    s_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 0)
-    l_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 1)
-    g = t.astype(jnp.uint32) * jnp.uint32(c) + s_idx * jnp.uint32(L) + l_idx
-    return _mix_u32(g ^ sign_seed)
+    return _mix_u32(_global_index(t, c, S, L, base) ^ sign_seed)
 
 
 def _flip_from_hash(h, row: int):
@@ -144,15 +174,14 @@ def _flip_from_hash(h, row: int):
     return (h << (15 - row)) & jnp.uint32(0x80000000)
 
 
-def _flip_chunk(t, row: int, sign_seed: np.uint32, c: int, S: int, L: int):
+def _flip_chunk(t, row: int, sign_seed: np.uint32, c: int, S: int, L: int,
+                base=0):
     """Per-(row, coord) mix fallback for r > 16 — replicates
     ops.sketch.CountSketch._signs_row on global indices
     ``t*c + s*L + l``, returned as a sign-bit flip mask (bit 16 of the
     row-salted mix moved to bit 31). ``row`` is a Python int; ``t`` is
     traced."""
-    s_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 0)
-    l_idx = jax.lax.broadcasted_iota(jnp.uint32, (S, L), 1)
-    g = t.astype(jnp.uint32) * jnp.uint32(c) + s_idx * jnp.uint32(L) + l_idx
+    g = _global_index(t, c, S, L, base)
     row_const = (np.uint32((row * 0x9E3779B9) & 0xFFFFFFFF) ^ sign_seed)
     h = _mix_u32(g ^ jnp.uint32(row_const))
     return (h << 15) & jnp.uint32(0x80000000)
@@ -218,7 +247,7 @@ def _median_network(vals):
 
 
 def _flips_for_chunk(t, sgn_block, one_mix: bool, seed, c, S, L, r,
-                     row_offset: int = 0):
+                     row_offset: int = 0, base=0):
     """Per-row sign-bit flip masks for chunk ``t``, cheapest source
     first: a streamed packed-sign block (bit ``row`` of a u8 per
     element — 2 shift/and ops per row, no hashing), else the in-kernel
@@ -226,17 +255,148 @@ def _flips_for_chunk(t, sgn_block, one_mix: bool, seed, c, S, L, r,
     ``row_offset`` shifts every row index by the table-row offset of a
     chunked call (--overlap_depth): the sign stream is keyed by the
     ABSOLUTE table row, so a chunk's rows flip identically to the same
-    rows of a whole-table call."""
+    rows of a whole-table call. ``base``: the tile starts that many
+    elements into the chunk (``_global_index``)."""
     if sgn_block is not None:
         b32 = sgn_block.astype(jnp.uint32)
         return [(b32 << (31 - (row_offset + row)))
                 & jnp.uint32(0x80000000) for row in range(r)]
     if one_mix:
-        h = _sign_hash_chunk(t, seed, c, S, L, r)
+        h = _sign_hash_chunk(t, seed, c, S, L, r, base)
         return [_flip_from_hash(h, row_offset + row)
                 for row in range(r)]
-    return [_flip_chunk(t, row_offset + row, seed, c, S, L)
+    return [_flip_chunk(t, row_offset + row, seed, c, S, L, base)
             for row in range(r)]
+
+
+#: elements of one float32 vreg (8 sublanes x 128 lanes): a rotation
+#: by a multiple of it moves whole vregs of the (c/128, 128) view
+_VREG = 1024
+#: rows of that view one step of the addressed row loop handles: one
+#: packed-sign (uint8) tile, four float32 vregs
+_ADDR_ROWS = 32
+#: blocks a step of that loop (read at 1 / 2 / 4 on the chip: 5.64 /
+#: 5.44 / 5.30 ms a sketch call at d = 772M, the estimates unmoved)
+_ADDR_UNROLL = 4
+
+
+def rotation_form(c: int, r: int, rot_step: int) -> str:
+    """Which of the kernels' two rotation forms a geometry gets, from
+    its shapes alone: ``"addressed"`` where every rotation is a whole
+    number of vregs (``rot_step`` a multiple of 1,024, the chunk whole
+    (32, 128) tiles, r <= 16, the table small enough to lie in VMEM
+    twice over beside its output block) — a chunk is then viewed as
+    (c/128, 128) and a rotation by 1024·j is the row offset 8·j into
+    the VMEM table, no element moves inside a vreg and nothing is
+    rolled; ``"rolled"`` otherwise (``_roll1d`` on an (S, L) tile)."""
+    whole = (rot_step > 0 and rot_step % _VREG == 0
+             and c % (_ADDR_ROWS * 128) == 0 and r <= 16
+             and _addressed_vmem(4 * r * c) <= _VMEM_CEILING)
+    return "addressed" if whole else "rolled"
+
+
+def _tile(c: int, r: int, rot_step: int, lanes: int | None):
+    """(addressed, S, L): the chunk's 2-D view. ``lanes`` (tests) pins
+    the rolled form at that lane width."""
+    if lanes is None and rotation_form(c, r, rot_step) == "addressed":
+        return True, c // 128, 128
+    L = lanes or _pick_lanes(c)
+    assert L is not None and c % L == 0
+    return False, c // L, L
+
+
+def _for_blocks(S: int, body, unroll: int = 1):
+    """``body(lo)`` for the first row ``lo`` of every ``_ADDR_ROWS``
+    block of an (S, 128) chunk, ``unroll`` blocks a loop step (Mosaic's
+    own ``fori_loop`` unrolls fully or not at all)."""
+    B, n = _ADDR_ROWS, S // _ADDR_ROWS
+    u = unroll if n % unroll == 0 else 1
+
+    def step(i, carry):
+        for j in range(u):
+            body(pl.multiple_of((i * u + j) * B, B))
+        return carry
+
+    jax.lax.fori_loop(0, n // u, step, 0)
+
+
+def _row_starts(rot_ref, rot_at, t, r: int, S: int):
+    """Where chunk ``t`` meets each sketch row of a table held twice
+    over, 2·S rows of 128 a sketch row: ``row·2S + 8·(rot // 1024)``.
+    The S rows from there on never wrap."""
+    return [row * 2 * S + 8 * (rot_at(rot_ref, row, t) // _VREG)
+            for row in range(r)]
+
+
+def _accumulate(acc_ref, rot_ref, rot_at, v_ref, sgn_ref, t, *, addressed,
+                one_mix, seed, c, S, L, r, row_offset=0):
+    """Add chunk ``t``, signed and rotated, into every row of the
+    (r·S, L) accumulator. Addressed: the accumulator is (r·2S, 128),
+    each sketch row twice as long, so that a chunk lands at one aligned
+    dynamic start with no wrap (``_row_starts``); what lands in a
+    row's upper half is what wrapped, and ``_fold`` adds it onto the
+    lower after the last chunk. (Addressing each vreg row modulo S
+    instead keeps the chunk order of the adds and the parent's table
+    to the bit, at four times the dynamic addresses: 9.9 against
+    5.3 ms a call at d = 772M, PERF.md section 6, PR 37.)"""
+    if not addressed:
+        chunk = v_ref[:]  # (S, L) chunk t, streamed
+        flips = _flips_for_chunk(
+            t, None if sgn_ref is None else sgn_ref[:],
+            one_mix, seed, c, S, L, r, row_offset)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
+        for row in range(r):
+            rolled = _roll1d(_apply_flip(chunk, flips[row]),
+                             rot_at(rot_ref, row, t), S, L, lane)
+            sl = slice(row * S, (row + 1) * S)
+            acc_ref[sl, :] = acc_ref[sl, :] + rolled
+        return
+    B = _ADDR_ROWS
+    starts = _row_starts(rot_ref, rot_at, t, r, S)
+
+    def block(lo):
+        src = pl.ds(lo, B)
+        x = v_ref[src, :]
+        flips = _flips_for_chunk(
+            t, None if sgn_ref is None else sgn_ref[src, :],
+            one_mix, seed, c, B, L, r, row_offset, base=lo * L)
+        dsts = [pl.ds(pl.multiple_of(starts[row] + lo, 8), B)
+                for row in range(r)]
+        # every load before the first store: the stores' addresses are
+        # dynamic, so a load after one would wait for it
+        olds = [acc_ref[dst, :] for dst in dsts]
+        for row in range(r):
+            acc_ref[dsts[row], :] = olds[row] + _apply_flip(x, flips[row])
+
+    _for_blocks(S, block, _ADDR_UNROLL)
+
+
+def _fold(acc_ref, dst_ref, dst_rows: int, r: int, S: int):
+    """The doubled accumulator's upper halves (what wrapped past a
+    table row's end) added onto its lower: table row ``row`` goes to
+    ``dst_ref[row·dst_rows : row·dst_rows + S]``."""
+    B = _ADDR_ROWS
+
+    def block(lo):
+        for row in range(r):
+            dst_ref[pl.ds(row * dst_rows + lo, B), :] = (
+                acc_ref[pl.ds(row * 2 * S + lo, B), :]
+                + acc_ref[pl.ds((row * 2 + 1) * S + lo, B), :])
+
+    _for_blocks(S, block)
+
+
+def _chunk_operands(vp, sgn, m: int, S: int, L: int):
+    """The streamed chunk operands and their BlockSpecs: the vector
+    (and the packed signs) as (m·S, L). At L = 128 that view is the
+    1-D array's own tiling, a bitcast in the compiled program; wider
+    tiles cost a d-sized relayout before the kernel."""
+    spec = pl.BlockSpec((S, L), lambda t: (t, 0), memory_space=pltpu.VMEM)
+    operands, specs = [vp.astype(jnp.float32).reshape(m * S, L)], [spec]
+    if sgn is not None:
+        operands.append(sgn.reshape(m * S, L))
+        specs.append(spec)
+    return operands, specs
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -248,69 +408,51 @@ def sketch_pallas(vp, rot, c: int, r: int, sign_seed: int,
     ``vp`` is the zero-padded flat vector (padded_d = m*c); ``rot`` is
     the (r, m) int32 host-derived rotation table (static per operator,
     passed as an array so the kernel is geometry-cached). ``rot_step``
-    > 0 promises every rotation is a multiple of it; when that step is
-    lane-aligned the 5-op arbitrary-shift roll collapses to a single
-    sublane roll (CountSketch.rot_lanes). ``sgn`` (optional,
-    (padded_d,) uint8): packed sign bits (bit row = hash bit 16+row,
-    CountSketch._packed_signs_traced) streamed alongside the vector —
-    removes the murmur mix (two emulated u32 multiplies per element,
-    the largest r-independent ALU block) from the kernel for ~1 extra
-    byte/element of HBM traffic."""
-    L = lanes or _pick_lanes(c)
-    assert L is not None and c % L == 0
-    S = c // L
+    > 0 promises every rotation is a multiple of it; where that makes
+    them whole vregs the kernel addresses the table instead of rolling
+    the chunk (``rotation_form``; CountSketch.rot_lanes). ``sgn``
+    (optional, (padded_d,) uint8): packed sign bits (bit row = hash
+    bit 16+row, CountSketch._packed_signs_traced) streamed alongside
+    the vector — removes the murmur mix (two emulated u32 multiplies
+    per element, the largest r-independent ALU block) from the kernel
+    for ~1 extra byte/element of HBM traffic."""
     m = vp.size // c
+    addressed, S, L = _tile(c, r, rot_step, lanes)
     seed = np.uint32(sign_seed)
-    sublane = rot_step > 0 and rot_step % L == 0
     packed = sgn is not None
     rot, rot_spec, rot_at = _rot_operand(rot, r, m)
 
     def kernel(rot_ref, v_ref, *refs):
-        (sgn_ref, out_ref) = refs if packed else (None, refs[0])
+        sgn_ref = refs[0] if packed else None
+        out_ref = refs[1 if packed else 0]
+        acc_ref = refs[-1]  # rolled: the output itself
         t = pl.program_id(0)
 
         @pl.when(t == 0)
         def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # NOTE: a 1-D (c,) input block with an in-kernel reshape was
-        # measured WORSE (sketch 8.3 -> 13.4 ms at d=124M): Mosaic
-        # relayouts every chunk inside the kernel, serialized with
-        # compute, while the XLA-side 2-D relayout copy costs ~1.5 ms
-        # once and overlaps. Keep the 2-D operand.
-        chunk = v_ref[:]  # (S, L) chunk t, streamed
-        flips = _flips_for_chunk(
-            t, sgn_ref[:] if packed else None,
-            one_mix, seed, c, S, L, r)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
-        for row in range(r):
-            signed = _apply_flip(chunk, flips[row])
-            if sublane:
-                rolled = pltpu.roll(signed, rot_at(rot_ref, row, t) // L,
-                                    axis=0)
-            else:
-                rolled = _roll1d(signed, rot_at(rot_ref, row, t), S, L, lane)
-            sl = slice(row * S, (row + 1) * S)
-            out_ref[sl, :] = out_ref[sl, :] + rolled
+        _accumulate(acc_ref, rot_ref, rot_at, v_ref, sgn_ref, t,
+                    addressed=addressed, one_mix=one_mix, seed=seed,
+                    c=c, S=S, L=L, r=r)
 
-    in_specs = [
-        rot_spec,
-        pl.BlockSpec((S, L), lambda t: (t, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    operands = [rot, vp.astype(jnp.float32).reshape(m * S, L)]
-    if packed:
-        in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
-                                     memory_space=pltpu.VMEM))
-        operands.append(sgn.reshape(m * S, L))
+        if addressed:
+            @pl.when(t == m - 1)
+            def _():
+                _fold(acc_ref, out_ref, S, r, S)
+
+    operands, chunk_specs = _chunk_operands(vp, sgn, m, S, L)
+    operands = [rot] + operands
     out = pl.pallas_call(
         kernel,
         grid=(m,),
-        in_specs=in_specs,
+        in_specs=[rot_spec] + chunk_specs,
         out_specs=pl.BlockSpec((r * S, L), lambda t: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=out_struct((r * S, L), jnp.float32, *operands),
-        compiler_params=_compiler_params(4 * r * c),
+        scratch_shapes=([pltpu.VMEM((r * 2 * S, L), jnp.float32)]
+                        if addressed else []),
+        compiler_params=_compiler_params(4 * r * c, addressed),
         interpret=interpret,
         name="sketch_pallas",
     )(*operands)
@@ -349,13 +491,11 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
     from commefficient_tpu.ops.quant import QMAX, wire_jnp_dtype
     qmax = QMAX["int8"]
     out_dtype = wire_jnp_dtype("int8")
-    L = lanes or _pick_lanes(c)
-    assert L is not None and c % L == 0
-    S = c // L
     m = vp.size // c
+    addressed, S, L = _tile(c, r, rot_step, lanes)
     seed = np.uint32(sign_seed)
-    sublane = rot_step > 0 and rot_step % L == 0
     packed = sgn is not None
+    T = 2 * S if addressed else S  # accumulator rows a table row
     assert row_offset >= 0
     if one_mix:
         # the one-mix hash carries 16 sign bits — absolute rows of a
@@ -374,56 +514,38 @@ def sketch_quant_pallas(vp, rot, c: int, r: int, sign_seed: int,
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        chunk = v_ref[:]
-        flips = _flips_for_chunk(
-            t, sgn_ref[:] if packed else None,
-            one_mix, seed, c, S, L, r, row_offset)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
-        for row in range(r):
-            signed = _apply_flip(chunk, flips[row])
-            if sublane:
-                rolled = pltpu.roll(signed, rot_at(rot_ref, row, t) // L,
-                                    axis=0)
-            else:
-                rolled = _roll1d(signed, rot_at(rot_ref, row, t), S, L, lane)
-            sl = slice(row * S, (row + 1) * S)
-            acc_ref[sl, :] = acc_ref[sl, :] + rolled
+        _accumulate(acc_ref, rot_ref, rot_at, v_ref, sgn_ref, t,
+                    addressed=addressed, one_mix=one_mix, seed=seed,
+                    c=c, S=S, L=L, r=r, row_offset=row_offset)
 
         @pl.when(t == m - 1)
         def _():
+            if addressed:
+                _fold(acc_ref, acc_ref, T, r, S)  # in place
             for row in range(r):
-                sl = slice(row * S, (row + 1) * S)
-                block = acc_ref[sl, :]
+                block = acc_ref[row * T:row * T + S, :]
                 rm = jnp.max(jnp.abs(block))
                 # identical scale algebra to quantize_local: full
                 # range against the local rowmax, zero-row guard 1.0
                 s = jnp.where(rm > 0.0, rm / qmax, 1.0)
                 q = jnp.clip(jnp.round(block / s), -qmax, qmax)
-                q_ref[sl, :] = q.astype(out_dtype)
+                q_ref[row * S:(row + 1) * S, :] = q.astype(out_dtype)
                 rm_ref[row, :] = jnp.full((L,), rm, jnp.float32)
 
-    in_specs = [
-        rot_spec,
-        pl.BlockSpec((S, L), lambda t: (t, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    operands = [rot, vp.astype(jnp.float32).reshape(m * S, L)]
-    if packed:
-        in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
-                                     memory_space=pltpu.VMEM))
-        operands.append(sgn.reshape(m * S, L))
+    operands, chunk_specs = _chunk_operands(vp, sgn, m, S, L)
+    operands = [rot] + operands
     q, rm = pl.pallas_call(
         kernel,
         grid=(m,),
-        in_specs=in_specs,
+        in_specs=[rot_spec] + chunk_specs,
         out_specs=(pl.BlockSpec((r * S, L), lambda t: (0, 0),
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((r, L), lambda t: (0, 0),
                                 memory_space=pltpu.VMEM)),
         out_shape=(out_struct((r * S, L), out_dtype, *operands),
                    out_struct((r, L), jnp.float32, *operands)),
-        scratch_shapes=[pltpu.VMEM((r * S, L), jnp.float32)],
-        compiler_params=_compiler_params(4 * r * c),
+        scratch_shapes=[pltpu.VMEM((r * T, L), jnp.float32)],
+        compiler_params=_compiler_params(4 * r * c, addressed),
         interpret=interpret,
         name="sketch_quant_pallas",
     )(*operands)
@@ -443,16 +565,18 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
     ``[:d]`` prefix-slice copy (CountSketch.estimates(padded=True)).
     ``sgn``: optional (padded_d,) packed sign bits, see
     ``sketch_pallas``."""
-    L = lanes or _pick_lanes(c)
-    assert L is not None and c % L == 0
-    S = c // L
     m = rot.shape[1]
+    addressed, S, L = _tile(c, r, rot_step, lanes)
     seed = np.uint32(sign_seed)
-    sublane = rot_step > 0 and rot_step % L == 0
     packed = sgn is not None
+    masked = valid is not None and valid < m * c
     rot, rot_spec, rot_at = _rot_operand(rot, r, m)
 
-    def kernel(rot_ref, tab_ref, *refs):
+    def tail_mask(med, t):
+        g = _global_index(t, c, S, L).astype(jnp.int32)
+        return jnp.where(g < valid, med, 0.0)
+
+    def rolled_kernel(rot_ref, tab_ref, *refs):
         (sgn_ref, out_ref) = refs if packed else (None, refs[0])
         t = pl.program_id(0)
         flips = _flips_for_chunk(
@@ -464,42 +588,93 @@ def estimates_pallas(table, rot, c: int, r: int, sign_seed: int,
             trow = tab_ref[row * S:(row + 1) * S, :]
             o = rot_at(rot_ref, row, t)
             back = (jnp.int32(c) - o) % jnp.int32(c)
-            if sublane:
-                unrolled = pltpu.roll(trow, back // L, axis=0)
-            else:
-                unrolled = _roll1d(trow, back, S, L, lane)
-            vals.append(_apply_flip(unrolled, flips[row]))
+            vals.append(_apply_flip(_roll1d(trow, back, S, L, lane),
+                                    flips[row]))
         med = _median_network(vals)
-        if valid is not None and valid < m * c:
-            s_idx = jax.lax.broadcasted_iota(jnp.int32, (S, L), 0)
-            l_idx = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
-            g = t * c + s_idx * L + l_idx
-            med = jnp.where(g < valid, med, 0.0)
+        if masked:
+            med = tail_mask(med, t)
         # 1-D output block: the (padded_d,) estimates leave in their
         # consumers' native linear layout (the 2-D (m*S, L) out_shape
         # cost a d-sized relayout on the way to selection)
         out_ref[:] = med.reshape(c)
 
+    def addressed_kernel(rot_ref, tab_ref, *refs):
+        sgn_ref = refs[0] if packed else None
+        out_ref, res_ref, sems = refs[-3:]
+        t = pl.program_id(0)
+        B = _ADDR_ROWS
+
+        @pl.when(t == 0)
+        def _():
+            # the table comes to VMEM once, each row twice over, so
+            # that one dynamic start below needs no wrap
+            copies = [pltpu.make_async_copy(
+                tab_ref.at[pl.ds(row * S, S), :],
+                res_ref.at[pl.ds((2 * row + half) * S, S), :],
+                sems.at[2 * row + half])
+                for row in range(r) for half in range(2)]
+            for copy in copies:
+                copy.start()
+            for copy in copies:
+                copy.wait()
+
+        # chunk t of row ``row`` is the table row read from vreg row
+        # rot // 1024 on, wrapping: rolled back by address
+        starts = _row_starts(rot_ref, rot_at, t, r, S)
+
+        def block(lo):
+            src = pl.ds(lo, B)
+            flips = _flips_for_chunk(
+                t, sgn_ref[src, :] if packed else None,
+                one_mix, seed, c, B, L, r, base=lo * L)
+            vals = [_apply_flip(
+                res_ref[pl.ds(pl.multiple_of(starts[row] + lo, 8), B), :],
+                flips[row]) for row in range(r)]
+            out_ref[src, :] = _median_network(vals)
+
+        _for_blocks(S, block, _ADDR_UNROLL)
+
+        if masked:
+            # the tail lies in the last chunk(s) only
+            @pl.when((t + 1) * c > valid)
+            def _():
+                out_ref[:] = tail_mask(out_ref[:], t)
+
+    operands = [rot, table.astype(jnp.float32).reshape(r * S, L)]
     in_specs = [
         rot_spec,
-        # table resident in VMEM across all chunk steps
-        pl.BlockSpec((r * S, L), lambda t: (0, 0),
-                     memory_space=pltpu.VMEM),
+        # addressed: the kernel fetches the table itself, twice over;
+        # rolled: resident in VMEM across all chunk steps
+        pl.BlockSpec(memory_space=pl.ANY) if addressed
+        else pl.BlockSpec((r * S, L), lambda t: (0, 0),
+                          memory_space=pltpu.VMEM),
     ]
-    operands = [rot, table.astype(jnp.float32).reshape(r * S, L)]
     if packed:
         in_specs.append(pl.BlockSpec((S, L), lambda t: (t, 0),
                                      memory_space=pltpu.VMEM))
         operands.append(sgn.reshape(m * S, L))
+    if addressed:
+        # the (m·S, 128) result is the 1-D estimates' own tiling: the
+        # reshape below is a bitcast in the compiled program
+        out_spec = pl.BlockSpec((S, L), lambda t: (t, 0),
+                                memory_space=pltpu.VMEM)
+        out_shape = out_struct((m * S, L), jnp.float32, *operands)
+        scratch = [pltpu.VMEM((r * 2 * S, L), jnp.float32),
+                   pltpu.SemaphoreType.DMA((2 * r,))]
+    else:
+        out_spec = pl.BlockSpec((c,), lambda t: (t,),
+                                memory_space=pltpu.VMEM)
+        out_shape = out_struct((m * c,), jnp.float32, *operands)
+        scratch = []
     out = pl.pallas_call(
-        kernel,
+        addressed_kernel if addressed else rolled_kernel,
         grid=(m,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((c,), lambda t: (t,),
-                               memory_space=pltpu.VMEM),
-        out_shape=out_struct((m * c,), jnp.float32, *operands),
-        compiler_params=_compiler_params(4 * r * c),
+        out_specs=out_spec,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(4 * r * c, addressed),
         interpret=interpret,
         name="estimates_pallas",
     )(*operands)
-    return out
+    return out.reshape(m * c)

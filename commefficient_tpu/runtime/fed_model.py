@@ -63,7 +63,8 @@ from commefficient_tpu.core.rounds import (ClientStates,
                                            build_client_round,
                                            build_server_round,
                                            build_val_fn, round_plan,
-                                           server_select_form)
+                                           server_select_form,
+                                           sketch_rot_form)
 from commefficient_tpu.core.server import ServerState
 from commefficient_tpu.privacy import build_accountant, noise_stream
 from commefficient_tpu.telemetry import build_telemetry, clock, trace
@@ -1286,6 +1287,7 @@ class FedOptimizer:
                                mesh=mesh),
             donate_argnums=(0, 1))
         self._select_form = server_select_form(self.args, mesh)
+        self._rot_form = sketch_rot_form(self.args)
         # legacy --do_dp server-mode noise stream: the seed+1 root key
         # comes from privacy/ (the one module allowed raw jax.random
         # noise — analysis/lint.py noise-confinement)
@@ -1368,6 +1370,10 @@ class FedOptimizer:
         if form is not None:
             m.telemetry.count("select." + form[0])
             m.telemetry.count("select.candidates", form[1])
+            # and which rotation form its sketch kernels were
+            m.telemetry.count("sketch.rot_" + (
+                self._rot_form if svar is None
+                else sketch_rot_form(svar.cfg)))
         sfirst = svar is not None and "server" not in svar.compiled
         cmark = compile_mark() if sfirst else None
         with m.telemetry.span("server"):

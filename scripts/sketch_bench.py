@@ -14,9 +14,18 @@ invocation shows what a dtype buys in both time and bytes. With
 ``--ledger`` the result also lands as a bench record and a run
 manifest under ``runs/`` (perf-gateable, wire-dtype keyed).
 
+``--kernels D[,D...]`` is PR 37's step 0 (PERF.md section 6): at each
+d given (the benchmark cells' are in PERF.md section 4; ``--c`` x
+``--r`` sketch, ``rot_lanes`` 1024, packed signs) it times the two Pallas kernels alone on operands made
+beforehand, then ``sketch``, ``sketch_from_leaves`` and ``estimates``
+as the round programs call them, prints each compiled program's
+temporaries and a checksum of the table and of the estimates (the same
+script run from a copy of another commit tells whether they moved).
+
 Usage:
   python scripts/sketch_bench.py [--d 124439808] [--c 524288] [--r 5]
       [--k 50000] [--reps 20] [--tree] [--sketch_dtype int8]
+  python scripts/sketch_bench.py --kernels 124444417,772160448
 """
 
 import argparse
@@ -76,8 +85,92 @@ def gpt2_like_shapes(d):
     return shapes
 
 
+def _leaves(v, d):
+    """``v`` cut into the leaves of ``gpt2_like_shapes(d)``."""
+    leaves, off = [], 0
+    for shape in gpt2_like_shapes(d):
+        n = int(np.prod(shape))
+        leaves.append(jax.lax.dynamic_slice(v, (off,), (n,))
+                      .reshape(shape))
+        off += n
+    assert off == d, (off, d)
+    return leaves
+
+
+def _checksum(x):
+    """Two wrapping uint32 sums over the bits of ``x``: equal on two
+    commits only if (short of a collision) every element is."""
+    bits = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint32)
+    pos = jnp.arange(bits.size, dtype=jnp.uint32) | jnp.uint32(1)
+    return [int(jnp.sum(bits, dtype=jnp.uint32)),
+            int(jnp.sum(bits * pos, dtype=jnp.uint32))]
+
+
+def run_kernels(args):
+    """Step 0 of PR 37: see the module docstring."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    from commefficient_tpu.ops.sketch import CountSketch
+
+    c, r, lanes = args.c, args.r, 1024
+    for d in map(int, args.kernels.split(",")):
+        cs = CountSketch(d=d, c=c, r=r, seed=21, backend=args.backend,
+                         rot_lanes=lanes)
+        interpret = cs._resolve_backend() == "pallas_interpret"
+        m, pd = cs._m, cs._padded_d
+        _, sign_seed = cs._seeds()
+        res = {"d": d, "padded_d": pd, "r_m": r * m}
+        if hasattr(sp, "rotation_form"):  # not in a copy before PR 37
+            res["form"] = sp.rotation_form(c, r, lanes)
+        v = jax.jit(lambda: jax.random.normal(
+            jax.random.PRNGKey(3), (d,), jnp.float32))()
+        vp = jnp.pad(v, (0, pd - d))
+        sgn = jax.jit(cs._packed_signs_traced)()
+        rot = jnp.asarray(cs._rotations())
+        ms, table = timed(
+            lambda a, b: sp.sketch_pallas(a, rot, c, r, int(sign_seed),
+                                          interpret, None, True, lanes, b),
+            vp, sgn, reps=args.reps)
+        res["sketch_kernel_ms"] = round(ms, 3)
+        res["table_checksum"] = _checksum(table)
+        del vp
+        # a table of its own: the estimates' checksum then tells the
+        # estimates kernel's bits apart from the sketch kernel's
+        tab0 = jax.random.normal(jax.random.PRNGKey(4), (r, c),
+                                 jnp.float32)
+        ms, est = timed(
+            lambda a, b: sp.estimates_pallas(
+                a, rot, c, r, int(sign_seed), interpret, None, True, d,
+                lanes, b),
+            tab0, sgn, reps=args.reps)
+        res["estimates_kernel_ms"] = round(ms, 3)
+        res["estimates_checksum"] = _checksum(est)
+        del est, sgn, tab0
+
+        def through(name, fn, *a):
+            exe = jax.jit(fn).lower(*a).compile()
+            res[name + "_temp_GB"] = round(
+                exe.memory_analysis().temp_size_in_bytes / 1e9, 3)
+            ms, o = timed(exe, *a, reps=args.reps)
+            res[name + "_ms"] = round(ms, 3)
+            return o
+
+        through("sketch", cs.sketch, v)
+        through("estimates", lambda t: cs.estimates(t, padded=True),
+                table)
+        leaves = _leaves(v, d)
+        del v
+        t2 = through("sketch_from_leaves",
+                     lambda ls: cs.sketch_from_leaves(ls), leaves)
+        res["leaves_table_equal"] = bool(jnp.array_equal(table, t2))
+        del leaves, table, t2
+        print(json.dumps(res), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--kernels", default="", metavar="D[,D...]",
+                    help="time the two sketch kernels alone and the "
+                    "three entry points at each of these d")
     ap.add_argument("--d", type=int, default=124_439_808)
     ap.add_argument("--c", type=int, default=524288)
     ap.add_argument("--r", type=int, default=5)
@@ -127,6 +220,9 @@ def main():
     args = ap.parse_args()
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    if args.kernels:
+        run_kernels(args)
+        return
 
     from commefficient_tpu.ops.sketch import CountSketch
     from commefficient_tpu.ops.topk import threshold_topk_indices
@@ -149,15 +245,7 @@ def main():
     res["sketch_flat_ms"] = round(ms, 2)
 
     if args.tree:
-        shapes = gpt2_like_shapes(args.d)
-        leaves = []
-        off = 0
-        for s in shapes:
-            n = int(np.prod(s))
-            leaves.append(jax.device_put(
-                jax.lax.dynamic_slice(v, (off,), (n,)).reshape(s)))
-            off += n
-        assert off == args.d, (off, args.d)
+        leaves = _leaves(v, args.d)
 
         fn = jax.jit(lambda ls: cs.sketch_from_leaves(ls))
         ms, table_t = timed(fn, leaves, reps=args.reps)

@@ -171,6 +171,72 @@ def test_select_counters_on_every_record(form, monkeypatch):
     assert masks == ([d // block, k * block] if form == "blocked" else [])
 
 
+@pytest.mark.parametrize("mode,geometry,want", [
+    # whole-vreg rotations on chunks of whole (32, 128) tiles,
+    # whatever the stream's length (16 chunks, 8)
+    ("sketch", dict(num_cols=8192, sketch_rot_lanes=1024), "addressed"),
+    ("sketch", dict(num_cols=16384, sketch_rot_lanes=1024), "addressed"),
+    ("sketch", dict(num_cols=8192, sketch_rot_lanes=0), "rolled"),
+    ("sketch", dict(num_cols=8192, sketch_rot_lanes=128), "rolled"),
+    # auto on the CPU: full-granularity rotations
+    ("sketch", dict(num_cols=8192), "rolled"),
+    ("true_topk", {}, None), ("uncompressed", {}, None),
+    ("local_topk", {}, None), ("fedavg", {}, None),
+])
+def test_rotation_form_on_every_sketch_round_record(mode, geometry, want):
+    """Every sketch-mode round record says which form its sketch
+    kernels apply a rotation in, from the operator's shapes alone
+    (d = 131,072 here); outside sketch mode no
+    record says either."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from commefficient_tpu.config import Config
+    from commefficient_tpu.runtime import FedModel, FedOptimizer
+    from test_round_contract import B, CLIENTS, W
+
+    class Lin(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(512, use_bias=False)(x)
+
+    module = Lin()
+    params = module.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 256)))["params"]
+    kw = dict(MODES[mode], **geometry)
+    kw.setdefault("local_batch_size", B)
+    args = Config(num_workers=W, num_clients=CLIENTS, num_devices=1,
+                  dataset_name="CIFAR10", seed=0, **kw)
+
+    def loss(p, batch, cfg):
+        pred = module.apply({"params": p}, batch["x"])
+        per = jnp.sum((pred - batch["y"][..., None]) ** 2, -1)
+        n = jnp.maximum(jnp.sum(batch["mask"]), 1.0)
+        l = jnp.sum(per * batch["mask"]) / n
+        return l, (l * 0.0 + 1.0,)
+
+    model = FedModel(module, params, loss, args, padded_batch_size=B)
+    opt = FedOptimizer([{"lr": 0.1}], args)
+    assert args.grad_size == 16 * 8192
+    sink = ListSink()
+    model.telemetry.add_sink(sink)
+    rng = np.random.RandomState(5)
+    for _ in range(3):
+        model({"x": rng.randn(W, B, 256).astype(np.float32),
+               "y": rng.randn(W, B).astype(np.float32),
+               "mask": np.ones((W, B), np.float32),
+               "client_ids": rng.choice(CLIENTS, W, replace=False)
+               .astype(np.int32)})
+        opt.step()
+    model.finalize()
+    assert len(sink.records) == 3
+    for rec in sink.records:
+        assert {n: v for n, v in rec["counters"].items()
+                if n.startswith("sketch.rot_")} == (
+                    {} if want is None else {"sketch.rot_" + want: 1})
+
+
 @pytest.mark.parametrize("mode", sorted(MODES))
 def test_checkpoint_with_the_slot_full_continues_the_same(mode, tmp_path):
     some = batches(7)

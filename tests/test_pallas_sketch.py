@@ -7,6 +7,8 @@ tree reduce), so sketch tables match to ULP-level tolerance; recovery
 from a given table is a pure permutation + median and matches
 bit-for-bit. On CPU the kernels run in interpreter mode."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -249,3 +251,205 @@ def test_supported_is_bounded_by_index_arithmetic_not_chunks():
     assert supported(700_903_424, c, 5)        # r * m = 6,685
     assert supported(2 ** 31 - c, c, 5)
     assert not supported(2 ** 31 - c + 1, c, 5)  # padded d = 2^31
+
+
+# --- the addressed rotation form (rotations that are whole vregs) -------
+
+def _rot_pair(d, c, r, seed=7, rot_lanes=1024):
+    xla = CountSketch(d=d, c=c, r=r, seed=seed, backend="xla",
+                      rot_lanes=rot_lanes)
+    pal = CountSketch(d=d, c=c, r=r, seed=seed,
+                      backend="pallas_interpret", rot_lanes=rot_lanes)
+    return xla, pal
+
+
+def _kernel_args(cs):
+    """(rot, sign_seed, sgn) as ``CountSketch`` hands them to the
+    kernels."""
+    _, sign_seed = cs._seeds()
+    sgn = (jax.jit(cs._packed_signs_traced)()
+           if cs._packed_sign_kernels else None)
+    return jnp.asarray(cs._rotations()), int(sign_seed), sgn
+
+
+@pytest.mark.parametrize("c,r,rot_step,form", [
+    (524288, 5, 1024, "addressed"),   # all five cells
+    (524288, 6, 1024, "addressed"),   # the most rows that fit at 2^19
+    (524288, 8, 1024, "rolled"),      # the table twice over: 67 MB
+    (8192, 5, 1024, "addressed"),
+    (4096, 5, 1024, "addressed"),     # one (32, 128) tile a vreg row
+    (8192, 16, 2048, "addressed"),    # whole vregs, two at a time
+    (8192, 5, 0, "rolled"),           # full-granularity rotations
+    (8192, 5, 512, "rolled"),         # half a vreg
+    (8192, 5, 128, "rolled"),
+    (9216, 5, 1024, "rolled"),        # c not whole (32, 128) tiles
+    (8192, 17, 1024, "rolled"),       # per-(row, coord) mix
+    (1024, 5, 1024, "rolled"),
+])
+def test_rotation_form_is_a_function_of_the_shapes(c, r, rot_step, form):
+    """(c, r, rot_step) alone: the stream's length has no say."""
+    from commefficient_tpu.ops.sketch_pallas import rotation_form
+    assert rotation_form(c, r, rot_step) == form
+    if rot_step == 0 or c % rot_step == 0:
+        for m in (1, 13, 238):
+            cs = CountSketch(d=m * c - 3, c=c, r=r, rot_lanes=rot_step,
+                             backend="pallas_interpret")
+            assert cs.rot_form == form
+
+
+# c / 1024 = 8 (the smallest rotation space the operator admits) and
+# 512 (the cells'), d with a padded tail, streams down to one chunk
+ADDR_GEOMS = [(20 * 8192 - 77, 8192, 5), (16 * 524288 - 1001, 524288, 2),
+              (16 * 8192, 8192, 3), (17 * 8192 - 1, 8192, 9),
+              (13 * 8192 - 9, 8192, 5), (8192 - 100, 8192, 5)]
+
+
+@pytest.mark.parametrize("d,c,r", ADDR_GEOMS)
+def test_addressed_matches_the_xla_twin(d, c, r):
+    xla, pal = _rot_pair(d, c, r)
+    assert pal.rot_form == "addressed"
+    assert pal._packed_sign_kernels == (r <= 8)
+    v = jnp.asarray(np.random.RandomState(0).randn(d).astype(np.float32))
+    tx = xla.sketch(v)
+    np.testing.assert_allclose(np.asarray(tx), np.asarray(pal.sketch(v)),
+                               rtol=1e-6, atol=1e-5)
+    for padded in (False, True):
+        np.testing.assert_array_equal(
+            np.asarray(xla.estimates(tx, padded=padded)),
+            np.asarray(pal.estimates(tx, padded=padded)))
+
+
+@pytest.mark.parametrize("d,c,r", ADDR_GEOMS[:1] + ADDR_GEOMS[2:5])
+@pytest.mark.parametrize("lanes", [1024, 128])
+def test_addressed_matches_the_rolled_form(d, c, r, lanes):
+    """``lanes=`` pins the rolled form (``_roll1d`` on an (S, lanes)
+    tile): the addressed table equals it to summation order, and the
+    estimates of one table are the same bits in both forms, tail mask
+    included."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    _, pal = _rot_pair(d, c, r)
+    rot, seed, sgn = _kernel_args(pal)
+    vp = jnp.asarray(np.random.RandomState(1).randn(pal._padded_d)
+                     .astype(np.float32))
+    t_addr, t_roll = (sp.sketch_pallas(vp, rot, c, r, seed, True, L,
+                                       pal._one_mix_signs, 1024, sgn)
+                      for L in (None, lanes))
+    np.testing.assert_allclose(np.asarray(t_addr), np.asarray(t_roll),
+                               rtol=1e-6, atol=1e-5)
+    for valid in (None, d):
+        e_addr, e_roll = (sp.estimates_pallas(
+            t_roll, rot, c, r, seed, True, L, pal._one_mix_signs,
+            valid, 1024, sgn) for L in (None, lanes))
+        np.testing.assert_array_equal(np.asarray(e_addr),
+                                      np.asarray(e_roll))
+
+
+@pytest.mark.parametrize("j", [0, 1, 7])
+def test_addressed_rotations_that_wrap(j):
+    """One row, 16 chunks of which one is not zero, every rotation
+    1024·j given by hand: j = 0 (nothing moves), 1, and S/8 − 1 = 7
+    (every vreg row but the first wraps past the table row's end). The
+    table is that chunk rolled, to the bit (one add of a non-zero a
+    bucket), and every chunk's estimates undo it."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    c, m = 8192, 16
+    chunk = np.random.RandomState(2).randn(c).astype(np.float32)
+    v = np.zeros((m, c), np.float32)
+    v[3] = chunk
+    rot = jnp.full((1, m), 1024 * j, jnp.int32)
+    sgn = jnp.zeros((m * c,), jnp.uint8)  # every sign +1
+    table = sp.sketch_pallas(jnp.asarray(v.reshape(-1)), rot, c, 1, 0,
+                             True, None, True, 1024, sgn)
+    np.testing.assert_array_equal(np.asarray(table[0]),
+                                  np.roll(chunk, 1024 * j))
+    est = sp.estimates_pallas(table, rot, c, 1, 0, True, None, True,
+                              None, 1024, sgn)
+    np.testing.assert_array_equal(np.asarray(est).reshape(m, c),
+                                  np.tile(chunk, (m, 1)))
+
+
+@pytest.mark.parametrize("valid", [1, 8192 + 33, 16 * 8192 - 1,
+                                   16 * 8192])
+def test_addressed_valid_mask(valid):
+    """Positions >= valid read zero, whichever chunk they lie in; the
+    rest are the unmasked estimates' bits."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    d, c, r = 16 * 8192, 8192, 5
+    _, pal = _rot_pair(d, c, r)
+    rot, seed, sgn = _kernel_args(pal)
+    table = jnp.asarray(np.random.RandomState(3).randn(r, c)
+                        .astype(np.float32))
+    full, cut = (np.asarray(sp.estimates_pallas(
+        table, rot, c, r, seed, True, None, True, va, 1024, sgn))
+        for va in (None, valid))
+    np.testing.assert_array_equal(cut[:valid], full[:valid])
+    assert not cut[valid:].any() and full.all()
+
+
+@pytest.mark.parametrize("rows", [None, (0, 2), (2, 3), (4, 1)])
+@pytest.mark.parametrize("m,rot_lanes,form", [
+    (20, 1024, "addressed"), (5, 1024, "addressed"), (20, 512, "rolled")])
+def test_sketch_quant_rows_take_the_operators_form(rows, m, rot_lanes,
+                                                   form):
+    """The fused int8 emit shares the row loop: the whole table and
+    every ``--overlap_depth`` row chunk (``row_offset`` != 0: signs
+    keyed by the absolute row) equal the XLA twin's, and a chunk is the
+    same bytes as those rows of the whole call, in either form (a
+    chunk takes the whole operator's)."""
+    d, c, r = m * 8192 - 77, 8192, 5
+    xla, pal = _rot_pair(d, c, r, rot_lanes=rot_lanes)
+    assert pal.rot_form == form
+    v = jnp.asarray(np.random.RandomState(4).randn(d).astype(np.float32))
+    (qx, sx), (qp, s_p) = (cs.sketch_quantized(v, "int8", rows=rows)
+                           for cs in (xla, pal))
+    np.testing.assert_allclose(np.asarray(sx), np.asarray(s_p), rtol=1e-6)
+    assert np.max(np.abs(np.asarray(qx, np.int32)
+                         - np.asarray(qp, np.int32))) <= 1
+    if rows is not None:
+        off, cnt = rows
+        q_all, s_all = pal.sketch_quantized(v, "int8")
+        np.testing.assert_array_equal(np.asarray(qp),
+                                      np.asarray(q_all[off:off + cnt]))
+        np.testing.assert_array_equal(np.asarray(s_p),
+                                      np.asarray(s_all[off:off + cnt]))
+
+
+def test_addressed_past_rot_whole():
+    """An (r, m) rotation table past ``_ROT_WHOLE`` goes through SMEM a
+    block at a time; only what is done with the rotation differs."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    c, r = 8192, 8
+    d = 520 * c - 5
+    assert r * 520 > sp._ROT_WHOLE
+    xla, pal = _rot_pair(d, c, r)
+    assert pal.rot_form == "addressed"
+    v = jnp.asarray(np.random.RandomState(5).randn(d).astype(np.float32))
+    tx = xla.sketch(v)
+    np.testing.assert_allclose(np.asarray(tx), np.asarray(pal.sketch(v)),
+                               rtol=1e-5, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(xla.estimates(tx)),
+                                  np.asarray(pal.estimates(tx)))
+
+
+def test_no_roll_on_the_addressed_path():
+    """The traced kernels of a whole-vreg geometry hold no roll; the
+    rolled form's do."""
+    from commefficient_tpu.ops import sketch_pallas as sp
+    d, c, r = 16 * 8192, 8192, 5
+    _, pal = _rot_pair(d, c, r)
+    rot, seed, sgn = _kernel_args(pal)
+    vp = jnp.zeros((d,), jnp.float32)
+    table = jnp.zeros((r, c), jnp.float32)
+    for lanes, rolls in ((None, False), (1024, True)):
+        for fn, args in (
+                (sp.sketch_pallas,
+                 (vp, rot, c, r, seed, True, lanes, True, 1024, sgn)),
+                (sp.sketch_quant_pallas,
+                 (vp, rot, c, r, seed, True, lanes, True, 1024, sgn)),
+                (sp.estimates_pallas,
+                 (table, rot, c, r, seed, True, lanes, True, d - 5, 1024,
+                  sgn))):
+            text = str(jax.make_jaxpr(
+                lambda *a, fn=fn, args=args: fn(*a, *args[1:]))(args[0]))
+            # the primitive, not a loop's ``unroll=``
+            assert bool(re.search(r"\broll\[", text)) == rolls, (fn, lanes)

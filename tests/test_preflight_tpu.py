@@ -125,9 +125,10 @@ def test_sketch_kernels_lower_at_flagship_geometry(d, rot_lanes):
     assert tpu_kernels(cs.estimates, sds((ROWS, COLS))) == 1
 
 
-def test_quantized_emit_lowers_fused_for_int8_unfused_for_fp8():
+@pytest.mark.parametrize("rot_lanes", [0, 1024])
+def test_quantized_emit_lowers_fused_for_int8_unfused_for_fp8(rot_lanes):
     cs = CountSketch(d=D_RESNET9, c=COLS, r=ROWS, seed=7,
-                     backend="pallas")
+                     backend="pallas", rot_lanes=rot_lanes)
     for wire in ("int8", "fp8"):
         # one kernel either way: the fused emit for int8; for fp8 the
         # plain sketch kernel, quantized by XLA ops (Mosaic cannot cast
