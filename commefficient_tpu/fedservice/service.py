@@ -253,6 +253,7 @@ class FedService:
         probes = {"admission_rejected": 1.0,
                   "job_active": float(self.active_jobs())}
         self.telemetry.begin_round(t)
+        self.telemetry.close_round()    # no dispatch to finish it under
         self.telemetry.merge_round_probes(t, probes)
         self.telemetry.set_round_bytes(t, 0, 0)
         try:
@@ -336,6 +337,7 @@ class FedService:
         self._ticks += 1
         probes = self._fairness_probes(runnable, chosen)
         self.telemetry.begin_round(t)
+        self.telemetry.close_round()    # no dispatch to finish it under
         if self._slo is not None:
             # the service's SLO objectives read the fairness probes
             # (starvation ticks); the burn probes merge INTO the tick

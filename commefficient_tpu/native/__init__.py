@@ -45,6 +45,13 @@ _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 _LOCK = threading.Lock()
 _lib_handle = None
 _build_failed = False
+_RING_THREADS = [0]     # worker threads of the rings now open
+
+
+def ring_threads() -> int:
+    """C++ worker threads the open ``Prefetcher`` rings hold: what the
+    round records' ``host.threads`` adds to the Python threads."""
+    return _RING_THREADS[0]
 
 
 def _compile() -> Optional[str]:
@@ -226,6 +233,9 @@ class Prefetcher:
             self._handle = plane._lib.cet_ring_create(
                 *plane._common_args(), depth, n_threads)
         assert self._handle
+        self._n_threads = n_threads
+        with _LOCK:
+            _RING_THREADS[0] += n_threads
 
     def submit(self, indices: np.ndarray, seed: int):
         with self.telemetry.span("data.submit"):
@@ -288,6 +298,8 @@ class Prefetcher:
                 self.plane._lib.cet_ring_destroy(self._handle)
             self._handle = None
             self._pool = []
+            with _LOCK:
+                _RING_THREADS[0] -= self._n_threads
 
     def __enter__(self):
         return self

@@ -805,6 +805,9 @@ class FedModel:
                                         0.9 * ra + 0.1 * s, ra),
                 self.model_state, new_stats)
 
+        # the recorder finishes the previous round's record here, under
+        # the program just dispatched, not between two dispatches
+        tel.close_round()
         self._settle_after_dispatch()
         with tel.span("metrics_host"):
             metrics = [_host(m) for m in res.metrics]

@@ -158,6 +158,7 @@ class SeqParallelFedModel(FedModel):
         self.pending_aggregated = agg
         self.pending_client_ids = jnp.asarray(ids_np, jnp.int32)
         self.round_index += 1
+        tel.close_round()
         self._settle_after_dispatch()
 
         # per-client losses, like the 1-D engine's metrics arrays —

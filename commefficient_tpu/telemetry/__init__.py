@@ -17,7 +17,6 @@ from commefficient_tpu.telemetry.core import (NULL_TELEMETRY, Telemetry,
                                               build_telemetry, current,
                                               hbm_peak_bytes,
                                               hbm_reserved_peak_bytes,
-                                              host_rss_peak_bytes,
                                               set_current, setup_span,
                                               setup_spans)
 from commefficient_tpu.telemetry.record import (LEDGER_SCHEMA_VERSION,
@@ -52,7 +51,6 @@ __all__ = [
     "set_current",
     "setup_span",
     "setup_spans",
-    "host_rss_peak_bytes",
     "hbm_peak_bytes",
     "hbm_reserved_peak_bytes",
     "LEDGER_SCHEMA_VERSION",

@@ -10,6 +10,9 @@ run (trainer state dicts, per-script JSON, a barely-used
 
 ``wall``  — epoch seconds, for timestamps humans correlate with logs.
 ``tick``  — monotonic high-resolution clock, for intervals/spans.
+``thread_cpu`` — CPU seconds the calling thread has used (user +
+            system): beside ``tick`` on every span, so that a span's
+            wall time splits into computing and waiting.
 """
 
 from __future__ import annotations
@@ -18,3 +21,4 @@ import time
 
 wall = time.time
 tick = time.perf_counter
+thread_cpu = time.thread_time

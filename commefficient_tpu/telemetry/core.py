@@ -3,10 +3,10 @@
 One ``Telemetry`` instance observes one run.  The hot-path contract:
 
 - **disabled** (no sinks): ``begin_round`` is a single truthiness
-  check, ``span()`` returns one shared no-op context manager, and
-  ``count()`` returns immediately — no per-round allocation, nothing
-  retained: the whole disabled path is a handful of attribute loads
-  per round.
+  check, ``span()`` returns one shared no-op context manager,
+  ``count()`` and ``close_round()`` return immediately — no per-round
+  allocation, nothing retained: the whole disabled path is a handful
+  of attribute loads per round.
 - **enabled**: ``begin_round`` opens a round record; ``span(name)``
   accumulates wall-time into it; ``count(name)`` bumps a counter.
   Records are emitted to every sink in round order once they are (a)
@@ -28,6 +28,16 @@ also is a ``fed_phase::<name>`` annotation (telemetry/trace.py), and
 the window's ``fed_clock`` annotations put every timeline entry on
 the device trace's clock.
 
+The resource clock (schema 9), beside the wall clock and only while a
+sink is attached: every span also reads its thread's CPU clock
+(``cpu`` by name as ``spans`` is, ``timeline_cpu`` by the timeline's
+index), so a span's wait is its wall minus its CPU minus its
+children's; every record carries the process's counters over its life
+(``counters["host.*"]``: one ``getrusage`` call where the record is
+finished); and a round open longer than ``stall_limit`` gets, once,
+every Python thread's stack as ``stall``, from a thread of the
+recorder's own that ``begin_round`` only arms.
+
 Code that is built before the run's ``Telemetry`` (the loaders) is
 handed it afterwards (``loader.telemetry = model.telemetry``, as the
 trainers do) or, failing that, finds it through ``current()``, which
@@ -37,11 +47,12 @@ list.
 
 Round lifecycle (mirrors runtime/fed_model.py):
 
-    begin_round(r)        # top of FedModel._call_train
+    begin_round(r)        # top of FedModel._call_train: swaps records
       span("h2d") ...     # client pass spans
+      close_round()       # after the dispatch: finishes r-1 -> emit
       set_round_bytes(r)  # end of _call_train
       span("server") ...  # FedOptimizer.step (record still current)
-    begin_round(r+1)      # closes r -> watermark snapshot -> emit
+    begin_round(r+1)      # r-1 not finished yet? at once. Swaps r out
 
 Compile events come from ``jax.monitoring``'s duration listener
 (registered once, process-wide); each record carries the delta of
@@ -51,9 +62,14 @@ compile count/seconds observed while it was current.
 from __future__ import annotations
 
 import contextlib
+import gc
+import os
+import resource
+import statistics
+import sys
 import threading
 import weakref
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 from commefficient_tpu.telemetry import clock, trace
 from commefficient_tpu.telemetry.record import (TIMELINE_CAP,
@@ -65,33 +81,42 @@ NULL_SPAN = trace.NULL_PHASE
 
 
 class _Span:
-    __slots__ = ("_tel", "_rec", "_name", "_entry", "_ann")
+    __slots__ = ("_tel", "_rec", "_name", "_top", "_entry", "_index",
+                 "_cpu0", "_ann")
 
-    def __init__(self, tel, rec, name):
+    def __init__(self, tel, rec, name, top=False):
         self._tel = tel
         self._rec = rec
         self._name = name
+        self._top = top     # no parent, whatever is open on the thread
 
     def __enter__(self):
         tel, rec, name = self._tel, self._rec, self._name
         stack = tel._open_spans()
         # parent: the span open on this thread, if it is on the same
         # record (a span that straddles begin_round is not)
-        parent = stack[-1][1] if stack and stack[-1][0] is rec else None
+        parent = (stack[-1][1] if stack and stack[-1][0] is rec
+                  and not self._top else None)
         self._ann = trace.phase(name)
         self._ann.__enter__()
         entry = self._entry = [name, clock.tick(), None, parent,
                                threading.current_thread().name]
-        stack.append((rec, tel._enter_timeline(rec, entry)))
+        self._index = tel._enter_timeline(rec, entry)
+        stack.append((rec, self._index))
+        self._cpu0 = clock.thread_cpu()
         return self
 
     def __exit__(self, *exc):
-        entry = self._entry
+        cpu = clock.thread_cpu() - self._cpu0
+        entry, rec, name = self._entry, self._rec, self._name
         entry[2] = t1 = clock.tick()
         self._ann.__exit__(None, None, None)
         self._tel._open_spans().pop()
-        spans = self._rec["spans"]
-        spans[self._name] = spans.get(self._name, 0.0) + t1 - entry[1]
+        spans = rec["spans"]
+        spans[name] = spans.get(name, 0.0) + t1 - entry[1]
+        rec["cpu"][name] = rec["cpu"].get(name, 0.0) + cpu
+        if self._index is not None:
+            rec["timeline_cpu"][self._index] = cpu
         return False
 
 
@@ -173,22 +198,156 @@ def setup_spans() -> list:
     return [list(e) for e in _SETUP_SPANS]
 
 
-def host_rss_peak_bytes():
-    """Peak resident set size of this process (bytes), or None."""
+# --- the host as a resource ----------------------------------------------
+# Collections are timed process-wide by one ``gc.callbacks`` entry,
+# registered with the first sink of the process and never before.
+_GC = {"secs": 0.0, "runs": 0, "t0": None}
+
+#: (file, key, seconds a unit) of the cgroup's throttled time: v2, v1;
+#: cut to the one that is there at the first sample ([] where none is)
+_CPU_STAT = [("/sys/fs/cgroup/cpu.stat", "throttled_usec", 1e-6),
+             ("/sys/fs/cgroup/cpu/cpu.stat", "throttled_time", 1e-9)]
+
+
+def _on_gc(phase, info):
+    if phase == "start":
+        _GC["t0"] = clock.tick()
+    elif _GC["t0"] is not None:
+        _GC["secs"] += clock.tick() - _GC["t0"]
+        _GC["runs"] += 1
+        _GC["t0"] = None
+
+
+def _ensure_gc_callback():
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _throttled_s():
+    """Seconds the process's cgroup has been held off its CPUs so far;
+    None where there is no such file."""
+    for source in list(_CPU_STAT):
+        path, key, scale = source
+        try:
+            with open(path) as f:
+                for line in f:
+                    if line.startswith(key):
+                        _CPU_STAT[:] = [source]
+                        return int(line.split()[1]) * scale
+        except OSError:
+            pass
+        _CPU_STAT.remove(source)
+    return None
+
+
+def host_sample():
+    """(the process's ``host.*`` counters so far, its peak resident
+    bytes): one ``getrusage`` call (all threads, the native ring's and
+    the runtime's included), the collector's accumulator and, where
+    there is one, the cgroup's ``cpu.stat``. A round record carries
+    the difference of two samples."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    now = {"host.cpu_user_s": ru.ru_utime, "host.cpu_sys_s": ru.ru_stime,
+           "host.minflt": ru.ru_minflt, "host.majflt": ru.ru_majflt,
+           "host.nvcsw": ru.ru_nvcsw, "host.nivcsw": ru.ru_nivcsw,
+           "host.gc_s": _GC["secs"], "host.gc_runs": _GC["runs"]}
+    throttled = _throttled_s()
+    if throttled is not None:
+        now["host.throttled_s"] = throttled
+    return now, int(ru.ru_maxrss) * 1024        # Linux: KiB
+
+
+def _host_shape():
+    """What the first record says of the machine: the cores the
+    process may run on and its threads. ``host.threads`` counts the
+    Python threads and the native rings' workers, ``host.os_threads``
+    every thread the kernel schedules for the process (the runtime's
+    too; left out where there is no /proc)."""
+    shape = {"host.cpus": len(os.sched_getaffinity(0)),
+             "host.threads": threading.active_count()}
+    native = sys.modules.get("commefficient_tpu.native")
+    if native is not None:
+        shape["host.threads"] += native.ring_threads()
     try:
-        with open("/proc/self/status") as f:
-            for line in f:
-                if line.startswith("VmHWM:"):
-                    return int(line.split()[1]) * 1024
+        shape["host.os_threads"] = len(os.listdir("/proc/self/task"))
     except OSError:
         pass
-    try:
-        import resource
-        return int(resource.getrusage(
-            resource.RUSAGE_SELF).ru_maxrss) * 1024  # Linux: KiB
-    except Exception:
-        return None
-    return None
+    return shape
+
+
+# --- the threads' stacks when a round runs long ---------------------------
+# A daemon thread over ``sys._current_frames()``, not
+# ``faulthandler.dump_traceback_later``: that one would have to be
+# re-armed by the round loop every round (a lock and a condition
+# variable a call, where arming this is one tuple store), writes
+# unbounded text into a file that somebody has to read back, and names
+# no thread. What it has over this is that it needs no interpreter
+# lock (see ``_watch``).
+#: a round open longer than max(STALL_MIN_S, STALL_FACTOR x the median
+#: of the last STALL_PERIODS periods) is a stall
+STALL_MIN_S = 1.0
+STALL_FACTOR = 8.0
+STALL_PERIODS = 32
+STALL_FRAMES = 8        # innermost frames kept of each thread
+STALL_BYTES = 4096      # most characters of frames one record keeps
+
+
+def stall_limit(periods) -> float:
+    """Seconds a round may stay open before its stacks are taken."""
+    if not periods:
+        return STALL_MIN_S
+    return max(STALL_MIN_S, STALL_FACTOR * statistics.median(periods))
+
+
+def thread_stacks(skip=()):
+    """{thread name: [innermost frame first, "file:line function"]} of
+    every Python thread but ``skip``, ``STALL_FRAMES`` frames each and
+    ``STALL_BYTES`` characters in all."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    out, room = {}, STALL_BYTES
+    for ident, frame in sys._current_frames().items():
+        if ident in skip:
+            continue
+        frames = []
+        while frame is not None and len(frames) < STALL_FRAMES:
+            code = frame.f_code
+            line = (f"{os.path.basename(code.co_filename)}:"
+                    f"{frame.f_lineno} {code.co_name}")[:max(room, 0)]
+            room -= len(line)
+            if line:
+                frames.append(line)
+            frame = frame.f_back
+        out[names.get(ident, str(ident))] = frames
+    return out
+
+
+def _watch(ref, stop):
+    """The stall watchdog's thread: sleeps until the armed record's
+    limit and, if that record is still the one armed, takes the stacks
+    and leaves them for ``_finish`` to put on it (the record itself is
+    written by the round loop's thread alone). Holds its Telemetry
+    weakly and only while awake, so a recorder dropped unclosed takes
+    the thread with it. A Python thread: it needs the interpreter lock,
+    so where the round loop holds that through a long call the stacks
+    come late, and ``after_s`` says by how much."""
+    done = None
+    while not stop.is_set():
+        tel = ref()
+        if tel is None:
+            return
+        rec, t_open = tel._armed
+        left = t_open + stall_limit(list(tel._periods)) - clock.tick()
+        if rec is done:
+            left = STALL_MIN_S
+        elif left <= 0:
+            stall = {
+                "after_s": round(clock.tick() - t_open, 6),
+                "threads": thread_stacks(skip=(threading.get_ident(),))}
+            if tel._armed[0] is rec:    # still the open round
+                tel._stalled = (rec, stall)
+            done, left = rec, STALL_MIN_S
+        del tel, rec
+        stop.wait(left)
 
 
 def _memory_stat(key):
@@ -225,7 +384,17 @@ class Telemetry:
         self._closed_rounds = set()     # indices no longer current
         self._alarm_counts = {}         # rule -> fires this run
         self._current = None            # the open round record
+        # (record, compile mark at its opening, at its swapping out):
+        # the record ``begin_round`` swapped out and nobody finished yet
+        self._closing = None
         self._compile_mark = dict(_COMPILE)
+        self._host_mark = None          # host_sample() at the last finish
+        # the stall watchdog: (the open record, when it opened), the
+        # last periods, and the thread's stop, once a round has begun
+        self._armed = None
+        self._periods = deque(maxlen=STALL_PERIODS)
+        self._stalled = None            # (record, its stall), from _watch
+        self._watch_stop = None
         self._seen_round = False
         self._shut = False
         # emission hold: a profiler trace window buffers closed
@@ -249,6 +418,7 @@ class Telemetry:
         self._timeline_lock = threading.Lock()
         if self._sinks:
             _ensure_compile_listener()
+            _ensure_gc_callback()
 
     # --- configuration --------------------------------------------------
 
@@ -261,6 +431,7 @@ class Telemetry:
         once the run's logdir exists)."""
         self._sinks.append(sink)
         _ensure_compile_listener()
+        _ensure_gc_callback()
 
     def emit(self, rec):
         for sink in self._sinks:
@@ -273,18 +444,28 @@ class Telemetry:
     # --- round lifecycle ------------------------------------------------
 
     def begin_round(self, index: int):
-        """Open round ``index``; closes (and may emit) the previous
-        round. No-op when disabled."""
+        """Open round ``index`` and swap the previous round's record
+        out; ``close_round`` finishes that one (``FedModel`` calls it
+        once its program is dispatched; the next ``begin_round`` does
+        where nobody has). No-op when disabled."""
         if not self._sinks:
             return None
         rec = make_round_record(index)
         self._records[index] = rec
+        self.close_round()
         # the new record is current before the old one is finished
         # (memory statistics, emission): a span another thread opens
         # meanwhile (a loader's producer, woken by the pop that
         # preceded this call) lands on it and is not dropped
-        self._close_current(rec)
-        mark = self._compile_mark = dict(_COMPILE)
+        old, self._current = self._current, rec
+        mark = dict(_COMPILE)
+        if old is not None:
+            self._closing = (old, self._compile_mark, mark)
+        self._compile_mark = mark
+        now = clock.tick()
+        if self._armed is not None:
+            self._periods.append(now - self._armed[1])
+        self._armed = (rec, now)
         if not self._seen_round:
             # what compiled before this run's first round (set-up):
             # with the per-round deltas, all the listener has counted
@@ -293,21 +474,44 @@ class Telemetry:
             c["compile_events_before"] = mark["events"]
             c["compile_secs_before"] = round(mark["secs"], 6)
             c["compile_cache_hits_before"] = mark["cache_hits"]
+            self._host_mark = host_sample()[0]
+            self._watch_stop = threading.Event()
+            threading.Thread(
+                target=_watch, args=(weakref.ref(self), self._watch_stop),
+                name="telemetry-stall", daemon=True).start()
         return rec
 
-    def _close_current(self, successor=None):
-        rec, self._current = self._current, successor
-        if rec is None:
+    def close_round(self):
+        """Finish the record the last ``begin_round`` swapped out, if
+        nobody has: memory statistics, the process's counters, compile
+        deltas, emission. The recorder's own work, so it is a span of
+        its own (``telemetry.close``, no parent, on the record now
+        current) and ``FedModel`` places it after the round's dispatch,
+        under the device's program, not before it."""
+        closing = self._closing
+        if closing is None:
             return
-        rec["host_rss_peak_bytes"] = host_rss_peak_bytes()
+        self._closing = None
+        with _Span(self, self._current, "telemetry.close", top=True):
+            self._finish(*closing)
+
+    def _finish(self, rec, mark, end):
+        now, rec["host_rss_peak_bytes"] = host_sample()
         rec["hbm_peak_bytes"] = hbm_peak_bytes()
         rec["hbm_reserved_peak_bytes"] = hbm_reserved_peak_bytes()
-        mark = self._compile_mark
         c = rec["counters"]
-        c["compile_events"] = _COMPILE["events"] - mark["events"]
-        c["compile_secs"] = round(_COMPILE["secs"] - mark["secs"], 6)
-        c["compile_cache_hits"] = (_COMPILE["cache_hits"]
-                                   - mark["cache_hits"])
+        c["compile_events"] = end["events"] - mark["events"]
+        c["compile_secs"] = round(end["secs"] - mark["secs"], 6)
+        c["compile_cache_hits"] = end["cache_hits"] - mark["cache_hits"]
+        if "compile_events_before" in c:    # the run's first record
+            c.update(_host_shape())
+        was, self._host_mark = self._host_mark, now
+        for key, v in now.items():
+            c[key] = round(v - was.get(key, v), 6)
+        stalled = self._stalled     # never cleared here: the watchdog
+        if stalled is not None and stalled[0] is rec:   # alone writes it
+            rec["stall"] = stalled[1]
+            c["stall.captured"] = 1
         self._closed_rounds.add(rec["round"])
         self._drain()
 
@@ -338,6 +542,7 @@ class Telemetry:
             timeline = rec["timeline"]
             if len(timeline) < TIMELINE_CAP:
                 timeline.append(entry)
+                rec["timeline_cpu"].append(None)
                 return len(timeline) - 1
             c = rec["counters"]
             c["timeline_dropped"] = c.get("timeline_dropped", 0) + 1
@@ -480,7 +685,12 @@ class Telemetry:
         if self._shut:
             return
         self._shut = True
-        self._close_current()
+        if self._watch_stop is not None:
+            self._watch_stop.set()
+        self.close_round()
+        rec, self._current = self._current, None
+        if rec is not None:
+            self._finish(rec, self._compile_mark, dict(_COMPILE))
         self._drain(force=True)
         if self._alarm_counts and self._sinks:
             from commefficient_tpu.telemetry.record import \

@@ -1,4 +1,4 @@
-"""Ledger record schema (version 8).
+"""Ledger record schema (version 9).
 
 A run ledger is a JSONL file: one self-describing record per line.
 Every record carries ``schema`` (this module's version) and ``kind``:
@@ -132,18 +132,51 @@ one span model, telemetry/core.py):
              TPU the round programs' temporaries are reserved, not
              allocated, so the chip's peak is the sum. None
              off-accelerator.
+
+Schema v9 adds NO required keys — three optional round-record keys
+and a family of counters (the resource clock, telemetry/core.py):
+
+``cpu``    — CPU seconds by span name, accumulated exactly as
+             ``spans`` accumulates wall seconds: the calling thread's
+             own clock (``clock.thread_cpu``), so a span's wall minus
+             its CPU is the time its thread did not run (a wait for
+             the device, a lock, the interpreter lock, a page fault's
+             disk, a core).
+``timeline_cpu`` — one number an entry of ``timeline``, same index:
+             that span's CPU seconds, None while it is open.
+             ``timeline`` entries keep their five fields.
+``stall``  — None unless the round stayed open past
+             ``core.stall_limit`` (max(1 s, 8 x the median of the last
+             32 periods)): ``{"after_s": seconds after the round
+             opened at which the stacks were taken, "threads": {thread
+             name: [innermost frame first, "file:line function", at
+             most 8]}}``, at most 4 KB, taken once by the recorder's
+             own thread; ``counters["stall.captured"]`` marks it.
+``counters["host.*"]`` — the process's counters from the previous
+             record's finishing to this one's, one ``getrusage`` call:
+             ``host.cpu_user_s`` / ``host.cpu_sys_s`` (all threads),
+             ``host.minflt`` / ``host.majflt``, ``host.nvcsw`` /
+             ``host.nivcsw``, ``host.gc_s`` / ``host.gc_runs`` (the
+             collector, through ``gc.callbacks``), ``host.throttled_s``
+             (the cgroup's ``cpu.stat``; absent where there is none).
+             The run's first record also carries ``host.cpus`` (the
+             cores the process may run on), ``host.threads`` (Python
+             threads + the native rings' workers) and
+             ``host.os_threads``. ``host_rss_peak_bytes`` is that
+             call's ``ru_maxrss``.
 """
 
 from __future__ import annotations
 
 from commefficient_tpu.telemetry import clock
 
-LEDGER_SCHEMA_VERSION = 8
+LEDGER_SCHEMA_VERSION = 9
 
 # versions validate_record accepts: v1 (pre-probe), v2 (pre-trace),
-# v3 (pre-fleet), v4 (pre-DP), v5 (pre-SLO), v6 (pre-causal) and v7
-# (pre-timeline) ledgers stay readable by the report tooling
-READABLE_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8)
+# v3 (pre-fleet), v4 (pre-DP), v5 (pre-SLO), v6 (pre-causal), v7
+# (pre-timeline) and v8 (pre-CPU) ledgers stay readable by the report
+# tooling
+READABLE_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9)
 
 # most timeline entries one round record keeps
 TIMELINE_CAP = 256
@@ -216,6 +249,9 @@ def make_round_record(round_index: int) -> dict:
         "slo": None,
         "timeline": [],
         "hbm_reserved_peak_bytes": None,
+        "cpu": {},
+        "timeline_cpu": [],
+        "stall": None,
     })
     return rec
 
@@ -266,6 +302,35 @@ def _validate_timeline(timeline) -> list:
     return problems
 
 
+def _validate_cpu(rec) -> list:
+    """Problems with the optional v9 keys (validated only when
+    present)."""
+    problems = []
+    cpu = rec.get("cpu", {})
+    if not isinstance(cpu, dict) or any(
+            not isinstance(v, (int, float)) for v in cpu.values()):
+        problems.append("cpu is not a {span: seconds} dict")
+    tcpu = rec.get("timeline_cpu")
+    if tcpu is not None:
+        if not isinstance(tcpu, list) or any(
+                not (v is None or isinstance(v, (int, float)))
+                for v in tcpu):
+            problems.append("timeline_cpu is not a list of seconds")
+        elif len(tcpu) != len(rec.get("timeline") or ()):
+            problems.append("timeline_cpu is not the timeline's length")
+    stall = rec.get("stall")
+    if stall is not None and not (
+            isinstance(stall, dict)
+            and isinstance(stall.get("after_s"), (int, float))
+            and isinstance(stall.get("threads"), dict)
+            and all(isinstance(f, list) and all(
+                isinstance(x, str) for x in f)
+                for f in stall["threads"].values())):
+        problems.append("stall is not {after_s, threads: {name: "
+                        "[frames]}}")
+    return problems
+
+
 def validate_record(rec) -> list:
     """Schema check: a list of problem strings, empty when valid."""
     problems = []
@@ -309,6 +374,7 @@ def validate_record(rec) -> list:
             problems.append("slo is not a dict")
         if "timeline" in rec:              # optional (v8): validate
             problems.extend(_validate_timeline(rec["timeline"]))
+        problems.extend(_validate_cpu(rec))       # optional (v9)
         v = rec.get("hbm_reserved_peak_bytes")    # optional (v8)
         if v is not None and not isinstance(v, (int, float)):
             problems.append("hbm_reserved_peak_bytes is non-numeric")

@@ -10,8 +10,9 @@ a host timestamp taken around each ``next(loader)`` and each round
 (``FedRun.step``) and, in a traced run, every round record the program
 emitted. Writes ``<out>/<cell>-<seed>-t<trace>.json``: per round the
 fetch and the step in ms, the rounds longer than a second with their
-index and which of the two held them, and the records' counters and
-spans (the timeline is dropped). The result line is the benchmark's
+index and which of the two held them, and the records' counters
+(the process's ``host.*`` among them), spans, their CPU and a long
+round's ``stall`` (the timeline is dropped). The result line is the benchmark's
 own, printed by it. What the benchmark cannot show: a rare round of
 seconds (PERF.md section 6) vanishes in ``updates_per_s`` and is
 invisible in ``round_ms_p90``.
@@ -56,7 +57,8 @@ def main(argv=None):
         sink_write(self, rec)
         if rec.get("kind") == "round":
             records.append({k: rec.get(k) for k in
-                            ("round", "counters", "spans")})
+                            ("round", "counters", "spans", "cpu",
+                             "stall")})
 
     bench.Feed.next = timed(fetch, feed_next)
     fedrun.FedRun.step = timed(step, run_step)

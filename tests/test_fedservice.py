@@ -31,7 +31,7 @@ W, B, DIM = 8, 2, 256
 #: solo run and a daemon-interleaved one; everything else must match
 NONDET_KEYS = ("ts", "spans", "counters", "timeline", "device_time",
                "host_rss_peak_bytes", "hbm_peak_bytes",
-               "hbm_reserved_peak_bytes")
+               "hbm_reserved_peak_bytes", "cpu", "timeline_cpu", "stall")
 
 
 def _loss(params, batch, cfg):
@@ -165,7 +165,7 @@ class TestDeterminism:
             rounds = [r for r in recs if r.get("kind") == "round"]
             assert rounds and n_rounds in (None, len(rounds))
             for rec in recs:
-                assert rec["schema"] == LEDGER_SCHEMA_VERSION == 8
+                assert rec["schema"] == LEDGER_SCHEMA_VERSION == 9
                 assert validate_record(rec) == [], rec
                 assert "causal" not in rec
             for rec in rounds:

@@ -548,6 +548,7 @@ def test_latency_alarm_bundle_carries_the_rounds_timeline(tmp_path,
     for r in range(6):
         tel.begin_round(r)
         with tel.span("client_pass"):
+            tel.close_round()           # where FedModel calls it
             with tel.span("metrics_host"):
                 pass
         with tel.span("server_pass"):
@@ -565,9 +566,12 @@ def test_latency_alarm_bundle_carries_the_rounds_timeline(tmp_path,
     assert [r["round"] for r in bundle["rounds"]] == [2, 3, 4, 5]
     for rec in bundle["rounds"]:
         assert [(e[0], e[3]) for e in rec["timeline"]] == [
-            ("client_pass", None), ("metrics_host", 0),
-            ("server_pass", None)]
+            ("client_pass", None), ("telemetry.close", None),
+            ("metrics_host", 0), ("server_pass", None)]
         assert all(e[1] <= e[2] for e in rec["timeline"])
+        assert len(rec["timeline_cpu"]) == 4 and set(rec["cpu"]) == {
+            e[0] for e in rec["timeline"]}
+        assert rec["counters"]["host.cpu_user_s"] >= 0.0
         assert "causal" not in rec
     report = _load_script("telemetry_report")
     assert report.main(["--postmortem", fr.last_bundle]) == 0

@@ -214,6 +214,45 @@ def test_persona_loader_spans_and_counters(tmp_path, depth, make, thread):
         assert {e[0] for e in kids} == {"data.pop_wait"}
 
 
+@pytest.mark.skipif(not native.available(), reason="no native toolchain")
+def test_the_first_record_counts_the_rings_threads():
+    """``host.threads``: the Python threads and the workers of the
+    rings open when the run's first record is finished."""
+    before = native.ring_threads()
+    loader = _cv_loader(NativeFedLoader, depth=DEPTH)
+    recs, _ = _drive(loader, epochs=1)
+    assert native.ring_threads() == before + loader.n_threads
+    c = recs[0]["counters"]
+    # the round loop's thread, the recorder's watchdog, the ring's two
+    assert c["host.threads"] >= 2 + before + loader.n_threads
+    assert c["host.cpus"] >= 1
+    assert all("host.threads" not in r["counters"]
+               for i, r in recs.items() if i)
+    loader.close()
+    assert native.ring_threads() == before
+
+
+@pytest.mark.parametrize("make, thread", [
+    (_persona_loader, "persona-prefetch"),
+    (_token_loader, "tokens-prefetch")], ids=["persona", "tokens"])
+def test_a_producers_spans_carry_their_own_threads_cpu(tmp_path, make,
+                                                       thread):
+    recs, _ = _drive(make(str(tmp_path), 3), epochs=1)
+    seen = 0
+    for rec in recs.values():
+        assert len(rec["timeline_cpu"]) == len(rec["timeline"])
+        for e, cpu in zip(rec["timeline"], rec["timeline_cpu"]):
+            if e[4] == thread and e[2] is not None:
+                seen += 1
+                # its own thread's clock: never more than its wall
+                assert 0.0 <= cpu <= e[2] - e[1] + 1e-3
+    assert seen >= 2
+    collate = sum(rec["cpu"].get("data.collate", 0.0)
+                  for rec in recs.values())
+    assert 0.0 < collate <= sum(rec["spans"].get("data.collate", 0.0)
+                                for rec in recs.values()) + 1e-3
+
+
 def test_without_a_live_telemetry_the_loaders_record_nothing():
     assert telemetry.current() is NULL_TELEMETRY
     batches = list(_cv_loader(FedLoader))

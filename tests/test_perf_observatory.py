@@ -559,7 +559,8 @@ class TestEmissionHold:
         for r in range(2):
             tel.begin_round(r)
             tel.set_round_bytes(r, 10.0, 20.0)
-        tel.begin_round(2)        # closes round 1
+        tel.begin_round(2)        # swaps round 1 out
+        tel.close_round()         # and this finishes it
         tel.set_round_bytes(2, 10.0, 20.0)
         assert sink.records == []  # everything buffered by the hold
         buckets = {"window_s": 1.0, "busy_s": 0.5, "compute_s": 0.4,
@@ -669,7 +670,7 @@ class TestProfileIntegration:
         assert all(not validate_record(r) for r in recs)
         rounds = [r for r in recs if r["kind"] == "round"]
         assert len(rounds) == 5
-        assert all(r["schema"] == 8 for r in rounds)
+        assert all(r["schema"] == 9 for r in rounds)
 
         traced = [r for r in rounds if r.get("device_time")]
         assert [r["round"] for r in traced] == [1, 2, 3, 4]

@@ -541,7 +541,7 @@ class TestRuntimeCharge:
             LEDGER_SCHEMA_VERSION, make_round_record, validate_record)
 
         rec = make_round_record(0)
-        assert rec["schema"] == 8 == LEDGER_SCHEMA_VERSION
+        assert rec["schema"] == 9 == LEDGER_SCHEMA_VERSION
         assert rec["dp_epsilon"] is None \
             and rec["dp_delta"] is None and rec["dp_sigma"] is None
         assert validate_record(rec) == []

@@ -113,6 +113,36 @@ def test_jsonl_ledger_schema_and_order(tmp_path):
     assert any(r["kind"] == "epoch" for r in records)
 
 
+def test_ledger_lines_carry_the_resource_clock(tmp_path):
+    path = str(tmp_path / "run.jsonl")
+    tel = Telemetry([JSONLSink(path)])
+    for r in range(3):
+        tel.begin_round(r)
+        with tel.span("client_pass"):
+            tel.close_round()           # where FedModel calls it
+            with tel.span("metrics_host"):
+                pass
+        tel.set_round_bytes(r, downlink=1.0, uplink=1.0)
+    tel.close()
+    with open(path) as f:
+        rounds = [json.loads(line) for line in f]
+    assert [r["round"] for r in rounds] == [0, 1, 2]
+    for rec in rounds:
+        assert rec["schema"] == 9 and validate_record(rec) == []
+        assert all(len(e) == 5 for e in rec["timeline"])
+        assert len(rec["timeline_cpu"]) == len(rec["timeline"])
+        assert set(rec["cpu"]) == set(rec["spans"])
+        assert all(0.0 <= rec["cpu"][k] <= rec["spans"][k] + 1e-3
+                   for k in rec["spans"])
+        assert rec["stall"] is None
+        assert isinstance(rec["host_rss_peak_bytes"], int)
+        c = rec["counters"]
+        assert {"host.cpu_user_s", "host.cpu_sys_s", "host.minflt",
+                "host.nvcsw", "host.gc_runs"} <= set(c)
+        assert ("host.cpus" in c) == (rec["round"] == 0)
+        assert ("telemetry.close" in rec["spans"]) == (rec["round"] > 0)
+
+
 def test_deferred_bytes_preserve_round_order(tmp_path):
     """Pipelined shape: rounds close before their bytes arrive (the
     flush replay attaches them later). Emission must wait and stay in
@@ -121,8 +151,9 @@ def test_deferred_bytes_preserve_round_order(tmp_path):
     sink = JSONLSink(path)
     tel = Telemetry([sink])
     tel.begin_round(0)
-    tel.begin_round(1)   # closes 0 — but 0 has no bytes yet
-    tel.begin_round(2)   # closes 1
+    tel.begin_round(1)   # swaps 0 out
+    tel.begin_round(2)   # finishes 0, which has no bytes yet; swaps 1 out
+    tel.close_round()    # finishes 1, as FedModel does after its dispatch
     with open(path) as f:
         assert f.read() == ""  # nothing emitted yet
     # bytes arrive out of order: 1 before 0
@@ -165,9 +196,9 @@ def test_schema_v4_device_time_round_trip(tmp_path):
     from commefficient_tpu.telemetry.record import (
         READABLE_SCHEMA_VERSIONS, make_round_record)
 
-    assert READABLE_SCHEMA_VERSIONS == (1, 2, 3, 4, 5, 6, 7, 8)
+    assert READABLE_SCHEMA_VERSIONS == (1, 2, 3, 4, 5, 6, 7, 8, 9)
     rec = make_round_record(0)
-    assert rec["schema"] == 8 and rec["device_time"] is None
+    assert rec["schema"] == 9 and rec["device_time"] is None
     assert rec["slo"] is None  # v6: the SLO stamp, None unless armed
     assert "causal" not in rec  # v7's key: nothing writes it any more
     assert validate_record(rec) == []
@@ -293,6 +324,33 @@ def test_report_summarize_round_trips(tmp_path):
     assert s["spans"]["server"]["mean_ms"] == 10.0
     text = report.render_summary(s)
     assert "rounds: 3" in text and "span server" in text
+
+
+@pytest.mark.parametrize("schema", [8, 9])
+def test_report_reads_a_ledger_with_or_without_the_resource_clock(
+        tmp_path, schema):
+    report = _load_report_module()
+    path = tmp_path / "a.jsonl"
+    _write_ledger(path, n_rounds=3, ms_per_round=10.0,
+                  bytes_per_round=100.0)
+    if schema == 8:         # as PR 37's writer left it
+        lines = []
+        for line in path.read_text().splitlines():
+            rec = json.loads(line)
+            rec["schema"] = 8
+            for key in ("cpu", "timeline_cpu", "stall"):
+                rec.pop(key, None)
+            rec["counters"] = {k: v for k, v in
+                               rec.get("counters", {}).items()
+                               if not k.startswith("host.")}
+            lines.append(json.dumps(rec))
+        path.write_text("\n".join(lines) + "\n")
+    records, problems = report.load_ledger(str(path))
+    assert problems == []
+    assert {r["schema"] for r in records} == {schema}
+    s = report.summarize(records)
+    assert s["rounds"] == 3 and s["host_rss_peak_bytes"] > 0
+    assert "rounds: 3" in report.render_summary(s)
 
 
 def test_report_diff(tmp_path):
