@@ -37,6 +37,7 @@ from commefficient_tpu.core.server import (ServerState, ServerUpdate,
                                            server_update,
                                            staleness_weights)
 from commefficient_tpu.ops.sketch import CountSketch
+from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
 
 
 class ClientStates(NamedTuple):
@@ -560,10 +561,15 @@ def build_client_round(cfg: Config, loss_fn: Optional[Callable],
                                  for m in (loss,) + tuple(metrics))
                     return w, mets
 
+                # the weights are this scope's own and the clients'
+                # losses are summed below: the axis is named so, and
+                # a layer may take every client's rows at once
+                # (parallel/mesh.py SHARED_CLIENTS)
+                over_clients = jax.vmap(one, axis_name=SHARED_CLIENTS)
                 if cw is None:
-                    weighted_l, metrics = jax.vmap(one)(batch)
+                    weighted_l, metrics = over_clients(batch)
                 else:
-                    weighted_l, metrics = jax.vmap(one)(batch, cw)
+                    weighted_l, metrics = over_clients(batch, cw)
                 return jnp.sum(weighted_l) / total, metrics
 
             return local_loss

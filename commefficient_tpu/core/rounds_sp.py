@@ -44,9 +44,9 @@ from jax.sharding import Mesh
 from commefficient_tpu.models.gpt2 import (GPT2Config, GPT2DoubleHeads,
                                            lm_nll_sums_chunked,
                                            token_nll)
-from commefficient_tpu.parallel.mesh import (CLIENT_AXIS, client_spec,
-                                             replicated_spec, shard_map,
-                                             spec)
+from commefficient_tpu.parallel.mesh import (CLIENT_AXIS, SHARED_CLIENTS,
+                                             client_spec, replicated_spec,
+                                             shard_map, spec)
 
 SEQ_AXIS = "seq"
 
@@ -159,7 +159,10 @@ def build_sp_gpt2_round(cfg: GPT2Config, mesh: Mesh,
                           + mc_coef * mc)
                 return share, report
 
-            shares, reports = jax.vmap(per_client)(
+            # ``f`` is shared and the shares are summed: the clients
+            # of this block pool their labelled rows in the head
+            shares, reports = jax.vmap(
+                per_client, axis_name=SHARED_CLIENTS)(
                 ids, tt, labels, mc_ids, mc_labels, ex_mask)
             return jnp.sum(shares * w), reports
 

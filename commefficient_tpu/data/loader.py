@@ -266,7 +266,9 @@ class _RoundLoaderBase:
         for round_spec in specs:
             with tel.span("data.collate"):
                 batch = self.collate(round_spec)
-            yield self._apply_dropout(batch)
+                counted = self.round_counters(batch)
+            batch = self._apply_dropout(batch)
+            yield staging.note(batch, counted) if counted else batch
 
     #: the consuming model's ``place_batch`` (data/staging.py); None:
     #: ``staging.current()`` at each epoch
@@ -358,6 +360,12 @@ class _RoundLoaderBase:
 
     def collate(self, round_spec) -> dict:
         raise NotImplementedError
+
+    def round_counters(self, batch) -> dict:
+        """What to count of a collated round on the record of the round
+        that consumes it (``staging.note``; read by
+        ``FedModel._client_pass``). Nothing, by default."""
+        return {}
 
     def close(self):
         """Release what the loader keeps between epochs (an unfinished
@@ -640,6 +648,15 @@ class PersonaFedLoader(_PrefetchedRoundLoader):
             batch["mask"][i, :n] = 1.0
         batch["client_ids"] = ids
         return batch
+
+    def round_counters(self, batch) -> dict:
+        """``head.positions``: the round's positions; ``head.labelled``:
+        those with a language-model label (the gold reply's tokens),
+        the rows the vocabulary head computes (models/gpt2.py
+        ``lm_nll_sums_chunked``)."""
+        labels = batch["lm_labels"]
+        return {"head.positions": labels.size,
+                "head.labelled": int(np.count_nonzero(labels != -1))}
 
 
 class TokenFedLoader(_PrefetchedRoundLoader):

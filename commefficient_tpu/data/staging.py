@@ -14,9 +14,12 @@ loader works as it did without one.
 
 A staged batch is a ``dict`` of the host fields, as ever (its readers
 index it, copy it, edit copies of its fields), with the device copy
-riding along as an attribute. Whatever rebuilds the batch between the
-loader and the model (``dict(batch)``, the async driver's fold, a chaos
-wrapper, mixup) makes a plain ``dict`` and thereby drops the copy;
+riding along as an attribute, and beside it what the loader counted in
+the batch for the consuming round's record (``note`` / ``counters_of``:
+the labelled positions of a language-model round). Whatever rebuilds
+the batch between the loader and the model (``dict(batch)``, the async
+driver's fold, a chaos wrapper, mixup) makes a plain ``dict`` and
+thereby drops both;
 :func:`staged_copy` also refuses one whose fields were replaced in
 place, or that another model's placement made.
 """
@@ -26,8 +29,8 @@ from __future__ import annotations
 import weakref
 from typing import Callable, NamedTuple, Optional
 
-__all__ = ["StagedBatch", "stage", "staged_copy", "publish", "withdraw",
-           "current"]
+__all__ = ["NotedBatch", "StagedBatch", "note", "counters_of", "stage",
+           "staged_copy", "publish", "withdraw", "current"]
 
 
 class _Staged(NamedTuple):
@@ -36,16 +39,38 @@ class _Staged(NamedTuple):
     device: object      # what the placement returned
 
 
-class StagedBatch(dict):
+class NotedBatch(dict):
+    """A round's host batch with what its loader counted in it
+    (``counters``: name -> number), for the record of the round that
+    consumes it: the loader's thread is rounds ahead of that record."""
+
+    __slots__ = ("counters",)
+
+
+class StagedBatch(NotedBatch):
     """A round's host batch with its device copy (``staged``)."""
 
     __slots__ = ("staged",)
 
 
+def note(batch: dict, counters: dict) -> NotedBatch:
+    """``batch`` with ``counters`` riding along."""
+    out = NotedBatch(batch)
+    out.counters = dict(counters)
+    return out
+
+
+def counters_of(batch) -> dict:
+    """What the batch's loader counted in it; {} for a plain ``dict``
+    (whatever rebuilt the batch dropped the counts with the copy)."""
+    return getattr(batch, "counters", None) or {}
+
+
 def stage(batch: dict, place: Callable) -> StagedBatch:
-    """``batch`` with ``place(batch)`` riding along. The copy may still
-    be in flight when this returns."""
+    """``batch`` with ``place(batch)`` riding along, and its counters
+    if it has any. The copy may still be in flight when this returns."""
     out = StagedBatch(batch)
+    out.counters = counters_of(batch)
     out.staged = _Staged(place, tuple(batch.items()), place(batch))
     return out
 

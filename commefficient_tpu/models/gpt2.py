@@ -21,13 +21,16 @@ mc_labels (B,).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap, sequential_vmap
 
 from commefficient_tpu.models import register_model
+from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,22 +257,30 @@ def token_nll(logits, labels, ignore_index=-100):
     return lse - tok, valid.astype(jnp.float32)
 
 
-def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
-                        tokens_per_chunk=1024):
-    """Per-example (Σ nll, Σ valid) of the tied-head LM cross-entropy
-    without materialising the (E, T, V) logits tensor.
+def _zeros(shape, dtype, like):
+    """Zeros to carry through a loop: derived from ``like`` and not
+    bare ``jnp.zeros``, so that inside a ``shard_map`` they vary over
+    the mesh axes the loop's results vary over (the loop carry-type
+    check; cf. models/moe.py ``_zeros``)."""
+    return jnp.zeros(shape, dtype) + (jnp.ravel(like)[0] * 0).astype(dtype)
 
-    ``h`` (E, Tm, C) are the final hidden states at the *predicting*
-    positions (callers pass ``h[:, :-1]``), ``labels`` (E, Tm) the
-    shifted targets. A ``lax.scan`` over token chunks computes each
-    chunk's logits, logsumexp and label gather in one compiler-fused
-    region; ``jax.checkpoint`` makes the backward recompute the chunk
-    logits instead of storing them, so peak logits memory is one chunk
-    (~200 MB f32 at 1024 tokens x 50k vocab) and the fwd+bwd HBM
-    traffic of the vocab head drops by the full-logits store/reload
-    chain. Same math as ``token_nll`` of the full logits (fp summation
-    order aside)."""
+
+def _axis_bound(name) -> bool:
+    """Whether the trace is inside a ``vmap`` that named its axis so."""
+    try:
+        jax.lax.axis_size(name)
+    except NameError:
+        return False
+    return True
+
+
+def _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk):
+    """Every position carries a label (raw token ids): straight slices
+    of ``tokens_per_chunk`` rows through a ``lax.scan``, each chunk's
+    logits recomputed in the backward (``jax.checkpoint``). No
+    partition, gather or write-back."""
     E, Tm, C = h.shape
+    pad_label = -1  # no token id; masks the positions padded below
     tc = max(1, min(Tm, tokens_per_chunk // max(E, 1)))
     num_chunks = -(-Tm // tc)
     pad = num_chunks * tc - Tm
@@ -280,14 +291,14 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     with jax.named_scope("lm_head"):
         hp = jnp.pad(h.astype(dtype), ((0, 0), (0, pad), (0, 0)))
         lp = jnp.pad(labels, ((0, 0), (0, pad)),
-                     constant_values=ignore_index)
+                     constant_values=pad_label)
         wte_c = wte.astype(dtype)  # cast once, outside the scan
 
     @jax.checkpoint
     def chunk_sums(hc, lc, w):
         logits = jnp.einsum("etc,vc->etv", hc, w,
                             preferred_element_type=jnp.float32)
-        nll, valid = token_nll(logits, lc, ignore_index)
+        nll, valid = token_nll(logits, lc, pad_label)
         return jnp.sum(nll * valid, -1), jnp.sum(valid, -1)
 
     def body(carry, i):
@@ -306,6 +317,219 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     with jax.named_scope("lm_head"):
         (sn, sv), _ = jax.lax.scan(
             body, init, jnp.arange(num_chunks, dtype=jnp.int32))
+    return sn, sv
+
+
+def _over_clients(one, pooled, table_grad=False):
+    """``one(h, wte, labels[, g])`` as it runs under a ``vmap``. Not
+    ``pooled``: an element at a time, whatever is batched. ``pooled``
+    (the ``vmap`` over ``SHARED_CLIENTS``): with the table shared the
+    batch axis is folded into the example axis and ``one`` runs once
+    over every client's rows; with ``table_grad`` its result is the
+    backward's ``(dh, dw)``, and ``dw`` is then the sum over the
+    clients, unbatched, which is what summed losses ask for a shared
+    weight. A table a client has no rows to share: an element at a
+    time again."""
+    seq = sequential_vmap(one)
+    if not pooled:
+        return seq
+    fn = custom_vmap(one)
+
+    @fn.def_vmap
+    def rule(axis_size, in_batched, h, wte, *per_example):
+        if in_batched[1]:
+            out = jax.vmap(seq, in_axes=[0 if b else None
+                                         for b in in_batched])(
+                h, wte, *per_example)
+            return out, jax.tree_util.tree_map(lambda _: True, out)
+
+        def fold(a, batched):
+            if not batched:
+                a = jnp.broadcast_to(a, (axis_size,) + a.shape)
+            return a.reshape((-1,) + a.shape[2:])
+
+        def unfold(a):
+            return a.reshape((axis_size, -1) + a.shape[1:])
+
+        out = fn(fold(h, in_batched[0]), wte, *(
+            fold(a, b) for a, b in zip(per_example, in_batched[2:])))
+        if table_grad:
+            return (unfold(out[0]), out[1]), (True, False)
+        return unfold(out), True
+
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _compact_nll(dtype, ignore_index, rows_max, pooled):
+    """``nll_sums(h (E, Tm, C) in dtype, wte (V, C), labels (E, Tm)) ->
+    (E,) f32 Σ nll`` over the labelled positions alone: the rows are
+    ordered labelled-first and only ``ceil(labelled / rows)`` chunks of
+    them meet the table, forward and backward. A loop of dynamic length
+    has no reverse mode, so the function carries its own VJP, which
+    recomputes each chunk's logits (one chunk's logits live at a time,
+    as under ``jax.checkpoint``) and sums the table's gradient in
+    float32 over the chunks. What a ``vmap`` makes of it:
+    ``_over_clients``."""
+
+    def plan(labels):
+        """(flat labels, the rows' order padded to whole chunks, the
+        labelled count, rows a chunk)."""
+        R = labels.size
+        rows = max(1, min(rows_max, R))
+        lab = labels.reshape(R)
+        valid = lab != ignore_index
+        # a stable partition: the labelled rows first, in place order
+        order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+        return (lab, jnp.pad(order, (0, -R % rows)),
+                jnp.sum(valid, dtype=jnp.int32), rows)
+
+    def chunk(i, hf, w, lab, order, n, rows):
+        """Chunk ``i``'s rows of ``hf``, which of them are labelled
+        rows (the last chunk's tail is not), their labels, logits and
+        logsumexp."""
+        idx = jax.lax.dynamic_slice_in_dim(order, i * rows, rows)
+        live = i * rows + jnp.arange(rows, dtype=jnp.int32) < n
+        hc = hf[idx]
+        lc = jnp.where(live, lab[idx], 0)
+        logits = jnp.einsum("rc,vc->rv", hc, w,
+                            preferred_element_type=jnp.float32)
+        return idx, live, hc, lc, logits, jax.nn.logsumexp(logits, -1)
+
+    def fwd_counted(h, wte, labels):
+        """(Σ nll by example, the chunk bodies that ran)."""
+        E, Tm, C = h.shape
+        with jax.named_scope("lm_head"):
+            hf, w = h.reshape(E * Tm, C), wte.astype(dtype)
+            lab, order, n, rows = plan(labels)
+
+            def body(i, carry):
+                sn, ran = carry
+                idx, live, _, lc, logits, lse = chunk(
+                    i, hf, w, lab, order, n, rows)
+                tok = jnp.take_along_axis(logits, lc[:, None], 1)[:, 0]
+                nll = jnp.where(live, lse - tok, 0.0)
+                # folded by the row's example; compare and sum, exact
+                # in float32 and no scatter
+                mine = (idx // Tm)[:, None] == jnp.arange(E)[None, :]
+                return (sn + jnp.sum(jnp.where(mine, nll[:, None], 0.0),
+                                     0), ran + 1)
+
+            return jax.lax.fori_loop(
+                0, (n + rows - 1) // rows, body,
+                (_zeros((E,), jnp.float32, hf), n * 0))
+
+    def fwd(h, wte, labels):
+        return fwd_counted(h, wte, labels)[0]
+
+    def bwd(h, wte, labels, g):
+        E, Tm, C = h.shape
+        with jax.named_scope("lm_head"):
+            hf, w = h.reshape(E * Tm, C), wte.astype(dtype)
+            lab, order, n, rows = plan(labels)
+
+            def body(i, carry):
+                dh, dw = carry
+                idx, live, hc, lc, logits, lse = chunk(
+                    i, hf, w, lab, order, n, rows)
+                # d nll / d logits = softmax - onehot(label), times the
+                # example's cotangent; 0 on the rows past n
+                p = jnp.exp(logits - lse[:, None])
+                hit = jnp.arange(w.shape[0])[None, :] == lc[:, None]
+                scale = jnp.where(live, g[idx // Tm], 0.0)
+                dl = (jnp.where(hit, p - 1.0, p)
+                      * scale[:, None]).astype(dtype)
+                dhc = jnp.einsum("rv,vc->rc", dl, w,
+                                 preferred_element_type=jnp.float32)
+                dw = dw + jnp.einsum("rv,rc->vc", dl, hc,
+                                     preferred_element_type=jnp.float32)
+                # each labelled row is written once; the rows past n
+                # go nowhere, the unlabelled stay the zeros they were
+                dh = dh.at[jnp.where(live, idx, E * Tm)].set(
+                    dhc.astype(dtype), mode="drop")
+                return dh, dw
+
+            dh, dw = jax.lax.fori_loop(
+                0, (n + rows - 1) // rows, body,
+                (_zeros(hf.shape, dtype, hf),
+                 _zeros(w.shape, jnp.float32, hf)))
+            return dh.reshape(h.shape), dw.astype(wte.dtype)
+
+    fwd = _over_clients(fwd, pooled)
+    bwd = _over_clients(bwd, pooled, table_grad=True)
+
+    @jax.custom_vjp
+    def nll_sums(h, wte, labels):
+        return fwd(h, wte, labels)
+
+    def vjp_fwd(h, wte, labels):
+        return fwd(h, wte, labels), (h, wte, labels)
+
+    def vjp_bwd(res, g):
+        dh, dw = bwd(*res, g)
+        return dh, dw, None
+
+    nll_sums.defvjp(vjp_fwd, vjp_bwd)
+    #: for tests: how many chunk bodies a forward ran
+    nll_sums.chunks_run = lambda *a: fwd_counted(*a)[1]
+    return nll_sums
+
+
+def head_compacts(ignore_index) -> bool:
+    """Whether ``lm_nll_sums_chunked`` called with this ``ignore_index``
+    orders the rows by label and computes the labelled ones alone
+    (round counter ``head.compact``)."""
+    return ignore_index is not None
+
+
+def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
+                        tokens_per_chunk=1024):
+    """Per-example (Σ nll, Σ valid) of the tied-head LM cross-entropy
+    without materialising the (E, T, V) logits tensor.
+
+    ``h`` (E, Tm, C) are the final hidden states at the *predicting*
+    positions (callers pass ``h[:, :-1]``), ``labels`` (E, Tm) the
+    shifted targets, ``tokens_per_chunk`` the rows of one chunk's
+    (rows, V) logits, the most that is live at a time: the backward
+    recomputes a chunk's logits instead of storing them. Same math as
+    ``token_nll`` of the full logits (fp summation order aside).
+
+    **What is computed and what is skipped.** A position whose label is
+    ``ignore_index`` adds 0 to Σ nll, 0 to Σ valid, a zero row to
+    ``h``'s gradient and nothing to the table's, so it never meets the
+    table: the rows are ordered labelled-first (one stable sort of the
+    E · Tm flags) and the chunk loops, forward and backward, run
+    ``ceil(labelled / rows)`` times, a count found at run time
+    (``_compact_nll``; PersonaChat labels the gold reply only, 1.3 % of
+    the positions). ``ignore_index=None`` says that every position
+    carries a label (the causal LMs' raw token ids): nothing is
+    ordered, gathered or written back, the chunks are straight slices
+    and their count is static (``_dense_nll_sums``).
+
+    Inside a ``vmap`` over clients that share the table and whose
+    losses are summed before they are differentiated, which its
+    builder says by naming the axis ``SHARED_CLIENTS``
+    (core/rounds.py ``make_local_loss``, core/rounds_sp.py), the
+    clients' rows fill common chunks: 8 clients of 55 labelled rows
+    are one chunk of 1,024, and the table's gradient comes out summed
+    over them, which is what that transformation asks for a shared
+    weight. Any other ``vmap`` runs one compaction an element: a
+    table a client, and per-client gradients of a shared table
+    (``vmap`` of ``grad``: core/rounds.py ``client_round``), where a
+    sum over the clients would be every client's wrong answer."""
+    if not head_compacts(ignore_index):
+        return _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk)
+    with jax.named_scope("lm_head"):
+        sv = jnp.sum(labels != ignore_index, axis=1, dtype=jnp.float32)
+        hd = h.astype(dtype)
+        # inside a shard_map the table's gradient is summed over the
+        # mesh axes the rows vary over and the table does not
+        vary = tuple(jax.typeof(hd).vma - jax.typeof(wte).vma)
+        if vary:
+            wte = jax.lax.pcast(wte, vary, to="varying")
+        sn = _compact_nll(jnp.dtype(dtype), int(ignore_index),
+                          int(tokens_per_chunk),
+                          _axis_bound(SHARED_CLIENTS))(hd, wte, labels)
     return sn, sv
 
 

@@ -233,6 +233,6 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
     final, head, stats = module.apply({"params": params}, input_ids)
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
-                                 module.cfg.dtype, ignore_index=-1,
+                                 module.cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
     return sn / jnp.maximum(sv, 1.0), stats

@@ -306,14 +306,14 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     cfg = module.cfg
     final, mtp, head, stats = module.apply({"params": params}, input_ids)
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
-                                 cfg.dtype, ignore_index=-1,
+                                 cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
     loss = sn / jnp.maximum(sv, 1.0)
     if mtp is not None:
         with jax.named_scope("mtp"):
             mn, mv = lm_nll_sums_chunked(
                 mtp[:, :-2], head, input_ids[:, 2:], cfg.dtype,
-                ignore_index=-1, tokens_per_chunk=tokens_per_chunk)
+                ignore_index=None, tokens_per_chunk=tokens_per_chunk)
         loss = loss + cfg.mtp_loss_weight * mn / jnp.maximum(mv, 1.0)
     expert_layers = (cfg.num_hidden_layers - cfg.first_k_dense_replace
                      + cfg.num_nextn_predict_layers)

@@ -264,7 +264,7 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     cfg = module.cfg
     final, head, stats, chunks = module.apply({"params": params}, input_ids)
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
-                                 cfg.dtype, ignore_index=-1,
+                                 cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
     mean = stats[0] / max(cfg.count("E") * cfg.n_held_experts, 1)
     return sn / jnp.maximum(sv, 1.0), (stats[0], stats[1], mean, stats[2],

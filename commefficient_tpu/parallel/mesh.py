@@ -29,6 +29,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 CLIENT_AXIS = "clients"
 MODEL_AXIS = "model"
+#: ``axis_name`` of a ``vmap`` (no mesh axis) over a round's clients
+#: where they share one set of weights and their losses are summed
+#: before they are differentiated (core/rounds.py ``make_local_loss``,
+#: core/rounds_sp.py): code under it may take every client's rows at
+#: once, and sum a shared weight's gradient over the clients itself,
+#: where that is less work than a client at a time (models/gpt2.py
+#: ``lm_nll_sums_chunked``). A ``vmap`` of per-client gradients
+#: (``client_round``) must not carry the name
+SHARED_CLIENTS = "shared_clients"
 
 
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:

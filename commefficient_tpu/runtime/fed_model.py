@@ -138,6 +138,10 @@ class FedModel:
         self.module = module
         self.args = args
         self.compute_loss_train = compute_loss
+        # static facts of the program the loss builds, counted on every
+        # round record (train/gpt2_train.py: ``head.compact``)
+        self._program_counters = dict(
+            getattr(compute_loss, "program_counters", None) or {})
         self.compute_loss_val = compute_loss_val or compute_loss
         # BatchNorm running-stats parity mode: ``stats_fn(params,
         # client_batch) -> stats_pytree`` records each participating
@@ -698,6 +702,12 @@ class FedModel:
                 placed = self.place_batch(batch)
         else:
             tel.count("h2d.staged")
+        # what the loader counted in this batch (the labelled positions
+        # of a language-model round) and what the loss says of the
+        # program it builds (``head.compact``): engagement, no metric
+        for name, n in {**staging.counters_of(batch),
+                        **self._program_counters}.items():
+            tel.count(name, n)
         dev_batch, ids = placed
 
         rng = jax.random.fold_in(self._rng, self.round_index)

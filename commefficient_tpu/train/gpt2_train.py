@@ -73,6 +73,21 @@ def causal_lm_file(model: str):
     return importlib.import_module(get_model(model).__module__)
 
 
+#: the persona loaders' label of a position that carries none
+#: (data/loader.py PersonaFedLoader)
+LM_IGNORE = -1
+
+
+def _head_counters(ignore_index) -> dict:
+    """``program_counters`` of a loss (runtime/fed_model.py counts them
+    on every round record): ``head.compact`` 1 where the round
+    program's vocabulary head is built ordering the rows by label
+    (static, as ``select.blocked``; 0 for a causal LM and under
+    ``--fused_ce``, whose kernels take every position)."""
+    from commefficient_tpu.models.gpt2 import head_compacts
+    return {"head.compact": int(head_compacts(ignore_index))}
+
+
 def _lm_nll_sums(module, params, batch, tokens_per_chunk=0,
                  fused=False, batch_mult=1):
     """Shared forward for the train and val losses: hidden states +
@@ -82,8 +97,15 @@ def _lm_nll_sums(module, params, batch, tokens_per_chunk=0,
     Pallas kernels (ops/flce_pallas.py, ``fused=True``) where even the
     per-chunk logits tiles stay in VMEM. Returns per-example
     ((B*N,) Σnll, (B*N,) Σvalid), mc_logits, B, N.
-    ``tokens_per_chunk`` 0 = auto (1024 — throughput-flat 512-4096
-    at the 8x geometry, BENCHMARKS.md)."""
+    ``tokens_per_chunk`` 0 = auto (1024 rows a chunk).
+
+    The blocks compute every position; the chunked head computes the
+    positions whose ``lm_labels`` is not ``LM_IGNORE`` and skips the
+    rest (PersonaChat labels the gold candidate's reply and ``<eos>``
+    only: of a client's B x N sequences B carry any label, on a few
+    positions each), the clients of a round sharing chunks
+    (``lm_nll_sums_chunked``). The fused kernels compute every
+    position."""
     from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
 
     ids = batch["input_ids"]
@@ -98,12 +120,14 @@ def _lm_nll_sums(module, params, batch, tokens_per_chunk=0,
         # the kernel's dX-partials OOM guard must see the vmapped
         # multiplicity — the buffer exists once PER CLIENT concurrently
         sn, sv = lm_nll_sums_fused(h[:, :-1], wte, labels[:, 1:],
-                                   module.cfg.dtype, ignore_index=-1,
+                                   module.cfg.dtype,
+                                   ignore_index=LM_IGNORE,
                                    tokens_per_chunk=tokens_per_chunk
                                    or 1024, batch_mult=batch_mult)
     else:
         sn, sv = lm_nll_sums_chunked(h[:, :-1], wte, labels[:, 1:],
-                                     module.cfg.dtype, ignore_index=-1,
+                                     module.cfg.dtype,
+                                     ignore_index=LM_IGNORE,
                                      tokens_per_chunk=tokens_per_chunk
                                      or 1024)
     return sn, sv, mc_logits, B, N
@@ -115,7 +139,7 @@ def _resolve_fused(args, module):
                             module.cfg.n_embd)
 
 
-def _token_nll(logits, labels, ignore_index=-1):
+def _token_nll(logits, labels, ignore_index=LM_IGNORE):
     """token_nll with the persona loaders' label padding default."""
     return token_nll(logits, labels, ignore_index)
 
@@ -138,6 +162,9 @@ def make_causal_loss(module, args, train=True):
         loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
         return loss, (tuple(stats) if train else (jnp.zeros(()),))
 
+    # every position of a packed stream carries a label: the files'
+    # ``causal_lm_loss`` say so to the head (``ignore_index=None``)
+    compute_loss.program_counters = _head_counters(None)
     return compute_loss
 
 
@@ -173,6 +200,8 @@ def make_compute_loss_train(module, args):
         loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
         return loss, ()
 
+    compute_loss.program_counters = _head_counters(
+        None if _resolve_fused(args, module) else LM_IGNORE)
     return compute_loss
 
 

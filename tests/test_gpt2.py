@@ -941,3 +941,269 @@ class TestSavePretrained:
         assert tok2.special == tok.special
         assert tok2.encode("low") == tok.encode("low")
         assert len(tok2) == len(tok)
+
+
+# --- the compacting vocabulary head (models/gpt2.py) -----------------------
+# lm_nll_sums_chunked with an ignore index computes the labelled rows
+# alone; everything below holds it to token_nll of the full logits.
+
+_HW, _HE, _HT, _HC, _HV, _HROWS = 3, 4, 15, 16, 67, 16
+
+
+def _head_labels(case):
+    """(W, E, Tm) labels of a case; -1 where a position carries none.
+    A client has E * Tm = 60 rows, a chunk 16."""
+    rng = np.random.RandomState(3)
+    full = rng.randint(0, _HV, (_HW, _HE * _HT)).astype(np.int32)
+    lab = np.full_like(full, -1)
+    per_client = {"sparse": 2, "all": _HE * _HT, "none": 0,
+                  "ragged": 7, "three_chunks": 40,
+                  "padding_example": 18}[case]
+    for c in range(_HW):
+        at = rng.choice(_HE * _HT, per_client, replace=False)
+        lab[c, at] = full[c, at]
+    lab = lab.reshape(_HW, _HE, _HT)
+    if case == "padding_example":
+        lab[:, 2] = -1
+    return jnp.asarray(lab)
+
+
+def _head_inputs():
+    rng = np.random.RandomState(5)
+    h = jnp.asarray(rng.randn(_HW, _HE, _HT, _HC), jnp.float32)
+    w = jnp.asarray(rng.randn(_HV, _HC) * 0.3, jnp.float32)
+    coef = jnp.asarray(rng.rand(_HW, _HE) + 0.5, jnp.float32)
+    return h, w, coef
+
+
+def _head_pair(dtype):
+    """(the head under test, token_nll of the full logits), both
+    ``(h (E, Tm, C), w, labels) -> (Σ nll, Σ valid)`` by example."""
+    from commefficient_tpu.models.gpt2 import (lm_nll_sums_chunked,
+                                               token_nll)
+
+    def head(h, w, lab):
+        return lm_nll_sums_chunked(h, w, lab, dtype, ignore_index=-1,
+                                   tokens_per_chunk=_HROWS)
+
+    def full(h, w, lab):
+        logits = jnp.einsum("etc,vc->etv", h.astype(dtype),
+                            w.astype(dtype),
+                            preferred_element_type=jnp.float32)
+        nll, valid = token_nll(logits, lab, -1)
+        return jnp.sum(nll * valid, -1), jnp.sum(valid, -1)
+
+    return head, full
+
+
+def _head_form(form, fn, lab, coef):
+    """``(h, w) -> (loss, (Σ nll, Σ valid))`` and the gradients'
+    arguments, for one way the head is called."""
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+
+    def one(h, w, lab, coef):
+        sn, sv = fn(h, w, lab)
+        return jnp.sum(sn * coef), (sn, sv)
+
+    if form == "unbatched":
+        return lambda h, w: one(h[0], w, lab[0], coef[0])
+    if form == "pooled":      # summed losses, the table shared
+        def loss(h, w):
+            ls, aux = jax.vmap(lambda h, l, c: one(h, w, l, c),
+                               axis_name=SHARED_CLIENTS)(h, lab, coef)
+            return jnp.sum(ls), aux
+        return loss
+    if form == "table_batched":   # a table a client: the fallback
+        def loss(h, w):
+            wb = w[None] * jnp.arange(1.0, 1.0 + _HW)[:, None, None]
+            ls, aux = jax.vmap(one, axis_name=SHARED_CLIENTS)(
+                h, wb, lab, coef)
+            return jnp.sum(ls), aux
+        return loss
+    assert form == "local_loss"   # core/rounds.py make_local_loss
+
+    def local_loss(h, w):
+        def one_client(h, l):
+            sn, sv = fn(h, w, l)
+            loss = jnp.sum(sn) / jnp.maximum(jnp.sum(sv), 1.0)
+            n = jnp.sum(sv)
+            return jnp.where(n > 0, loss * n, 0.0), (sn, sv)
+        weighted, aux = jax.vmap(one_client,
+                                 axis_name=SHARED_CLIENTS)(h, lab)
+        return jnp.sum(weighted) / 7.0, aux
+    return local_loss
+
+
+def _close(got, want, tol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = max(float(np.max(np.abs(want))), 1e-30)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol * scale)
+
+
+_HEAD_CASES = ("sparse", "all", "none", "ragged", "three_chunks",
+               "padding_example")
+_HEAD_FORMS = ("unbatched", "pooled", "table_batched", "local_loss")
+
+
+@pytest.mark.parametrize("case,form,dtype", [
+    (c, f, "float32") for c in _HEAD_CASES for f in _HEAD_FORMS] + [
+    (c, "pooled", "bfloat16") for c in _HEAD_CASES])
+def test_compacting_head_matches_full_logits(case, form, dtype):
+    """Per-example Σ nll and Σ valid, and the gradients w.r.t. the
+    hidden states and the table, against ``token_nll`` of the full
+    logits: few labels, all, none (zero chunks: zeros, no NaN), a count
+    that is no multiple of the chunk, one that needs three chunks, an
+    example with no label inside a batch; called alone, under the
+    clients' ``vmap`` with the table shared (its gradient the sum over
+    the clients) or a table a client, and through ``value_and_grad``
+    of the summed vmapped loss as ``make_local_loss`` builds it."""
+    dt = jnp.dtype(dtype)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    h, w, coef = _head_inputs()
+    lab = _head_labels(case)
+    head, full = _head_pair(dt)
+    (l1, (sn1, sv1)), g1 = jax.jit(jax.value_and_grad(
+        _head_form(form, head, lab, coef), (0, 1), has_aux=True))(h, w)
+    (l0, (sn0, sv0)), g0 = jax.value_and_grad(
+        _head_form(form, full, lab, coef), (0, 1), has_aux=True)(h, w)
+    np.testing.assert_array_equal(np.asarray(sv1), np.asarray(sv0))
+    _close(sn1, sn0, tol)
+    _close(l1, l0, tol)
+    for a, b in zip(g1, g0):
+        assert np.all(np.isfinite(np.asarray(a)))
+        _close(a, b, tol)
+    if case == "none":
+        assert float(jnp.sum(sv1)) == 0.0 and float(l1) == 0.0
+        assert not np.any(np.asarray(g1[0])) and not np.any(
+            np.asarray(g1[1]))
+
+
+def test_compacting_head_gives_each_client_its_own_table_gradient():
+    """``vmap`` of ``grad`` with a shared table (core/rounds.py
+    ``client_round``'s per-client path) asks for every client's own
+    gradient of the table: no pooling there, whatever the axis is
+    called in the fused round."""
+    h, w, coef = _head_inputs()
+    lab = _head_labels("ragged")
+    head, full = _head_pair(jnp.dtype("float32"))
+
+    def per_client(fn):
+        def one(h, l, c):
+            return jax.grad(lambda w: jnp.sum(fn(h, w, l)[0] * c))(w)
+        return jax.vmap(one)(h, lab, coef)
+
+    got, want = jax.jit(lambda: per_client(head))(), per_client(full)
+    assert got.shape == (_HW, _HV, _HC)
+    _close(got, want, 1e-5)
+
+
+@pytest.mark.parametrize("n", [0, 1, _HROWS, _HROWS + 1])
+def test_head_work_follows_the_labelled_count(n):
+    """The chunk bodies a forward runs: ceil(labelled / rows a chunk),
+    found at run time, not the positions' count fixed at trace time."""
+    from commefficient_tpu.models.gpt2 import _compact_nll
+    h, w, _ = _head_inputs()
+    lab = np.full((_HE * _HT,), -1, np.int32)
+    lab[np.random.RandomState(n).choice(lab.size, n, replace=False)] = 1
+    run = jax.jit(_compact_nll(jnp.dtype("float32"), -1, _HROWS,
+                               False).chunks_run)
+    got = int(run(h[0], w, jnp.asarray(lab.reshape(_HE, _HT))))
+    assert got == -(-n // _HROWS)
+
+
+def _ops_by_name(lowered):
+    """[(operation, its location: the name stack and the Python frames
+    it was traced under)] of a lowering, regions included."""
+    def walk(op):
+        yield op.name, str(op.location)
+        for region in op.regions:
+            for block in region.blocks:
+                for child in block.operations:
+                    yield from walk(child.operation)
+    return list(walk(lowered.compiler_ir("stablehlo").operation))
+
+
+def test_a_causal_callers_head_orders_gathers_and_writes_back_nothing():
+    """``ignore_index=None``: every position carries a label. No sort,
+    and no gather or scatter but the label's logit (``token_nll``'s
+    ``take_along_axis``) and its transpose; with an ignore index the
+    same call holds all three."""
+    from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
+    h, w, _ = _head_inputs()
+    lab = jnp.abs(_head_labels("all"))[0]
+
+    def lowered(ignore):
+        def loss(h, w):
+            sn, sv = lm_nll_sums_chunked(h, w, lab, jnp.float32,
+                                         ignore_index=ignore,
+                                         tokens_per_chunk=_HROWS)
+            return jnp.sum(sn / jnp.maximum(sv, 1.0))
+        return _ops_by_name(jax.jit(jax.value_and_grad(loss, (0, 1)))
+                            .lower(h[0], w))
+
+    def moved_rows(ops):
+        return [(op, name) for op, name in ops
+                if op in ("stablehlo.gather", "stablehlo.scatter",
+                          "stablehlo.sort")
+                and "token_nll" not in name]
+
+    causal, masked = lowered(None), lowered(-1)
+    assert any(op == "stablehlo.gather" for op, _ in causal)
+    assert moved_rows(causal) == []
+    assert {op for op, _ in moved_rows(masked)} == {
+        "stablehlo.gather", "stablehlo.scatter", "stablehlo.sort"}
+    sn_c, sv_c = lm_nll_sums_chunked(h[0], w, lab, jnp.float32,
+                                     ignore_index=None,
+                                     tokens_per_chunk=_HROWS)
+    sn_m, sv_m = lm_nll_sums_chunked(h[0], w, lab, jnp.float32,
+                                     ignore_index=-1,
+                                     tokens_per_chunk=_HROWS)
+    np.testing.assert_array_equal(np.asarray(sv_c), np.asarray(sv_m))
+    _close(sn_c, sn_m, 1e-5)
+
+
+def test_persona_batches_carry_their_labelled_count(tmp_path):
+    """``data.collate`` counts the round's positions and those with a
+    language-model label on the loader's thread; the counts ride along
+    with the batch (through a dropout's rebuilt mask too) for the
+    record of the round that consumes it."""
+    from commefficient_tpu.data import staging
+    from commefficient_tpu.data.fed_persona import (
+        generate_synthetic_personachat)
+    generate_synthetic_personachat(str(tmp_path))
+    batches = TestPersonaPrefetch()._stack(str(tmp_path), depth=3,
+                                           epochs=1)
+    assert len(batches) > 2
+    for b in batches:
+        labels = np.asarray(b["lm_labels"])
+        assert staging.counters_of(b) == {
+            "head.positions": labels.size,
+            "head.labelled": int((labels != -1).sum())}
+        assert 0 < staging.counters_of(b)["head.labelled"] < labels.size
+    assert staging.counters_of(dict(batches[0])) == {}
+
+
+@pytest.mark.parametrize("mode", ["sketch", "local_topk"])
+def test_round_records_carry_the_head_counters(tmp_path, mode):
+    """Every round record of a PersonaChat run: ``head.positions``,
+    ``head.labelled`` (its own batch's) and ``head.compact`` 1, in the
+    fused round (the clients pool their rows) and the per-client one."""
+    from commefficient_tpu.train import gpt2_train
+    flags = {"sketch": ["--error_type", "virtual", "--virtual_momentum",
+                        "0.9"],
+             "local_topk": ["--error_type", "local", "--k", "50"]}[mode]
+    results = gpt2_train.main([
+        "--test", "--dataset_name", "PERSONA", "--dataset_dir",
+        str(tmp_path), "--mode", mode, "--local_momentum", "0",
+        "--num_workers", "2", "--local_batch_size", "2", "--num_epochs",
+        "3", "--lr_scale", "0.01", "--num_devices", "1",
+        "--ledger", str(tmp_path / "ledger.jsonl"), *flags])
+    assert np.isfinite(results[0]["train_loss"])
+    with open(tmp_path / "ledger.jsonl") as f:
+        recs = [r["counters"] for r in map(json.loads, f)
+                if r.get("kind") == "round"]
+    assert len(recs) >= 2
+    for c in recs:
+        assert c["head.compact"] == 1
+        assert c["head.positions"] == 2 * 2 * 2 * gpt2_train.MAX_SEQ_LEN
+        assert 0 < c["head.labelled"] < c["head.positions"] // 8

@@ -341,6 +341,8 @@ def test_the_trainer_trains_it_through_fedmodel(tmp_path):
         # 4 clients x 2 sequences x 32 / 8 chunks x 9 Mamba-2 layers
         assert c["ssm.chunks"] == 4 * 2 * 4 * 9
         assert (c["attn.dense"], c["attn.blocked"]) == (1, 0)
+        # a packed stream labels every position: the head orders none
+        assert c["head.compact"] == 0 and "head.labelled" not in c
 
 
 @pytest.mark.parametrize("model,model_type", [

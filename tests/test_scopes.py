@@ -10,6 +10,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from commefficient_tpu.config import Config
@@ -130,7 +131,10 @@ def test_lm_head_scope_sits_inside_fwd_bwd():
             return jax.value_and_grad(loss)(p)
 
     text = jax.jit(step).lower(params).as_text(debug_info=True)
-    names = re.findall(r'loc\("([^"]*)"', text)
+    # the operations' names (a function's own location, inside the
+    # head's loops, starts at the scope it was traced in)
+    names = [n for n in re.findall(r'loc\("([^"]*)"', text)
+             if n.startswith("jit(step)")]
     head = [n for n in names if re.search(r"(?<![\w.])lm_head(?![\w.])", n)]
     assert head and all("fwd_bwd" in n for n in head)
     # forward and backward of the head are both named
@@ -141,6 +145,53 @@ def test_lm_head_scope_sits_inside_fwd_bwd():
         {"params": p}, ids, jnp.zeros((2, 2), jnp.int32), ids)[0]).lower(
         params).as_text(debug_info=True)
     assert re.search(r"(?<![\w.])lm_head(?![\w.])", logits)
+
+
+@pytest.mark.parametrize("form", ["alone", "pooled", "per_client"])
+def test_every_operation_of_the_compacting_head_is_under_lm_head(form):
+    """``round.head_ms`` reads the ``lm_head`` scope: the partition,
+    the gathers, both chunk loops with their products and the
+    write-back, forward and in the custom VJP's backward (which
+    inherits no ``transpose(jvp(lm_head))`` and opens the scope
+    itself), all carry it, inside ``fwd_bwd``."""
+    from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+    h = jnp.ones((2, 3, 7, 8))
+    w = jnp.ones((19, 8))
+    lab = jnp.asarray(np.where(np.arange(42).reshape(2, 3, 7) % 5, -1, 3))
+
+    def one(h, w, lab):
+        sn, sv = lm_nll_sums_chunked(h, w, lab, jnp.bfloat16,
+                                     ignore_index=-1, tokens_per_chunk=8)
+        return jnp.sum(sn) / jnp.maximum(jnp.sum(sv), 1.0)
+
+    def loss(h, w, lab):
+        if form == "alone":
+            return one(h[0], w, lab[0])
+        return jnp.sum(jax.vmap(
+            lambda h, l: one(h, w, l),
+            axis_name=SHARED_CLIENTS if form == "pooled" else None)(h, lab))
+
+    def step(h, w, lab):
+        with jax.named_scope("fwd_bwd"):
+            return jax.value_and_grad(loss, (0, 1))(h, w, lab)
+
+    # the compiled program's own metadata: what a trace's ``tf_op`` is
+    hlo = jax.jit(step).lower(h, w, lab).compile().as_text()
+    ops = [(m.group(1), n.group(1)) for m, n in (
+        (re.search(r"[\])}] (sort|gather|scatter|dot|while)\(", line),
+         re.search(r'op_name="([^"]*)"', line))
+        for line in hlo.splitlines()) if m and n]
+    held = re.compile(r"(?<![\w.])lm_head(?![\w.])").search
+    assert {op for op, _ in ops} == {"sort", "gather", "scatter", "dot",
+                                     "while"}
+    assert all(held(name) and "fwd_bwd" in name for _, name in ops), [
+        o for o in ops if not held(o[1])][:3]
+    # the logits' product, forward and again in the backward, and the
+    # backward's two: three of the four under a transposition
+    dots = [name for op, name in ops if op == "dot"]
+    assert len(dots) == 4
+    assert sum("transpose(" in name for name in dots) == 3
 
 
 # --- kernel names in the TPU lowering (tests/test_preflight_tpu.py's
