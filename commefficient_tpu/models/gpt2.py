@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.custom_batching import custom_vmap, sequential_vmap
 
 from commefficient_tpu.models import register_model
-from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+from commefficient_tpu.parallel.mesh import SHARED_CLIENTS, axis_bound
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,15 +263,6 @@ def _zeros(shape, dtype, like):
     the mesh axes the loop's results vary over (the loop carry-type
     check; cf. models/moe.py ``_zeros``)."""
     return jnp.zeros(shape, dtype) + (jnp.ravel(like)[0] * 0).astype(dtype)
-
-
-def _axis_bound(name) -> bool:
-    """Whether the trace is inside a ``vmap`` that named its axis so."""
-    try:
-        jax.lax.axis_size(name)
-    except NameError:
-        return False
-    return True
 
 
 def _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk):
@@ -529,7 +520,7 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
             wte = jax.lax.pcast(wte, vary, to="varying")
         sn = _compact_nll(jnp.dtype(dtype), int(ignore_index),
                           int(tokens_per_chunk),
-                          _axis_bound(SHARED_CLIENTS))(hd, wte, labels)
+                          axis_bound(SHARED_CLIENTS))(hd, wte, labels)
     return sn, sv
 
 

@@ -2,7 +2,9 @@
 hybrid language models share (``models/nemotron_h.py``,
 ``models/granite_hybrid.py``), as ``models/moe.py`` is the expert layer
 two models route with. One code, parametrised by what differs between
-the models: attention's score scale.
+the models: attention's score scale, and per layer whether q and k
+are rotated by their positions and whether a query sees a window of
+its past only.
 
     M:  [z | xBC | dt] = x W_in;  xBC = silu(conv4(xBC) + b)
         [x | B | C] = xBC;  delta = softplus(dt + dt_bias)
@@ -10,7 +12,10 @@ the models: attention's score scale.
         y_t = S_t C_t + D x_t;   A = -exp(A_log)
         out = W_out GroupRMSNorm(y * silu(z))
     *:  softmax_causal(scale q k^T) v, the query heads of a group
-        sharing its key/value head; no positional embedding
+        sharing its key/value head; positions only where a layer asks
+        for them (``rope_theta``: RoPE on q and k, the half-split
+        pairing, the whole head), and where it states a ``window`` a
+        query i sees the keys j <= i with i - j < window
 
 A configuration is any object with the fields the mixers read:
 ``mamba_num_heads``, ``mamba_head_dim``, ``n_groups``,
@@ -47,6 +52,13 @@ What is TPU-shaped here:
   rows are whole, so its softmax is the plain one, in float32, and
   nothing of the mathematics differs from the dense form; each block
   under ``jax.checkpoint``. Up to that size the dense form is built.
+- **A window layer's cost follows the window, not T.** Where a layer
+  states a window shorter than T a block of queries meets only the
+  keys its band can reach: a slice of static length (the window
+  rounded up to whole blocks, and the block itself) cut with
+  ``lax.dynamic_slice`` from keys padded in front, masked to the exact
+  band, the plain float32 softmax of the short rows (``attn_plan``).
+  A window of T or more is the plain causal layer and is built as one.
 
 Both forms are chosen from the shapes alone: no flag, no environment
 variable.
@@ -54,13 +66,16 @@ variable.
 Scopes (``PERF.md`` section 3): ``ssm_mixer`` (the whole Mamba-2
 mixer) > ``ssm_scan`` (decay sums, the in-chunk products, the state
 scan; conv, gate-norm and projections outside it); ``gqa_attn``
-(scores, softmax, value product; the four projections outside it).
+(scores, softmax, value product; the four projections outside it) and,
+where a model names its layers' kinds, ``attn_window`` / ``attn_full``
+inside it; ``rope`` (the rotation of q and k, outside ``gqa_attn``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -199,7 +214,94 @@ def attn_query_block(S, T, Hq):
     return int(max(1, ATTN_BLOCK_BYTES // (S * Hq * T * 4 * 128)) * 128)
 
 
-def gqa_attention(q, k, v, scale, query_block=None):
+class AttnPlan(NamedTuple):
+    """How ``gqa_attention`` builds a layer, from the shapes alone:
+    ``block`` queries at a time, each block against ``keys`` keys;
+    ``blocked`` unless every query meets every key at once (the dense
+    form); ``banded`` where a window cuts the keys a block meets.
+    ``pairs`` (query, key) scores a head of a sequence computes that
+    way and ``needed`` of them lie in the causal band."""
+    block: int
+    keys: int
+    blocked: bool
+    banded: bool
+    pairs: int
+    needed: int
+
+
+def attn_plan(S, T, Hq, window=None, query_block=None):
+    """The ``AttnPlan`` of (S, T, Hq) under ``window`` (None, or T or
+    more: the whole past). A banded block of ``b`` queries from
+    ``first`` meets the keys ``[first - back, first + b)`` with ``back``
+    = ``window - 1`` rounded up to whole blocks: ``b + back`` keys,
+    whatever ``first`` is."""
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} keys sees nothing")
+    if window is None or window >= T:
+        bq = int(query_block or attn_query_block(S, T, Hq))
+        blocked = bq < T
+        return AttnPlan(bq, T, blocked, False,
+                        -(-T // bq) * bq * T if blocked else T * T,
+                        T * (T + 1) // 2)
+    w = int(window)
+    if query_block:
+        bq = int(query_block)
+    else:       # a block's scores within ATTN_BLOCK_BYTES, as the full
+        # form's; no wider than the window: the slack is a block a block
+        bq = int(min(max(1, ATTN_BLOCK_BYTES // (S * Hq * (w + 128) * 4
+                                                 * 128)), -(-w // 128))
+                 * 128)
+    keys = bq + -(-(w - 1) // bq) * bq
+    return AttnPlan(bq, keys, True, True, -(-T // bq) * bq * keys,
+                    w * (w + 1) // 2 + (T - w) * w)
+
+
+def rope(x, theta):
+    """Rotary positions 0 .. T-1 on the last axis of ``x`` (S, T, ...,
+    D), float32 in and out: dimension i < D/2 is paired with i + D/2
+    (the ``rotate_half`` convention) and turned by t * theta^(-2i/D)."""
+    T, D = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    ang = ang.reshape((1, T) + (1,) * (x.ndim - 3) + (D // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :D // 2], x[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def _banded_attention(q, k, v, scale, plan, window):
+    """``gqa_attention`` of a window shorter than T."""
+    S, T, Hkv, g, D = q.shape
+    bq, L = plan.block, plan.keys
+    back = L - bq
+    nq = -(-T // bq)
+    tail = nq * bq - T
+    kp = jnp.pad(k, ((0, 0), (back, tail), (0, 0), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (back, tail), (0, 0), (0, 0)))
+
+    @jax.checkpoint
+    def band(qb, first):
+        """The queries at positions first, first + 1, ... against the
+        keys at first - back, ...: row ``first`` of the padded keys."""
+        kb = jax.lax.dynamic_slice_in_dim(kp, first, L, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(vp, first, L, axis=1)
+        att = jnp.einsum("stgqd,sugd->sgqtu", qb, kb,
+                         preferred_element_type=jnp.float32) * scale
+        i = (first + jnp.arange(bq))[:, None]
+        j = (first - back + jnp.arange(L))[None, :]
+        seen = (j >= 0) & (j <= i) & (i - j < window)
+        att = jax.nn.softmax(jnp.where(seen, att, -jnp.inf), axis=-1)
+        return jnp.einsum("sgqtu,sugd->stgqd", att.astype(qb.dtype), vb)
+
+    qp = jnp.pad(q, ((0, 0), (0, tail)) + ((0, 0),) * 3)
+    qp = jnp.moveaxis(qp.reshape(S, nq, bq, Hkv, g, D), 1, 0)
+    out = jax.lax.map(lambda a: band(*a),
+                      (qp, jnp.arange(nq, dtype=jnp.int32) * bq))
+    return jnp.moveaxis(out, 0, 1).reshape(S, nq * bq, Hkv, g, D)[:, :T]
+
+
+def gqa_attention(q, k, v, scale, query_block=None, window=None):
     """Causal softmax attention of grouped query heads, exact: ``q``
     (S, T, Hkv, Hq / Hkv, D), ``k`` / ``v`` (S, T, Hkv, D) ->
     ((S, T, Hkv, Hq / Hkv, D) in q's dtype, whether the blocked form
@@ -208,8 +310,14 @@ def gqa_attention(q, k, v, scale, query_block=None):
     ``attn_query_block`` of the shapes); with fewer than T the (heads,
     T, T) scores never exist: each block of queries meets every key,
     masks what lies ahead of it and takes the plain softmax of its
-    whole rows, under ``jax.checkpoint``."""
+    whole rows, under ``jax.checkpoint``. With a ``window`` shorter
+    than T, query i sees the keys j <= i with i - j < window, and a
+    block of queries meets only the slice of keys its band reaches
+    (``attn_plan``): the same softmax of shorter rows."""
     S, T, Hkv, g, D = q.shape
+    if window is not None and window < T:
+        plan = attn_plan(S, T, Hkv * g, window, query_block)
+        return _banded_attention(q, k, v, scale, plan, int(window)), True
     bq = int(query_block or attn_query_block(S, T, Hkv * g))
 
     if bq >= T:                     # the dense form, as it always was
@@ -317,9 +425,17 @@ class Mamba2Mixer(Weights):
 class GQAttention(Weights):
     """``scale`` multiplies the scores: ``head_dim ** -0.5`` where the
     model states none. With ``with_form`` the result is ``(y, whether
-    the blocked form was built)``, for a model that counts it."""
+    the blocked form was built)``, for a model that counts it. A layer
+    with ``rope_theta`` rotates q and k by their positions 0 .. T-1
+    (float32, scope ``rope``); one with a ``window`` sees that many
+    keys, itself among them; ``kind_scope`` names the layer's kind
+    inside ``gqa_attn``; ``query_block`` as ``gqa_attention``'s."""
     scale: Optional[float] = None
     with_form: bool = False
+    window: Optional[int] = None
+    rope_theta: Optional[float] = None
+    kind_scope: Optional[str] = None
+    query_block: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -332,8 +448,15 @@ class GQAttention(Weights):
         q = (x @ wq.astype(dt)).reshape(S, T, Hkv, Hq // Hkv, D)
         k = (x @ wk.astype(dt)).reshape(S, T, Hkv, D)
         v = (x @ wv.astype(dt)).reshape(S, T, Hkv, D)
-        with jax.named_scope("gqa_attn"):
+        if self.rope_theta is not None:
+            with jax.named_scope("rope"):
+                q = rope(q.astype(jnp.float32), self.rope_theta).astype(dt)
+                k = rope(k.astype(jnp.float32), self.rope_theta).astype(dt)
+        kind = contextlib.nullcontext() if self.kind_scope is None \
+            else jax.named_scope(self.kind_scope)
+        with jax.named_scope("gqa_attn"), kind:
             out, blocked = gqa_attention(q, k, v, float(
-                D ** -0.5 if self.scale is None else self.scale))
+                D ** -0.5 if self.scale is None else self.scale),
+                self.query_block, self.window)
         out = out.reshape(S, T, Hq * D) @ wo.astype(dt)
         return (out, blocked) if self.with_form else out

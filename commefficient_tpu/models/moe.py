@@ -1,11 +1,14 @@
 """The expert-parallel layer on one chip, for every model that has one:
-sigmoid routing over all the router's experts, the dispatch order of
-the assignments that land on the experts held here, their ragged
-products, and the combine under the gates.
+routing over all the router's experts (sigmoid scores, or the softmax
+of the chosen logits), the dispatch order of the assignments that land
+on the experts held here, their ragged products, and the combine under
+the gates.
 
 Shared by ``models/joyai.py`` (gated SiLU experts on the hidden state,
-8 picks of 256) and ``models/nemotron_h.py`` (squared-ReLU experts in a
-latent, 22 picks of 512). What is TPU-shaped:
+8 sigmoid picks of 256), ``models/nemotron_h.py`` (squared-ReLU experts
+in a latent, 22 sigmoid picks of 512) and ``models/smallthinker.py``
+(gated ReLU experts, 6 softmax picks of 64, the router read before
+attention). What is TPU-shaped:
 
 - The layer is told which experts it holds (``E`` of them from
   ``expert_offset``), routes over all the router's outputs in float32,
@@ -24,11 +27,15 @@ latent, 22 picks of 512). What is TPU-shaped:
   batched one would copy the experts per client: ``routed_experts``
   carries its own VJP and runs once per client
   (``jax.custom_batching``), which is also what lets its loop have a
-  length of its own per client; the weights stay shared, and their
-  gradient is summed over the clients inside the backward's own loop
-  (a stack of per-client expert gradients, W x 88 MB a weight at
-  Nemotron-3-Super's widths, would outlive its layer: PERF.md section
-  6, PR 32).
+  length of its own per client; the weights stay shared. Where the
+  ``vmap`` says that its clients' losses are summed before they are
+  differentiated (``parallel/mesh.py SHARED_CLIENTS``: the fused
+  round) their gradient is summed over the clients inside the
+  backward's own loop (a stack of per-client expert gradients, W x 88
+  MB a weight at Nemotron-3-Super's widths, would outlive its layer:
+  PERF.md section 6, PR 32); under any other ``vmap`` (per-client
+  gradients of shared weights, ``core/rounds.py client_round``) each
+  client gets its own.
 
 Scopes (``PERF.md`` section 3): ``moe_route`` (scores, top-k, dispatch
 order and the gathers), ``moe_experts`` (the ragged products),
@@ -43,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import custom_vmap, sequential_vmap
+
+from commefficient_tpu.parallel.mesh import SHARED_CLIENTS, axis_bound
 
 #: a client's routing counts, which a causal LM's loss returns beside
 #: the loss and ``train/gpt2_train.py`` turns into the round's ``moe.*``
@@ -137,10 +146,33 @@ def _relu2_back(xg, saved, h, do, valid, sizes, w1, w2):
     return dxg, (_ragged_outer(xg, da, sizes), _ragged_outer(h, do, sizes))
 
 
+def _reglu_ffn(xg, valid, sizes, wg, wu, wd):
+    a = jnp.where(valid, _ragged(xg, wg, sizes), 0.0)
+    b = jnp.where(valid, _ragged(xg, wu, sizes), 0.0)
+    h = (jax.nn.relu(a) * b).astype(xg.dtype)
+    return (a, b), h, jnp.where(valid, _ragged(h, wd, sizes), 0.0)
+
+
+def _reglu_back(xg, saved, h, do, valid, sizes, wg, wu, wd):
+    a, b = saved
+    dt = xg.dtype
+    dh = jnp.where(
+        valid, _ragged(do, jnp.swapaxes(wd, 1, 2), sizes), 0.0)
+    da = jnp.where(a > 0.0, dh * b, 0.0).astype(dt)
+    db = (dh * jax.nn.relu(a)).astype(dt)
+    dxg = jnp.where(
+        valid, _ragged(da, jnp.swapaxes(wg, 1, 2), sizes)
+        + _ragged(db, jnp.swapaxes(wu, 1, 2), sizes), 0.0)
+    return dxg, (_ragged_outer(xg, da, sizes), _ragged_outer(xg, db, sizes),
+                 _ragged_outer(h, do, sizes))
+
+
 #: expert form -> (forward, backward); "swiglu": (gate, up, down) with
-#: down(silu(gate x) * up x); "relu2": (w1, w2) with w2 relu(w1 x)^2
+#: down(silu(gate x) * up x); "relu2": (w1, w2) with w2 relu(w1 x)^2;
+#: "reglu": (gate, up, down) with down(relu(gate x) * up x)
 FORMS = {"swiglu": (_swiglu_ffn, _swiglu_back),
-         "relu2": (_relu2_ffn, _relu2_back)}
+         "relu2": (_relu2_ffn, _relu2_back),
+         "reglu": (_reglu_ffn, _reglu_back)}
 
 
 def _zeros(shape, load):
@@ -159,9 +191,12 @@ def passes(load, N):
 
 
 @functools.lru_cache(maxsize=None)
-def _routed(form):
+def _routed(form, pooled):
     """``routed_experts`` of one expert form: the forward and backward
-    pass loops, each run once per client, under one custom VJP."""
+    pass loops, each run once per client, under one custom VJP.
+    ``pooled``: a ``vmap`` over it sums the clients' losses before it
+    differentiates them, so the shared weights' gradient is summed over
+    the clients as they are taken; else each client's is its own."""
     ffn, back = FORMS[form]
 
     @sequential_vmap
@@ -207,13 +242,16 @@ def _routed(form):
             (_zeros(x.shape, load), _zeros(gate.shape, load), *dw))
         return (dx.astype(dt), dgate, *dw)
 
-    @custom_vmap
-    def bwd(x, token, gate, load, *rest):
+    def bwd_alone(x, token, gate, load, *rest):
         *w, dy = rest
         return bwd_one(x, token, gate, load, w, dy,
                        [_zeros(a.shape, load) for a in w])
 
-    @bwd.def_vmap
+    # not pooled: a client at a time, every result batched, the weight
+    # gradients among them (W x the experts, which only a path that
+    # wants per-client gradients pays)
+    bwd = custom_vmap(bwd_alone) if pooled else sequential_vmap(bwd_alone)
+
     def bwd_over_clients(axis_size, in_batched, x, token, gate, load,
                          *rest):
         """The clients one after the other, as ``sequential_vmap`` would
@@ -240,6 +278,9 @@ def _routed(form):
             tuple(per_client))
         return (dx, dgate, *dw), (True, True) + (False,) * len(w)
 
+    if pooled:
+        bwd.def_vmap(bwd_over_clients)
+
     @jax.custom_vjp
     def routed(x, token, gate, load, *w):
         return fwd(x, token, gate, load, *w)
@@ -263,7 +304,8 @@ def routed_experts(x, token, gate, load, weights, form="swiglu"):
     to held experts first and sorted by expert; ``load`` (E,): how many
     each held expert has; ``weights``: the held experts' float32 stacks
     in the order their ``form`` of ``FORMS`` takes them ("swiglu":
-    (E, C, F), (E, C, F), (E, F, C); "relu2": (E, C, F), (E, F, C)).
+    (E, C, F), (E, C, F), (E, F, C); "reglu": the same three; "relu2":
+    (E, C, F), (E, F, C)).
     The assignments are taken N rows a pass, as many passes as the load
     needs (one, unless the average token picks more than one expert
     held here), each pass the form's ragged products: every assignment
@@ -271,21 +313,39 @@ def routed_experts(x, token, gate, load, weights, form="swiglu"):
     Carries its own VJP (no reverse mode runs through a loop of dynamic
     length) and recomputes the pass's activations there. Under ``vmap``
     it runs once per batch element with the weights shared (they may
-    not be batched), and their gradient is summed over the batch in
-    float32 as the elements are taken."""
-    return _routed(form)(x, token, gate, load, *weights)
+    not be batched); inside a ``vmap`` named ``SHARED_CLIENTS`` (the
+    losses are summed before they are differentiated) their gradient is
+    summed over the batch in float32 as the elements are taken, inside
+    any other each element has its own."""
+    return _routed(form, axis_bound(SHARED_CLIENTS))(
+        x, token, gate, load, *weights)
 
 
 # --- routing and dispatch ---------------------------------------------------
 
-def route(x, router, bias, k, scaling, norm_topk_prob=True):
+def route(x, router, bias, k, scaling, norm_topk_prob=True,
+          scoring="sigmoid"):
     """(N, C) tokens -> ((N, k) expert ids among all the router's
-    outputs, (N, k) gates): sigmoid scores in float32, the k largest of
-    score + bias (the bias takes no gradient), the chosen scores
-    normalised to ``scaling``."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router,
-        precision=jax.lax.Precision.HIGHEST))
+    outputs, (N, k) gates), float32. ``scoring`` "sigmoid": sigmoid
+    scores, the k largest of score + bias (the bias takes no gradient),
+    the chosen scores normalised to ``scaling``. "softmax": the k
+    largest logits (no bias), the gates ``scaling`` x the softmax over
+    the chosen logits, which is the softmax over all renormalised over
+    the chosen (without ``norm_topk_prob``: not renormalised)."""
+    r = jnp.dot(x.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        if bias is not None:
+            raise ValueError("softmax routing takes no bias")
+        chosen, top = jax.lax.top_k(r, k)
+        if norm_topk_prob:
+            g = jax.nn.softmax(chosen, -1)
+        else:
+            g = jnp.take_along_axis(jax.nn.softmax(r, -1), top, axis=-1)
+        return top, scaling * g
+    if scoring != "sigmoid":
+        raise ValueError(f"no router scoring {scoring!r}")
+    s = jax.nn.sigmoid(r)
     _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
     sel = jnp.take_along_axis(s, top, axis=-1)
     if norm_topk_prob:

@@ -40,6 +40,15 @@ MODEL_AXIS = "model"
 SHARED_CLIENTS = "shared_clients"
 
 
+def axis_bound(name) -> bool:
+    """Whether the trace is inside a ``vmap`` that named its axis so."""
+    try:
+        jax.lax.axis_size(name)
+    except NameError:
+        return False
+    return True
+
+
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     devices = list(devices) if devices is not None else jax.devices()
     return Mesh(np.array(devices), (CLIENT_AXIS,))

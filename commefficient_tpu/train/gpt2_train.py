@@ -49,7 +49,8 @@ MAX_SEQ_LEN = 256  # static pad length (persona sequences are short)
 #: ``model_type`` and ``config_class``, ``causal_lm_loss`` and
 #: ``COUNTERS``); any other value (the shared parser's default is a CV
 #: model) means GPT2DoubleHeads, as before the flag was read here
-CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM", "GraniteHybridLM")
+CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM", "GraniteHybridLM",
+              "SmallThinkerLM")
 
 
 def is_causal_lm(args) -> bool:

@@ -5,6 +5,7 @@ clients ``vmap``, at a cell's shapes?
 
     python3 scripts/mixer_probe.py [--clients 4] [--seq 2048] [--reps 5]
         [--attn 32,8,64] [--ssd 64,1,64,128,256] [--rehearse]
+        [--window 4096] [--rope_theta 1.5e6]
 
 Attention (``--attn`` query heads, key/value heads, head size): the
 dense form (the float32 (heads, T, T) scores written out), the blocked
@@ -19,6 +20,16 @@ clients of the ``jax.checkpoint``-ed form on bf16 operands, compiled
 temporaries pass the chip is reported and skipped), run once, then
 timed over ``--reps`` calls. Every form's gradient is compared with
 the first's that ran. PR 34's step 1 (PERF.md section 6).
+
+With ``--window`` one attention layer is timed alone (no scan): the
+whole-row forms above, which are what a window layer costs when it is
+built as a full layer with a mask, then the band form at 128 / 256 /
+512 queries a block, each block against the slice of keys its band
+reaches (``gqa_attention(window=...)``; their gradients are compared
+among themselves: the mask is another). ``--rope_theta`` rotates q and
+k inside every timed form, as a layer with positions does. PR 41's
+probe: ``--clients 2 --seq 8192 --attn 28,4,128 --window 4096
+--rope_theta 1.5e6``.
 """
 
 import argparse
@@ -39,6 +50,10 @@ def main(argv=None):
     ap.add_argument("--attn", default="32,8,64")
     ap.add_argument("--ssd", default="64,1,64,128,256")
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--window", type=int, default=None,
+                    help="also the band form of a window layer; no scan")
+    ap.add_argument("--rope_theta", type=float, default=None,
+                    help="rotate q and k by their positions first")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on whatever backend there is")
     a = ap.parse_args(argv)
@@ -51,8 +66,10 @@ def main(argv=None):
     W, T = a.clients, a.seq
     Hq, Hkv, D = map(int, a.attn.split(","))
     H, G, P, N, Q = map(int, a.ssd.split(","))
+    window = a.window
     if a.rehearse:
         T, Hq, Hkv, D, H, P, N, Q = 256, 4, 2, 8, 8, 8, 8, 16
+        window = window and 96
     dt = jnp.bfloat16
     tpu = jax.devices()[0].platform == "tpu"
     limit = 15.5e9 if tpu else float("inf")
@@ -100,9 +117,16 @@ def main(argv=None):
     print(json.dumps({"attention": {"clients": W, "T": T, "Hq": Hq,
                                     "Hkv": Hkv, "D": D, "scale": scale}}))
 
-    def attn_loss(block):
+    def turned(x):
+        if a.rope_theta is None:
+            return x
+        from commefficient_tpu.models.mixers import rope
+        return rope(x.astype(jnp.float32), a.rope_theta).astype(dt)
+
+    def attn_loss(block, window=None):
         one = jax.checkpoint(lambda q, k, v: gqa_attention(
-            q, k, v, scale, query_block=block)[0])
+            turned(q), turned(k), v, scale, query_block=block,
+            window=window)[0])
         return lambda q, k, v: jnp.sum(jnp.sin(
             jax.vmap(one)(q, k, v).astype(jnp.float32)))
 
@@ -132,9 +156,22 @@ def main(argv=None):
         if block <= T:
             first = measure("attn." + name, attn_loss(block), (q, kk, v),
                             first)
-    if tpu:
+    if tpu and a.rope_theta is None:
         measure("attn.flash_pallas_kv_repeated", flash_loss, (q, kk, v),
                 first)
+    if window is not None:
+        from commefficient_tpu.models.mixers import attn_plan
+        first = None
+        for block in (128, 256, 512):
+            if block <= T:
+                plan = attn_plan(1, T, Hq, window, block)
+                print(json.dumps({"band": {"window": window, "block": block,
+                                           "keys": plan.keys,
+                                           "pairs_over_needed":
+                                           plan.pairs / plan.needed}}))
+                first = measure(f"attn.band_{block}",
+                                attn_loss(block, window), (q, kk, v), first)
+        return 0
 
     # --- the scan -----------------------------------------------------------
     k = jax.random.split(jax.random.PRNGKey(1), 5)

@@ -18,9 +18,9 @@ import pytest
 from commefficient_tpu.models import mixers
 from commefficient_tpu.models.granite_hybrid import GraniteHybridConfig
 from commefficient_tpu.models.mixers import (GQAttention, Mamba2Mixer,
-                                             attn_query_block,
-                                             gqa_attention, ssd_chunked,
-                                             ssd_head_block)
+                                             attn_plan, attn_query_block,
+                                             gqa_attention, rope,
+                                             ssd_chunked, ssd_head_block)
 from commefficient_tpu.models.nemotron_h import NemotronHConfig
 from test_nemotron_h import _close, _load   # the helpers, not the cases
 
@@ -172,6 +172,158 @@ def test_blocked_attention_is_the_dense_form(twin, T, bq):
     np.testing.assert_allclose(want, plain, rtol=2e-5, atol=2e-6)
 
 
+def _dense_masked(q, k, v, scale, window):
+    """Every query against every key, the band by a (T, T) mask."""
+    T = q.shape[1]
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    seen = (j <= i) & (i - j < window)
+    att = jnp.einsum("stgqd,sugd->sgqtu", q, k) * scale
+    att = jax.nn.softmax(jnp.where(seen, att, -jnp.inf), axis=-1)
+    return jnp.einsum("sgqtu,sugd->stgqd", att, v)
+
+
+BANDS = {"window-of-blocks": (32, 16, 8), "window-no-multiple": (37, 12, 8),
+         "T-no-multiple": (21, 8, 8), "window-under-block": (40, 5, 16),
+         "window-1": (19, 1, 8), "one-padded-block": (20, 6, 128),
+         "block-of-1": (9, 4, 1)}
+
+
+@pytest.mark.parametrize("case", sorted(BANDS))
+def test_the_band_form_is_the_dense_masked_form(case):
+    """Values and gradients over (T, window, block): a window that is a
+    multiple of the block and one that is none, T that is none, a
+    window inside one block, a window of 1 (every query sees itself
+    alone: the output is v); under the clients ``vmap`` and
+    ``jax.checkpoint``, grouped key/value heads."""
+    T, window, bq = BANDS[case]
+    W, S, Hkv, g, D, scale = 2, 2, 2, 3, 8, 0.41
+    k = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(k[0], (W, S, T, Hkv, g, D))
+    kk = jax.random.normal(k[1], (W, S, T, Hkv, D))
+    v = jax.random.normal(k[2], (W, S, T, Hkv, D))
+
+    def loss(fn):
+        fn = jax.checkpoint(fn)
+        return lambda q, k, v: jnp.sum(jnp.sin(jax.vmap(fn)(q, k, v)))
+
+    def band(q, k, v):
+        return gqa_attention(q, k, v, scale, query_block=bq,
+                             window=window)[0]
+
+    def dense(q, k, v):
+        return _dense_masked(q, k, v, scale, window)
+
+    with HIGHEST:
+        got, blocked = jax.vmap(lambda *a: gqa_attention(
+            *a, scale, query_block=bq, window=window),
+            out_axes=(0, None))(q, kk, v)
+        want = jax.vmap(dense)(q, kk, v)
+        gp = jax.jit(jax.grad(loss(band), argnums=(0, 1, 2)))(q, kk, v)
+        gr = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(q, kk, v)
+    assert blocked is True and got.shape == q.shape
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    _close(gp, gr, rel=2e-5, leaf=2e-4)
+    if window == 1:
+        np.testing.assert_allclose(
+            got, jnp.broadcast_to(v[:, :, :, :, None], got.shape),
+            rtol=1e-6, atol=1e-6)
+    plan = attn_plan(S, T, Hkv * g, window, bq)
+    assert plan.banded and plan.keys <= window + 2 * bq
+    assert plan.needed == sum(min(i + 1, window) for i in range(T))
+    assert plan.pairs == -(-T // bq) * bq * plan.keys >= plan.needed
+
+
+@pytest.mark.parametrize("window", [20, 33], ids=["window=T", "window>T"])
+@pytest.mark.parametrize("bq", [8, 20], ids=["blocked", "dense"])
+def test_a_window_of_T_or_more_is_plain_causal_attention(window, bq):
+    """Bit for bit: the same code path is taken."""
+    T = 20
+    k = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(k[0], (2, T, 2, 2, 8))
+    kk = jax.random.normal(k[1], (2, T, 2, 8))
+    v = jax.random.normal(k[2], (2, T, 2, 8))
+    got, blocked = gqa_attention(q, kk, v, 0.3, query_block=bq,
+                                 window=window)
+    want, same = gqa_attention(q, kk, v, 0.3, query_block=bq)
+    assert blocked is same is (bq < T)
+    np.testing.assert_array_equal(got, want)
+    assert attn_plan(2, T, 4, window, bq) == attn_plan(2, T, 4, None, bq)
+    with pytest.raises(ValueError, match="window"):
+        gqa_attention(q, kk, v, 0.3, window=0)
+
+
+def test_rope_is_the_complex_rotation():
+    """Dimension i < D/2 and i + D/2 as one complex number, multiplied
+    by exp(i t theta^(-2i/D)): the textbook rotation; norms are kept
+    and position 0 is left as it is."""
+    S, T, H, D, theta = 2, 11, 3, 16, 1.5e6
+    x = jax.random.normal(jax.random.PRNGKey(13), (S, T, H, D))
+    z = np.asarray(x[..., :D // 2]) + 1j * np.asarray(x[..., D // 2:])
+    freq = theta ** (-np.arange(0, D, 2) / D)
+    turned = z * np.exp(1j * np.arange(T)[None, :, None, None] * freq)
+    got = rope(x, theta)
+    np.testing.assert_allclose(got[..., :D // 2], turned.real, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(got[..., D // 2:], turned.imag, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    np.testing.assert_array_equal(got[:, 0], x[:, 0])
+    # the grouped query layout (S, T, Hkv, g, D) turns the same way
+    np.testing.assert_allclose(
+        rope(x.reshape(S, T, 1, H, D), theta).reshape(x.shape), got,
+        rtol=1e-6, atol=1e-7)
+
+
+def _shifted_scores(q, k, theta, shift):
+    """q_i . k_j with both rotated at positions shifted by ``shift``."""
+    pad = ((0, 0), (shift, 0), (0, 0), (0, 0))
+    qr = rope(jnp.pad(q, pad), theta)[:, shift:]
+    kr = rope(jnp.pad(k, pad), theta)[:, shift:]
+    return jnp.einsum("sthd,suhd->shtu", qr, kr)
+
+
+def test_rope_scores_depend_on_the_distance_alone():
+    """A RoPE layer's scores q_i . k_j are those of i - j: the same at
+    positions 0 .. T-1 and 5 .. T+4; and they are not the unrotated
+    ones."""
+    k = jax.random.split(jax.random.PRNGKey(14), 2)
+    q = jax.random.normal(k[0], (1, 12, 2, 16))
+    kk = jax.random.normal(k[1], (1, 12, 2, 16))
+    with HIGHEST:
+        here = _shifted_scores(q, kk, 100.0, 0)
+        there = _shifted_scores(q, kk, 100.0, 5)
+        plain = jnp.einsum("sthd,suhd->shtu", q, kk)
+    np.testing.assert_allclose(here, there, rtol=2e-4, atol=2e-4)
+    assert float(jnp.abs(here - plain).max()) > 0.1
+
+
+@pytest.mark.parametrize("theta", [None, 100.0], ids=["nope", "rope"])
+def test_a_layers_output_is_reached_through_the_distance_alone(theta):
+    """With no positions or with RoPE, a token's output depends on the
+    tokens its window shows and on how far back each lies, not on where
+    the sequence starts: the last 9 positions of 17, whose window of 4
+    hides the first 5 tokens, read what the same tokens give in a
+    sequence that starts 5 later. And only a layer with positions
+    differs from the plain one."""
+    cfg = dataclasses.replace(NemotronHConfig.tiny(), num_attention_heads=4,
+                              num_key_value_heads=2)
+    layer = GQAttention(cfg, rope_theta=theta, window=4, query_block=4)
+    x = jax.random.normal(jax.random.PRNGKey(15), (1, 17, cfg.hidden_size))
+    params = layer.init(jax.random.PRNGKey(16), x)["params"]
+    with HIGHEST:
+        whole = layer.apply({"params": params}, x)
+        late = layer.apply({"params": params}, x[:, 5:])
+    # position 8 of the whole sees tokens 5 .. 8, which the late start
+    # holds at positions 0 .. 3
+    np.testing.assert_allclose(whole[:, 8:], late[:, 3:], rtol=2e-4,
+                               atol=2e-5)
+    # without a window the layer with positions is not the plain one
+    full = GQAttention(cfg, rope_theta=theta).apply({"params": params}, x)
+    plain = GQAttention(cfg).apply({"params": params}, x)
+    assert (float(jnp.abs(full - plain).max()) > 1e-4) is (theta is not None)
+
+
 def test_the_forms_are_chosen_from_the_shapes():
     """The two cells' shapes, a client at a time as the rounds' vmap
     hands them over: Nemotron's 16 heads x chunks of 128 and 4 query
@@ -189,6 +341,21 @@ def test_the_forms_are_chosen_from_the_shapes():
     for S, T, H, G, Q in [(1, 2048, 64, 1, 256), (2, 4096, 128, 8, 128)]:
         hb = ssd_head_block(S, T, H, G, Q)
         assert S * T * G * hb * Q * 4 <= mixers.SSD_DECAY_BYTES
+    # SmallThinker's cell: one client's 8,192-token sequence, 28 heads:
+    # the full layer 128 queries against 8,192 keys, a window layer 128
+    # against 4,096 + 128; 1.57 scores computed for each one needed
+    full, band = attn_plan(1, 8192, 28), attn_plan(1, 8192, 28, 4096)
+    assert (full.block, full.keys, full.blocked, full.banded) == (
+        128, 8192, True, False)
+    assert (band.block, band.keys, band.blocked, band.banded) == (
+        128, 4224, True, True)
+    assert band.keys <= 4096 + 2 * band.block
+    assert full.pairs == 8192 ** 2 and band.pairs == 8192 * 4224
+    ratio = (full.pairs + 3 * band.pairs) / (full.needed + 3 * band.needed)
+    assert 1.5 < ratio < 1.6
+    # a short window takes wider blocks, never wider than the window
+    assert attn_plan(1, 8192, 4, 256).block == 256
+    assert attn_plan(1, 2048, 4, 4096) == attn_plan(1, 2048, 4)
 
 
 def _whiles(fn, *shapes):

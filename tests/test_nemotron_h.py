@@ -283,8 +283,9 @@ def _dense_experts(form, x, top, g, offset, w):
     held = offset + jnp.arange(E)
     gate = jnp.sum(jnp.where(top[:, :, None] == held[None, None, :],
                              g[:, :, None], 0.0), axis=1)
-    if form == "swiglu":
-        h = jax.nn.silu(jnp.einsum("nc,ecf->enf", x, w[0])) \
+    if form in ("swiglu", "reglu"):
+        act = jax.nn.silu if form == "swiglu" else jax.nn.relu
+        h = act(jnp.einsum("nc,ecf->enf", x, w[0])) \
             * jnp.einsum("nc,ecf->enf", x, w[1])
     else:
         h = jnp.square(jax.nn.relu(jnp.einsum("nc,ecf->enf", x, w[0])))
@@ -339,6 +340,130 @@ def test_routed_experts_serves_both_expert_forms(form, k, R, E):
     assert float(stats[2]) == 0.0 and float(stats[0]) > 0
     if R == E:
         assert float(stats[0]) == N * k > N   # more than one buffer
+
+
+# --- the expert layer's shared code: softmax routing, the gated-ReLU form,
+# --- and whose gradient a vmap gets (models/smallthinker.py's additions) -----
+
+def test_softmax_routing_is_the_softmax_over_all_renormalised():
+    """``route(scoring="softmax")``: the k largest logits, their gates
+    the softmax over the chosen, which is the softmax over all the
+    router's outputs renormalised over the chosen; without
+    ``norm_topk_prob`` it is not renormalised; ``scaling`` multiplies;
+    it takes no bias; and the sigmoid path is the one it was."""
+    N, C, R, k = 40, 16, 64, 6
+    key = jax.random.split(jax.random.PRNGKey(21), 3)
+    x = jax.random.normal(key[0], (N, C))
+    router = jax.random.normal(key[1], (C, R))
+    with HIGHEST:
+        top, g = moe.route(x, router, None, k, 1.0, scoring="softmax")
+        logits = x @ router
+    p = jax.nn.softmax(logits, axis=-1)
+    want_top = jnp.argsort(-logits, axis=-1)[:, :k]
+    np.testing.assert_array_equal(top, want_top)
+    chosen = jnp.take_along_axis(p, top, axis=-1)
+    np.testing.assert_allclose(
+        g, chosen / jnp.sum(chosen, -1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(jnp.sum(g, -1), 1.0, rtol=1e-6)
+    with HIGHEST:
+        _, raw = moe.route(x, router, None, k, 2.0, norm_topk_prob=False,
+                           scoring="softmax")
+        stop, sg = moe.route(x, router, jnp.zeros((R,)), k, 2.5)
+    np.testing.assert_allclose(raw, 2.0 * chosen, rtol=1e-5)
+    with pytest.raises(ValueError, match="no bias"):
+        moe.route(x, router, jnp.zeros((R,)), k, 1.0, scoring="softmax")
+    sig = jnp.take_along_axis(jax.nn.sigmoid(logits), stop, axis=-1)
+    np.testing.assert_allclose(
+        sg, 2.5 * sig / jnp.sum(sig, -1, keepdims=True), rtol=1e-5)
+    with pytest.raises(ValueError, match="scoring"):
+        moe.route(x, router, None, k, 1.0, scoring="tanh")
+
+
+def _expert_case(form, W=None, seed=23):
+    """(x, router, weights) with 64 router outputs, 8 held from 16."""
+    N, C, F, E = 24, 16, 12, 8
+    key = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(key[0], ((N, C) if W is None else (W, N, C)))
+    # the held experts' columns larger, so that tokens land here
+    router = jax.random.normal(key[1], (C, 64)) * jnp.where(
+        (jnp.arange(64) >= 16) & (jnp.arange(64) < 24), 3.0, 1.0)
+    n_w = 2 if form == "relu2" else 3
+    w = tuple(0.3 * jax.random.normal(
+        key[2 + i], (E, C, F) if i < n_w - 1 else (E, F, C))
+        for i in range(n_w))
+    return x, router, w
+
+
+def _routed_or_plain(form, plain):
+    def fn(x, router, w):
+        top, g = moe.route(x, router, None, 6, 1.0, scoring="softmax")
+        if plain:
+            return _dense_experts(form, x, top, g, 16, w)
+        token, gate, load = moe.dispatch(top, g, 16, 8)
+        return moe.routed_experts(x, token, gate, load, w, form)
+    return fn
+
+
+def test_the_gated_relu_form_has_the_plain_forms_gradient():
+    """``"reglu"``: values and the hand-written VJP (ReLU's derivative
+    on the gate's side) against autodiff of the plain form, alone."""
+    x, router, w = _expert_case("reglu")
+    with HIGHEST:
+        got = _routed_or_plain("reglu", False)(x, router, w)
+        want = _routed_or_plain("reglu", True)(x, router, w)
+        gp, gr = (jax.grad(lambda *a: jnp.sum(jnp.sin(
+            _routed_or_plain("reglu", plain)(*a))), argnums=(0, 1, 2))(
+            x, router, w) for plain in (False, True))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    _close(gp, gr, rel=2e-5, leaf=2e-4)
+    assert float(jnp.abs(want).max()) > 0
+
+
+@pytest.mark.parametrize("named", [True, False], ids=["pooled", "unnamed"])
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+def test_grad_of_vmap_sums_the_shared_weights_gradient(form, named):
+    """The fused round's transformation: the clients' losses summed,
+    then differentiated. Under a ``vmap`` named ``SHARED_CLIENTS`` the
+    backward sums the experts' weight gradient over the clients itself;
+    under one that says nothing each client's comes out and the
+    transformation sums them: the same numbers."""
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+    x, router, w = _expert_case(form, W=3)
+    axis = SHARED_CLIENTS if named else None
+
+    def loss(plain):
+        fn = _routed_or_plain(form, plain)
+        return lambda x, w: jnp.sum(jnp.sin(jax.vmap(
+            lambda xi: fn(xi, router, w), axis_name=axis)(x)))
+
+    with HIGHEST:
+        gp = jax.jit(jax.grad(loss(False), argnums=(0, 1)))(x, w)
+        gr = jax.jit(jax.grad(loss(True), argnums=(0, 1)))(x, w)
+    _close(gp, gr, rel=2e-5, leaf=2e-4)
+
+
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+def test_vmap_of_grad_gives_each_client_its_own_gradient(form):
+    """``core/rounds.py client_round``'s per-client path: shared
+    weights, one gradient a client. Every client gets its own (W, E, C,
+    F) gradient of the experts' weights, not the sum over the clients
+    (which the ``custom_vmap`` rule returned whatever the
+    transformation, before the rule read the axis' name)."""
+    x, router, w = _expert_case(form, W=3)
+
+    def per_client(plain):
+        fn = _routed_or_plain(form, plain)
+        return jax.vmap(jax.grad(
+            lambda xi, w: jnp.sum(jnp.sin(fn(xi, router, w))),
+            argnums=(0, 1)), in_axes=(0, None))
+
+    with HIGHEST:
+        gp = jax.jit(per_client(False))(x, w)
+        gr = jax.jit(per_client(True))(x, w)
+    assert gp[1][0].shape == (3,) + w[0].shape
+    _close(gp, gr, rel=2e-5, leaf=2e-4)
+    # the clients' gradients differ: none of them is the sum
+    assert float(jnp.abs(gr[1][0][0] - gr[1][0][1]).max()) > 1e-3
 
 
 # --- configuration, trainer, FedModel ------------------------------------------
