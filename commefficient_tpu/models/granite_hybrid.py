@@ -48,18 +48,19 @@ import numpy as np
 
 from commefficient_tpu.models import register_model
 from commefficient_tpu.models.mixers import (GQAttention, Mamba2Mixer,
-                                             Weights)
+                                             Weights, attn_plan)
 from commefficient_tpu.models.norms import RMSNorm
 
 #: a client's counts, which ``causal_lm_loss`` returns beside the loss:
 #: the chunks its Mamba-2 mixers scanned (sequences x chunks a sequence
 #: x ``mamba`` layers), and which form its attention layers were built
-#: in (1 / 0; both 0 with no attention layer)
-STATS = ("ssm_chunks", "attn_blocked", "attn_dense")
+#: in (1 / 0; both 0 with no attention layer), and how many of them
+#: the flash kernel built (``models/mixers.py attn_plan``)
+STATS = ("ssm_chunks", "attn_blocked", "attn_dense", "attn_kernel_layers")
 
 #: how ``FedModel`` folds them into the round record's counters
 COUNTERS = (("ssm.chunks", np.sum), ("attn.blocked", np.max),
-            ("attn.dense", np.max))
+            ("attn.dense", np.max), ("attn.kernel_layers", np.max))
 
 #: the 40 published layers: attention at 5, 15, 25, 35
 PUBLISHED_LAYER_TYPES = tuple(
@@ -223,9 +224,15 @@ class GraniteHybridLM(nn.Module):
             h, b = block_cls(cfg, kind, name=f"layer_{i}")(h)
             built = tuple(x + y for x, y in zip(built, b))
         chunks, blocked, dense = (jnp.float32(x) for x in built)
+        # as ``gqa_attention`` builds the attention layers, from the shapes
+        plan = attn_plan(*input_ids.shape, cfg.num_attention_heads,
+                         head_dim=cfg.head_dim)
+        kernel = cfg.layer_types.count("attention") * (
+            plan.kernel is not None)
         final = RMSNorm(cfg.rms_norm_eps, name="norm")(h)
         return (final / cfg.logits_scaling, head,
-                (chunks, jnp.minimum(blocked, 1), jnp.minimum(dense, 1)))
+                (chunks, jnp.minimum(blocked, 1), jnp.minimum(dense, 1),
+                 jnp.float32(kernel)))
 
 
 def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):

@@ -59,21 +59,52 @@ What is TPU-shaped here:
   ``lax.dynamic_slice`` from keys padded in front, masked to the exact
   band, the plain float32 softmax of the short rows (``attn_plan``).
   A window of T or more is the plain causal layer and is built as one.
+- **On the chip both blocked forms are one flash kernel.** Where the
+  program runs on a TPU and would build a blocked form (the full
+  layer's or the band's), with no ``query_block`` stated, T whole
+  tiles and a head size of 64 or 128, ``gqa_attention`` calls the
+  library's splash kernel instead (``jax.experimental.pallas.ops.tpu.
+  splash_attention``, its multi-query form: one call a key/value head
+  with its group of query heads, under a ``vmap`` over key/value heads
+  and one over sequences): online softmax over (tile, tile) blocks of
+  scores that never leave VMEM, float32 scores, maxima, sums and
+  accumulator, bf16 operands; a ``CausalMask`` or a ``LocalMask((T, T),
+  (window - 1, 0), 0)``, whose tables are built at trace time, once a
+  shape, and name the tiles the grid visits: the causal half, the
+  band. The backward pass is the kernel's own (dq and dk/dv kernels
+  that recompute the tiles from the kept output and log-sum-exp), so
+  the blocked forms' ``jax.checkpoint`` and its forward pass are gone.
+  The tile is 1,024 where that wastes at most 15 % on what the mask
+  hides, else 512 (``attn_kernel_block``; PERF.md section 6, PR 42:
+  SmallThinker's full layer 204.7 -> 45.1 ms, a window layer 108.9 ->
+  41.7, Granite's layer 29.5 -> 11.8, forward + backward alone). The
+  scale is folded into q (float32, rounded to bf16 once more; exact
+  where it is a power of two). Off the chip, with a ``query_block``
+  (``SmallThinkerConfig.attn_query_block``, the probe's ``blocked_*``
+  and ``band_*`` rows) and at any other shape the ``jax.numpy`` forms
+  above are built, and the dense form is untouched: Nemotron's 4 query
+  heads at T 2,048 stay dense on the chip too.
 
-Both forms are chosen from the shapes alone: no flag, no environment
-variable.
+Every form is chosen from the platform and the shapes alone
+(``attn_plan``): no flag, no environment variable, no model's name.
 
 Scopes (``PERF.md`` section 3): ``ssm_mixer`` (the whole Mamba-2
 mixer) > ``ssm_scan`` (decay sums, the in-chunk products, the state
 scan; conv, gate-norm and projections outside it); ``gqa_attn``
 (scores, softmax, value product; the four projections outside it) and,
 where a model names its layers' kinds, ``attn_window`` / ``attn_full``
-inside it; ``rope`` (the rotation of q and k, outside ``gqa_attn``).
+inside it: on the kernel path they hold the scale's fold, the
+transposes to the kernel's (heads, T, D) layout and the kernel's
+device operations, ``splash_mqa_fwd_residuals`` (twice a layer under
+``--remat``), ``splash_mqa_dq_no_residuals`` and
+``splash_mqa_dkv_no_residuals``; ``rope`` (the rotation of q and k,
+outside ``gqa_attn``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import Any, NamedTuple, Optional
 
@@ -89,6 +120,18 @@ SSD_DECAY_BYTES = 1 << 25
 #: that ``GQAttention`` materialises, and the most a block of queries'
 ATTN_SCORE_BYTES = 1 << 27
 ATTN_BLOCK_BYTES = 1 << 25
+
+#: the flash kernel's tiles: (edge, edge) scores of a block of queries
+#: against a block of keys, the same in its three device operations
+#: (forward, dq, dk/dv). The larger tile costs less a score (on the chip
+#: at T 8,192, 28 / 4 heads of 128: 1.19 against 1.58 ns) and computes
+#: more of what the mask hides: it is taken where the tiles visited hold
+#: at most ``ATTN_KERNEL_WASTE`` scores for each one needed (the causal
+#: half of 8,192: 1.12; its band of 4,096: 1.25, so 512 there). And the
+#: head sizes the kernel is given
+ATTN_KERNEL_BLOCKS = (1024, 512)
+ATTN_KERNEL_WASTE = 1.15
+ATTN_KERNEL_HEAD_DIMS = (64, 128)
 
 
 # --- the Mamba-2 recurrence, by chunks --------------------------------------
@@ -215,45 +258,96 @@ def attn_query_block(S, T, Hq):
 
 
 class AttnPlan(NamedTuple):
-    """How ``gqa_attention`` builds a layer, from the shapes alone:
-    ``block`` queries at a time, each block against ``keys`` keys;
-    ``blocked`` unless every query meets every key at once (the dense
-    form); ``banded`` where a window cuts the keys a block meets.
-    ``pairs`` (query, key) scores a head of a sequence computes that
-    way and ``needed`` of them lie in the causal band."""
+    """How ``gqa_attention`` builds a layer, from the platform and the
+    shapes alone: ``block`` queries at a time, each block against
+    ``keys`` keys; ``blocked`` unless every query meets every key at
+    once (the dense form); ``banded`` where a window cuts the keys a
+    block meets. ``pairs`` (query, key) scores a head of a sequence
+    computes that way and ``needed`` of them lie in the causal band.
+    ``kernel``: None for the ``jax.numpy`` forms; ``"splash"`` where
+    the flash kernel is called, on (``block``, ``block``) tiles of the
+    scores (``attn_kernel_block``): ``keys`` is then the most keys a
+    block of queries visits and ``pairs`` the tiles its grid visits
+    times their size; ``"splash_interpret"`` the same kernel
+    interpreted off the chip (the tests')."""
     block: int
     keys: int
     blocked: bool
     banded: bool
     pairs: int
     needed: int
+    kernel: Optional[str] = None
 
 
-def attn_plan(S, T, Hq, window=None, query_block=None):
+def _platform():
+    """Where the program runs, as ``ops/sketch.py`` resolves its
+    backend. The tests patch it: ``"tpu"`` to lower the kernel path
+    from the CPU, ``"interpret"`` to run it there by value."""
+    return jax.devices()[0].platform
+
+
+def kernel_tiles(T, block, window=None):
+    """(tiles of (block, block) scores the flash kernel's grid visits,
+    the most of them in a row of tiles): a row's tiles from the one
+    that holds its first query's oldest key to the one on the
+    diagonal."""
+    rows = [row + 1 - (0 if window is None else max(
+        0, (row * block - (window - 1)) // block))
+        for row in range(T // block)]
+    return sum(rows), max(rows)
+
+
+def attn_kernel_block(T, needed, window=None):
+    """The flash kernel's tile edge for T positions under ``window``
+    (None: the whole past), ``needed`` scores in its mask: of
+    ``ATTN_KERNEL_BLOCKS`` that T is whole tiles of, the largest that
+    wastes no more than ``ATTN_KERNEL_WASTE`` allows, else the
+    smallest; None where T is whole tiles of none."""
+    fit = [b for b in ATTN_KERNEL_BLOCKS if T % b == 0]
+    return next((b for b in fit if kernel_tiles(T, b, window)[0] * b * b
+                 <= ATTN_KERNEL_WASTE * needed), min(fit, default=None))
+
+
+def attn_plan(S, T, Hq, window=None, query_block=None, head_dim=None,
+              platform=None):
     """The ``AttnPlan`` of (S, T, Hq) under ``window`` (None, or T or
     more: the whole past). A banded block of ``b`` queries from
     ``first`` meets the keys ``[first - back, first + b)`` with ``back``
     = ``window - 1`` rounded up to whole blocks: ``b + back`` keys,
-    whatever ``first`` is."""
+    whatever ``first`` is.
+
+    The flash kernel takes the layer where all of this holds, and
+    nothing else is asked: the program runs on a TPU (``platform``,
+    default ``_platform()``); no ``query_block`` is given; the
+    ``jax.numpy`` form would be a blocked one; T is whole tiles
+    (``attn_kernel_block``); ``head_dim`` is one of
+    ``ATTN_KERNEL_HEAD_DIMS`` (None: not said, no kernel)."""
     if window is not None and window < 1:
         raise ValueError(f"a window of {window} keys sees nothing")
-    if window is None or window >= T:
-        bq = int(query_block or attn_query_block(S, T, Hq))
-        blocked = bq < T
+    w = None if window is None or window >= T else int(window)
+    needed = T * (T + 1) // 2 if w is None \
+        else w * (w + 1) // 2 + (T - w) * w
+    bq = int(query_block or attn_query_block(S, T, Hq))
+    blocked = w is not None or bq < T
+    kernel = {"tpu": "splash", "interpret": "splash_interpret"}.get(
+        _platform() if platform is None else platform)
+    tile = attn_kernel_block(T, needed, w) if kernel and blocked \
+        and not query_block and head_dim in ATTN_KERNEL_HEAD_DIMS else None
+    if tile:
+        tiles, widest = kernel_tiles(T, tile, w)
+        return AttnPlan(tile, widest * tile, True, w is not None,
+                        tiles * tile * tile, needed, kernel)
+    if w is None:
         return AttnPlan(bq, T, blocked, False,
-                        -(-T // bq) * bq * T if blocked else T * T,
-                        T * (T + 1) // 2)
-    w = int(window)
-    if query_block:
-        bq = int(query_block)
-    else:       # a block's scores within ATTN_BLOCK_BYTES, as the full
-        # form's; no wider than the window: the slack is a block a block
+                        -(-T // bq) * bq * T if blocked else T * T, needed)
+    if not query_block:
+        # a block's scores within ATTN_BLOCK_BYTES, as the full form's;
+        # no wider than the window: the slack is a block a block
         bq = int(min(max(1, ATTN_BLOCK_BYTES // (S * Hq * (w + 128) * 4
                                                  * 128)), -(-w // 128))
                  * 128)
     keys = bq + -(-(w - 1) // bq) * bq
-    return AttnPlan(bq, keys, True, True, -(-T // bq) * bq * keys,
-                    w * (w + 1) // 2 + (T - w) * w)
+    return AttnPlan(bq, keys, True, True, -(-T // bq) * bq * keys, needed)
 
 
 def rope(x, theta):
@@ -301,24 +395,69 @@ def _banded_attention(q, k, v, scale, plan, window):
     return jnp.moveaxis(out, 0, 1).reshape(S, nq * bq, Hkv, g, D)[:, :T]
 
 
+@functools.lru_cache(maxsize=32)
+def _splash_kernel(T, window, group, block, interpret):
+    """The library's splash kernel in its multi-query form: ``group``
+    query heads (group, T, D) on one key/value head (T, D), the causal
+    mask or the band ``i - j < window``, (block, block) tiles in all
+    three device operations. The mask's tables are built here, once a
+    shape, and kept as numpy: constants of whichever program is
+    traced."""
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+    mask = masks.CausalMask((T, T)) if window is None \
+        else masks.LocalMask((T, T), (window - 1, 0), 0)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mqa_single_device(
+            mask=masks.MultiHeadMask([mask] * group), block_sizes=sizes,
+            interpret=interpret)
+    return jax.tree.map(np.asarray, kernel)
+
+
+def _kernel_attention(q, k, v, scale, plan, window):
+    """``gqa_attention`` through the flash kernel: online softmax over
+    the tiles the mask lets through, the scores never outside VMEM;
+    its backward pass (two more kernels) keeps the output and the rows'
+    log-sum-exp and recomputes the tiles. The kernel takes no scale:
+    it is folded into q, in float32, rounded once more to q's dtype."""
+    S, T, Hkv, g, D = q.shape
+    kernel = _splash_kernel(T, int(window) if plan.banded else None, g,
+                            plan.block, plan.kernel == "splash_interpret")
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    heads = jax.vmap(jax.vmap(kernel))      # sequences, key/value heads
+    out = heads(jnp.transpose(qs, (0, 2, 3, 1, 4)),
+                jnp.transpose(k, (0, 2, 1, 3)),
+                jnp.transpose(v, (0, 2, 1, 3)))     # (S, Hkv, g, T, D)
+    return jnp.transpose(out, (0, 3, 1, 2, 4))
+
+
 def gqa_attention(q, k, v, scale, query_block=None, window=None):
     """Causal softmax attention of grouped query heads, exact: ``q``
     (S, T, Hkv, Hq / Hkv, D), ``k`` / ``v`` (S, T, Hkv, D) ->
-    ((S, T, Hkv, Hq / Hkv, D) in q's dtype, whether the blocked form
-    was built); scores, softmax and its statistics float32, the value
-    product in q's dtype. ``query_block`` queries at a time (default:
-    ``attn_query_block`` of the shapes); with fewer than T the (heads,
-    T, T) scores never exist: each block of queries meets every key,
-    masks what lies ahead of it and takes the plain softmax of its
-    whole rows, under ``jax.checkpoint``. With a ``window`` shorter
-    than T, query i sees the keys j <= i with i - j < window, and a
-    block of queries meets only the slice of keys its band reaches
-    (``attn_plan``): the same softmax of shorter rows."""
+    ((S, T, Hkv, Hq / Hkv, D) in q's dtype, whether the (heads, T, T)
+    scores never exist: a blocked form or the kernel); scores, softmax
+    and its statistics float32, the value product in q's dtype.
+    ``query_block`` queries at a time (default: ``attn_query_block`` of
+    the shapes); with fewer than T the (heads, T, T) scores never
+    exist: each block of queries meets every key, masks what lies ahead
+    of it and takes the plain softmax of its whole rows, under
+    ``jax.checkpoint``. With a ``window`` shorter than T, query i sees
+    the keys j <= i with i - j < window, and a block of queries meets
+    only the slice of keys its band reaches (``attn_plan``): the same
+    softmax of shorter rows. On a TPU, with no ``query_block`` given,
+    both blocked forms are the flash kernel's (``attn_plan``)."""
     S, T, Hkv, g, D = q.shape
-    if window is not None and window < T:
-        plan = attn_plan(S, T, Hkv * g, window, query_block)
+    plan = attn_plan(S, T, Hkv * g, window, query_block, D)
+    if plan.kernel:
+        return _kernel_attention(q, k, v, scale, plan, window), True
+    if plan.banded:
         return _banded_attention(q, k, v, scale, plan, int(window)), True
-    bq = int(query_block or attn_query_block(S, T, Hkv * g))
+    bq = plan.block
 
     if bq >= T:                     # the dense form, as it always was
         att = jnp.einsum("stgqd,sugd->sgqtu", q, k,

@@ -66,17 +66,20 @@ from commefficient_tpu.models.norms import RMSNorm
 #: any layer was built a block of queries at a time; the keys a query
 #: block of a window layer meets; the (query, key) scores the client's
 #: attention computes over heads, sequences and layers, and how many of
-#: them the causal bands need; 1: the router read the block's input
+#: them the causal bands need; 1: the router read the block's input;
+#: how many layers the flash kernel built (``models/mixers.py
+#: attn_plan``: on a TPU, with no ``attn_query_block`` given)
 STATS = MOE_STATS + ("attn_window_layers", "attn_full_layers",
                      "attn_blocked", "attn_window_keys", "attn_pairs",
-                     "attn_pairs_needed", "router_pre_attn")
+                     "attn_pairs_needed", "router_pre_attn",
+                     "attn_kernel_layers")
 
 #: how ``FedModel`` folds them into the round record's counters
 COUNTERS = MOE_COUNTERS + (
     ("attn.window_layers", np.max), ("attn.full_layers", np.max),
     ("attn.blocked", np.max), ("attn.window_keys", np.max),
     ("attn.pairs", np.sum), ("attn.pairs_needed", np.sum),
-    ("moe.router_pre_attn", np.max))
+    ("moe.router_pre_attn", np.max), ("attn.kernel_layers", np.max))
 
 #: the 52 published layers: 0, 4, 8, ... see the whole past and carry no
 #: positions, the three after each see 4,096 keys and carry RoPE
@@ -271,14 +274,15 @@ class SmallThinkerLM(nn.Module):
             blocked, pre = jnp.maximum(blocked, b[0]), jnp.minimum(pre, b[1])
         # as ``gqa_attention`` builds each layer, from the shapes alone
         plans = [attn_plan(S, T, cfg.num_attention_heads, cfg.window(i),
-                           cfg.attn_query_block)
+                           cfg.attn_query_block, cfg.head_dim)
                  for i in range(cfg.num_hidden_layers)]
         banded = [p for p in plans if p.banded]
         heads = S * cfg.num_attention_heads
         attn = (len(banded), len(plans) - len(banded), blocked,
                 max((p.keys for p in banded), default=0),
                 heads * sum(p.pairs for p in plans),
-                heads * sum(p.needed for p in plans), pre)
+                heads * sum(p.needed for p in plans), pre,
+                sum(p.kernel is not None for p in plans))
         return (RMSNorm(cfg.rms_norm_eps, name="norm")(h), head, stats,
                 tuple(jnp.float32(v) for v in attn))
 

@@ -5,13 +5,18 @@ clients ``vmap``, at a cell's shapes?
 
     python3 scripts/mixer_probe.py [--clients 4] [--seq 2048] [--reps 5]
         [--attn 32,8,64] [--ssd 64,1,64,128,256] [--rehearse]
-        [--window 4096] [--rope_theta 1.5e6]
+        [--window 4096] [--rope_theta 1.5e6] [--kernel_blocks 256,512]
 
 Attention (``--attn`` query heads, key/value heads, head size): the
 dense form (the float32 (heads, T, T) scores written out), the blocked
 form at 128 / 256 / 512 queries a block (``gqa_attention``), and the
 library's Pallas flash kernel that ``models/gpt2.py --attn_impl flash``
-calls, with the key/value heads repeated to the query heads' count.
+calls, with the key/value heads repeated to the query heads' count;
+and ``attn.kernel``: ``gqa_attention`` with no block given, which on
+the chip is the flash kernel the cells run (``attn_plan``; the row
+says which form was built), once a ``--kernel_blocks`` tile size (the
+probe's own knob: it sets ``mixers.ATTN_KERNEL_BLOCKS`` for the row;
+default: the program's own choice, ``attn_kernel_block``).
 The scan (``--ssd`` heads, groups, head size, state, chunk):
 ``ssd_chunked`` with all the heads at once and with 8 / 16 / 32 a
 block. Each is jitted as ``jax.grad`` of a sum over a ``vmap`` over
@@ -26,7 +31,8 @@ whole-row forms above, which are what a window layer costs when it is
 built as a full layer with a mask, then the band form at 128 / 256 /
 512 queries a block, each block against the slice of keys its band
 reaches (``gqa_attention(window=...)``; their gradients are compared
-among themselves: the mask is another). ``--rope_theta`` rotates q and
+among themselves: the mask is another), then ``attn.kernel_band``, the
+same layer with no block given. ``--rope_theta`` rotates q and
 k inside every timed form, as a layer with positions does. PR 41's
 probe: ``--clients 2 --seq 8192 --attn 28,4,128 --window 4096
 --rope_theta 1.5e6``.
@@ -54,6 +60,8 @@ def main(argv=None):
                     help="also the band form of a window layer; no scan")
     ap.add_argument("--rope_theta", type=float, default=None,
                     help="rotate q and k by their positions first")
+    ap.add_argument("--kernel_blocks", default=None,
+                    help="tile sizes of the attn.kernel rows")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on whatever backend there is")
     a = ap.parse_args(argv)
@@ -61,7 +69,9 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from commefficient_tpu.models.mixers import gqa_attention, ssd_chunked
+    from commefficient_tpu.models import mixers
+    from commefficient_tpu.models.mixers import (attn_plan, gqa_attention,
+                                                 ssd_chunked)
 
     W, T = a.clients, a.seq
     Hq, Hkv, D = map(int, a.attn.split(","))
@@ -150,6 +160,21 @@ def main(argv=None):
             return out.transpose(0, 2, 1, 3).reshape(q.shape)
         return jnp.sum(jnp.sin(jax.vmap(one)(q, k, v).astype(jnp.float32)))
 
+    def kernel_rows(name, window, first):
+        """``gqa_attention`` as the cells call it: no block given."""
+        kept = mixers.ATTN_KERNEL_BLOCKS
+        for b in a.kernel_blocks.split(",") if a.kernel_blocks else [None]:
+            if b is not None:
+                mixers.ATTN_KERNEL_BLOCKS = (int(b),)
+            plan = attn_plan(1, T, Hq, window, None, D)
+            print(json.dumps({name: {
+                "kernel": plan.kernel, "block": plan.block,
+                "banded": plan.banded,
+                "pairs_over_needed": plan.pairs / plan.needed}}))
+            measure(f"attn.{name}_{plan.block}", attn_loss(None, window),
+                    (q, kk, v), first)
+        mixers.ATTN_KERNEL_BLOCKS = kept
+
     first = None
     for name, block in [("dense", T), ("blocked_128", 128),
                         ("blocked_256", 256), ("blocked_512", 512)]:
@@ -159,8 +184,8 @@ def main(argv=None):
     if tpu and a.rope_theta is None:
         measure("attn.flash_pallas_kv_repeated", flash_loss, (q, kk, v),
                 first)
+    kernel_rows("kernel", None, first)
     if window is not None:
-        from commefficient_tpu.models.mixers import attn_plan
         first = None
         for block in (128, 256, 512):
             if block <= T:
@@ -171,6 +196,7 @@ def main(argv=None):
                                            plan.pairs / plan.needed}}))
                 first = measure(f"attn.band_{block}",
                                 attn_loss(block, window), (q, kk, v), first)
+        kernel_rows("kernel_band", window, first)
         return 0
 
     # --- the scan -----------------------------------------------------------

@@ -2,10 +2,10 @@
 suite cannot (it runs on a virtual CPU mesh with Pallas in interpret
 mode). Run on a machine with a TPU attached:
 
-    python scripts/tpu_selftest.py
+    python scripts/tpu_selftest.py [check ...]
 
-Prints one PASS/FAIL line per check and exits nonzero on any failure.
-This process holds the chip, so no check may start a child that needs
+(every check, or the named ones.) Prints one PASS/FAIL line per check
+and exits nonzero on any failure. This process holds the chip, so no check may start a child that needs
 it (the one child, scaling_smoke's, is pinned to the CPU). Kernel
 parity at flagship geometry and the trainer end to end are
 ``chip_smoke.py``'s; the headline bench runs on its own
@@ -504,6 +504,49 @@ def flash_attention_parity():
         rel = np.abs(gx - gf) / denom
         assert np.median(rel) < 2e-2, (T, float(np.median(rel)))
         details.append(f"T={T} grad rel {np.median(rel):.1e}")
+    return "; ".join(details)
+
+
+def gqa_kernel_parity():
+    """``models/mixers.py gqa_attention`` on the kernel path (what it
+    builds on the chip with no block given) against its blocked form at
+    128 queries a block, outputs and the three gradients in bf16 under
+    a 2-client ``vmap`` and ``jax.checkpoint``: SmallThinker's window
+    and full layers (8,192 x 28 / 4 heads of 128, window 4,096) and
+    Granite's layer (2,048 x 32 / 8 heads of 64)."""
+    from commefficient_tpu.models.mixers import attn_plan, gqa_attention
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    details = []
+    for T, Hq, Hkv, D, window in ((8192, 28, 4, 128, 4096),
+                                  (8192, 28, 4, 128, None),
+                                  (2048, 32, 8, 64, None)):
+        plan = attn_plan(1, T, Hq, window, None, D)
+        assert plan.kernel == "splash", plan
+        k = jax.random.split(jax.random.PRNGKey(T + D), 3)
+        q = jax.random.normal(k[0], (2, 1, T, Hkv, Hq // Hkv, D),
+                              jnp.bfloat16)
+        kk = jax.random.normal(k[1], (2, 1, T, Hkv, D), jnp.bfloat16)
+        v = jax.random.normal(k[2], (2, 1, T, Hkv, D), jnp.bfloat16)
+
+        def both(block, window=window, D=D):
+            fn = jax.checkpoint(lambda q, k, v: gqa_attention(
+                q, k, v, D ** -0.5, query_block=block, window=window)[0])
+            out = jax.vmap(fn)
+
+            def loss(q, k, v):
+                return jnp.sum(jnp.sin(out(q, k, v).astype(jnp.float32)))
+            return jax.jit(lambda *a: (out(*a),) + jax.grad(
+                loss, argnums=(0, 1, 2))(*a))
+
+        got, want = both(None)(q, kk, v), both(128)(q, kk, v)
+        worst = max(rel(a, b) for a, b in zip(got, want))
+        assert worst < 2e-2, (T, D, window, worst)
+        details.append(f"T={T} D={D} window={window} tile {plan.block} "
+                       f"worst rel {worst:.1e}")
     return "; ".join(details)
 
 
@@ -1054,23 +1097,31 @@ def main():
     from commefficient_tpu.utils import setup_compile_cache
     setup_compile_cache()
     print(f"devices: {jax.devices()}")
-    check("bf16_flagship_round", bf16_round_trains)
-    check("probe_smoke", probe_smoke)
-    check("quant_smoke", quant_smoke)
-    check("overlap_smoke", overlap_smoke)
-    check("async_smoke", async_smoke)
-    check("service_smoke", service_smoke)
-    check("autopilot_smoke", autopilot_smoke)
-    check("audit_smoke", audit_smoke)
-    check("flowlint_smoke", flowlint_smoke)
-    check("trace_smoke", trace_smoke)
-    check("scaling_smoke", scaling_smoke)
-    check("mesh2d_smoke", mesh2d_smoke)
-    check("elastic_smoke", elastic_smoke)
-    check("flash_attention_parity", flash_attention_parity)
-    check("chaos_smoke", chaos_smoke)
-    check("dp_smoke", dp_smoke)
-    check("live_smoke", live_smoke)
+    checks = [("bf16_flagship_round", bf16_round_trains),
+              ("probe_smoke", probe_smoke),
+              ("quant_smoke", quant_smoke),
+              ("overlap_smoke", overlap_smoke),
+              ("async_smoke", async_smoke),
+              ("service_smoke", service_smoke),
+              ("autopilot_smoke", autopilot_smoke),
+              ("audit_smoke", audit_smoke),
+              ("flowlint_smoke", flowlint_smoke),
+              ("trace_smoke", trace_smoke),
+              ("scaling_smoke", scaling_smoke),
+              ("mesh2d_smoke", mesh2d_smoke),
+              ("elastic_smoke", elastic_smoke),
+              ("flash_attention_parity", flash_attention_parity),
+              ("gqa_kernel_parity", gqa_kernel_parity),
+              ("chaos_smoke", chaos_smoke),
+              ("dp_smoke", dp_smoke),
+              ("live_smoke", live_smoke)]
+    asked = sys.argv[1:]
+    unknown = set(asked) - {name for name, _ in checks}
+    if unknown:
+        sys.exit(f"no such check: {sorted(unknown)}")
+    for name, fn in checks:
+        if not asked or name in asked:
+            check(name, fn)
     if FAILED:
         print(f"\n{len(FAILED)} check(s) failed: {FAILED}")
         sys.exit(1)

@@ -197,7 +197,8 @@ def test_a_blocked_model_is_the_reference_and_says_so(monkeypatch):
     assert abs(float(lp) - float(lr)) <= 2e-6 * abs(float(lr))
     _close(gp, gr)
     assert dict(zip(STATS, map(float, stats))) == {
-        "ssm_chunks": 2 * 20 * 2, "attn_blocked": 1.0, "attn_dense": 0.0}
+        "ssm_chunks": 2 * 20 * 2, "attn_blocked": 1.0, "attn_dense": 0.0,
+        "attn_kernel_layers": 0.0}      # off the chip: the blocked form
 
 
 def test_a_model_with_no_attention_layer_counts_neither_form():
@@ -206,9 +207,9 @@ def test_a_model_with_no_attention_layer_counts_neither_form():
     params = module.init(jax.random.PRNGKey(0),
                          jnp.zeros((1, 8), jnp.int32))["params"]
     _, stats = causal_lm_loss(module, params, jnp.zeros((1, 8), jnp.int32))
-    assert list(map(float, stats)) == [1.0, 0.0, 0.0]
+    assert list(map(float, stats)) == [1.0, 0.0, 0.0, 0.0]
     assert [name for name, _ in COUNTERS] == [
-        "ssm.chunks", "attn.blocked", "attn.dense"]
+        "ssm.chunks", "attn.blocked", "attn.dense", "attn.kernel_layers"]
 
 
 # --- the vocabulary's shares add up to the uncut loss ---------------------------
@@ -341,6 +342,7 @@ def test_the_trainer_trains_it_through_fedmodel(tmp_path):
         # 4 clients x 2 sequences x 32 / 8 chunks x 9 Mamba-2 layers
         assert c["ssm.chunks"] == 4 * 2 * 4 * 9
         assert (c["attn.dense"], c["attn.blocked"]) == (1, 0)
+        assert c["attn.kernel_layers"] == 0
         # a packed stream labels every position: the head orders none
         assert c["head.compact"] == 0 and "head.labelled" not in c
 

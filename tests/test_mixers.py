@@ -384,3 +384,298 @@ def test_lowered_for_the_tpu_each_cell_gets_its_form(
                 (1, 8, cfg.hidden_size), jnp.bfloat16))["params"])
         assert _whiles(lambda p, x, m=module: m.apply({"params": p}, x),
                        params, x) == loops
+
+
+# --- the flash kernel (``attn_plan``'s kernel path), interpreted on the CPU ---
+
+KERNEL_MASKS = {"full": None, "window": 128, "window-no-tile-multiple": 200}
+
+
+def _rel(got, want):
+    got, want = (jnp.asarray(a, jnp.float32) for a in (got, want))
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The kernel path as the chip takes it, interpreted: tiles of 128
+    so that T = 256 holds several, and a score budget the twins pass,
+    so that a full layer is a blocked one."""
+    monkeypatch.setattr(mixers, "_platform", lambda: "interpret")
+    monkeypatch.setattr(mixers, "ATTN_KERNEL_BLOCKS", (128,))
+    monkeypatch.setattr(mixers, "ATTN_SCORE_BYTES", 1 << 16)
+    monkeypatch.setattr(mixers, "ATTN_BLOCK_BYTES", 1 << 14)
+
+
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("g", [1, 4, 7])
+@pytest.mark.parametrize("mask", sorted(KERNEL_MASKS))
+def test_the_kernel_is_the_dense_masked_form(interpreted, mask, g, D):
+    """Outputs and the three gradients of ``gqa_attention`` itself on
+    the kernel path against every query meeting every key under a
+    (T, T) mask: float32 to 1e-5; bf16 no further from the float32
+    truth than the blocked form of the same operands is, by more than
+    the second rounding of the scaled q. Under the clients ``vmap``
+    and ``jax.checkpoint``; a scale that is no power of two."""
+    T, W, S, Hkv, scale = 256, 2, 1, 2, 0.37
+    window = KERNEL_MASKS[mask]
+    k = jax.random.split(jax.random.PRNGKey(21), 3)
+    q = jax.random.normal(k[0], (W, S, T, Hkv, g, D))
+    kk = jax.random.normal(k[1], (W, S, T, Hkv, D))
+    v = jax.random.normal(k[2], (W, S, T, Hkv, D))
+    plan = attn_plan(S, T, Hkv * g, window, None, D)
+    assert plan.kernel == "splash_interpret" and plan.block == 128
+    assert plan.banded is (window is not None)
+
+    def both(fn, *a):
+        fn = jax.checkpoint(fn)
+        out = jax.vmap(fn)(*a)
+        grads = jax.grad(lambda *a: jnp.sum(jnp.sin(
+            jax.vmap(fn)(*a).astype(jnp.float32))), argnums=(0, 1, 2))(*a)
+        return (out,) + grads
+
+    def program(block):
+        return lambda q, k, v: gqa_attention(
+            q, k, v, scale, query_block=block, window=window)[0]
+
+    def dense(q, k, v):
+        return _dense_masked(q, k, v, scale, T if window is None else window)
+
+    with HIGHEST:
+        want = jax.jit(lambda *a: both(dense, *a))(q, kk, v)
+        got = jax.jit(lambda *a: both(program(None), *a))(q, kk, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and _rel(a, b) < 1e-5
+    half = [a.astype(jnp.bfloat16) for a in (q, kk, v)]
+    kernel = jax.jit(lambda *a: both(program(None), *a))(*half)
+    blocked = jax.jit(lambda *a: both(program(64), *a))(*half)
+    for a, b, truth in zip(kernel, blocked, want):
+        assert a.dtype == jnp.bfloat16
+        assert _rel(a, truth) < 1.5 * _rel(b, truth) + 1e-3
+
+
+# The six cells' attention, a client at a time as the rounds' vmap hands
+# it over: (S, T, query heads, head size, window). ResNet9 has none;
+# GPT-2 (``models/gpt2.py --attn_impl``) and JoyAI (``models/joyai.py
+# mla_attn``: 192- and 128-wide heads) run code of their own and never
+# ask ``attn_plan``; asked at their shapes it would say what follows.
+CELL_SHAPES = {
+    "gpt2": (1, 256, 12, 64, None),
+    "joyai": (1, 1024, 32, 192, None),
+    "nemotron": (1, 2048, 4, 128, None),
+    "granite": (1, 2048, 32, 64, None),
+    "smallthinker-full": (1, 8192, 28, 128, None),
+    "smallthinker-window": (1, 8192, 28, 128, 4096)}
+# on the chip / off it: (form, queries a block or the tile's edge)
+CELL_FORMS = {
+    "gpt2": (("dense", 256), ("dense", 256)),
+    "joyai": (("dense", 1024), ("dense", 1024)),        # 128 MiB: dense
+    "nemotron": (("dense", 2048), ("dense", 2048)),
+    "granite": (("kernel", 512), ("blocked", 128)),
+    "smallthinker-full": (("kernel", 1024), ("blocked", 128)),
+    "smallthinker-window": (("kernel-band", 512), ("band", 128))}
+
+
+def _form(plan):
+    if plan.kernel:
+        return "kernel-band" if plan.banded else "kernel"
+    return "band" if plan.banded else "blocked" if plan.blocked else "dense"
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_kernel_is_chosen_from_the_platform_and_the_shapes(cell):
+    """The rule as a table; and what never gives the kernel: a stated
+    ``query_block``, a platform that is no TPU (this one), a T that is
+    not whole tiles, a head size the kernel is not given (or none
+    said)."""
+    S, T, Hq, D, window = CELL_SHAPES[cell]
+    chip, host = CELL_FORMS[cell]
+    on = attn_plan(S, T, Hq, window, None, D, platform="tpu")
+    off = attn_plan(S, T, Hq, window, None, D, platform="cpu")
+    assert (_form(on), on.block) == chip and (_form(off), off.block) == host
+    assert off == attn_plan(S, T, Hq, window, None, D) \
+        == attn_plan(S, T, Hq, window, None, None, platform="tpu") \
+        == attn_plan(S, T, Hq, window)
+    assert off.kernel is None and on.needed == off.needed
+    assert on.blocked is off.blocked and on.banded is off.banded
+    for plan in (attn_plan(S, T, Hq, window, 128, D, platform="tpu"),
+                 attn_plan(S, T + 128, Hq, window, None, D, platform="tpu"),
+                 attn_plan(S, T, Hq, window, None, 96, platform="tpu"),
+                 attn_plan(S, T, Hq, window, None, D, platform="gpu")):
+        assert plan.kernel is None
+    if on.kernel:
+        assert on.pairs < off.pairs and on.pairs <= 1.25 * on.needed
+        assert attn_plan(S, T, Hq, window, None, D,
+                         platform="interpret")._replace(kernel="splash") == on
+    if cell == "smallthinker-window":
+        full = attn_plan(*CELL_SHAPES["smallthinker-full"][:3], None, None,
+                         D, platform="tpu")
+        ratio = (full.pairs + 3 * on.pairs) / (full.needed + 3 * on.needed)
+        assert 1.12 < ratio < 1.13          # 1.57 off the chip
+
+
+TILINGS = [(1024, 256, None), (1024, 256, 512), (1024, 256, 300),
+           (1024, 128, 1), (2048, 512, 700), (1536, 512, 1536),
+           (2048, 1024, 1025), (512, 512, 100)]
+
+
+@pytest.mark.parametrize("T,tile,window", TILINGS)
+def test_the_kernels_pairs_are_the_tiles_its_grid_visits(T, tile, window,
+                                                         monkeypatch):
+    """``AttnPlan.pairs`` on the kernel path against a count of the
+    tiles that hold any pair the mask lets through, made from the
+    (T, T) mask itself; and against the library's own table of the
+    tiles its forward grid computes."""
+    monkeypatch.setattr(mixers, "ATTN_KERNEL_BLOCKS", (tile,))
+    plan = attn_plan(1, T, 64, window, None, 64, platform="tpu")
+    assert plan.kernel == "splash" and plan.block == tile
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    seen = (j <= i) & (i - j < (window or T))
+    tiles = seen.reshape(T // tile, tile, T // tile, tile).any(axis=(1, 3))
+    assert plan.needed == seen.sum()
+    assert plan.pairs == tiles.sum() * tile * tile
+    assert plan.keys == tiles.sum(axis=1).max() * tile
+    assert mixers.kernel_tiles(T, tile, window if plan.banded else None) \
+        == (tiles.sum(), tiles.sum(axis=1).max())
+    kernel = mixers._splash_kernel(T, window if plan.banded else None, 2,
+                                   tile, False)
+    table = kernel.fwd_mask_info.block_mask
+    assert isinstance(table, np.ndarray)
+    assert (table > 0).sum() == tiles.sum()
+
+
+def _small_thinker(**over):
+    from commefficient_tpu.models.smallthinker import SmallThinkerConfig
+    return dataclasses.replace(SmallThinkerConfig.tiny(), **dict(dict(
+        head_dim=64, sliding_window_size=72,
+        sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+        attn_query_block=None), **over))
+
+
+def test_a_smallthinker_record_counts_the_kernels_layers(interpreted):
+    """``attn.kernel_layers``: 4 where the plan's platform is the
+    kernel's and no query block is stated, 0 with one stated; and the
+    model's loss on the kernel path is the blocked forms' (the same
+    weights, one (1, 256) sequence; the platform undone by hand)."""
+    from commefficient_tpu.models import smallthinker as st
+    ids = jax.random.randint(jax.random.PRNGKey(31), (1, 256), 0, 96)
+    module = st.SmallThinkerLM(_small_thinker())
+    params = module.init(jax.random.PRNGKey(32), ids[:, :8])["params"]
+
+    def run(module):
+        with HIGHEST:
+            loss, stats = st.causal_lm_loss(module, params, ids)
+        return float(loss[0]), dict(zip(st.STATS, map(float, stats)))
+
+    loss, stats = run(module)
+    assert (stats["attn_kernel_layers"], stats["attn_blocked"]) == (4, 1)
+    assert (stats["attn_window_layers"], stats["attn_full_layers"]) == (3, 1)
+    # tiles of 128: the full layer 3 of 4, a window layer 1 + 2
+    assert stats["attn_pairs"] == 4 * (3 + 3 * 3) * 128 * 128
+    assert stats["attn_window_keys"] == 256
+    stated, counts = run(st.SmallThinkerLM(_small_thinker(
+        attn_query_block=64)))
+    assert counts["attn_kernel_layers"] == 0 and counts["attn_blocked"] == 1
+    assert abs(loss - stated) <= 1e-5 * abs(stated)
+    assert dict(st.COUNTERS)["attn.kernel_layers"] is np.max
+    assert st.STATS.index("attn_kernel_layers") == [
+        n for n, _ in st.COUNTERS].index("attn.kernel_layers")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lowered_for_the_tpu_the_client_round_holds_the_kernels(
+        remat, monkeypatch):
+    """SmallThinker's cell (2 clients x one 8,192-token sequence, bf16),
+    the clients' loss and its gradient lowered for the TPU with nothing
+    run: with the platform the chip's, three Mosaic calls a layer kind
+    (forward keeping its residuals, dq, dk/dv; under ``--remat`` the
+    forward twice); with this host's, none, and the loops of the
+    blocked forms instead."""
+    import collections
+    import re
+    from commefficient_tpu.models import smallthinker as st
+    cfg = dataclasses.replace(
+        st.SmallThinkerConfig.from_hf(_json(
+            "configs", "smallthinker-21ba3b-ep8")),
+        dtype=jnp.bfloat16, remat=remat)
+    module = st.SmallThinkerLM(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    ids = jax.ShapeDtypeStruct((2, 1, 8192), jnp.int32)
+
+    def loss(p, ids):
+        losses, _ = jax.vmap(lambda i: st.causal_lm_loss(module, p, i))(ids)
+        return jnp.sum(losses)
+
+    def lowered():
+        return jax.jit(jax.grad(loss)).trace(params, ids).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    host = lowered()
+    assert "tpu_custom_call" not in host
+    monkeypatch.setattr(mixers, "_platform", lambda: "tpu")
+    chip = lowered()
+    calls = collections.Counter(re.findall(r'kernel_name = "([^"]+)"', chip))
+    assert calls == {"splash_mqa_fwd_residuals": 2 * (1 + remat),
+                     "splash_mqa_dq_no_residuals": 2,
+                     "splash_mqa_dkv_no_residuals": 2}
+    assert chip.count("tpu_custom_call") == sum(calls.values())
+    # the full layer's and the band's loops over blocks of queries, one
+    # a pass of each kind, are gone
+    assert chip.count("stablehlo.while") < host.count("stablehlo.while")
+
+
+# --- the benchmark's reader of the kernel's share of its roofline ----------
+
+def _traced_ctx(ops, cell, config, monkeypatch):
+    """A context as ``benchmark/run.py`` hands its readers, over a
+    device trace made by hand: two traced rounds of 1 s on one device,
+    ``ops`` (name, start us, duration us) on its ``XLA Ops`` line."""
+    from types import SimpleNamespace
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmark.lib.peaks import peaks_of
+    from benchmark.run import load
+    key = (7, 1)
+    events = [{"ph": "X", "pid": 7, "tid": 1, "name": n, "ts": ts,
+               "dur": dur} for n, ts, dur in ops]
+    spec = _json("configs", config)
+    ctx = {"cell": _json("workloads", cell), "ref": load("reference", config),
+           "run": SimpleNamespace(ref_spec=spec), "trace_dir": "by hand",
+           "peaks": peaks_of("TPU v5 lite"), "records": [],
+           "window": {"first": 0, "first_traced": 0},
+           "_trace": {"events": events, "lanes": {key: 0},
+                      "windows": [(0, 0.0, 1e6), (1, 1e6, 2e6)],
+                      "names": ({7: "/device:TPU:0"}, {key: "XLA Ops"})}}
+    return ctx, load("metrics", "kernels.attn_roofline")
+
+
+@pytest.mark.parametrize("cell,config,least_ms", [
+    ("smallthinker_fetchsgd_w2_t8192", "smallthinker-21ba3b-ep8", 47.616),
+    ("granite4hm_fetchsgd_w4_t2048", CONFIG, 1.047)])
+def test_the_attention_roofline_reader(cell, config, least_ms, monkeypatch):
+    """On a trace that names no kernel operation the reader finds
+    nothing, and says so by None (the parent's program, a cell off the
+    kernel path); on one that does, the share is the needed pairs'
+    time at the peak over the operations' time a round, whatever else
+    the trace holds and outside the traced rounds' windows nothing."""
+    others = [("while.570", 10.0, 4e5), ("fusion.12", 5e5, 1e4),
+              ("sketch_pallas.3", 6e5, 2e3)]
+    ctx, reader = _traced_ctx(others, cell, config, monkeypatch)
+    assert reader.read(ctx) is None
+    kernel = [("splash_mqa_fwd_residuals", 1e5, 3e4),
+              ("splash_mqa_fwd_residuals.1", 2e5, 3e4),
+              ("splash_mqa_dkv_no_residuals", 1.2e6, 5e4),
+              ("splash_mqa_dq_no_residuals.2", 1.4e6, 9e4),
+              ("splash_mqa_dq_no_residuals.2", 2.5e6, 9e4)]   # past them
+    ctx, reader = _traced_ctx(others + kernel, cell, config, monkeypatch)
+    # 200 ms of kernel operations in two rounds: 100 ms a round
+    assert reader.read(ctx) == pytest.approx(least_ms, rel=1e-3)
+    z = ctx["ref"]._sizes(ctx["run"].ref_spec)
+    T = ctx["cell"]["sequence_length"]
+    causal = T * (T + 1) // 2
+    # Granite's cut holds one attention layer; SmallThinker's one full
+    # and three that see 4,096 keys
+    pairs = causal if "kinds" in z else causal + 3 * (
+        4096 * 4097 // 2 + (T - 4096) * 4096)
+    flops = 12 * z["D"] * z["Hq"] * pairs * ctx["cell"]["clients_per_round"]
+    assert 100.0 * flops / 197e12 / 0.1 == pytest.approx(least_ms, rel=1e-3)

@@ -126,6 +126,7 @@ def test_under_the_clients_vmap_the_gradient_is_the_references():
     assert stats["attn_window_layers"].tolist() == [6.0] * 3
     assert stats["attn_full_layers"].tolist() == [2.0] * 3
     assert stats["attn_blocked"].tolist() == [1.0] * 3
+    assert stats["attn_kernel_layers"].tolist() == [0.0] * 3
     # a block of 8 queries meets 8 + 16 keys: window - 1 = 11 in blocks
     assert stats["attn_window_keys"].tolist() == [24.0] * 3
     # 2 sequences x 4 heads x (2 x 32 x 32 + 6 x 32 x 24)
@@ -320,6 +321,8 @@ def test_the_trainer_trains_it_through_fedmodel(tmp_path):
         assert c["moe.router_pre_attn"] == 1
         assert (c["attn.window_layers"], c["attn.full_layers"],
                 c["attn.blocked"], c["attn.window_keys"]) == (6, 2, 1, 24)
+        # the tiny preset states its query block: never the kernel
+        assert c["attn.kernel_layers"] == 0
         # 4 clients x 2 sequences x 4 heads x ...
         assert c["attn.pairs"] == 4 * 8 * 6656
         assert c["attn.pairs_needed"] == 4 * 8 * 2964
