@@ -39,8 +39,9 @@ from commefficient_tpu.models import register_model
 from commefficient_tpu.models.norms import RMSNorm
 from commefficient_tpu.models.moe import MOE_COUNTERS as COUNTERS  # noqa: F401
 from commefficient_tpu.models.moe import MOE_STATS  # noqa: F401
-from commefficient_tpu.models.moe import (dispatch, fold_stats, layer_stats,
-                                          route, routed_experts)
+from commefficient_tpu.models.moe import (client_stats, dispatch, fold_stats,
+                                          layer_stats, no_stats, route,
+                                          routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,7 +198,7 @@ class _Experts(_Weights):
 
 class ExpertLayer(_Weights):
     """The expert layer of one chip: ``(y, stats)`` with ``stats`` =
-    float32 (assignments here, the fullest expert's, dropped)."""
+    ``models/moe.py layer_stats``' five float32 counts."""
 
     @nn.compact
     def __call__(self, x):
@@ -214,14 +215,14 @@ class ExpertLayer(_Weights):
                            cfg.norm_topk_prob)                # (N, k)
             self.sow("intermediates", "top", top)
             token, gate, load = dispatch(top, g, cfg.expert_offset, E)
+        share = E / cfg.n_router_experts
         routed = routed_experts(x.astype(dt), token, gate, load,
-                                (gate_w, up_w, down_w), "swiglu")
+                                (gate_w, up_w, down_w), "swiglu", share)
         shared = SwiGLU(cfg, cfg.moe_intermediate_size
                         * cfg.n_shared_experts, name="shared")(x)
         with jax.named_scope("moe_combine"):
             y = (routed + shared.astype(jnp.float32)).astype(dt)
-        stats = layer_stats(load, N)
-        return y.reshape(shape), stats
+        return y.reshape(shape), layer_stats(load, N, k, share)
 
 
 class Block(nn.Module):
@@ -236,7 +237,7 @@ class Block(nn.Module):
         h = RMSNorm(cfg.rms_norm_eps, name="ffn_norm")(x).astype(dt)
         if not self.moe:
             return x + SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h), \
-                jnp.zeros((3,), jnp.float32)
+                no_stats()
         y, stats = ExpertLayer(cfg, name="moe")(h)
         return x + y, stats
 
@@ -261,8 +262,8 @@ class MTPModule(_Weights):
 @register_model("JoyAIFlashLM")
 class JoyAIFlashLM(nn.Module):
     """(S, T) token ids -> (final hidden (S, T, C) float32, MTP hidden
-    or None, head weight (V, C), the expert layers' (assignments here,
-    fullest expert's load, dropped) folded over layers).
+    or None, head weight (V, C), the expert layers' ``layer_stats`` folded
+    over layers).
     The heads are applied by the loss in token chunks
     (``models/gpt2.py lm_nll_sums_chunked``), so no (tokens, vocab)
     logits tensor exists."""
@@ -281,7 +282,7 @@ class JoyAIFlashLM(nn.Module):
                           (cfg.vocab_size, cfg.hidden_size))
         block_cls = nn.remat(Block) if cfg.remat else Block
         h = embed[input_ids].astype(dt)
-        stats = jnp.zeros((3,), jnp.float32)
+        stats = no_stats()
         for i in range(cfg.num_hidden_layers):
             h, s = block_cls(cfg, moe=i >= cfg.first_k_dense_replace,
                              name=f"layer_{i}")(h)
@@ -317,5 +318,4 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
         loss = loss + cfg.mtp_loss_weight * mn / jnp.maximum(mv, 1.0)
     expert_layers = (cfg.num_hidden_layers - cfg.first_k_dense_replace
                      + cfg.num_nextn_predict_layers)
-    mean = stats[0] / max(expert_layers * cfg.n_held_experts, 1)
-    return loss, (stats[0], stats[1], mean, stats[2])
+    return loss, client_stats(stats, expert_layers * cfg.n_held_experts)

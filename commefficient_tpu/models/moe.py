@@ -13,29 +13,37 @@ attention). What is TPU-shaped:
 - The layer is told which experts it holds (``E`` of them from
   ``expert_offset``), routes over all the router's outputs in float32,
   and computes its own experts' part: the (token, expert) assignments
-  that land here are sorted by expert and taken a buffer of ``tokens``
-  rows at a time (static: a round program has no dynamic shape): a
-  gather, the ragged products (``jax.lax.ragged_dot``: XLA's tiled TPU
-  kernel, whose cost follows the rows, not rows x experts) and a
-  scatter-add under the gates. A routing that sends the average token
-  to more than one held expert takes a further pass (a loop of dynamic
-  length), so no assignment is ever left out (``moe.dropped`` counts
-  what the passes did not reach: 0). No exchange, and nothing stands in
-  for the absent chips.
+  that land here are sorted by expert and taken a buffer of rows at a
+  time (static: a round program has no dynamic shape): a gather, the
+  ragged products (``jax.lax.ragged_dot``: XLA's tiled TPU kernel,
+  whose cost follows the rows, not rows x experts) and a scatter-add
+  under the gates. A routing that fills more than the buffer takes a
+  further pass (a loop of dynamic length), so no assignment is ever
+  left out (``moe.dropped`` counts what the passes did not reach: 0).
+  No exchange, and nothing stands in for the absent chips.
 - **Under the clients ``vmap``** (``core/rounds.py make_local_loss``)
   ``ragged_dot`` has no batching rule for an unbatched weight, and a
   batched one would copy the experts per client: ``routed_experts``
-  carries its own VJP and runs once per client
-  (``jax.custom_batching``), which is also what lets its loop have a
-  length of its own per client; the weights stay shared. Where the
+  carries its own VJP, and its forward and backward each carry their
+  own batching rule (``jax.custom_batching``); the weights stay
+  shared. **Once per client**, always: ``route`` and ``dispatch`` (the
+  scores, the top-k, the sort that puts a client's held assignments
+  first and in expert order). **Once per round and layer**, where the
   ``vmap`` says that its clients' losses are summed before they are
   differentiated (``parallel/mesh.py SHARED_CLIENTS``: the fused
-  round) their gradient is summed over the clients inside the
-  backward's own loop (a stack of per-client expert gradients, W x 88
-  MB a weight at Nemotron-3-Super's widths, would outlive its layer:
-  PERF.md section 6, PR 32); under any other ``vmap`` (per-client
-  gradients of shared weights, ``core/rounds.py client_round``) each
-  client gets its own.
+  round): the pass loop. To the experts W clients of N tokens are W x N
+  tokens: the clients' sorted lists are read as one, "expert by expert,
+  within an expert client by client" (``_pool_order``: three cumulative
+  sums of the (W, E) loads and a look-up in a table of E x W entries, no
+  new sort), a buffer of ``pool_rows`` rows a pass, sized from the load
+  the shapes predict and not from N; each weight is cast to the rows'
+  dtype (and, backward, transposed) once a call, and the weights'
+  gradient comes out of the grouped products already summed over the
+  clients, in float32 (``moe.pool_rows`` / ``moe.pool_passes`` on the
+  round record say so; PERF.md section 6, PR 45). Under any other
+  ``vmap`` (per-client gradients of shared weights, ``core/rounds.py
+  client_round``) and alone, the same loop runs once per client, N rows
+  a pass, and each client gets its own weight gradient.
 
 Scopes (``PERF.md`` section 3): ``moe_route`` (scores, top-k, dispatch
 order and the gathers), ``moe_experts`` (the ragged products),
@@ -57,22 +65,27 @@ from commefficient_tpu.parallel.mesh import SHARED_CLIENTS, axis_bound
 #: the loss and ``train/gpt2_train.py`` turns into the round's ``moe.*``
 #: counters: (token, expert) assignments to experts held here over all
 #: expert layers; the fullest (layer, expert)'s; the mean over (layer,
-#: expert); assignments no pass of ``routed_experts`` reached (0)
-MOE_STATS = ("assignments_here", "load_max", "load_mean", "dropped")
+#: expert); assignments no pass of ``routed_experts`` reached (0); the
+#: rows of the buffer that took every client's assignments at once (0
+#: where each client had its own loop); the most passes an expert layer
+#: took to get through them (1 unless the routing is skewed)
+MOE_STATS = ("assignments_here", "load_max", "load_mean", "dropped",
+             "pool_rows", "pool_passes")
 
 #: how ``FedModel`` folds the clients' ``MOE_STATS`` (in that order)
 #: into the round record's ``moe.*`` counters
 MOE_COUNTERS = (("moe.assignments_here", np.sum), ("moe.load_max", np.max),
-                ("moe.load_mean", np.mean), ("moe.dropped", np.sum))
+                ("moe.load_mean", np.mean), ("moe.dropped", np.sum),
+                ("moe.pool_rows", np.max), ("moe.pool_passes", np.max))
 
 
 # --- the held experts' products -------------------------------------------
 
 def _ragged(x, w, sizes):
-    """(M, K) rows sorted by group, (G, K, N) float32, (G,) -> (M, N)
-    float32, computed in ``x``'s dtype. Rows past the groups are zero on
-    the CPU and whatever the buffer held on the TPU: mask them."""
-    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
+    """(M, K) rows sorted by group, (G, K, N) in ``x``'s dtype, (G,) ->
+    (M, N) float32. Rows past the groups are zero on the CPU and
+    whatever the buffer held on the TPU: mask them."""
+    return jax.lax.ragged_dot(x, w, sizes,
                               preferred_element_type=jnp.float32)
 
 
@@ -85,27 +98,12 @@ def _ragged_outer(x, dy, sizes):
         x, dy, sizes, dims, preferred_element_type=jnp.float32)
 
 
-def _pass(p, x, token, gate, load):
-    """Pass ``p`` of the sorted assignments: rows [pN, (p+1)N). Returns
-    the rows' tokens, gates, validity, each expert's share of the rows
-    and the gathered inputs."""
-    N = x.shape[0]
-    with jax.named_scope("moe_route"):
-        lo = p * N
-        rows = jax.lax.dynamic_slice_in_dim(token, lo, N)
-        g = jax.lax.dynamic_slice_in_dim(gate, lo, N)
-        ends = jnp.cumsum(load)
-        sizes = (jnp.clip(ends, lo, lo + N)
-                 - jnp.clip(ends - load, lo, lo + N)).astype(jnp.int32)
-        valid = ((lo + jnp.arange(N)) < ends[-1])[:, None]
-        xg = x[rows]
-    return rows, g, valid, sizes, xg
-
-
 # An expert form: ``ffn(xg, valid, sizes, *w) -> (saved, h, o)`` (what
 # the backward needs of the pre-activations, the last product's input,
-# the experts' outputs) and ``back(xg, saved, h, do, valid, sizes, *w)
-# -> (dxg, dw...)``. The TPU kernel leaves the rows past the groups
+# the experts' outputs) and ``back(xg, saved, h, do, valid, sizes, *wt)
+# -> (dxg, dw...)``. ``w`` are the stacks in the rows' dtype and ``wt``
+# their transposes (E, out, in): the caller casts and transposes once,
+# outside its pass loop. The TPU kernel leaves the rows past the groups
 # unwritten: every ragged product is masked before anything reads it.
 
 def _swiglu_ffn(xg, valid, sizes, wg, wu, wd):
@@ -115,17 +113,15 @@ def _swiglu_ffn(xg, valid, sizes, wg, wu, wd):
     return (a, b), h, jnp.where(valid, _ragged(h, wd, sizes), 0.0)
 
 
-def _swiglu_back(xg, saved, h, do, valid, sizes, wg, wu, wd):
+def _swiglu_back(xg, saved, h, do, valid, sizes, wgt, wut, wdt):
     a, b = saved
     dt = xg.dtype
-    dh = jnp.where(
-        valid, _ragged(do, jnp.swapaxes(wd, 1, 2), sizes), 0.0)
+    dh = jnp.where(valid, _ragged(do, wdt, sizes), 0.0)
     sa = jax.nn.sigmoid(a)
     da = (dh * b * sa * (1.0 + a * (1.0 - sa))).astype(dt)
     db = (dh * a * sa).astype(dt)
     dxg = jnp.where(
-        valid, _ragged(da, jnp.swapaxes(wg, 1, 2), sizes)
-        + _ragged(db, jnp.swapaxes(wu, 1, 2), sizes), 0.0)
+        valid, _ragged(da, wgt, sizes) + _ragged(db, wut, sizes), 0.0)
     return dxg, (_ragged_outer(xg, da, sizes), _ragged_outer(xg, db, sizes),
                  _ragged_outer(h, do, sizes))
 
@@ -136,13 +132,11 @@ def _relu2_ffn(xg, valid, sizes, w1, w2):
     return (a,), h, jnp.where(valid, _ragged(h, w2, sizes), 0.0)
 
 
-def _relu2_back(xg, saved, h, do, valid, sizes, w1, w2):
+def _relu2_back(xg, saved, h, do, valid, sizes, w1t, w2t):
     (a,) = saved
-    dh = jnp.where(
-        valid, _ragged(do, jnp.swapaxes(w2, 1, 2), sizes), 0.0)
+    dh = jnp.where(valid, _ragged(do, w2t, sizes), 0.0)
     da = (dh * 2.0 * jax.nn.relu(a)).astype(xg.dtype)
-    dxg = jnp.where(
-        valid, _ragged(da, jnp.swapaxes(w1, 1, 2), sizes), 0.0)
+    dxg = jnp.where(valid, _ragged(da, w1t, sizes), 0.0)
     return dxg, (_ragged_outer(xg, da, sizes), _ragged_outer(h, do, sizes))
 
 
@@ -153,16 +147,14 @@ def _reglu_ffn(xg, valid, sizes, wg, wu, wd):
     return (a, b), h, jnp.where(valid, _ragged(h, wd, sizes), 0.0)
 
 
-def _reglu_back(xg, saved, h, do, valid, sizes, wg, wu, wd):
+def _reglu_back(xg, saved, h, do, valid, sizes, wgt, wut, wdt):
     a, b = saved
     dt = xg.dtype
-    dh = jnp.where(
-        valid, _ragged(do, jnp.swapaxes(wd, 1, 2), sizes), 0.0)
+    dh = jnp.where(valid, _ragged(do, wdt, sizes), 0.0)
     da = jnp.where(a > 0.0, dh * b, 0.0).astype(dt)
     db = (dh * jax.nn.relu(a)).astype(dt)
     dxg = jnp.where(
-        valid, _ragged(da, jnp.swapaxes(wg, 1, 2), sizes)
-        + _ragged(db, jnp.swapaxes(wu, 1, 2), sizes), 0.0)
+        valid, _ragged(da, wgt, sizes) + _ragged(db, wut, sizes), 0.0)
     return dxg, (_ragged_outer(xg, da, sizes), _ragged_outer(xg, db, sizes),
                  _ragged_outer(h, do, sizes))
 
@@ -175,149 +167,245 @@ FORMS = {"swiglu": (_swiglu_ffn, _swiglu_back),
          "reglu": (_reglu_ffn, _reglu_back)}
 
 
+# --- the pass loops: one client's rows, or every client's in one pool -----
+
+#: the pool's buffer over the load its shapes predict (W x A x the held
+#: share of the router's experts), and the rows it is rounded up to.
+#: ``scripts/moe_probe.py`` on one v5e chip (PR 45; PERF.md section 6),
+#: one layer forward + backward, ms at a buffer of 1 / 1.25 / 1.5 / 2 x
+#: the predicted load and at W x N, against the per-client loops' 21.9 /
+#: 19.5 / 21.1: JoyAI 7.7 / 8.2 / 8.8 / 10.4 / 14.7 (8,183 held of 8,192
+#: predicted), Nemotron 8.7 (two passes: 5,841 held of 5,632 predicted)
+#: / 5.7 / 6.7 / 7.1 / 7.2, SmallThinker 16.2 / 17.0 / 18.2 / 19.7 /
+#: 17.4. The products' time follows the rows filled, the gathers' and
+#: scatter-adds' the buffer: a quarter of slack costs 0.5-0.8 ms a
+#: layer, and an even router's draw already passes 1 x
+POOL_SLACK = 1.25
+POOL_ALIGN = 256
+
+
+def pool_rows(W, assignments, held_share):
+    """Rows M of the buffer that takes the pooled held assignments of
+    ``W`` clients with ``assignments`` (token, expert) picks each, of
+    which ``held_share`` land here if the router spreads them evenly:
+    static, from the shapes alone. Never more than every pick."""
+    want = int(np.ceil(POOL_SLACK * W * assignments * held_share))
+    return min(W * assignments, -(-want // POOL_ALIGN) * POOL_ALIGN)
+
+
+def passes(total, rows):
+    """Buffers of ``rows`` rows that ``total`` assignments fill."""
+    return (total + rows - 1) // rows
+
+
 def _zeros(shape, load):
     """float32 zeros to carry through a pass loop: derived from the
-    client's ``load`` and not ``jnp.zeros``, so that inside a
+    clients' ``load`` and not ``jnp.zeros``, so that inside a
     ``shard_map`` over clients they vary over the mesh axis as the
     loop's results do (the scan carry-type check; cf. models/gpt2.py
     ``lm_nll_sums_chunked``)."""
     return jnp.zeros(shape, jnp.float32) \
-        + (load[0] * 0).astype(jnp.float32)
+        + (load.reshape(-1)[0] * 0).astype(jnp.float32)
 
 
-def passes(load, N):
-    """Buffers of ``N`` rows the held assignments fill."""
-    return (jnp.sum(load) + N - 1) // N
+def _pool_order(load, A, N):
+    """The pooled order of W clients' held assignments, "expert by
+    expert, within an expert client by client", from their (W, E)
+    loads alone: each client's list is already sorted by expert, so a
+    pooled position's source follows from the (expert, client) group
+    it falls in. Returns the groups' pooled ends (E*W,), what to add to
+    a pooled position in a group to get its place in the flattened
+    (W*A,) lists, the group's offset into the flattened (W*N,) tokens,
+    and the experts' pooled starts and ends (E,)."""
+    W, E = load.shape
+    groups = load.T.reshape(-1)                   # (E*W,): expert-major
+    ends = jnp.cumsum(groups)
+    own = (jnp.cumsum(load, axis=1) - load).T.reshape(-1)
+    client = jnp.tile(jnp.arange(W, dtype=load.dtype), E)
+    held = jnp.sum(load, axis=0)
+    expert_ends = jnp.cumsum(held)
+    return (ends, client * A + own - (ends - groups), client * N,
+            expert_ends - held, expert_ends)
+
+
+def _pass(p, M, order, x, token, gate):
+    """Pass ``p`` of the pooled order: positions [pM, (p+1)M). Returns
+    the rows' (flattened) tokens, their places in the flattened lists
+    (past the lists' end where the row is none), gates, validity, each
+    expert's share of the rows and the gathered inputs."""
+    ends, to_list, to_token, starts, expert_ends = order
+    with jax.named_scope("moe_route"):
+        lo = p * M
+        j = lo + jnp.arange(M, dtype=ends.dtype)
+        valid = j < ends[-1]
+        group = jnp.minimum(jnp.searchsorted(
+            ends, j, side="right", method="compare_all"),
+                            ends.shape[0] - 1)
+        place = to_list[group] + j
+        src = jnp.where(valid, place, 0)
+        rows = token[src] + to_token[group]
+        sizes = (jnp.clip(expert_ends, lo, lo + M)
+                 - jnp.clip(starts, lo, lo + M)).astype(jnp.int32)
+    return (rows, jnp.where(valid, place, token.shape[0]), gate[src],
+            valid[:, None], sizes, x[rows])
+
+
+def _pool_fwd(ffn, M, x, token, gate, load, w):
+    """(W, N, C), (W, A), (W, A), (W, E) -> float32 (W, N, C): one pass
+    loop over the W clients' pooled held assignments, ``M`` rows a
+    pass, the weights cast once."""
+    W, N, C = x.shape
+    order = _pool_order(load, token.shape[1], N)
+    x, token, gate = x.reshape(W * N, C), token.reshape(-1), gate.reshape(-1)
+    with jax.named_scope("moe_experts"):
+        w = [a.astype(x.dtype) for a in w]
+
+    def body(p, y):
+        rows, _, g, valid, sizes, xg = _pass(p, M, order, x, token, gate)
+        with jax.named_scope("moe_experts"):
+            o = ffn(xg, valid, sizes, *w)[2]
+        with jax.named_scope("moe_combine"):
+            return y.at[rows].add(o * g[:, None])
+
+    y = jax.lax.fori_loop(0, passes(order[0][-1], M), body,
+                          _zeros((W * N, C), load))
+    return y.reshape(W, N, C)
+
+
+def _pool_bwd(ffn, back, M, x, token, gate, load, dy, w):
+    """The backward of ``_pool_fwd``: ``(dx (W, N, C), dgate (W, A),
+    float32 dw... summed over the W clients)``. Recomputes each pass;
+    the weights are cast, and transposed, once."""
+    W, N, C = x.shape
+    A = token.shape[1]
+    dt = x.dtype
+    order = _pool_order(load, A, N)
+    x, token, gate = x.reshape(W * N, C), token.reshape(-1), gate.reshape(-1)
+    dy = dy.reshape(W * N, C)
+    shapes = [a.shape for a in w]
+    with jax.named_scope("moe_experts"):
+        w = [a.astype(dt) for a in w]
+        wt = [jnp.swapaxes(a, 1, 2) for a in w]
+
+    def body(p, carry):
+        dx, dgate, *dw = carry
+        rows, src, g, valid, sizes, xg = _pass(p, M, order, x, token, gate)
+        with jax.named_scope("moe_experts"):
+            saved, h, o = ffn(xg, valid, sizes, *w)
+        with jax.named_scope("moe_combine"):
+            dyg = jnp.where(valid, dy[rows], 0.0)
+            dg = jnp.sum(dyg * o, axis=-1)
+            do = (dyg * g[:, None]).astype(dt)
+        with jax.named_scope("moe_experts"):
+            dxg, dws = back(xg, saved, h, do, valid, sizes, *wt)
+            dw = [acc + one for acc, one in zip(dw, dws)]
+        with jax.named_scope("moe_route"):
+            dx = dx.at[rows].add(dxg)
+            dgate = dgate.at[src].set(dg, mode="drop", unique_indices=True)
+        return (dx, dgate, *dw)
+
+    dx, dgate, *dw = jax.lax.fori_loop(
+        0, passes(order[0][-1], M), body,
+        (_zeros((W * N, C), load), _zeros((W * A,), load),
+         *[_zeros(s, load) for s in shapes]))
+    return (dx.astype(dt).reshape(W, N, C), dgate.reshape(W, A), *dw)
+
+
+def _over_clients(run, n, n_out, pooled, held_share):
+    """``run(M, *stacked, w) -> results`` as a function of ``n``
+    per-client arguments and then the shared weights, with its batching
+    rule. ``stacked``: the per-client arguments with a leading clients'
+    axis, which the first ``n_out`` results have too. Alone it is a
+    pool of one, N rows a pass, and so it is once per element under a
+    ``vmap`` that says nothing (every result batched, the weight
+    gradients among them: W x the experts, which only a path that wants
+    per-client gradients pays); ``pooled``: once for all the ``vmap``'s
+    elements, ``pool_rows`` a pass, the other results unbatched (the
+    weights are the batch's own, so what the transformation asks of
+    their cotangent is its sum over the batch: what the pooled products
+    give, and a stack of per-client (E, C, F) gradients is never
+    made)."""
+    def alone(*args):
+        out = run(args[0].shape[0], *[a[None] for a in args[:n]], args[n:])
+        return tuple(o[0] for o in out[:n_out]) + tuple(out[n_out:])
+
+    if not pooled:
+        return sequential_vmap(alone)
+    fn = custom_vmap(alone)
+
+    @fn.def_vmap
+    def over_clients(axis_size, in_batched, *args):
+        if any(in_batched[n:]):
+            raise NotImplementedError(
+                "routed_experts under vmap shares the experts' weights")
+        stacked = [a if batched else jnp.broadcast_to(
+            a, (axis_size,) + a.shape)
+            for a, batched in zip(args[:n], in_batched)]
+        M = pool_rows(axis_size, stacked[1].shape[1], held_share)
+        out = tuple(run(M, *stacked, args[n:]))
+        return out, (True,) * n_out + (False,) * (len(out) - n_out)
+
+    return fn
 
 
 @functools.lru_cache(maxsize=None)
-def _routed(form, pooled):
+def _routed(form, pooled, held_share):
     """``routed_experts`` of one expert form: the forward and backward
-    pass loops, each run once per client, under one custom VJP.
-    ``pooled``: a ``vmap`` over it sums the clients' losses before it
-    differentiates them, so the shared weights' gradient is summed over
-    the clients as they are taken; else each client's is its own."""
+    pass loops under one custom VJP, each with ``_over_clients``'
+    batching rule. ``pooled``: a ``vmap`` over it sums the clients'
+    losses before it differentiates them."""
     ffn, back = FORMS[form]
-
-    @sequential_vmap
-    def fwd(x, token, gate, load, *w):
-        N, C = x.shape
-
-        def body(p, y):
-            rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
-            with jax.named_scope("moe_experts"):
-                o = ffn(xg, valid, sizes, *w)[2]
-            with jax.named_scope("moe_combine"):
-                return y.at[rows].add(o * g[:, None])
-
-        return jax.lax.fori_loop(0, passes(load, N), body,
-                                 _zeros((N, C), load))
-
-    def bwd_one(x, token, gate, load, w, dy, dw):
-        """One client's backward pass loop: ``(dx, dgate, dw + this
-        client's weight gradients)``."""
-        N, C = x.shape
-        dt = x.dtype
-
-        def body(p, carry):
-            dx, dgate, *dw = carry
-            rows, g, valid, sizes, xg = _pass(p, x, token, gate, load)
-            with jax.named_scope("moe_experts"):
-                saved, h, o = ffn(xg, valid, sizes, *w)
-            with jax.named_scope("moe_combine"):
-                dyg = jnp.where(valid, dy[rows], 0.0)
-                dg = jnp.sum(dyg * o, axis=-1)
-                do = (dyg * g[:, None]).astype(dt)
-            with jax.named_scope("moe_experts"):
-                dxg, dws = back(xg, saved, h, do, valid, sizes, *w)
-                dw = [acc + one for acc, one in zip(dw, dws)]
-            with jax.named_scope("moe_route"):
-                dx = dx.at[rows].add(dxg)
-                dgate = jax.lax.dynamic_update_slice_in_dim(
-                    dgate, dg, p * N, axis=0)
-            return (dx, dgate, *dw)
-
-        dx, dgate, *dw = jax.lax.fori_loop(
-            0, passes(load, N), body,
-            (_zeros(x.shape, load), _zeros(gate.shape, load), *dw))
-        return (dx.astype(dt), dgate, *dw)
-
-    def bwd_alone(x, token, gate, load, *rest):
-        *w, dy = rest
-        return bwd_one(x, token, gate, load, w, dy,
-                       [_zeros(a.shape, load) for a in w])
-
-    # not pooled: a client at a time, every result batched, the weight
-    # gradients among them (W x the experts, which only a path that
-    # wants per-client gradients pays)
-    bwd = custom_vmap(bwd_alone) if pooled else sequential_vmap(bwd_alone)
-
-    def bwd_over_clients(axis_size, in_batched, x, token, gate, load,
-                         *rest):
-        """The clients one after the other, as ``sequential_vmap`` would
-        take them, but with the weight gradients summed as they go: the
-        weights are the batch's own (unbatched), so what the
-        transformation asks of their cotangent is its sum over the
-        batch, and a stack of per-client (E, C, F) gradients (W times
-        the experts, a layer) is never made."""
-        *w, dy = rest
-        if any(in_batched[4:-1]):
-            raise NotImplementedError(
-                "routed_experts under vmap shares the experts' weights")
-        per_client = [a if batched else jnp.broadcast_to(
-            a, (axis_size,) + a.shape) for a, batched in zip(
-            (x, token, gate, load, dy),
-            tuple(in_batched[:4]) + (in_batched[-1],))]
-
-        def step(dw, c):
-            dx, dgate, *dw = bwd_one(*c[:4], w, c[4], dw)
-            return dw, (dx, dgate)
-
-        dw, (dx, dgate) = jax.lax.scan(
-            step, [_zeros(a.shape, per_client[3][0]) for a in w],
-            tuple(per_client))
-        return (dx, dgate, *dw), (True, True) + (False,) * len(w)
-
-    if pooled:
-        bwd.def_vmap(bwd_over_clients)
+    fwd = _over_clients(
+        lambda M, *a: (_pool_fwd(ffn, M, *a),), 4, 1, pooled, held_share)
+    bwd = _over_clients(
+        functools.partial(_pool_bwd, ffn, back), 5, 2, pooled, held_share)
 
     @jax.custom_vjp
-    def routed(x, token, gate, load, *w):
-        return fwd(x, token, gate, load, *w)
+    def routed(*args):
+        return fwd(*args)[0]
 
     def vjp_fwd(*args):
-        return fwd(*args), args
+        return fwd(*args)[0], args
 
     def vjp_bwd(res, dy):
-        dx, dgate, *dw = bwd(*res, dy)
+        dx, dgate, *dw = bwd(*res[:4], dy, *res[4:])
         return (dx, None, dgate, None, *dw)
 
     routed.defvjp(vjp_fwd, vjp_bwd)
     return routed
 
 
-def routed_experts(x, token, gate, load, weights, form="swiglu"):
+def routed_experts(x, token, gate, load, weights, form="swiglu",
+                   held_share=1.0):
     """What the experts held here add to each token, float32 (N, C).
 
-    ``x`` (N, C) in the compute dtype; ``token`` / ``gate`` (A_max,):
-    the token and the gate of every (token, expert) assignment, those
-    to held experts first and sorted by expert; ``load`` (E,): how many
+    ``x`` (N, C) in the compute dtype; ``token`` / ``gate`` (A,): the
+    token and the gate of every (token, expert) assignment, those to
+    held experts first and sorted by expert; ``load`` (E,): how many
     each held expert has; ``weights``: the held experts' float32 stacks
     in the order their ``form`` of ``FORMS`` takes them ("swiglu":
     (E, C, F), (E, C, F), (E, F, C); "reglu": the same three; "relu2":
-    (E, C, F), (E, F, C)).
-    The assignments are taken N rows a pass, as many passes as the load
-    needs (one, unless the average token picks more than one expert
-    held here), each pass the form's ragged products: every assignment
-    is computed whatever the routing, at a cost that follows the load.
-    Carries its own VJP (no reverse mode runs through a loop of dynamic
-    length) and recomputes the pass's activations there. Under ``vmap``
-    it runs once per batch element with the weights shared (they may
-    not be batched); inside a ``vmap`` named ``SHARED_CLIENTS`` (the
-    losses are summed before they are differentiated) their gradient is
-    summed over the batch in float32 as the elements are taken, inside
-    any other each element has its own."""
-    return _routed(form, axis_bound(SHARED_CLIENTS))(
+    (E, C, F), (E, F, C)); ``held_share``: the held experts over all
+    the router's (static: it sizes the pool's buffer, below).
+
+    The held assignments are taken a buffer of rows a pass, as many
+    passes as the load needs, each pass a gather, the form's ragged
+    products, the masked tail and a scatter-add under the gates: every
+    assignment is computed whatever the routing, at a cost that follows
+    the load. Carries its own VJP (no reverse mode runs through a loop
+    of dynamic length) and recomputes the pass's activations there; the
+    weights are cast (and, backward, transposed) once a call.
+
+    Once per client: alone, and under a ``vmap`` that says nothing of
+    its losses (N rows a pass; the weights shared, they may not be
+    batched; each element has its own weight gradient). Once per round
+    and layer: inside a ``vmap`` named ``SHARED_CLIENTS`` (the losses
+    are summed before they are differentiated) the W clients' lists are
+    read as one, expert by expert and within an expert client by
+    client, ``pool_rows(W, A, held_share)`` rows a pass (one pass
+    unless the routing is skewed), and the weights' gradient comes out
+    of the products summed over the clients, in float32."""
+    return _routed(form, axis_bound(SHARED_CLIENTS), float(held_share))(
         x, token, gate, load, *weights)
 
 
@@ -366,16 +454,60 @@ def dispatch(top, g, expert_offset, E):
     return order // k, g.reshape(-1)[order], load
 
 
-def layer_stats(load, N):
-    """float32 (assignments here, the fullest expert's, dropped) of one
-    expert layer of one client."""
+@custom_vmap
+def _pooled_total(load):
+    """Σ load over the clients of the ``vmap`` this is traced under (as
+    the pooled rule of ``routed_experts`` sees them), on each client.
+    Not ``psum``: inside a ``shard_map`` its varying-axes check refuses
+    a ``psum`` over a ``vmap``'s axis (jax 0.9.0)."""
+    return jnp.sum(load)
+
+
+@_pooled_total.def_vmap
+def _pooled_total_over_clients(axis_size, in_batched, load):
+    return jnp.broadcast_to(jnp.sum(load), (axis_size,)), True
+
+
+def layer_stats(load, N, k=1, held_share=1.0):
+    """float32 ``LAYER_STATS`` of one expert layer of one client with
+    ``N`` tokens of ``k`` picks, as ``routed_experts`` takes it where
+    this is traced: the client's assignments here, its fullest
+    expert's, what the passes left out, and the pool's buffer and
+    passes (0 where each client has a loop of its own, N rows a
+    pass)."""
     total = jnp.sum(load)
-    done = jnp.minimum(total, passes(load, N) * N)
-    return jnp.stack([total, jnp.max(load),
-                      total - done]).astype(jnp.float32)
+    if axis_bound(SHARED_CLIENTS):
+        W = jax.lax.axis_size(SHARED_CLIENTS)
+        rows = pool_rows(W, N * k, held_share)
+        pooled = _pooled_total(load)
+        n = passes(pooled, rows)
+        # the pool's count, a W-th on each client
+        left = (pooled - jnp.minimum(pooled, n * rows)) / W
+        pool = (rows, n)
+    else:
+        left = total - jnp.minimum(total, passes(total, N) * N)
+        pool = (0, 0)
+    return jnp.stack([jnp.float32(v) for v in (
+        total, jnp.max(load), left, *pool)])
+
+
+#: how ``fold_stats`` folds each of ``layer_stats``' entries over layers
+LAYER_STATS = (jnp.add, jnp.maximum, jnp.add, jnp.maximum, jnp.maximum)
+
+
+def no_stats():
+    """What a layer with no experts adds to ``fold_stats``."""
+    return jnp.zeros((len(LAYER_STATS),), jnp.float32)
 
 
 def fold_stats(total, layer):
-    """Sum assignments and drops over layers, keep the fullest expert."""
-    return jnp.stack([total[0] + layer[0], jnp.maximum(total[1], layer[1]),
-                      total[2] + layer[2]])
+    """Sum assignments and drops over layers, keep the fullest expert,
+    the pool's buffer and the most passes a layer took."""
+    return jnp.stack([fold(total[i], layer[i])
+                      for i, fold in enumerate(LAYER_STATS)])
+
+
+def client_stats(stats, experts):
+    """The folded ``layer_stats`` of a client as ``MOE_STATS`` names
+    them; ``experts``: (layer, expert) pairs the mean load is over."""
+    return (stats[0], stats[1], stats[0] / max(experts, 1), *stats[2:])

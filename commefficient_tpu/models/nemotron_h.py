@@ -56,8 +56,9 @@ from commefficient_tpu.models import register_model
 from commefficient_tpu.models.mixers import (GQAttention,  # noqa: F401
                                              Mamba2Mixer, ssd_chunked)
 from commefficient_tpu.models.mixers import Weights as _Weights
-from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS, dispatch,
-                                          fold_stats, layer_stats, route,
+from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS,
+                                          client_stats, dispatch, fold_stats,
+                                          layer_stats, no_stats, route,
                                           routed_experts)
 from commefficient_tpu.models.norms import RMSNorm
 
@@ -172,7 +173,7 @@ class _Pair(_Weights):
 
 class LatentMoE(_Weights):
     """The expert layer of one chip: ``(y, stats)`` with ``stats`` =
-    float32 (assignments here, the fullest expert's, dropped)."""
+    ``models/moe.py layer_stats``' five float32 counts."""
 
     @nn.compact
     def __call__(self, x):
@@ -196,18 +197,21 @@ class LatentMoE(_Weights):
             token, gate, load = dispatch(top, g, cfg.expert_offset, E)
         with jax.named_scope("moe_experts"):
             u = x @ down.astype(dt)
-        routed = routed_experts(u, token, gate, load, (w1, w2), "relu2")
+        share = E / cfg.n_router_experts
+        routed = routed_experts(u, token, gate, load, (w1, w2), "relu2",
+                                share)
         with jax.named_scope("moe_experts"):
             routed = routed.astype(dt) @ up.astype(dt)
         shared = _relu2(x @ s1.astype(dt)) @ s2.astype(dt)
         with jax.named_scope("moe_combine"):
             y = routed + shared
-        return y.reshape(shape), layer_stats(load, N)
+        return y.reshape(shape), layer_stats(
+            load, N, cfg.num_experts_per_tok, share)
 
 
 class Block(nn.Module):
-    """``(x + mixer(norm(x)), the expert layer's (assignments here,
-    fullest expert's, dropped), chunks scanned)``."""
+    """``(x + mixer(norm(x)), the expert layer's ``layer_stats``,
+    chunks scanned)``."""
     cfg: NemotronHConfig
     kind: str = "M"
 
@@ -215,7 +219,7 @@ class Block(nn.Module):
     def __call__(self, x):
         cfg, dt = self.cfg, self.cfg.dtype
         h = RMSNorm(cfg.layer_norm_epsilon, name="norm")(x).astype(dt)
-        moe, chunks = jnp.zeros((3,), jnp.float32), 0
+        moe, chunks = no_stats(), 0
         if self.kind == "M":
             y, chunks = Mamba2Mixer(cfg, name="mixer")(h)
         elif self.kind == "*":
@@ -230,8 +234,8 @@ class Block(nn.Module):
 @register_model("NemotronHLM")
 class NemotronHLM(nn.Module):
     """(S, T) token ids -> (final hidden (S, T, C) float32, head weight
-    (V, C), the expert layers' (assignments here, fullest expert's
-    load, dropped) folded over layers, chunks scanned). The head is applied
+    (V, C), the expert layers' ``layer_stats`` folded over layers, chunks
+    scanned). The head is applied
     by the loss in token chunks (``models/gpt2.py
     lm_nll_sums_chunked``), so no (tokens, vocab) logits tensor
     exists."""
@@ -250,7 +254,7 @@ class NemotronHLM(nn.Module):
                           (cfg.vocab_size, cfg.hidden_size))
         block_cls = nn.remat(Block) if cfg.remat else Block
         h = embed[input_ids].astype(dt)
-        stats, chunks = jnp.zeros((3,), jnp.float32), 0
+        stats, chunks = no_stats(), 0
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             h, s, n = block_cls(cfg, kind, name=f"layer_{i}")(h)
             stats, chunks = fold_stats(stats, s), chunks + n
@@ -266,6 +270,5 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
                                  cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
-    mean = stats[0] / max(cfg.count("E") * cfg.n_held_experts, 1)
-    return sn / jnp.maximum(sv, 1.0), (stats[0], stats[1], mean, stats[2],
-                                       chunks)
+    return sn / jnp.maximum(sv, 1.0), client_stats(
+        stats, cfg.count("E") * cfg.n_held_experts) + (chunks,)

@@ -55,8 +55,9 @@ import numpy as np
 
 from commefficient_tpu.models import register_model
 from commefficient_tpu.models.mixers import GQAttention, Weights, attn_plan
-from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS, dispatch,
-                                          fold_stats, layer_stats, route,
+from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS,
+                                          client_stats, dispatch, fold_stats,
+                                          layer_stats, no_stats, route,
                                           routed_experts)
 from commefficient_tpu.models.norms import RMSNorm
 
@@ -198,7 +199,7 @@ class _Experts(Weights):
 
 class Block(Weights):
     """``(h after attention and the expert part, the expert part's
-    (assignments here, fullest expert's, dropped), (whether attention
+    ``layer_stats``, (whether attention
     was built a block of queries at a time, whether the router read the
     block's input))``. ``router_after_attention`` is the other
     placement, which no published layer has: the tests' and the cell's
@@ -235,20 +236,20 @@ class Block(Weights):
         if self.router_after_attention:
             token, gate, load = routing(h)
         m = RMSNorm(cfg.rms_norm_eps, name="ffn_norm")(h).astype(dt)
+        share = E / cfg.n_router_experts
         y = routed_experts(m.reshape(-1, C), token, gate, load, weights,
-                           "reglu")
+                           "reglu", share)
         with jax.named_scope("moe_combine"):
             out = h + y.astype(dt).reshape(S, T, C)
-        return out, layer_stats(load, S * T), jnp.float32(
+        return out, layer_stats(load, S * T, k, share), jnp.float32(
             [blocked, not self.router_after_attention])
 
 
 @register_model("SmallThinkerLM")
 class SmallThinkerLM(nn.Module):
     """(S, T) token ids -> (final hidden (S, T, C) float32, head weight
-    (V, C), the expert parts' (assignments here, fullest expert's load,
-    dropped) folded over layers, the attention layers' counts as
-    ``STATS`` names them). The head is applied by the loss in token
+    (V, C), the expert parts' ``layer_stats`` folded over layers, the
+    attention layers' counts as ``STATS`` names them). The head is applied by the loss in token
     chunks (``models/gpt2.py lm_nll_sums_chunked``), so no (tokens,
     vocab) logits tensor exists."""
     cfg: SmallThinkerConfig = SmallThinkerConfig()
@@ -267,7 +268,7 @@ class SmallThinkerLM(nn.Module):
                           (cfg.vocab_size, cfg.hidden_size))
         block_cls = nn.remat(Block) if cfg.remat else Block
         h = embed[input_ids].astype(dt)
-        stats, blocked, pre = jnp.zeros((3,), jnp.float32), 0.0, 1.0
+        stats, blocked, pre = no_stats(), 0.0, 1.0
         for i in range(cfg.num_hidden_layers):
             h, s, b = block_cls(cfg, i, name=f"layer_{i}")(h)
             stats = fold_stats(stats, s)
@@ -295,6 +296,5 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
                                  cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
-    mean = stats[0] / max(cfg.num_hidden_layers * cfg.n_held_experts, 1)
-    return sn / jnp.maximum(sv, 1.0), (stats[0], stats[1], mean,
-                                       stats[2]) + attn
+    return sn / jnp.maximum(sv, 1.0), client_stats(
+        stats, cfg.num_hidden_layers * cfg.n_held_experts) + attn

@@ -550,6 +550,52 @@ def gqa_kernel_parity():
     return "; ".join(details)
 
 
+def moe_pool_parity():
+    """``models/moe.py routed_experts`` at the Nemotron cell's expert
+    layer (8 clients x 2,048 tokens x 22 picks, 8 held of 512, latent
+    1,024, experts 2,688 wide, squared ReLU, bf16 rows): every client's
+    held rows in one grouped pass (the ``vmap`` named
+    ``SHARED_CLIENTS``) against a client at a time (a ``vmap`` that
+    says nothing), the outputs and the gradients of the rows, the gates
+    and both weights."""
+    from commefficient_tpu.models import moe
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+    W, N, k, E, R, C, F = 8, 2048, 22, 8, 512, 1024, 2688
+    key = jax.random.split(jax.random.PRNGKey(45), 5)
+    x = jax.random.normal(key[0], (W, N, C), jnp.bfloat16)
+    router = jax.random.normal(key[1], (C, R)) / C ** 0.5
+    w = (0.02 * jax.random.normal(key[2], (E, C, F)),
+         0.02 * jax.random.normal(key[3], (E, F, C)))
+    cot = jax.random.normal(key[4], (W, N, C))
+
+    def routing(xi):
+        top, g = moe.route(xi, router, jnp.zeros((R,)), k, 1.0)
+        return moe.dispatch(top, g, 0, E)
+
+    token, gate, load = jax.jit(jax.vmap(routing))(x)
+
+    def both(axis):
+        def out(x, gate, w):
+            return jax.vmap(lambda xi, ti, gi, li: moe.routed_experts(
+                xi, ti, gi, li, w, "relu2", E / R),
+                axis_name=axis)(x, token, gate, load)
+        return jax.jit(lambda *a: (out(*a),) + jax.grad(
+            lambda *a: jnp.sum(out(*a) * cot), argnums=(0, 1, 2))(*a))
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    got, want = (jax.tree_util.tree_leaves(both(axis)(x, gate, w))
+                 for axis in (SHARED_CLIENTS, None))
+    worst = max(rel(a, b) for a, b in zip(got, want))
+    assert worst < 1e-2, worst
+    held, rows = int(jnp.sum(load)), moe.pool_rows(W, N * k, E / R)
+    assert held <= rows, (held, rows)    # one pass at the cell's routing
+    return f"{held} held assignments in one buffer of {rows}; worst rel " \
+           f"{worst:.1e}"
+
+
 def trace_smoke():
     """Device-time attribution round-trip on the REAL backend: a
     trace_window around a few marked rounds of device work must
@@ -1112,6 +1158,7 @@ def main():
               ("elastic_smoke", elastic_smoke),
               ("flash_attention_parity", flash_attention_parity),
               ("gqa_kernel_parity", gqa_kernel_parity),
+              ("moe_pool_parity", moe_pool_parity),
               ("chaos_smoke", chaos_smoke),
               ("dp_smoke", dp_smoke),
               ("live_smoke", live_smoke)]

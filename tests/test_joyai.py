@@ -215,7 +215,7 @@ def test_every_token_routed_to_one_expert_drops_none():
     with HIGHEST:
         y, stats = ExpertLayer(cfg).apply({"params": p}, x)
         want = ref._moe(p, x, spec, lambda a: a)
-    assert [float(s) for s in stats] == [48.0, 48.0, 0.0]
+    assert [float(s) for s in stats] == [48.0, 48.0, 0.0, 0.0, 0.0]
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
 
 
@@ -241,7 +241,7 @@ def test_more_than_one_held_expert_a_token_takes_more_passes():
         gp = jax.grad(program, argnums=(0, 1), has_aux=True)(p, x)[0]
         gr = jax.grad(lambda p, x: jnp.sum(jnp.sin(
             ref._moe(p, x, spec, lambda a: a))), argnums=(0, 1))(p, x)
-    assert [float(s) for s in stats] == [32.0, 8.0, 0.0]
+    assert [float(s) for s in stats] == [32.0, 8.0, 0.0, 0.0, 0.0]
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
     assert _rel(gp, gr) <= 2e-5
 

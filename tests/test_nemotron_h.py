@@ -466,6 +466,207 @@ def test_vmap_of_grad_gives_each_client_its_own_gradient(form):
     assert float(jnp.abs(gr[1][0][0] - gr[1][0][1]).max()) > 1e-3
 
 
+# --- every client's held rows in one grouped pass (the named vmap's rule) ----
+
+def _steered(router, targets, seed=31):
+    """(W, N, C) tokens whose logits on the 8 held experts (columns 16
+    to 24 of ``_expert_case``'s router) are ``targets`` (W, 8) plus a
+    little noise: a shift solved from the held columns."""
+    W, N, C = targets.shape[0], 24, router.shape[0]
+    shift = targets @ jnp.linalg.pinv(router[:, 16:24])        # (W, C)
+    return 0.05 * jax.random.normal(jax.random.PRNGKey(seed), (W, N, C)) \
+        + shift[:, None, :]
+
+
+def _pool_case(form, case):
+    """``(x (3, N, C), router, weights, held_share, remat)`` of one
+    case of the pooled rule's tests."""
+    x, router, w = _expert_case(form, W=3)
+    share, remat = 8 / 64, False
+    if case == "several-passes":
+        share = 1 / 64     # a buffer of 16 rows (POOL_ALIGN patched to 8)
+    elif case == "a-client-with-none":
+        x = x.at[1].set(_steered(router, jnp.full((1, 8), -40.0))[0])
+    elif case == "all-on-one-expert":
+        x = _steered(router, jnp.tile(jnp.float32(
+            [[-40.0] * 3 + [40.0] + [-40.0] * 4]), (3, 1)))
+        share = 1 / 64
+    elif case == "checkpoint":
+        share, remat = 1 / 64, True
+    return x, router, w, share, remat
+
+
+def _loads(x, router):
+    def one(xi):
+        top, g = moe.route(xi, router, None, 6, 1.0, scoring="softmax")
+        return moe.dispatch(top, g, 16, 8)[2]
+    with HIGHEST:
+        return np.asarray(jax.vmap(one)(x))
+
+
+@pytest.mark.parametrize("case", ["several-passes", "a-client-with-none",
+                                  "all-on-one-expert", "checkpoint"])
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+def test_the_pooled_rule_is_the_per_client_rule(form, case, monkeypatch):
+    """Under a ``vmap`` named ``SHARED_CLIENTS`` the forward and the
+    backward take every client's held assignments in one pass loop:
+    the values and the gradients of ``x``, the router and every weight
+    are the per-client rule's (a ``vmap`` that says nothing) and those
+    of every held expert computed on every token; with a buffer far
+    smaller than the load (several passes), a client that holds
+    nothing, every assignment on one expert, and under
+    ``jax.checkpoint``."""
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+    monkeypatch.setattr(moe, "POOL_ALIGN", 8)
+    x, router, w, share, remat = _pool_case(form, case)
+    loads = _loads(x, router)
+    rows = moe.pool_rows(3, 24 * 6, share)
+    if case == "a-client-with-none":
+        assert loads[1].sum() == 0 and loads[0].sum() > 0
+    elif case == "all-on-one-expert":
+        assert (loads == np.eye(8, dtype=int)[3] * 24).all()
+    if share < 8 / 64:
+        assert loads.sum() > 3 * rows      # more than three buffers' worth
+
+    def loss(how):
+        def one(xi, router, w):
+            top, g = moe.route(xi, router, None, 6, 1.0, scoring="softmax")
+            if how == "plain":
+                return _dense_experts(form, xi, top, g, 16, w)
+            token, gate, load = moe.dispatch(top, g, 16, 8)
+            return moe.routed_experts(xi, token, gate, load, w, form, share)
+        if remat:
+            one = jax.checkpoint(one)
+        axis = SHARED_CLIENTS if how == "pooled" else None
+
+        def both(x, router, w):
+            y = jax.vmap(lambda xi: one(xi, router, w), axis_name=axis)(x)
+            return jnp.sum(jnp.sin(y)), y
+        return jax.jit(jax.value_and_grad(both, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    with HIGHEST:
+        (_, yp), gp = loss("pooled")(x, router, w)
+        (_, yc), gc = loss("per-client")(x, router, w)
+        (_, yr), gr = loss("plain")(x, router, w)
+    np.testing.assert_allclose(yp, yr, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(yp, yc, rtol=2e-5, atol=2e-6)
+    _close(gp, gr, rel=2e-5, leaf=2e-4)
+    _close(gp, gc, rel=2e-5, leaf=2e-4)
+    assert float(jnp.abs(yr).max()) > 0
+    assert all(float(jnp.abs(g).max()) > 0
+               for g in jax.tree_util.tree_leaves(gr))
+
+
+def test_inside_a_shard_map_the_pool_is_one_devices_clients():
+    """``rounds_sp`` and the multi-chip meshes: the named ``vmap`` runs
+    inside a ``shard_map`` over ``clients``, so the pool is the two
+    clients a device holds; the loops' carries and the counters must
+    pass its varying-axes check, and the numbers are the plain form's
+    over all four clients."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from commefficient_tpu.parallel.mesh import (CLIENT_AXIS, SHARED_CLIENTS,
+                                                 shard_map)
+    x, router, w = _expert_case("swiglu", W=4)
+    mesh = Mesh(np.array(jax.devices()[:2]), (CLIENT_AXIS,))
+
+    def one(xi, w):
+        top, g = moe.route(xi, router, None, 6, 1.0, scoring="softmax")
+        token, gate, load = moe.dispatch(top, g, 16, 8)
+        return (moe.routed_experts(xi, token, gate, load, w, "swiglu",
+                                   8 / 64),
+                moe.layer_stats(load, 24, 6, 8 / 64))
+
+    def block(x, w):
+        # as ``core/rounds.py`` hands a device its weights: varying, so
+        # that the gradient is the device's own until the round sums it
+        w = jax.lax.pcast(w, CLIENT_AXIS, to="varying")
+
+        def loss(x, w):
+            y, stats = jax.vmap(lambda xi: one(xi, w),
+                                axis_name=SHARED_CLIENTS)(x)
+            return jnp.sum(jnp.sin(y)), stats
+        (_, stats), (dx, dw) = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(x, w)
+        return dx, jax.lax.psum(dw, CLIENT_AXIS), stats
+
+    with HIGHEST:
+        dx, dw, stats = jax.jit(shard_map(
+            block, mesh=mesh, in_specs=(P(CLIENT_AXIS), P()),
+            out_specs=(P(CLIENT_AXIS), P(), P(CLIENT_AXIS))))(x, w)
+        want = jax.grad(lambda x, w: jnp.sum(jnp.sin(jax.vmap(
+            lambda xi: _routed_or_plain("swiglu", True)(xi, router, w))(x))),
+            argnums=(0, 1))(x, w)
+    _close((dx, dw), want, rel=2e-5, leaf=2e-4)
+    rows = moe.pool_rows(2, 24 * 6, 8 / 64)
+    assert stats.shape == (4, 5) and (stats[:, 2] == 0).all()
+    assert (stats[:, 3] == rows).all() and (stats[:, 4] >= 1).all()
+    held = np.asarray(stats[:, 0]).reshape(2, 2).sum(1)      # a device
+    assert (np.asarray(stats[:, 4]).reshape(2, 2)
+            == -(-held // rows)[:, None]).all()
+
+
+def _count(jaxpr, found):
+    """Every equation of ``jaxpr`` and of the programs inside it."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+def test_the_pooled_rules_program_does_not_grow_with_the_clients(form):
+    """``grad(vmap(..., axis_name=SHARED_CLIENTS))``: as many ragged
+    products whatever W, and no loop over the W clients; the ``vmap``
+    that says nothing keeps its loop (what the walk must find)."""
+    from commefficient_tpu.parallel.mesh import SHARED_CLIENTS
+    fn = _routed_or_plain(form, False)
+
+    def eqns(W, axis):
+        x, router, w = _expert_case(form, W=W)
+        return _count(jax.make_jaxpr(jax.grad(
+            lambda x, w: jnp.sum(jnp.sin(jax.vmap(
+                lambda xi: fn(xi, router, w), axis_name=axis)(x))),
+            argnums=(0, 1)))(x, w).jaxpr, [])
+
+    def ragged(found):
+        return sum(e.primitive.name.startswith("ragged_dot") for e in found)
+
+    def loops_over(found, W):
+        return sum(e.primitive.name == "scan" and e.params["length"] == W
+                   for e in found)
+
+    few, many = eqns(2, SHARED_CLIENTS), eqns(5, SHARED_CLIENTS)
+    n_w = 2 if form == "relu2" else 3
+    # forward: a product a weight; backward: those recomputed, their
+    # transposes and the weights' outer products
+    assert ragged(few) == ragged(many) == 4 * n_w
+    assert loops_over(few, 2) == loops_over(many, 5) == 0
+    assert loops_over(eqns(5, None), 5) > 0
+
+
+@pytest.mark.parametrize("model", ["NemotronHLM", "JoyAIFlashLM",
+                                   "SmallThinkerLM"])
+def test_the_fused_round_pools_the_clients_held_rows(tmp_path, model):
+    """Each trainer's tiny model through ``FedModel``: every round
+    record says that the expert layers took the clients' rows in one
+    buffer (``moe.pool_rows`` > 0), in how many passes, and that none
+    was left out."""
+    _tiny_run(tmp_path, ["--ledger", str(tmp_path / "ledger.jsonl")],
+              model=model)
+    with open(tmp_path / "ledger.jsonl") as f:
+        recs = [r for r in map(json.loads, f) if r.get("kind") == "round"]
+    assert recs
+    for c in (r["counters"] for r in recs):
+        assert c["moe.dropped"] == 0 and c["moe.assignments_here"] > 0
+        assert c["moe.pool_rows"] > 0 and c["moe.pool_passes"] >= 1
+        assert c["moe.pool_rows"] % 256 == 0
+
+
 # --- configuration, trainer, FedModel ------------------------------------------
 
 def _config():
