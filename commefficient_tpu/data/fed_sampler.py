@@ -18,7 +18,10 @@ class _Lookahead:
     thread (runtime/fed_model.py) needs round N+1's participant ids
     while round N computes. Each underlying draw happens one ``next``
     earlier than it would unbuffered, but the draw ORDER (and hence
-    the sampler RNG stream a checkpoint captures) is unchanged."""
+    the sampler RNG stream a checkpoint captures) is unchanged.
+    ``peek()`` is None once the epoch's last round is handed out: a
+    loader that reads ahead opens the next epoch then, on its own
+    thread (data/loader.py)."""
 
     def __init__(self, it):
         self._it = it
@@ -81,7 +84,16 @@ class FedSampler:
         rounds bit-exactly: the buffered spec is re-yielded first,
         then the generator continues from the restored cursor/RNG.
         None when no epoch iterator is active (epoch boundary — the
-        plain end-of-epoch RNG capture suffices there)."""
+        plain end-of-epoch RNG capture suffices there).
+
+        The snapshot is of the epoch ``__iter__`` opened last. A loader
+        whose thread opens the next epoch while the consumer is still
+        in this one calls this where the last round is dealt, before
+        that ``__iter__`` (cursors at their ends, no buffered spec, the
+        RNG from which the next epoch's permutations are drawn), and
+        keeps the answer for the checkpoints taken until the consumer
+        enters the opened epoch (``held_back()``, data/loader.py): the
+        live state is then one epoch further than the run."""
         if self._lookahead is None or self._permuted is None:
             return None
         spec = self._lookahead.peek()

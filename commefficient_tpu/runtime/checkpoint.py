@@ -356,52 +356,61 @@ def save_checkpoint(path: str, model, opt, scheduler=None,
         # are in flight, like the native ring's: a mid-epoch resume
         # continues, bit-exactly, with the round after them
         settle()
+    # an epoch's end is not in flight: where that thread has opened
+    # the next epoch before this one was taken to its end, the loader
+    # holds the streams as they stood at the end (``held_back()``), and
+    # those are what is saved: the resumed run opens that epoch itself,
+    # to the same first round
+    held_back = getattr(loader, "held_back", None)
+    held = {k: v for k, v in (held_back() if held_back else {}).items()
+            if v is not None}
+
+    def put_rng(name, state):
+        meta[name] = [state[0], None, int(state[2]), int(state[3]),
+                      float(state[4])]
+        arrays[name + "_keys"] = np.asarray(state[1])
+
     if sampler is not None and hasattr(sampler.rng, "get_state"):
-        state = sampler.rng.get_state()
-        meta["sampler_rng"] = [state[0], None, int(state[2]),
-                               int(state[3]), float(state[4])]
-        arrays["sampler_rng_keys"] = np.asarray(state[1])
+        put_rng("sampler_rng",
+                held.get("sampler_rng") or sampler.rng.get_state())
     # datasets with stateful per-item RNG (e.g. FedPERSONA's
     # personality shuffles) advance it on every access — capture it or
     # a resumed epoch sees different records than the uninterrupted run
     ds = getattr(sampler, "dataset", None)
     ds_rng = getattr(ds, "_rng", None)
     if ds_rng is not None and hasattr(ds_rng, "getstate"):
-        version, internal, gauss = ds_rng.getstate()
+        version, internal, gauss = (held.get("dataset_rng")
+                                    or ds_rng.getstate())
         meta["dataset_rng"] = [int(version), gauss]
         arrays["dataset_rng_state"] = np.asarray(internal, np.int64)
     # the CV transform stacks draw from the GLOBAL numpy RNG — capture
     # it too, or augmentation replays from the re-seeded stream after
     # resume while the uninterrupted run's stream had advanced
-    g = np.random.get_state()
-    meta["np_global_rng"] = [g[0], None, int(g[2]), int(g[3]),
-                             float(g[4])]
-    arrays["np_global_rng_keys"] = np.asarray(g[1])
+    put_rng("np_global_rng",
+            held.get("np_global_rng") or np.random.get_state())
     # the native data-plane derives per-round augmentation seeds from
     # its round counter
     if loader is not None and hasattr(loader, "_round_counter"):
-        meta["loader_round_counter"] = int(loader._round_counter)
+        meta["loader_round_counter"] = int(held.get(
+            "loader_round_counter", loader._round_counter))
     # --dropout_prob draws from the loader's own RNG stream every
     # round — capture it or a resumed run replays drops from the
     # re-seeded stream while the uninterrupted run's had advanced
     dr = getattr(loader, "_dropout_rng", None)
     if dr is not None and hasattr(dr, "get_state"):
-        g = dr.get_state()
-        meta["dropout_rng"] = [g[0], None, int(g[2]), int(g[3]),
-                               float(g[4])]
-        arrays["dropout_rng_keys"] = np.asarray(g[1])
+        put_rng("dropout_rng", held.get("dropout_rng") or dr.get_state())
     if mid_epoch and sampler is not None \
             and hasattr(sampler, "export_state"):
-        st = sampler.export_state()
+        # with the next epoch opened ahead, the epoch the consumer is
+        # still in has no round left to deal: the resumed run re-enters
+        # it for nothing and opens the next
+        st = held.get("sampler_mid") or sampler.export_state()
         if st is not None:
             meta["sampler_mid_epoch"] = True
             arrays["sampler_mid_permuted"] = np.asarray(st["permuted"])
             arrays["sampler_mid_cur"] = np.asarray(st["cur"])
             if st.get("rng_state") is not None:
-                rs = st["rng_state"]
-                meta["sampler_mid_rng"] = [rs[0], None, int(rs[2]),
-                                           int(rs[3]), float(rs[4])]
-                arrays["sampler_mid_rng_keys"] = np.asarray(rs[1])
+                put_rng("sampler_mid_rng", st["rng_state"])
             if st.get("spec_workers") is not None:
                 arrays["sampler_mid_spec_workers"] = st["spec_workers"]
                 arrays["sampler_mid_spec_sizes"] = st["spec_sizes"]
@@ -660,6 +669,12 @@ def load_checkpoint(path: str, model, opt, scheduler=None,
         opt._step_count = meta["opt_step_count"]
         if scheduler is not None and "scheduler_step" in meta:
             scheduler._step = meta["scheduler_step"]
+        drop = getattr(loader, "close", None)
+        if drop is not None:
+            # an epoch the loader's thread had opened ahead was drawn
+            # from the streams as they were: the restored ones open
+            # the next epoch
+            drop()
         if sampler is not None and "sampler_rng" in meta:
             s = meta["sampler_rng"]
             sampler.rng.set_state((s[0], np.asarray(z["sampler_rng_keys"]),
@@ -781,7 +796,18 @@ class RoundAutosaver:
     cost; falls back to a copy on link-hostile filesystems) and prunes
     the oldest beyond the budget. A SIGTERM at any point leaves either
     the previous or the new checkpoint intact — never a torn one
-    (the save itself is tmp+rename atomic)."""
+    (the save itself is tmp+rename atomic).
+
+    What such a checkpoint holds of the data path near an epoch's end:
+    once the sampler has dealt the epoch's last round the loader's
+    thread opens the next epoch ahead (data/loader.py), and the save
+    then records, through ``loader.held_back()``, the sampler as it
+    stood at the end (an epoch with no round left, and the RNG from
+    which the next epoch's permutations are drawn), not as it stands.
+    The rounds of this epoch that were made and not yet trained on are
+    in flight and lost, as ever; the resumed run re-enters the epoch
+    for nothing and opens the next one itself, to the rounds the
+    uninterrupted run deals."""
 
     def __init__(self, args, model, opt, scheduler, sampler, loader,
                  tag: str):

@@ -675,6 +675,9 @@ def run(argv=None) -> TrainRun:
             tokenizer.save_pretrained(logdir)
         print(f"saved model + tokenizer to {logdir}"
               + (" (HF torch format)" if args.do_hf_export else ""))
+    # the loader's thread, with the epoch it opened ahead, ends with
+    # the run
+    train_loader.close()
     return TrainRun(results, model, opt, train_loader)
 
 

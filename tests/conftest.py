@@ -36,6 +36,18 @@ def no_model_left_live(monkeypatch):
     monkeypatch.setattr(staging, "_LIVE", [])
 
 
+@pytest.fixture(autouse=True)
+def no_loader_thread_left():
+    """A loader that reads ahead keeps its thread, with the next epoch
+    opened on it, until ``close()`` (data/loader.py): what a test left
+    running is stopped here, so that the next test of this worker
+    counts its own threads alone."""
+    yield
+    from commefficient_tpu.data.loader import _ReadAhead
+    for reader in list(_ReadAhead.live):
+        reader.stop()
+
+
 @pytest.fixture(scope="session")
 def package_parse():
     """One timed cold flowlint run (parse + both lint tiers) on the

@@ -8,6 +8,7 @@ import glob
 import json
 import os
 import signal
+import threading
 
 import numpy as np
 import pytest
@@ -368,3 +369,163 @@ def test_round_autosave_retention_across_resume_boundary(tmp_path):
     assert rounds == [6, 8], rounds
     _, meta = _load_state(d)
     assert meta["round_index"] == 8
+
+
+# -- the data path's streams where the loader has opened an epoch ahead --
+
+
+def _cv_data_path(kind, drop):
+    from commefficient_tpu.data.fed_sampler import FedSampler
+    from commefficient_tpu.data.loader import FedLoader, NativeFedLoader
+    from commefficient_tpu.data.synthetic import FedSynthetic
+    from commefficient_tpu.data.transforms import cifar_train_transform
+    tf = cifar_train_transform(np.float32(0.1), np.float32(1.1))
+    ds = FedSynthetic("", "Synthetic", transform=tf, num_classes=4,
+                      per_class=16, num_val=8, gen_seed=3)
+    sampler = FedSampler(ds, num_workers=2, local_batch_size=4, seed=0)
+    kw = dict(dropout_prob=drop, dropout_seed=5)
+    if kind == "fed":
+        return FedLoader(ds, sampler, **kw)
+    return NativeFedLoader(ds, sampler, seed=11, depth=3, **kw)
+
+
+def _tiny_model():
+    import jax.numpy as jnp
+
+    from commefficient_tpu.config import Config
+    from commefficient_tpu.runtime.fed_model import (FedModel,
+                                                     FedOptimizer)
+    cfg = Config(mode="sketch", error_type="virtual", local_momentum=0.0,
+                 virtual_momentum=0.9, k=16, num_rows=3, num_cols=128,
+                 num_workers=2, local_batch_size=4, seed=5,
+                 num_clients=16, num_devices=1)
+
+    def loss(params, batch, cfg):
+        return jnp.float32(0.0), (jnp.float32(0.0),)
+
+    model = FedModel(None, {"w": jnp.zeros((12, 4), jnp.float32)}, loss,
+                     cfg, padded_batch_size=4)
+    return model, FedOptimizer([{"lr": 0.25}], cfg, model=model)
+
+
+def _copies(batches):
+    return [{k: np.array(v) for k, v in b.items()} for b in batches]
+
+
+def _assert_dealt(got, want):
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        for k in b:
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+def _native_or_skip(kind):
+    from commefficient_tpu import native
+    if kind == "native" and not native.available():
+        pytest.skip("no native toolchain")
+
+
+@pytest.mark.parametrize("at", ["epoch_end", "last_round", "mid_epoch"])
+@pytest.mark.parametrize("kind", ["fed", "native"])
+def test_resume_beside_an_epoch_opened_ahead_deals_the_same_rounds(
+        tmp_path, kind, at):
+    """With a model live the loader's thread opens epoch e+1 when the
+    sampler has dealt epoch e's last round (data/loader.py): the live
+    streams are then past the boundary, and the checkpoint records what
+    the loader held back of it. ``epoch_end``: saved between two epochs,
+    the resumed loader deals the next epoch whole, its first round
+    included. ``last_round``: saved mid-epoch where every round of the
+    epoch is taken, it re-enters that epoch for nothing and deals the
+    next whole. ``mid_epoch``: nothing is opened ahead, and it continues
+    after the rounds that were in flight, as ever. Against three epochs
+    of the loader that makes its batches on the consumer's thread."""
+    from commefficient_tpu.data import staging
+    from commefficient_tpu.runtime.checkpoint import (load_checkpoint,
+                                                      save_checkpoint)
+    _native_or_skip(kind)
+    # the native ring's rounds draw their drop-out when popped: those
+    # in flight at a mid-epoch save shift that stream, as ever
+    drop = 0.3 if kind == "fed" or at == "epoch_end" else 0.0
+    plain = _cv_data_path(kind, drop)
+    assert staging.current() is None
+    np.random.seed(77)
+    ref = [_copies(plain) for _ in range(3)]
+    plain.close()
+
+    model, opt = _tiny_model()
+    assert staging.current() is not None
+    loader = _cv_data_path(kind, drop)
+    np.random.seed(77)
+    _assert_dealt(_copies(loader), ref[0])
+    path = str(tmp_path / "ck.npz")
+    taken = {"epoch_end": 0, "last_round": len(ref[1]), "mid_epoch": 3}[at]
+    it = iter(loader)
+    head = _copies(next(it) for _ in range(taken))
+    save_checkpoint(path, model, opt, sampler=loader.sampler, epoch=1,
+                    loader=loader, mid_epoch=at != "epoch_end")
+    assert bool(loader.held_back()) == (at != "mid_epoch")
+    # the run that was not interrupted is not disturbed by the save
+    _assert_dealt(head + _copies(it), ref[1])
+    _assert_dealt(_copies(loader), ref[2])
+    loader.close()
+
+    resumed = _cv_data_path(kind, drop)
+    np.random.seed(999)
+    # (FedLoader reads the image shape off one item, once, by a draw of
+    # the transforms': before the streams are restored, here)
+    next(iter(resumed))
+    meta = load_checkpoint(path, model, opt, sampler=resumed.sampler,
+                           loader=resumed)
+    assert bool(meta.get("sampler_mid_epoch")) == (at != "epoch_end")
+    if at != "epoch_end":
+        rest = _copies(resumed)
+        in_flight = {"last_round": 0, "mid_epoch": 1 if kind == "fed"
+                     else resumed.depth + 1}[at]
+        assert len(rest) == len(ref[1]) - taken - in_flight
+        _assert_dealt(rest, ref[1][len(ref[1]) - len(rest):])
+    _assert_dealt(_copies(resumed), ref[1 if at == "epoch_end" else 2])
+    if at == "epoch_end":
+        _assert_dealt(_copies(resumed), ref[2])
+    resumed.close()
+    model.finalize()
+    assert not [t for t in threading.enumerate()
+                if t.name == "loader-stage"]
+
+
+def test_checkpoints_written_before_the_read_ahead_crossed_epochs_load(
+        tmp_path):
+    """The archive's keys are what they were: a loader that holds
+    nothing back writes what ``save_checkpoint`` always wrote."""
+    from commefficient_tpu.runtime.checkpoint import (load_checkpoint,
+                                                      save_checkpoint)
+    model, opt = _tiny_model()
+    loader = _cv_data_path("fed", 0.3)
+    loader.placement = None
+    loader.telemetry = model.telemetry
+    np.random.seed(3)
+    it = iter(loader)
+    next(it), next(it)
+    assert loader._reader is not None and not loader.held_back()
+    path = str(tmp_path / "ck.npz")
+    save_checkpoint(path, model, opt, sampler=loader.sampler, epoch=0,
+                    loader=loader, mid_epoch=True)
+    with np.load(path) as z:
+        names, meta = set(z.files), json.loads(str(z["meta"]))
+    assert {"sampler_rng_keys", "np_global_rng_keys", "dropout_rng_keys",
+            "sampler_mid_rng_keys", "sampler_mid_permuted",
+            "sampler_mid_cur", "sampler_mid_spec_workers"} <= names
+    for key in ("sampler_rng", "np_global_rng", "dropout_rng",
+                "sampler_mid_rng"):
+        kind, keys, pos, has_gauss, gauss = meta[key]
+        assert kind == "MT19937" and keys is None
+    assert meta["sampler_mid_epoch"] is True
+    rest = _copies(it)
+    loader.close()
+    resumed = _cv_data_path("fed", 0.3)
+    next(iter(resumed))     # the image shape, read once by a draw
+    load_checkpoint(path, model, opt, sampler=resumed.sampler,
+                    loader=resumed)
+    again = _copies(resumed)
+    _assert_dealt(again, rest[1:])      # one round was in flight
+    resumed.close()
+    model.finalize()

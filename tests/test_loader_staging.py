@@ -7,8 +7,13 @@ own makes round r+1 and places it while round r computes
 What must hold: the same batches in the same order with the same RNG
 streams as the loader without read-ahead; one owner of sampler and
 ring at a time, whatever ends an epoch; errors raised from ``next()``;
-a rebuilt batch placed inline, as every batch used to be. Every wait
-here is under ``LIMIT`` seconds: a hang fails its test, not the run.
+a rebuilt batch placed inline, as every batch used to be. The thread is
+the loader's for its life: when an epoch's last round is dealt it opens
+the next epoch, which the next ``__iter__`` adopts, and a checkpoint
+reads the streams as they stood at the end (``held_back()``); a sampler
+on the ``np.random`` module is opened on the consumer's thread, one
+thread an epoch. Every wait here is under ``LIMIT`` seconds: a hang
+fails its test, not the run.
 """
 
 import gc
@@ -119,23 +124,30 @@ class _Placer:
 
 
 def _states(loader):
-    """Every RNG stream and counter a checkpoint reads off a loader."""
+    """Every RNG stream and counter a checkpoint reads off a loader,
+    as ``save_checkpoint`` reads them: between rounds, and what the
+    loader holds back of an epoch's end in place of what stands."""
+    loader.settle()
+    held = loader.held_back()
     ds_rng = getattr(loader.dataset, "_rng", None)
-    return {"sampler": loader.sampler.rng.get_state(),
-            "dropout": loader._dropout_rng.get_state(),
-            "numpy": np.random.get_state(),
-            "round_counter": getattr(loader, "_round_counter", None),
-            "dataset": None if ds_rng is None else ds_rng.getstate()}
+    live = {"sampler_rng": loader.sampler.rng.get_state(),
+            "dropout_rng": loader._dropout_rng.get_state(),
+            "np_global_rng": np.random.get_state(),
+            "loader_round_counter": getattr(loader, "_round_counter",
+                                            None),
+            "dataset_rng": None if ds_rng is None else ds_rng.getstate()}
+    return {k: v if held.get(k) is None else held[k]
+            for k, v in live.items()}
 
 
 def _set_states(loader, st):
-    loader.sampler.rng.set_state(st["sampler"])
-    loader._dropout_rng.set_state(st["dropout"])
-    np.random.set_state(st["numpy"])
-    if st["round_counter"] is not None:
-        loader._round_counter = st["round_counter"]
-    if st["dataset"] is not None:
-        loader.dataset._rng.setstate(st["dataset"])
+    loader.sampler.rng.set_state(st["sampler_rng"])
+    loader._dropout_rng.set_state(st["dropout_rng"])
+    np.random.set_state(st["np_global_rng"])
+    if st["loader_round_counter"] is not None:
+        loader._round_counter = st["loader_round_counter"]
+    if st["dataset_rng"] is not None:
+        loader.dataset._rng.setstate(st["dataset_rng"])
 
 
 def _assert_same(a, b):
@@ -171,12 +183,16 @@ def test_read_ahead_deals_the_same_rounds(tmp_path, kind, n_dev):
     want = _epochs(plain, 3)
     assert not any(isinstance(b, staging.StagedBatch)
                    for bs, _ in want for b in bs)
+    plain.close()       # the language-model loaders' host read-ahead
+    assert not _loader_threads()
 
     placer = _Placer(n_dev)
     ahead = _make(kind, str(tmp_path / "b"))
     ahead.placement = placer.place_batch
     got = _epochs(ahead, 3)
-    assert not _loader_threads()        # each epoch's thread went with it
+    # one thread for the three, with the fourth epoch opened on it;
+    # the states are what a checkpoint would have recorded at each end
+    assert len(_loader_threads()) == 1 and ahead.held_back()
     for (gb, gs), (wb, ws) in zip(got, want):
         assert len(gb) >= 3
         _assert_batches_equal(gb, wb)
@@ -194,17 +210,22 @@ def test_read_ahead_deals_the_same_rounds(tmp_path, kind, n_dev):
             assert ids.sharding.is_equivalent_to(
                 replicated(placer.mesh), 1)
             assert np.array_equal(np.asarray(ids), b["client_ids"])
-    plain.close()
     ahead.close()
+    assert not _loader_threads() and not ahead.held_back()
+    if kind == "native":
+        assert ahead._ring is None and ahead._epoch is None
 
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_however_an_epoch_ends_the_thread_goes_and_the_next_is_right(
         tmp_path, kind):
     """An epoch abandoned mid-way (closed, collected), a second
-    ``__iter__``, ``close()`` twice: the loader's thread is joined each
-    time, and the epoch after is the one a fresh loader deals from the
-    same RNG states (so nothing stale was left in ring or hand-over)."""
+    ``__iter__``, ``close()`` twice: with no successor opened the
+    loader's thread is joined each time, and the epoch after is the one
+    a fresh loader deals from the same RNG states (so nothing stale was
+    left in ring or hand-over). An epoch dealt whole leaves the thread
+    alive with the next one opened, which the next ``__iter__``
+    adopts."""
     placer = _Placer(1)
     loader = _make(kind, str(tmp_path / "a"))
     twin = _make(kind, str(tmp_path / "b"))       # no read-ahead
@@ -220,19 +241,22 @@ def test_however_an_epoch_ends_the_thread_goes_and_the_next_is_right(
         want = list(twin)
         _assert_batches_equal(got, want)
         _assert_same(_states(twin), end)
-        assert not _loader_threads()
+        twin.close()    # a language-model twin reads ahead on the host
+        assert len(_loader_threads()) == 1      # the next is opened
 
     # abandoned: closed
     it = iter(loader)
     next(it), next(it)
     assert len(_loader_threads()) == 1
     it.close()
+    assert not _loader_threads()
     check()
     # abandoned: dropped and collected, nothing ever closed it
-    it = iter(loader)
+    it = iter(loader)       # adopts the epoch check() left opened
     next(it)
     del it
     gc.collect()
+    assert not _loader_threads()
     check()
     # a second __iter__ retires the first, whose next() raises
     first = iter(loader)
@@ -244,7 +268,7 @@ def test_however_an_epoch_ends_the_thread_goes_and_the_next_is_right(
         next(first)
     rest = list(second)
     assert isinstance(b0, staging.StagedBatch) and len(rest) >= 2
-    assert not _loader_threads()
+    assert len(_loader_threads()) == 1
     check()
     # close() mid-epoch, twice
     it = iter(loader)
@@ -468,3 +492,187 @@ def test_no_placement_with_no_model_live_or_with_several():
     assert staging.current() is None
     _, recs, kinds = _fed_run(async_buffer_size=2)
     assert _h2d(recs) == [(0, 1)] * 3 and kinds == [dict] * 3
+
+
+# --- across an epoch's end ---------------------------------------------
+
+
+def _drive(loader, epochs):
+    """The trainers' loop over ``epochs`` epochs with a recorder of its
+    own: round r's record is open while batch r + 1 is fetched. Returns
+    the batches per epoch and the records by round."""
+    from commefficient_tpu.telemetry import Telemetry
+    sink = ListSink()
+    loader.telemetry = tel = Telemetry([sink])
+    r, out = 0, []
+    tel.begin_round(r)
+    for _ in range(epochs):
+        out.append([])
+        it = iter(loader)
+        while True:
+            with tel.span("sampler"):
+                batch = next(it, None)
+            if batch is None:
+                break
+            out[-1].append(batch)
+            tel.set_round_bytes(r, 0.0, 0.0)
+            r += 1
+            tel.begin_round(r)
+    loader.settle()
+    tel.set_round_bytes(r, 0.0, 0.0)
+    tel.close()
+    return out, {x["round"]: x for x in sink.records}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_an_opened_epoch_is_adopted_once(tmp_path, kind):
+    """From the second epoch on every ``__iter__`` takes the epoch the
+    thread opened: ``data.epoch_preopened`` beside ``data.epoch_start``
+    on the same record, one ``data.epoch_open`` an epoch on the loader's
+    thread, with no parent, around the ``data.sample`` that opens; the
+    consumer's thread opens the first epoch and no other."""
+    loader = _make(kind, str(tmp_path))
+    loader.placement = _Placer(1).place_batch
+    epochs, recs = _drive(loader, 3)
+    n = [len(e) for e in epochs]
+    firsts = [0, n[0], n[0] + n[1]]
+    starts = {r: rec["counters"].get("data.epoch_start", 0)
+              for r, rec in recs.items()}
+    adopted = {r: rec["counters"].get("data.epoch_preopened", 0)
+               for r, rec in recs.items()}
+    assert [r for r, c in starts.items() if c] == firsts
+    assert all(starts[r] == 1 for r in firsts)
+    assert [r for r, c in adopted.items() if c] == firsts[1:]
+    assert sum(adopted.values()) / (sum(starts.values()) - 1) == 1.0
+    tl = [(rec["round"], i, e) for rec in recs.values()
+          for i, e in enumerate(rec["timeline"])]
+    opens = [(r, i, e) for r, i, e in tl if e[0] == "data.epoch_open"]
+    assert len(opens) == 3              # the fourth is opened, unadopted
+    for r, i, e in opens:
+        assert e[3] is None and e[4] in THREADS
+        inside = [k for rr, _, k in tl
+                  if rr == r and k[3] == i and k[4] == e[4]]
+        assert [k[0] for k in inside] == ["data.sample"]
+    samples = {e[4] for _, _, e in tl if e[0] == "data.sample"}
+    assert samples == {"MainThread", loader._thread_name}
+    main = [r for r, _, e in tl
+            if e[0] == "data.sample" and e[4] == "MainThread"]
+    assert main == [0]                  # the first opening, and only it
+    loader.close()
+    assert not _loader_threads()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_sampler_on_the_numpy_module_opens_on_the_consumers_thread(
+        tmp_path, kind):
+    """``seed=None``: the sampler shares ``np.random`` with whoever else
+    draws from it, so nothing of it is drawn early from another thread.
+    One thread an epoch, gone with it, nothing held back, and the rounds
+    of the loader without read-ahead."""
+    def make(root):
+        loader = _make(kind, root)
+        loader.sampler.rng = np.random          # as seed=None leaves it
+        return loader
+
+    plain, loader = make(str(tmp_path / "a")), make(str(tmp_path / "b"))
+    plain.placement = None
+    loader.placement = _Placer(1).place_batch
+    assert not loader._opens_ahead()
+    np.random.seed(21)
+    want = [list(plain), list(plain)]
+    plain.close()
+    np.random.seed(21)
+    got, recs = _drive(loader, 2)
+    assert not _loader_threads() and loader._reader is None
+    assert not loader.held_back()
+    for g, w in zip(got, want):
+        _assert_batches_equal(g, w)
+    assert not any("data.epoch_preopened" in rec["counters"]
+                   or "data.epoch_open" in rec["spans"]
+                   for rec in recs.values())
+    opening = [rec["round"] for rec in recs.values()
+               for e in rec["timeline"]
+               if e[0] == "data.sample" and e[4] == "MainThread"]
+    assert opening == [0, len(got[0])]
+    loader.close()
+
+
+@pytest.mark.parametrize("kind", ["fed", pytest.param(
+    "native", marks=pytest.mark.skipif(
+        not native.available(), reason="no native toolchain"))])
+def test_an_error_while_opening_ahead_is_raised_from_the_next_epochs_next(
+        tmp_path, kind, monkeypatch):
+    """The epoch that is ending is dealt whole, the native ring's rounds
+    included; the error waits for the ``next()`` that would have entered
+    the epoch that could not be opened."""
+    loader = _make(kind, str(tmp_path))
+    loader.placement = _Placer(1).place_batch
+    real, calls = type(loader.sampler).__iter__, []
+
+    def second_fails(sampler):
+        calls.append(1)
+        if len(calls) == 2:
+            raise ValueError("the sampler broke")
+        return real(sampler)
+    monkeypatch.setattr(type(loader.sampler), "__iter__", second_fails)
+    assert len(list(loader)) == 8
+    loader.settle()
+    assert len(calls) == 2 and loader.held_back()
+    it = iter(loader)
+    with pytest.raises(ValueError, match="the sampler broke"):
+        next(it)
+    assert next(it, None) is None
+    assert not _loader_threads() and not loader.held_back()
+    assert len(list(loader)) == 8       # and the loader is not broken
+    loader.close()
+
+
+@pytest.mark.parametrize("taken", [6, 8])
+@pytest.mark.parametrize("kind", KINDS[:2])
+def test_an_abandoned_epoch_whose_successor_is_opened_is_adopted(
+        tmp_path, kind, taken):
+    """``--test`` breaks out of an epoch after a round, a fractional
+    last epoch after some: where the sampler had dealt the epoch's last
+    round by then (all 8 taken; 6 of them with the native ring's 3
+    rounds of depth), the thread has the next epoch opened, and the
+    ``__iter__`` after the abandoned one adopts it: no second opening
+    is drawn. What follows is the loader without read-ahead's next
+    epoch."""
+    plain = _make(kind, str(tmp_path / "a"))
+    np.random.seed(4)
+    list(plain)
+    want = list(plain)
+    loader = _make(kind, str(tmp_path / "b"))
+    loader.placement = _Placer(1).place_batch
+    np.random.seed(4)
+    it = iter(loader)
+    for _ in range(taken):
+        next(it)
+    loader.settle()
+    opened = bool(loader.held_back())
+    assert opened == (taken == 8 or kind == "native")
+    it.close()
+    assert len(_loader_threads()) == opened
+    if opened:
+        _assert_batches_equal(list(loader), want)
+    loader.close()
+    plain.close()
+    assert not _loader_threads()
+
+
+def test_the_participant_feed_sees_past_an_epochs_end(tmp_path):
+    """At an epoch's last round the feed answers with the first round of
+    the epoch opened ahead, which the next ``__iter__`` hands over."""
+    loader = _make("fed", str(tmp_path))
+    loader.placement = _Placer(1).place_batch
+    it = iter(loader)
+    for _ in range(8):
+        last = next(it)
+    loader.settle()
+    peeked = loader.peek_next_client_ids()
+    assert peeked is not None and next(it, None) is None
+    assert np.array_equal(peeked, loader.peek_next_client_ids())
+    first = next(iter(loader))
+    assert np.array_equal(peeked, first["client_ids"])
+    assert not np.array_equal(last["x"], first["x"])
+    loader.close()

@@ -546,3 +546,82 @@ def test_ring_reset_discards_what_was_submitted():
                     for a, b in zip(pf.pop(), want):
                         np.testing.assert_array_equal(a, b)
     _within(60, body)
+
+
+# --- the loader's thread, across an epoch's end --------------------------
+
+
+def _place(batch):
+    """A placement as a loader sees one: any callable of the batch. With
+    one, the loader makes its batches on a thread of its own, which
+    opens the next epoch while this one is dealt (data/loader.py)."""
+    return {k: np.array(v) for k, v in batch.items()}
+
+
+def _stage_threads():
+    return [t for t in threading.enumerate() if t.name == "loader-stage"]
+
+
+@pytest.mark.parametrize("tf", [AUG, PLAIN], ids=["aug", "plain"])
+def test_the_ring_does_not_drain_where_the_next_epoch_is_opened(tf, rings):
+    """Read ahead, the next epoch's first ``depth`` + 1 rounds are in
+    the ring when this one's last is taken, and what a checkpoint would
+    record there is the boundary, not where the thread stands. The three
+    epochs are those of a ring an epoch, bit for bit."""
+    def body():
+        ds = _dataset(tf)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), seed=11)
+        loader.placement = _place
+        got = []
+        for e in range(3):
+            it = iter(loader)
+            got.append([_copy(next(it)) for _ in range(ROUNDS)])
+            loader.settle()
+            # the last round is taken, not yet the end: the thread has
+            # submitted depth rounds of the next epoch and made one more
+            held = loader.held_back()
+            assert held["loader_round_counter"] == ROUNDS * (e + 1)
+            assert loader._round_counter \
+                == ROUNDS * (e + 1) + loader.depth + 1
+            assert "dropout_rng" in held and "np_global_rng" not in held
+            assert next(it, None) is None
+        assert len(rings) == 1 and len(_stage_threads()) == 1
+        loader.close()
+        for mine, theirs in zip(got, _ring_per_epoch(tf, 11, [None] * 3)):
+            assert len(mine) == len(theirs)
+            for a, b in zip(mine, theirs):
+                _same(a, b)
+    _within(60, body)
+
+
+@pytest.mark.skipif(not os.path.isdir("/proc/self/task"),
+                    reason="needs /proc")
+def test_close_drops_an_epoch_nobody_adopted(rings):
+    """The run's last epoch has a successor opened too: ``close()``
+    stops the loader's thread, which empties the ring it owns, and then
+    the ring goes; nothing is left running, and the loader reopens."""
+    def body():
+        gc.collect()
+        before = _ring_threads()
+        ds = _dataset(AUG)
+        loader = NativeFedLoader(ds, _sampler(ds, seed=5), seed=3)
+        loader.placement = _place
+        assert len(list(loader)) == ROUNDS
+        loader.settle()
+        ring = loader._ring
+        assert loader.held_back() and len(_stage_threads()) == 1
+        resets = []
+        real = ring.reset
+        ring.reset = lambda: (resets.append(1), real())[1]
+        loader.close()
+        assert resets == [1]            # by the thread that owned it
+        assert loader._ring is None and loader._epoch is None
+        assert loader._reader is None and not loader.held_back()
+        assert not _stage_threads()
+        assert _ring_threads(before) == before
+        loader.close()
+        # (a third epoch by the sampler's count: 7 or 8 whole rounds)
+        assert len(list(loader)) >= ROUNDS - 1 and len(rings) == 2
+        loader.close()
+        assert not _stage_threads()
+    _within(60, body)
