@@ -1,6 +1,6 @@
 """Dtype-aware wire-byte accounting.
 
-One home for every byte-width decision the ledger, cost model and
+One home for every byte-width decision the ledger and the
 auditor make. Before the quantized wire path every accounting site
 hardcoded ``* 4`` (f32); now the uplink table, its per-row scales and
 the downlink payload each carry their own dtype, so the arithmetic

@@ -6,7 +6,7 @@ onto the wire format: ``ops/quant.py`` (encode/decode) and
 ``parallel/wire.py`` (the collective that moves the encoded bytes).
 A stray ``.astype(jnp.int8)`` anywhere else is an unaccounted
 quantization — it changes recovery error and wire bytes without the
-autopilot, the accountant, or the perf gate seeing it. Likewise the
+autopilot, the accountant, or the registry seeing it. Likewise the
 byte-width tables (``{"int8": 1, ...}``) live in ``accounting.py``
 and ``config.py`` only; a private copy silently forks the pricing.
 

@@ -64,24 +64,6 @@ _DOT_RE = re.compile(
     r"stablehlo\.(dot_general|convolution)\b[^\n]*?:\s*"
     r"\(tensor<([^>]*)>,\s*tensor<([^>]*)>\)")
 
-# dot_general with its full dimension-number + type signature:
-# ``contracting_dims = [1] x [0] : (tensor<2x64xf32>,
-# tensor<64x32xf32>) -> tensor<2x32xf32>``
-_DOT_FLOPS_RE = re.compile(
-    r"stablehlo\.dot_general\b[^\n]*?"
-    r"contracting_dims\s*=\s*\[([0-9,\s]*)\]\s*x\s*\[[0-9,\s]*\]"
-    r"[^\n]*?:\s*\(tensor<([^>]*)>,\s*tensor<([^>]*)>\)\s*"
-    r"->\s*tensor<([^>]*)>")
-
-# convolution with its dim_numbers kernel spec (``x[0, 1, i, o]->``)
-# and type signature — the ``o`` position locates the output-feature
-# dim of the kernel shape
-_CONV_FLOPS_RE = re.compile(
-    r"stablehlo\.convolution\b[^\n]*?"
-    r"x\[([^\]]*)\]\s*->\s*\[[^\]]*\]"
-    r"[^\n]*?:\s*\(tensor<([^>]*)>,\s*tensor<([^>]*)>\)\s*"
-    r"->\s*tensor<([^>]*)>")
-
 
 def parse_shape(dtype: str, dims: str) -> Tuple[str, Tuple[int, ...], int]:
     """``("f32", "5,16")`` -> (dtype, (5, 16), byte size)."""
@@ -214,69 +196,6 @@ def dot_dtype_inventory(stablehlo_text: str) -> Dict[str, int]:
         elem = lhs.rsplit("x", 1)[-1] if "x" in lhs else lhs
         counts[elem] = counts.get(elem, 0) + 1
     return counts
-
-
-def _tensor_dims(spec: str) -> Tuple[Tuple[int, ...], str]:
-    """``"2x64xf32"`` -> ((2, 64), "f32"); ``"f32"`` -> ((), "f32")."""
-    parts = spec.strip().split("x")
-    dtype = parts[-1]
-    dims = tuple(int(p) for p in parts[:-1])
-    return dims, dtype
-
-
-def _numel(dims: Tuple[int, ...]) -> int:
-    n = 1
-    for d in dims:
-        n *= d
-    return n
-
-
-def flop_inventory(stablehlo_text: str) -> Dict:
-    """Multiply-add FLOP estimate for every dot_general/convolution in
-    a lowered module (counted as 2 FLOPs per MAC, the roofline
-    convention).
-
-    * dot_general: 2 x numel(result) x prod(lhs contracting dims) —
-      exact for any batching/contracting layout, since every result
-      element is one length-K inner product.
-    * convolution: 2 x numel(result) x (numel(kernel) / O) where O is
-      the kernel's output-feature dim (from the ``x[...]`` dim-numbers
-      spec) — each output element contracts over the kernel's spatial
-      x input-feature extent. Exact for dense convs; an upper bound
-      under feature-group counts (rare here).
-
-    Returns ``{"dot_flops", "conv_flops", "total_flops", "dot_count",
-    "conv_count", "by_dtype": {elem: flops}}``.
-    """
-    dot_flops = conv_flops = 0
-    dot_count = conv_count = 0
-    by_dtype: Dict[str, int] = {}
-    for m in _DOT_FLOPS_RE.finditer(stablehlo_text):
-        lhs_contract, lhs_spec, _rhs_spec, out_spec = m.groups()
-        lhs_dims, dtype = _tensor_dims(lhs_spec)
-        out_dims, _ = _tensor_dims(out_spec)
-        k = 1
-        for idx in (int(x) for x in lhs_contract.split(",") if
-                    x.strip()):
-            k *= lhs_dims[idx]
-        f = 2 * _numel(out_dims) * k
-        dot_flops += f
-        dot_count += 1
-        by_dtype[dtype] = by_dtype.get(dtype, 0) + f
-    for m in _CONV_FLOPS_RE.finditer(stablehlo_text):
-        kern_spec, _lhs_spec, rhs_spec, out_spec = m.groups()
-        rhs_dims, dtype = _tensor_dims(rhs_spec)
-        out_dims, _ = _tensor_dims(out_spec)
-        o_pos = [p.strip() for p in kern_spec.split(",")].index("o")
-        o = rhs_dims[o_pos]
-        f = 2 * _numel(out_dims) * (_numel(rhs_dims) // max(o, 1))
-        conv_flops += f
-        conv_count += 1
-        by_dtype[dtype] = by_dtype.get(dtype, 0) + f
-    return {"dot_flops": dot_flops, "conv_flops": conv_flops,
-            "total_flops": dot_flops + conv_flops,
-            "dot_count": dot_count, "conv_count": conv_count,
-            "by_dtype": by_dtype}
 
 
 _LOC_LINE = re.compile(r"^#loc")

@@ -123,7 +123,7 @@ def parse_band(band: str) -> Tuple[float, float]:
 
 
 def band_str(band: Tuple[float, float]) -> str:
-    """Canonical compact spelling, shared with the perf-gate topology
+    """Canonical compact spelling, shared with the registry's topology
     fragment: ``(0.2, 0.6) -> "0.2-0.6"`` (``:`` is not filename- or
     key-safe)."""
     def fmt(x: float) -> str:

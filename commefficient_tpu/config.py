@@ -291,15 +291,15 @@ class Config:
     # cross-entropy (models/gpt2.py lm_nll_sums_chunked) — the
     # vocab-head temp memory scales with this chunk, not the sequence.
     # 0 = auto: 256 on the sequence-parallel path (the measured memory
-    # knee, BENCHMARKS.md SP table), 1024 on the single-device path
+    # knee, core/rounds_sp.py), 1024 on the single-device path
     # (throughput-flat across 512-4096 at the 8x geometry).
     tokens_per_chunk: int = 0
     # GPT-2: fused-linear-CE vocab head (ops/flce_pallas.py) — the
     # per-chunk logits round-trips of the chunked path go away
     # entirely. "auto" = Pallas kernels on a TPU default backend at
     # lane-aligned widths, chunked elsewhere; "on"/"off" force.
-    # Default off pending the on-chip A/B (scripts/gpt2_bench.py
-    # --fused_ce).
+    # Default off: no cell can show it winning where it is wired
+    # (ROADMAP S4).
     fused_ce: str = "off"
     # Per-client state placement (commefficient_tpu/clientstore):
     # "device" keeps the dense (num_clients, *transmit_shape) arrays in
@@ -1026,7 +1026,7 @@ def build_parser(default_lr: Optional[float] = None,
                         "of this lane width (-1 = auto: 1024 on TPU "
                         "at large-d Pallas-eligible geometries, else "
                         "0; 0 = force full granularity); speeds the "
-                        "Pallas kernels' rolls, see BENCHMARKS.md")
+                        "Pallas kernels' rolls")
     parser.add_argument("--sketch_dtype", type=str, default="f32",
                         choices=list(SKETCH_DTYPES),
                         help="wire dtype of the uplinked sketch "

@@ -107,7 +107,7 @@ def resolve_rot_lanes(cfg: Config) -> int:
     large-d geometry (−44% on the sketch/estimates kernel pair at
     d=124M, −8% on the flagship GPT-2 federated round; 24-epoch
     anchor tail accuracy at parity with full-granularity rotations at
-    both seeds — BENCHMARKS.md round-5 sections). Everywhere else
+    both seeds: round 5's chip, another machine). Everywhere else
     auto resolves to 0 (full granularity). Explicit values pass
     through untouched. The default-backend probe lives here, NOT in
     CountSketch.__post_init__: round build runs after any
@@ -179,7 +179,7 @@ def round_plan(cfg: Config) -> dict:
     plan["downlink_encoding"] = getattr(cfg, "downlink_encoding",
                                         "dense")
     if getattr(cfg, "dp", "off") != "off":
-        # enough to re-derive the accountant (and the perf-gate's
+        # enough to re-derive the accountant (and the registry's
         # p<eps> key fragment) from the ledger alone
         plan["dp"] = {"mode": str(cfg.dp),
                       "clip": float(cfg.dp_clip),

@@ -94,7 +94,7 @@ def build_sp_gpt2_round(cfg: GPT2Config, mesh: Mesh,
     model = GPT2DoubleHeads(sp_cfg)
     ignore = ignore_index
     # 0 = auto: 256 tokens/chunk — the measured knee of the SP
-    # temp-memory table (BENCHMARKS.md / scripts/sp_mem_bench.py:
+    # temp-memory table (round 5, compiled temporaries on the CPU:
     # 0.89 GB vs 1.20 GB at the old 1024 default and 1.91 GB for the
     # dense-equivalent full-shard chunk at T_local=1024; within noise
     # of 128) and throughput-flat. --tokens_per_chunk overrides.

@@ -314,7 +314,7 @@ def _sketched(cfg, sketched_grad, state, lr, sketch, noise_rng,
     # (d,) update itself is never materialised (with_dense=False).
     # In the dense regime, exact recovery uses the threshold-select
     # mask instead of the top-k sort (22.3 -> ~11 ms full round at
-    # ResNet9 scale, BENCHMARKS.md).
+    # ResNet9 scale, rounds 1-5's chip).
     sparse = sketch.prefer_sparse_resketch(cfg.k)
     # pre-mask residual mass for the coverage probe: the true dense
     # residual never exists in sketch mode, so its energy comes from

@@ -242,10 +242,10 @@ class ArrivalSchedule:
     """Seeded, replayable per-client ARRIVAL process — when each
     issued client's update actually lands, in fold-step units.
 
-    This is the arrival-side twin of the dropout trace above,
-    promoted out of ``scripts/host_scale_bench.py`` so benches,
-    tests and the asyncfed driver all replay the same schedule from
-    one seed. Three kinds:
+    This is the arrival-side twin of the dropout trace above, kept
+    in one place so that tests and the asyncfed driver all replay
+    the same schedule from one seed.
+    Three kinds:
 
     ``uniform``
         Every client arrives the round it was issued (delay 0) —
@@ -332,8 +332,8 @@ class ArrivalSchedule:
     @staticmethod
     def replay_stats(alive: Sequence[float], cohort: int) -> dict:
         """Burst statistics of a replayed trace, from the per-round
-        alive fractions a run observed. Exactly the summary
-        ``host_scale_bench`` reports (the bench now calls this)."""
+        alive fractions a run observed: bursts, their lengths, the
+        least and mean alive share, client-rounds dropped."""
         alive = [float(a) for a in alive]
         ragged = [a for a in alive if a < 1.0]
         burst_rounds, bursts, in_burst = 0, 0, False

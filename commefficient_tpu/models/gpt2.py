@@ -59,8 +59,8 @@ class GPT2Config:
     # single-chip attention lowering: "xla" = jax.nn.dot_product_
     # attention (XLA fusion), "flash" = the Pallas TPU flash-attention
     # kernel (jax.experimental.pallas.ops.tpu.flash_attention) — the
-    # model-side kernel experiment; measured head-to-head in
-    # BENCHMARKS.md (scripts/gpt2_bench.py --attn_impl)
+    # model-side kernel experiment; no cell rules on it yet
+    # (ROADMAP S11 (c), D7)
     attn_impl: str = "xla"
     # rematerialise each transformer block's activations in the
     # backward pass (jax.checkpoint): peak activation memory drops
@@ -120,7 +120,7 @@ class CausalSelfAttention(nn.Module):
             # by block // 128 and traces to a broadcasting error at
             # unaligned T (reproduced at T=8/64/200 on jax 0.9.0) —
             # and at short T the XLA lowering wins anyway
-            # (BENCHMARKS.md flash table)
+            # (rounds 1-5's chip)
             from jax.experimental.pallas.ops.tpu.flash_attention import (
                 BlockSizes, flash_attention)
             # kernel layout is (B, H, T, hd); scale explicitly — the

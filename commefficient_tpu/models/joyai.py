@@ -5,8 +5,8 @@ untied embedding and head over a vocabulary slice.
 
 The block is DeepSeek-V3's (arXiv:2412.19437) as JoyAI-LLM-Flash's
 ``config.json`` sizes it; the equations are restated in
-``models/joyai_reference.py``, the plain float32 reference this module
-is tested against. What is TPU-shaped here:
+``benchmark/reference/joyai-llm-flash-ep32.py``, the plain float32
+reference this module is tested against. What is TPU-shaped here:
 
 - **The expert layer is the expert-parallel layer on one chip**
   (``models/moe.py``, shared with ``models/nemotron_h.py``): it is told
@@ -98,7 +98,7 @@ class JoyAIConfig:
         return JoyAIConfig(**kw)
 
     def reference_spec(self) -> dict:
-        """The same sizes under the keys ``joyai_reference`` reads."""
+        """The same sizes under the keys the reference reads."""
         spec = {f.name: getattr(self, f.name)
                 for f in dataclasses.fields(self)
                 if f.name not in ("dtype", "remat", "n_router_experts",

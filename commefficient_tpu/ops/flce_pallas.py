@@ -353,7 +353,7 @@ def lm_nll_sums_fused(h, wte, labels, dtype, ignore_index=-100,
     exceed _DXP_LIMIT across ``batch_mult`` concurrent vmapped calls,
     and — unless ``interpret`` — on non-TPU default backends, where
     the Mosaic kernels cannot lower. The fallback warns once per
-    reason: it used to be silent, so flce_bench could 'measure' the
+    reason: it used to be silent, so a timing could 'measure' the
     chunked path against itself."""
     e, tm, c = h.shape
     reason = fused_fallback_reason(e, tm, c, wte.shape[0], dtype,

@@ -131,8 +131,8 @@ class CountSketch:
     # with probability rot_lanes/c instead of 1/c; all other cross-
     # chunk pairs never collide. The AVERAGE per-pair collision rate
     # stays 1/c, so expected recovery error is unchanged while the
-    # tail is heavier — quality measured in scripts/rot_quality.py
-    # and BENCHMARKS.md before any default changes. 0 = off (full-
+    # tail is heavier (round 5 measured recovery quality before the
+    # auto default, core/rounds.py, was set). 0 = off (full-
     # granularity rotations, the reference-quality default).
     rot_lanes: int = 0
     # stream precomputed packed sign bits ((padded_d,) uint8, bit row
@@ -653,7 +653,7 @@ class CountSketch:
         """Dense-regime exact recovery via the threshold mask: wins
         once d is large enough that lax.top_k lowers to an expensive
         full sort (~13 ms extra per round at ResNet9's d=6.6M,
-        BENCHMARKS.md; the mask's `select` scope reads 1.18 ms there,
+        rounds 1-5; the mask's `select` scope reads 1.18 ms there,
         PERF.md section 5). Approximate recovery (approx_topk) stays
         on the index path — approx_max_k is cheaper than the nibble
         search's count passes; and the sparse-resketch regime needs

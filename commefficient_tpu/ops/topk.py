@@ -21,7 +21,7 @@ import jax.numpy as jnp
 # extraction does not), run over all of d (flat) or, where the k
 # candidate blocks are a small part of d, over those alone (two-level,
 # PR 33). On one v5e chip at k = 50,000, the selection and the gather
-# of its values alone (scripts/select_probe.py, my chip run, PR 33):
+# of its values alone (PR 33's probe, my chip run; PERF.md section 6):
 # flat 34.9 / 87.0 / 157.2 ms and two-level 15.9 / 19.6 / 22.5 ms at
 # d = 124M / 376M / 701M. Only batched index selections and
 # approx_max_k requests remain on the XLA primitives.
@@ -298,8 +298,8 @@ def _flat_topk_indices(sq: jax.Array, k: int,
 # TPU, so the (d/128, 128) view costs nothing, and d/b + k·b, the flat
 # work that is left, is least at b = sqrt(d/k) = 50 … 118 for the
 # benchmark's cells; 256 / 512 / 1024 read 28.0 / 27.6 / 34.6 ms where
-# 128 reads 22.5 at d = 701M (scripts/select_probe.py, my chip run,
-# PR 33: PERF.md section 6). The ratio: a flat index selection costs
+# 128 reads 22.5 at d = 701M (PR 33's probe, my chip run: PERF.md
+# section 6). The ratio: a flat index selection costs
 # about 4.5 ms + 0.24 ms a million coordinates, the two-level form
 # two small ones + one read of d (4.6 ms at 701M), which crosses near
 # d = 6·k·128.
@@ -402,7 +402,7 @@ def topk(vec: jax.Array, k: int, approx: bool = False,
     count passes) feeds a ``where``, no sort and no gather/scatter —
     which measures faster than even ``approx_max_k`` + scatter while
     being exact (127 → 20 ms for the full local_topk round at ResNet9
-    scale, BENCHMARKS.md). ``approx`` therefore only affects dense
+    scale, rounds 1-5's chip). ``approx`` therefore only affects dense
     selections below the threshold size; the index-producing
     selections (unsketch recovery) still honor it everywhere."""
     k = min(k, vec.shape[-1])
@@ -422,7 +422,7 @@ def topk(vec: jax.Array, k: int, approx: bool = False,
                 logging.getLogger(__name__).info(
                     "approx=True ignored for dense selection at d=%d "
                     ">= %d: the exact threshold-select path is faster "
-                    "than the approximate sort (BENCHMARKS.md); "
+                    "than the approximate sort; "
                     "selected sets differ from pre-threshold-select "
                     "builds", vec.shape[-1], _THRESHOLD_SELECT_MIN_D)
         take = _threshold_topk_mask(jax.lax.square(vec), k)

@@ -201,7 +201,7 @@ def first_local_device() -> jax.Device:
 
 def topology_summary() -> dict:
     """The run's device topology, as recorded by run manifests and
-    ledger meta records (and used to key perf-gate baselines):
+    ledger meta records (the registry's run_key reads the counts):
     ``{device_count, local_device_count, process_index, process_count,
     backend, device_kind}``. A backend that will not initialise
     raises: a made-up topology on a record is worse than no record."""

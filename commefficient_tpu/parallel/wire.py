@@ -56,7 +56,7 @@ def row_chunks(r: int, depth: int):
     ``[(offset, count), ...]``. Depth is clamped (never an error) so
     one sweep flag works across geometries; clamped depths still name
     distinct programs (an o4 run of a 3-row table is 3 chunks — a
-    different program from o2's 2, so the perf-gate ``o<N>`` keys
+    different program from o2's 2, so the registry's ``o<N>`` keys
     stay honest). Chunks are disjoint row ranges: the collective over
     each composes with per-row quantization scales exactly, so the
     chunked fold is bit-identical to the whole-table crossing."""

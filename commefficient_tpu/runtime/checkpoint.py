@@ -148,8 +148,8 @@ def resume_manifest_extra(model) -> dict:
     entry per topology the lineage has run under, the restored chain
     plus the current segment). Empty for unresumed runs, so trainers
     can unconditionally splat it into ``maybe_write_manifest``'s
-    extra. The perf gate refuses to resolve a pin when the segments
-    span more than one topology (telemetry/registry.py
+    extra. A lineage whose segments span more than one topology is
+    flagged, never read as one experiment (telemetry/registry.py
     run_topology_changed)."""
     info = getattr(model, "_resume_info", None)
     if not info:
@@ -282,8 +282,8 @@ def save_checkpoint(path: str, model, opt, scheduler=None,
         # elastic-pod lineage: the topology this archive was written
         # under, plus the chain of earlier segments a resumed run
         # restored through — restore migrates placement whenever the
-        # reader's topology differs, and manifests/perf-gate use the
-        # segment list to refuse cross-topology pin resolution
+        # reader's topology differs, and the manifests' segment list
+        # flags a ledger that spans topologies
         "topology": current_topology(getattr(model, "mesh", None)),
         "segments": (list(getattr(model, "_restored_segments", []))
                      + [{**current_topology(getattr(model, "mesh",

@@ -389,9 +389,6 @@ class FedModel:
         # Its cumulative ε lands on the schema-v5 ledger keys and feeds
         # the privacy_budget_exhausted alarm. None with --dp off.
         self._accountant = build_accountant(args)
-        # roofline cost model (analysis/cost.py), computed lazily at
-        # the first --profile'd round from the lowered round program
-        self._cost_model = None
         from commefficient_tpu.parallel import mesh as mesh_lib
         topo = mesh_lib.topology_summary()
         # live operations plane (telemetry/live.py + flightrec.py):
@@ -739,12 +736,6 @@ class FedModel:
                  else (shard_batch(self.mesh, jnp.asarray(staleness)),))
         rargs = (self.ps_weights, cs_in, dev_batch, ids, rng,
                  jnp.float32(self.fedavg_lr)) + sargs
-        if (self._cost_model is None and tel.enabled
-                and getattr(args, "do_profile", False)):
-            # roofline expectation from this round's lowered program —
-            # once per run, text-only (no second compile; always the
-            # jit wrapper — AOT executables don't re-lower)
-            self._emit_cost_model(jit_fn, rargs)
         if self._round_abstract is None:
             # uncommitted arguments (the round key, the scalar LR) stay
             # unplaced: their default-device sharding would clash with
@@ -1035,39 +1026,6 @@ class FedModel:
         ``autopilot`` block), or None with the autopilot off."""
         return (None if self._autopilot is None
                 else self._autopilot.record())
-
-    def _emit_cost_model(self, round_fn, round_args):
-        """Roofline expectation for this run's round program
-        (analysis/cost.py): lower the jitted round with the first
-        profiled round's concrete arguments — text only, the XLA
-        compile is NOT repeated — count its dot/conv FLOPs and emit
-        the cost model as a ledger meta record. Registers
-        ``expected_round_s`` on the telemetry so the trace window's
-        device-time buckets carry ``roofline_utilization``. Any
-        failure degrades to a warning; the marker stays set so it is
-        not retried every round."""
-        self._cost_model = {}
-        try:
-            from commefficient_tpu.analysis.cost import build_cost_model
-            text = round_fn.lower(*round_args).as_text()
-            n_dev = int(np.prod(self.mesh.devices.shape))
-            dev0 = self.mesh.devices.flat[0]
-            cost = build_cost_model(
-                text,
-                backend=jax.default_backend(),
-                device_kind=getattr(dev0, "device_kind", ""),
-                n_devices=n_dev,
-                allreduce_payload_bytes=float(
-                    self.args.upload_wire_bytes_per_client),
-                wire_dtype=getattr(self.args, "sketch_dtype", "f32"),
-                label=(f"{self.args.mode}/{self.clientstore}/"
-                       f"{n_dev}dev"))
-            self._cost_model = cost
-            self.telemetry.expected_round_s = cost["expected_round_s"]
-            self.telemetry.emit_meta(cost_model=cost)
-        except Exception as e:  # noqa: BLE001 — observability only
-            print(f"WARNING: roofline cost model skipped "
-                  f"({type(e).__name__}: {e})")
 
     def _rebuild_round_counts(self):
         """Histogram of ``last_updated`` by round (index = round + 1).

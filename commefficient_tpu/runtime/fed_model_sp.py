@@ -148,10 +148,6 @@ class SeqParallelFedModel(FedModel):
         if (self._sp_round_probed is not None
                 and ridx % self.probe_period == 0):
             round_fn = self._sp_round_probed
-        if (self._cost_model is None and tel.enabled
-                and getattr(self.args, "do_profile", False)):
-            self._emit_cost_model(round_fn,
-                                  (self.ps_weights, sp_batch))
         with tel.span("round_dispatch"):
             agg, per_client_loss, probes = round_fn(self.ps_weights,
                                                     sp_batch)

@@ -20,7 +20,6 @@ from commefficient_tpu.telemetry.core import (NULL_TELEMETRY, Telemetry,
                                               set_current, setup_span,
                                               setup_spans)
 from commefficient_tpu.telemetry.record import (LEDGER_SCHEMA_VERSION,
-                                                make_bench_record,
                                                 make_meta_record,
                                                 make_round_record,
                                                 validate_record)
@@ -34,7 +33,6 @@ from commefficient_tpu.telemetry.live import (LiveMetricsSink,
                                               shutdown_plane)
 from commefficient_tpu.telemetry.sinks import (ConsoleSink, JSONLSink,
                                                TensorBoardSink,
-                                               append_bench_record,
                                                job_index_of_ledger,
                                                job_ledger_path,
                                                recover_ledger_shards)
@@ -54,14 +52,12 @@ __all__ = [
     "hbm_peak_bytes",
     "hbm_reserved_peak_bytes",
     "LEDGER_SCHEMA_VERSION",
-    "make_bench_record",
     "make_meta_record",
     "make_round_record",
     "validate_record",
     "ConsoleSink",
     "JSONLSink",
     "TensorBoardSink",
-    "append_bench_record",
     "job_ledger_path",
     "job_index_of_ledger",
     "recover_ledger_shards",

@@ -403,10 +403,6 @@ class Telemetry:
         # sinks. Round ORDER is unchanged — the hold only delays the
         # drain.
         self._hold = False
-        # expected lower-bound round seconds (analysis/cost.py),
-        # registered by FedModel under --profile; merged device-time
-        # buckets derive roofline_utilization from it
-        self.expected_round_s = None
         # optional callback(round_index, buckets) invoked when trace
         # buckets merge — FedModel points it at the alarm engine's
         # collective-skew check so trace-derived skew can escalate
@@ -618,19 +614,11 @@ class Telemetry:
     def merge_round_device_time(self, index: int, buckets: dict):
         """Attach trace-derived device-time buckets (schema v3) to
         round ``index``'s record — called by the trace window at exit,
-        while ``hold_emission`` keeps the records buffered. Derives
-        ``roofline_utilization`` when a cost model registered
-        ``expected_round_s``."""
+        while ``hold_emission`` keeps the records buffered."""
         rec = self._records.get(index)
         if rec is None or not buckets:
             return
         buckets = dict(buckets)
-        exp = self.expected_round_s
-        busy = buckets.get("busy_s")
-        if exp and busy:
-            # 6 dp: CPU-scale utilizations sit at 1e-6..1e-3 and must
-            # not round to zero
-            buckets["roofline_utilization"] = round(exp / busy, 6)
         rec["device_time"] = buckets
         cb = self.on_device_time
         if cb is not None:

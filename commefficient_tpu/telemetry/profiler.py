@@ -72,8 +72,7 @@ class trace_window:
                         trace_logdir=self.logdir,
                         trace_rounds=n,
                         trace_busy_s=round(busy, 6),
-                        trace_window_s=round(win, 6),
-                        expected_round_s=tel.expected_round_s)
+                        trace_window_s=round(win, 6))
             except Exception as e:  # noqa: BLE001 — observability only
                 from commefficient_tpu.telemetry.alarms import \
                     DivergenceAbort

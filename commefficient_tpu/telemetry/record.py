@@ -15,9 +15,9 @@ Every record carries ``schema`` (this module's version) and ``kind``:
              accounting counters), and host-RSS / HBM peak
              watermarks.
 ``epoch``  — the trainer's per-epoch TableLogger row.
-``bench``  — a benchmark headline metric (bench.py, scripts/*_bench):
-             the same schema whether it lands in BENCH_*.json's
-             harness line or a run ledger.
+``bench``  — a headline metric the timing scripts of before PR 47
+             appended to a run ledger; nothing writes one now, and
+             older ledgers still validate.
 ``summary``— end-of-run aggregate (ConsoleSink's closing record).
 
 Span attribution note: the ``sampler`` span measures fetching the
@@ -44,11 +44,10 @@ Schema v3 adds one key to round records:
              window (``--profile``), else the parsed device-timeline
              buckets (telemetry/trace.py attribute_rounds): window_s /
              busy_s / compute_s / collective_s / transfer_s /
-             host_gap_s, plus ``roofline_utilization`` (expected
-             lower-bound round time over measured busy time,
-             analysis/cost.py) when a cost model was registered.
-             compute + collective + transfer + host_gap == window by
-             construction.
+             host_gap_s. compute + collective + transfer + host_gap
+             == window by construction. Any other numeric key
+             validates: ledgers from before PR 47 carry a
+             ``roofline_utilization`` bucket nothing writes now.
 
 Schema v4 is a fleet extension — no new required keys, two content
 changes:
@@ -260,14 +259,6 @@ def make_epoch_record(row: dict, epoch: int) -> dict:
     rec = _base("epoch")
     rec["epoch"] = int(epoch)
     rec["row"] = {k: v for k, v in row.items()}
-    return rec
-
-
-def make_bench_record(metric: str, value, unit: str, **extra) -> dict:
-    rec = _base("bench")
-    rec.update({"metric": str(metric), "value": value,
-                "unit": str(unit)})
-    rec.update(extra)
     return rec
 
 

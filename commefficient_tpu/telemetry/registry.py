@@ -8,10 +8,11 @@ is navigable without the launching shell history:
     runs/manifests/run_<utc-seconds>_<confighash8>.json
 
 ``scripts/telemetry_report.py --runs_dir`` discovers ledgers through
-these, and ``scripts/perf_gate.py`` uses them to pick "latest vs
-baseline" without hand-typed paths. Manifests are written by process 0
-only and never on bare smoke invocations (no ``--ledger``) — ``runs/``
-stays free of junk from every pytest run.
+these and pairs "latest vs previous" by :func:`run_key`, whose format
+(the ``d<D>p<P>`` topology key and its fragments) lives here.
+Manifests are written by process 0 only and never on bare smoke
+invocations (no ``--ledger``) — ``runs/`` stays free of junk from
+every pytest run.
 """
 
 from __future__ import annotations
@@ -35,6 +36,123 @@ MANIFEST_PREFIX = "run_"
 _HASH_EXCLUDE = ("ledger", "telemetry_console", "use_tensorboard",
                  "do_profile", "clientstore_dir", "live_port",
                  "flightrec_rounds", "postmortem_dir")
+
+#: topology key for runs whose device/process counts are unknown
+#: (pre-fleet ledgers with no meta record)
+ANY_TOPOLOGY = "any"
+
+
+def mesh_suffix(mesh_shape) -> str:
+    """Key fragment for a run's mesh layout: ``m<C>x<M>`` for a
+    genuinely 2D (clients x model) mesh, ``""`` for the 1-D layouts
+    every pre-mesh run used, so ``d<D>p<P>`` keeps matching 1-D runs
+    and only mesh-sharded runs get a key of their own. Accepts the
+    ledger/manifest dict form ({"clients": C, "model": M}) or a
+    (C, M) pair."""
+    if not mesh_shape:
+        return ""
+    if isinstance(mesh_shape, dict):
+        c = int(mesh_shape.get("clients", 0) or 0)
+        m = int(mesh_shape.get("model", 0) or 0)
+    else:
+        c, m = (int(x) for x in tuple(mesh_shape)[:2])
+    if m <= 1:
+        return ""
+    return f"m{c}x{m}"
+
+
+def wire_suffix(wire_dtype) -> str:
+    """Key fragment for a run's uplink wire dtype: ``q<dtype>`` for
+    quantized sketches (``qint8``, ``qbf16``, ``qfp8``), ``""`` for
+    f32/unknown. An int8 round moves ~4x fewer collective bytes than
+    the f32 one, so the two are never paired as one experiment."""
+    if not wire_dtype or str(wire_dtype) == "f32":
+        return ""
+    return f"q{wire_dtype}"
+
+
+def async_suffix(async_k) -> str:
+    """Key fragment for a buffered-arrival run: ``a<K>`` when
+    ``--async_buffer_size K`` was on, ``""`` for the synchronous
+    barrier. A buffered round overlaps the next cohort's arrivals
+    with the fold: a different experiment from the synchronous run
+    of the same config."""
+    k = int(async_k or 0)
+    return f"a{k}" if k > 0 else ""
+
+
+def overlap_suffix(overlap_depth) -> str:
+    """Key fragment for a chunked-emission run: ``o<N>`` when
+    ``--overlap_depth N`` > 1 was on, ``""`` for the serial round
+    (depth 1 is HLO-identical to it, so it keeps the bare key)."""
+    n = int(overlap_depth or 0)
+    return f"o{n}" if n > 1 else ""
+
+
+def band_suffix(band) -> str:
+    """Key fragment for an autopilot-controlled run: ``b<lo-hi>``
+    (``b0.2-0.6``) when ``--autopilot on`` held the recovery error
+    inside ``--autopilot_band LO:HI``, ``""`` for static-knob runs.
+    An autopilot run mixes every lattice point the controller
+    visited, and two bands walk different ladders: neither pairs
+    with a static run nor with another band's. Accepts "LO:HI",
+    "LO-HI", or a (lo, hi) pair."""
+    if not band:
+        return ""
+    if isinstance(band, str):
+        s = band.replace(":", "-")
+    else:
+        lo, hi = (float(x) for x in tuple(band)[:2])
+        s = f"{lo:g}-{hi:g}"
+    return f"b{s}"
+
+
+def privacy_suffix(dp_epsilon) -> str:
+    """Key fragment for a differentially-private run: ``p<eps>``
+    (``p3.5``; ``p0`` is DP with an unlimited budget) when ``--dp
+    sketch`` clipped the clients and noised the aggregated table,
+    ``""`` for noiseless runs. ``dp_epsilon`` must be None for non-DP
+    runs: 0.0 is a real value (unlimited budget), not an absence."""
+    if dp_epsilon is None:
+        return ""
+    return f"p{float(dp_epsilon):g}"
+
+
+def service_suffix(service_jobs) -> str:
+    """Key fragment for a multi-tenant fedservice run: ``j<J>`` when
+    the daemon multiplexed J >= 2 jobs over the pod, ``""`` for solo
+    runs: a single job through the daemon is bit-identical to driving
+    the model directly (the fedservice parity contract), so it keeps
+    the bare key."""
+    j = int(service_jobs or 0)
+    return f"j{j}" if j > 1 else ""
+
+
+def _fragments(wire_dtype, async_k, overlap_depth, band, dp_epsilon,
+               service_jobs) -> str:
+    """Every fragment but the mesh's, in the one order a key has."""
+    return (wire_suffix(wire_dtype) + async_suffix(async_k)
+            + overlap_suffix(overlap_depth) + band_suffix(band)
+            + privacy_suffix(dp_epsilon) + service_suffix(service_jobs))
+
+
+def topology_key(device_count=None, process_count=None,
+                 mesh_shape=None, wire_dtype=None,
+                 async_k=None, overlap_depth=None, band=None,
+                 dp_epsilon=None, service_jobs=None) -> str:
+    """One topology point as a string: ``d<D>p<P>`` when both counts
+    are known, followed by whichever of the fragments above apply
+    (``d8p1m4x2qint8``); :data:`ANY_TOPOLOGY` otherwise, so unknown
+    topologies form their own bucket rather than silently matching a
+    counted one, still split by their fragments (``any-qint8``,
+    ``any-a4``). No fragment ever falls back to the bare key."""
+    fragments = _fragments(wire_dtype, async_k, overlap_depth, band,
+                           dp_epsilon, service_jobs)
+    if device_count is None or process_count is None:
+        return f"{ANY_TOPOLOGY}-{fragments}" if fragments \
+            else ANY_TOPOLOGY
+    return (f"d{int(device_count)}p{int(process_count)}"
+            f"{mesh_suffix(mesh_shape)}{fragments}")
 
 
 def config_dict(args) -> dict:
@@ -108,9 +226,8 @@ def run_wire_dtype(manifest: dict):
     manifests — they only ever carried f32 on the wire. An autopilot
     run reports the dtype of the point the controller CONVERGED on
     (the recorded trajectory's ``final`` key): that is the wire the
-    steady-state rounds — the ones a perf pin should describe —
-    actually moved, so a walk that lands on int8 pins as
-    ``...qint8b<lo-hi>``."""
+    steady-state rounds actually moved, so a walk that lands on int8
+    keys as ``...qint8b<lo-hi>``."""
     cfg = manifest.get("config") or {}
     if cfg.get("mode") != "sketch":
         return None
@@ -157,7 +274,7 @@ def run_autopilot(manifest: dict):
 def run_band(manifest: dict):
     """The run's ``--autopilot_band LO:HI`` string, or None for
     static-knob manifests — the band half of the ``b<lo-hi>``
-    topology fragment (telemetry/gate.py band_suffix)."""
+    topology fragment (:func:`band_suffix`)."""
     cfg = manifest.get("config") or {}
     if str(cfg.get("autopilot") or "off") != "on":
         return None
@@ -168,9 +285,8 @@ def run_dp_epsilon(manifest: dict):
     """The run's privacy budget (``--dp_epsilon``) from its recorded
     config when the run was differentially private (``--dp`` != off),
     or None for noiseless / pre-privacy manifests — the budget half
-    of the ``p<eps>`` topology fragment (telemetry/gate.py
-    privacy_suffix). 0.0 is a REAL return (DP on, unlimited budget):
-    such a run keys ``p0``, never the bare noiseless key."""
+    of the ``p<eps>`` topology fragment (:func:`privacy_suffix`).
+    0.0 is a REAL return (DP on, unlimited budget): such a run keys ``p0``, never the bare noiseless key."""
     cfg = manifest.get("config") or {}
     if str(cfg.get("dp") or "off") == "off":
         return None
@@ -182,8 +298,7 @@ def run_service_jobs(manifest: dict):
     run (``service_jobs``, stamped by the service/bench manifest
     writer), or None for solo / pre-service manifests — and for
     single-job daemon runs, which are bit-identical to the direct
-    path and honestly share its key (telemetry/gate.py
-    service_suffix)."""
+    path and honestly share its key (:func:`service_suffix`)."""
     j = int(manifest.get("service_jobs") or 0)
     return j if j > 1 else None
 
@@ -211,9 +326,8 @@ def run_topology_changed(manifest: dict) -> bool:
     """True when a resumed run crossed a topology boundary mid-run:
     its segments span more than one distinct (device_count,
     process_count, mesh_shape). Such a run's ledger mixes rounds
-    measured under different topologies, so the perf gate must NEVER
-    resolve it to a single baseline pin — gate each segment's own
-    ledger instead (scripts/perf_gate.py refuses)."""
+    measured under different topologies, so it is never read as one
+    experiment: each segment's own ledger is (the report says so)."""
     keys = set()
     for s in run_segments(manifest):
         ms = s.get("mesh_shape")
@@ -225,10 +339,10 @@ def run_topology_changed(manifest: dict) -> bool:
 
 def run_key(manifest: dict) -> tuple:
     """(config_hash, device_count, process_count): two runs are
-    comparable — diffable by the report, gateable against one
-    baseline entry — only when ALL three match. Config hash alone is
-    not an identity: the same config on 1 vs 8 devices is a scaling
-    experiment, not a regression. 2D-mesh runs append their
+    comparable — diffable by the report — only when ALL three match.
+    Config hash alone is not an identity: the same config on 1 vs 8
+    devices is a scaling experiment, not a regression. 2D-mesh runs
+    append their
     ``m<C>x<M>`` fragment, quantized-wire runs their ``q<dtype>``
     fragment, buffered-arrival runs their ``a<K>`` fragment and
     chunk-pipelined runs their ``o<N>`` fragment and
@@ -243,21 +357,14 @@ def run_key(manifest: dict) -> tuple:
     any solo run); 1-D f32
     synchronous serial static noiseless solo runs keep the historical
     3-tuple, so old manifests stay comparable to each other."""
-    from commefficient_tpu.telemetry.gate import (async_suffix,
-                                                  band_suffix,
-                                                  mesh_suffix,
-                                                  overlap_suffix,
-                                                  privacy_suffix,
-                                                  service_suffix,
-                                                  wire_suffix)
     key = (manifest.get("config_hash") or "",) + run_topology(manifest)
     suffix = (mesh_suffix(run_mesh_shape(manifest))
-              + wire_suffix(run_wire_dtype(manifest))
-              + async_suffix(run_async_k(manifest))
-              + overlap_suffix(run_overlap_depth(manifest))
-              + band_suffix(run_band(manifest))
-              + privacy_suffix(run_dp_epsilon(manifest))
-              + service_suffix(run_service_jobs(manifest)))
+              + _fragments(run_wire_dtype(manifest),
+                           run_async_k(manifest),
+                           run_overlap_depth(manifest),
+                           run_band(manifest),
+                           run_dp_epsilon(manifest),
+                           run_service_jobs(manifest)))
     return key + (suffix,) if suffix else key
 
 
@@ -364,7 +471,7 @@ def latest_ledgers(runs_dir: str = "runs", n: int = 2,
     newest FIRST: [(manifest_path, manifest, ledger_path), ...].
 
     ``key`` (a ``run_key`` tuple) restricts hits to comparable runs —
-    the report/gate pass the newest run's key so "latest vs previous"
+    the report passes the newest run's key so "latest vs previous"
     never pairs different configs or topologies. ``job`` restricts
     hits to one fedservice tenant's lineage (manifests whose
     ``job_id`` matches), so a shared runs/ directory answers "this

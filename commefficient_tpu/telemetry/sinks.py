@@ -16,8 +16,7 @@ import threading
 
 import numpy as np
 
-from commefficient_tpu.telemetry.record import (make_bench_record,
-                                                make_summary_record)
+from commefficient_tpu.telemetry.record import make_summary_record
 
 #: lock-confinement declaration (flowlint ``lock-confinement``): the
 #: JSONLSink two-writer guard is a process-wide class dict — a daemon
@@ -235,17 +234,6 @@ def _json_default(obj):
     if isinstance(obj, np.ndarray):
         return obj.tolist()
     return str(obj)
-
-
-def append_bench_record(path: str, metric: str, result, **extra):
-    """One-call ``--ledger`` helper for the bench scripts: append
-    their headline result dict as a schema-v1 bench record (stdout
-    output stays the harness contract, untouched)."""
-    sink = JSONLSink(path)
-    try:
-        sink.write(make_bench_record(metric, result, "json", **extra))
-    finally:
-        sink.close()
 
 
 class TensorBoardSink:

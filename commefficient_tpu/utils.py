@@ -22,7 +22,7 @@ def setup_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a place that can be
     chosen from outside; returns the directory in use.
 
-    For process entry points only (``chip_smoke.py``, ``bench.py``,
+    For process entry points only (``chip_smoke.py``,
     ``scripts/tpu_selftest.py``, the trainers' ``cli``), before first
     device use — never from ``main(argv)``, which the tests call
     in-process dozens of times and would fill the checkout with CPU

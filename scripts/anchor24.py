@@ -6,8 +6,8 @@ at the FetchSGD paper federation geometry (10 000 one-class clients ×
 (--synthetic_separation 0.025: Bayes ceiling ~0.86,
 FedSynthetic.bayes_accuracy) — sub-1.0 ceiling, so the anchor
 discriminates accuracy instead of saturating from epoch 1 (round-3
-review weak #1). Measured orderings (BENCHMARKS.md "24-epoch
-mode-ordering anchor"): at the SHARED reference peak (--lr_scale
+review weak #1). Measured orderings (round 5; the logs are
+``runs/anchor24_*``): at the SHARED reference peak (--lr_scale
 0.4), true_topk ≈ sketch ≫ fedavg ≈ uncompressed ≫
 local_topk-at-one-class (chance). The round-5 per-mode LR sweep
 showed the dense-mode gap was an over-hot-LR artifact, not a

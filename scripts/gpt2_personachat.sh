@@ -6,7 +6,7 @@
 # pytorch_model.bin under $MODEL_CHECKPOINT (zero-egress environment —
 # nothing downloads). --num_cols 524288 is the lane-aligned twin of
 # the reference's 500000 default: same compression ratio within 5%,
-# and it engages the fused Pallas sketch kernels (BENCHMARKS.md).
+# and it engages the fused Pallas sketch kernels.
 # --approx_topk is the perf choice at GPT-2 scale (74 vs 105 ms/round);
 # drop it for the exact reference-parity selection — since round 3 the
 # exact path costs ~40% more instead of 7x (threshold select).

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""By hand, on the chip: what does the vocabulary head cost where few
-positions carry a label (PR 39's step 0, PERF.md section 6)?
+"""ROADMAP S4, by hand, on the chip: what does the vocabulary head cost
+where few positions carry a label (PR 39's step 0, PERF.md section 6)?
 
     python3 scripts/head_probe.py [--clients 8] [--examples 16]
         [--tokens 255] [--width 768] [--vocab 50262] [--labelled 55]

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
-"""By hand, on the chip: what do the two forms of the hybrid models'
-mixers (``models/mixers.py``) cost, forward and backward under the
-clients ``vmap``, at a cell's shapes?
+"""ROADMAP S14 and S11, by hand, on the chip: what do the two forms of
+the hybrid models' mixers (``models/mixers.py``) cost, forward and
+backward under the clients ``vmap``, at a cell's shapes?
 
     python3 scripts/mixer_probe.py [--clients 4] [--seq 2048] [--reps 5]
         [--attn 32,8,64] [--ssd 64,1,64,128,256] [--rehearse]

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""By hand, on the chip: what does one expert layer
+"""ROADMAP S10, by hand, on the chip: what does one expert layer
 (``models/moe.py routed_experts``) cost, forward + backward, when the
 clients' held rows are taken a client at a time and when they are taken
 in one grouped pass (PR 45's step 0, PERF.md section 6)?

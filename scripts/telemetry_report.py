@@ -15,8 +15,8 @@
         diff it against the previous COMPARABLE run (same config hash
         AND same (device_count, process_count) topology — an 8-device
         run never diffs against a single-chip one), and render any
-        scaling curves (scripts/scaling_bench.py sweeps) found in the
-        registry — no hand-typed paths
+        scaling curves (manifests that carry a ``scaling`` dict) found
+        in the registry — no hand-typed paths
 
     python scripts/telemetry_report.py --audit
         findings diff: the committed audit_baseline.json vs a fresh
@@ -24,8 +24,8 @@
         "did this branch move the static-analysis needle" view
 
 Schema-v3 ledgers additionally render the trace-derived device-time
-breakdown (compute / collective / transfer / host-gap per round) and
-the roofline expectation next to the host-span percentiles. Schema-v4
+breakdown (compute / collective / transfer / host-gap per round) next
+to the host-span percentiles. Schema-v4
 ledgers add per-device lanes (busy/collective/wait/wire per device),
 round collective-skew stats, and — for merged multi-host ledgers
 (scripts/ledger_merge.py) — per-process shard summaries with each
@@ -131,7 +131,8 @@ def summarize(records) -> dict:
         # v3-only: trace-derived device-time buckets
         dt = r.get("device_time") or {}
         for name, val in dt.items():
-            if isinstance(val, (int, float)):
+            # the time buckets only: every one is named ``*_s``
+            if name.endswith("_s") and isinstance(val, (int, float)):
                 device_vals.setdefault(name, []).append(float(val))
         # v4-only: per-device lanes + collective-skew stats
         pd = dt.get("per_device")
@@ -236,18 +237,12 @@ def summarize(records) -> dict:
     device_time = {}
     for name, vals in sorted(device_vals.items()):
         sv = sorted(vals)
-        if name == "roofline_utilization":
-            device_time[name] = {"n": len(sv),
-                                 "mean": round(sum(sv) / len(sv), 4),
-                                 "min": round(sv[0], 4),
-                                 "max": round(sv[-1], 4)}
-        else:
-            device_time[name] = {
-                "n": len(sv),
-                "total_s": round(sum(sv), 4),
-                "mean_ms": round(1e3 * sum(sv) / len(sv), 3),
-                "p50_ms": round(1e3 * _pct(sv, 50), 3),
-                "p95_ms": round(1e3 * _pct(sv, 95), 3)}
+        device_time[name] = {
+            "n": len(sv),
+            "total_s": round(sum(sv), 4),
+            "mean_ms": round(1e3 * sum(sv) / len(sv), 3),
+            "p50_ms": round(1e3 * _pct(sv, 50), 3),
+            "p95_ms": round(1e3 * _pct(sv, 95), 3)}
     # overlap fraction (--overlap_depth pipelining): how much of the
     # round's collective wall time ran hidden under some lane's
     # compute — 0.0 for serial rounds, the pipeline's win otherwise
@@ -358,9 +353,6 @@ def summarize(records) -> dict:
         "per_device": per_device,
         "collective_skew": collective_skew,
         "shards": shards,
-        "cost_model": next(
-            (r.get("cost_model") for r in records
-             if r["kind"] == "meta" and r.get("cost_model")), None),
         "probes": probes,
         "alarm_rounds": alarm_rounds,
         "alarm_totals": dict(sorted(alarm_totals.items())),
@@ -410,14 +402,9 @@ def render_summary(s, label="") -> str:
     # device-time breakdown (schema v3, --profile runs) next to the
     # host-span percentiles above
     for name, v in s.get("device_time", {}).items():
-        if name == "roofline_utilization":
-            lines.append(f"  device {name}: mean {v['mean']} "
-                         f"(min {v['min']}, max {v['max']}, "
-                         f"{v['n']} rounds)")
-        else:
-            lines.append(f"  device {name}: mean {v['mean_ms']} "
-                         f"ms/round (p50 {v['p50_ms']}, "
-                         f"p95 {v['p95_ms']}, {v['n']} rounds)")
+        lines.append(f"  device {name}: mean {v['mean_ms']} "
+                     f"ms/round (p50 {v['p50_ms']}, "
+                     f"p95 {v['p95_ms']}, {v['n']} rounds)")
     if s.get("overlap_fraction") is not None:
         lines.append(
             f"  overlap: {100 * s['overlap_fraction']:.1f}% of "
@@ -454,15 +441,6 @@ def render_summary(s, label="") -> str:
             f"  job {jk}: {js['rounds']} rounds, uplink "
             f"{_mib(js['uplink_bytes'])}, downlink "
             f"{_mib(js['downlink_bytes'])}, {alarms} alarm(s)")
-    cm = s.get("cost_model")
-    if cm:
-        lines.append(
-            f"  roofline: {cm.get('label', '')} on {cm.get('chip')}"
-            f" x{cm.get('n_devices')}, "
-            f"{cm.get('total_flops', 0):.4g} FLOPs, expected "
-            f"{cm.get('expected_round_s', 0):.6g} s/round "
-            f"(compute {cm.get('compute_floor_s', 0):.6g}, "
-            f"collective {cm.get('collective_floor_s', 0):.6g})")
     for name, p in s.get("probes", {}).items():
         lines.append(f"  probe {name}: first {p['first']:.6g} -> "
                      f"last {p['last']:.6g}, mean {p['mean']:.6g}, "
@@ -554,10 +532,9 @@ def diff_summaries(a: dict, b: dict) -> dict:
     for name in sorted(set(a.get("device_time", {}))
                        & set(b.get("device_time", {}))):
         da, db = a["device_time"][name], b["device_time"][name]
-        ka = "mean" if name == "roofline_utilization" else "mean_ms"
-        entry = {"a": da[ka], "b": db[ka]}
-        if da[ka]:
-            entry["ratio"] = round(db[ka] / da[ka], 4)
+        entry = {"a": da["mean_ms"], "b": db["mean_ms"]}
+        if da["mean_ms"]:
+            entry["ratio"] = round(db["mean_ms"] / da["mean_ms"], 4)
         dev_diff[name] = entry
     if dev_diff:
         out["device_time"] = dev_diff
@@ -629,8 +606,8 @@ def render_diff(d, label_a, label_b) -> str:
                      f"{e['b_mean_ms']} ms/round{r}")
     for name, e in d.get("device_time", {}).items():
         r = f" ({e['ratio']}x)" if "ratio" in e else ""
-        unit = "" if name == "roofline_utilization" else " ms/round"
-        lines.append(f"  device {name}: {e['a']} -> {e['b']}{unit}{r}")
+        lines.append(f"  device {name}: {e['a']} -> {e['b']} "
+                     f"ms/round{r}")
     if "overlap_fraction" in d:
         e = d["overlap_fraction"]
         fmt = lambda v: f"{100 * v:.1f}%" if v is not None else "-"
@@ -669,7 +646,7 @@ def render_diff(d, label_a, label_b) -> str:
 
 def scaling_curves(manifests) -> list:
     """Scaling-curve points from the registry: manifests carrying a
-    ``scaling`` dict (scripts/scaling_bench.py) grouped by config
+    ``scaling`` dict (a sweep's per-topology result) grouped by config
     hash, newest manifest per topology point, sorted by device count.
     Only groups with >= 2 distinct points form a curve."""
     from commefficient_tpu.telemetry import registry
@@ -796,9 +773,9 @@ def render_lineages(lins) -> str:
                 if segs else _segment_label(e)
             lines.append(f"  {name}: {chain}{tail}")
         if lin["topology_changed"]:
-            lines.append("  NOTE: topology changed mid-lineage — the "
-                         "perf gate treats each segment separately "
-                         "and refuses to pin the merged ledger")
+            lines.append("  NOTE: topology changed mid-lineage — "
+                         "read each segment's own ledger, not the "
+                         "merged one")
     return "\n".join(lines)
 
 

@@ -5,11 +5,10 @@ mode). Run on a machine with a TPU attached:
     python scripts/tpu_selftest.py [check ...]
 
 (every check, or the named ones.) Prints one PASS/FAIL line per check
-and exits nonzero on any failure. This process holds the chip, so no check may start a child that needs
-it (the one child, scaling_smoke's, is pinned to the CPU). Kernel
-parity at flagship geometry and the trainer end to end are
-``chip_smoke.py``'s; the headline bench runs on its own
-(``python bench.py``).
+and exits nonzero on any failure. Every check asserts values, none a
+time. This process holds the chip, so no check may start a child that
+needs it. Kernel parity at flagship geometry and the trainer end to
+end are ``chip_smoke.py``'s; speeds are ``benchmark/run.py``'s.
 """
 
 import os
@@ -634,46 +633,6 @@ def trace_smoke():
         shutil.rmtree(logdir, ignore_errors=True)
 
 
-def scaling_smoke():
-    """Two-point CPU scaling sweep straight through the run registry:
-    scripts/scaling_bench.py must register one manifest per topology
-    point (distinct (device_count, process_count) keys, shared config
-    hash) with a ``scaling`` block the report can render as a curve.
-    Pinned to the virtual CPU mesh on purpose — the registry/manifest
-    plumbing is backend-independent, and the real-TPU throughput
-    points come from running scaling_bench against the pod itself."""
-    import shutil
-    import subprocess
-    import tempfile
-
-    from commefficient_tpu.telemetry import registry
-
-    runs_dir = tempfile.mkdtemp(prefix="scaling_smoke_")
-    try:
-        script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "scaling_bench.py")
-        out = subprocess.run(
-            [sys.executable, script, "--device_counts", "1,2",
-             "--rounds", "3", "--runs_dir", runs_dir],
-            capture_output=True, text=True, timeout=560)
-        assert out.returncode == 0, out.stdout + out.stderr
-        manifests = registry.list_manifests(runs_dir)
-        topos = sorted(registry.run_topology(m) for _, m in manifests)
-        assert topos == [(1, 1), (2, 1)], topos
-        hashes = {m.get("config_hash") for _, m in manifests}
-        assert len(hashes) == 1, hashes
-        for _, m in manifests:
-            sc = m.get("scaling")
-            assert sc and sc["clients_per_s"] > 0, m
-            assert 0.0 < sc["parallel_efficiency"], m
-        eff2 = [m["scaling"]["parallel_efficiency"]
-                for _, m in manifests
-                if registry.run_topology(m) == (2, 1)][0]
-        return f"2 points registered, d2p1 efficiency {eff2:.2f}"
-    finally:
-        shutil.rmtree(runs_dir, ignore_errors=True)
-
-
 def mesh2d_smoke():
     """2D clients x model mesh on the REAL backend: the pod-scale
     sketch round (partial tables reduce-scattered over ``model``,
@@ -1153,7 +1112,6 @@ def main():
               ("audit_smoke", audit_smoke),
               ("flowlint_smoke", flowlint_smoke),
               ("trace_smoke", trace_smoke),
-              ("scaling_smoke", scaling_smoke),
               ("mesh2d_smoke", mesh2d_smoke),
               ("elastic_smoke", elastic_smoke),
               ("flash_attention_parity", flash_attention_parity),

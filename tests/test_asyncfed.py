@@ -17,8 +17,7 @@ Four layers of guarantee:
   ``--sketch_dtype int8``, under churny and bursty traces.
 
 Plus the observatory surface: the ``async_staleness`` alarm rule, the
-``a<K>`` perf-gate topology fragment (no cross-mode fallback), and
-the registry run_key fragment.
+``a<K>`` topology fragment and the registry run_key fragment.
 """
 
 import os
@@ -88,8 +87,8 @@ def test_arrival_schedule_replays(kind):
 
 
 def test_replay_stats_matches_inline_summary():
-    """replay_stats == the summary host_scale_bench historically
-    computed inline (satellite: the bench now calls this)."""
+    """replay_stats on a hand-worked trace: two bursts of two rounds,
+    16 client-rounds dropped; an empty trace reads all alive."""
     alive = [1.0, 0.5, 0.25, 1.0, 1.0, 0.75, 0.5, 1.0]
     st = ArrivalSchedule.replay_stats(alive, 8)
     assert st == {"burst_count": 2, "burst_rounds": 4,
@@ -469,40 +468,17 @@ def test_async_staleness_alarm_rule():
         == []
 
 
-def test_gate_async_topology_key_no_fallback():
-    from commefficient_tpu.telemetry import gate
+def test_async_topology_key_forms():
+    from commefficient_tpu.telemetry import registry
 
-    assert gate.async_suffix(None) == ""
-    assert gate.async_suffix(0) == ""
-    assert gate.async_suffix(4) == "a4"
-    assert gate.topology_key(8, 1, None, None, 4) == "d8p1a4"
-    assert gate.topology_key(None, None, None, None, 4) == "any-a4"
-
-    base = {}
-    base = gate.update_baseline(base, {"round_ms": {"median": 1.0,
-                                                    "mad": 0.1}},
-                                source="x", device_count=8,
-                                process_count=1)
-    # a buffered run must NEVER fall back onto the synchronous entry
-    assert gate.baseline_entry(base, 8, 1, None, None, 4) is None
-    base = gate.update_baseline(base, {"round_ms": {"median": 2.0,
-                                                    "mad": 0.1}},
-                                source="y", device_count=8,
-                                process_count=1, async_k=4)
-    e = gate.baseline_entry(base, 8, 1, None, None, 4)
-    assert e and e["metrics"]["round_ms"]["median"] == 2.0
-    # ...and a synchronous run never reads the buffered entry
-    e = gate.baseline_entry(base, 8, 1, None, None, None)
-    assert e and e["metrics"]["round_ms"]["median"] == 1.0
-    # the mesh-blind fallback drops ONLY the mesh fragment: the a<K>
-    # fragment survives it
-    base = gate.update_baseline(base, {"round_ms": {"median": 3.0,
-                                                    "mad": 0.1}},
-                                source="z", device_count=8,
-                                process_count=1, async_k=2)
-    hit = gate.baseline_entry(base, 8, 1,
-                              {"clients": 4, "model": 2}, None, 2)
-    assert hit and hit["metrics"]["round_ms"]["median"] == 3.0
+    assert registry.async_suffix(None) == ""
+    assert registry.async_suffix(0) == ""
+    assert registry.async_suffix(4) == "a4"
+    assert registry.topology_key(8, 1, None, None, 4) == "d8p1a4"
+    assert registry.topology_key(None, None, None, None, 4) == "any-a4"
+    # the a<K> fragment rides behind a mesh fragment, never instead
+    assert registry.topology_key(
+        8, 1, {"clients": 4, "model": 2}, None, 2) == "d8p1m4x2a2"
 
 
 def test_registry_run_key_async_fragment():
@@ -516,23 +492,6 @@ def test_registry_run_key_async_fragment():
     man["config"]["async_buffer_size"] = 0
     assert registry.run_async_k(man) is None
     assert registry.run_key(man) == ("abc", 8, 1)
-
-
-def test_perf_gate_resolves_async_k():
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    import perf_gate
-
-    man = {"config": {"mode": "sketch", "async_buffer_size": 3},
-           "device_count": 2, "process_count": 1}
-    assert perf_gate.resolve_topology(man)[4] == 3
-    recs = [{"kind": "meta", "num_devices": 4,
-             "plan": {"async_buffer_size": 6}}]
-    assert perf_gate.resolve_topology(None, recs)[4] == 6
-    # CLI override wins; synchronous runs resolve to None
-    assert perf_gate.resolve_topology(man, async_k=8)[4] == 8
-    man["config"]["async_buffer_size"] = 0
-    assert perf_gate.resolve_topology(man)[4] is None
 
 
 def test_config_validates_async_bounds():

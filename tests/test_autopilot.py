@@ -1,7 +1,7 @@
 """Adaptive compression autopilot: the knob lattice, the bounded
 re-jit cache (isolation: LRU bound, hit/miss counters, eviction), the
-deterministic band controller and its bit-exact replay, the perf-gate
-band keying (no cross-band fallback), and the FedModel integration —
+deterministic band controller and its bit-exact replay, the run
+registry's band keying, and the FedModel integration —
 autopilot-off object identity, pinned-knob bit parity with the
 equivalent static config, variant-switch bit parity with a fresh
 jax.jit, and warm-ahead never compiling an unvisited lattice point."""
@@ -23,7 +23,7 @@ from commefficient_tpu.autopilot import (AutopilotController,
                                          parse_key, replay_record,
                                          variant_bytes)
 from commefficient_tpu.config import Config
-from commefficient_tpu.telemetry import gate
+from commefficient_tpu.telemetry import registry
 
 
 def make_cfg(**kw):
@@ -231,48 +231,21 @@ def test_build_controller_modes():
     assert key_str(off.key) == "int8-k8-r3-c128-re9500"
 
 
-# --- perf-gate band keying ----------------------------------------------
+# --- band keying -------------------------------------------------------
 
 
 def test_band_suffix_forms():
-    assert gate.band_suffix(None) == ""
-    assert gate.band_suffix("") == ""
-    assert gate.band_suffix("0.2:0.6") == "b0.2-0.6"
-    assert gate.band_suffix("0.2-0.6") == "b0.2-0.6"
-    assert gate.band_suffix((0.05, 0.6)) == "b0.05-0.6"
-    assert gate.topology_key(8, 1, band="0.05:0.6") == "d8p1b0.05-0.6"
-    assert gate.topology_key(8, 1, wire_dtype="int8",
-                             band="0.05:0.6") == "d8p1qint8b0.05-0.6"
-
-
-def test_no_cross_band_fallback():
-    m = {"round_total": {"median": 1.0, "mad": 0.1, "n": 5,
-                         "unit": "ms"}}
-    base = gate.make_baseline(m, device_count=8, process_count=1)
-    base = gate.update_baseline(base, m, device_count=8,
-                                process_count=1, band="0.05:0.6")
-    # banded run resolves ONLY its own band
-    assert gate.baseline_entry(base, 8, 1, band="0.05:0.6") is not None
-    assert gate.baseline_entry(base, 8, 1, band="0.2:0.6") is None
-    # a banded run never resolves the static pin, and a static run
-    # never resolves a banded one
-    assert gate.baseline_entry(base, 8, 1) is not None
-    assert gate.baseline_entry(base, 8, 1)\
-        .get("autopilot_band") is None
-    only_band = gate.make_baseline(m, device_count=8,
-                                   process_count=1, band="0.05:0.6")
-    assert gate.baseline_entry(only_band, 8, 1) is None
-    with pytest.raises(ValueError):
-        gate.compare(only_band, m, device_count=8, process_count=1)
-    # mesh fallback keeps the band fragment (mesh is the ONLY
-    # fragment with a migration fallback)
-    assert gate.baseline_entry(
-        base, 8, 1, mesh_shape={"clients": 4, "model": 2},
-        band="0.05:0.6") is not None
+    assert registry.band_suffix(None) == ""
+    assert registry.band_suffix("") == ""
+    assert registry.band_suffix("0.2:0.6") == "b0.2-0.6"
+    assert registry.band_suffix("0.2-0.6") == "b0.2-0.6"
+    assert registry.band_suffix((0.05, 0.6)) == "b0.05-0.6"
+    assert registry.topology_key(8, 1, band="0.05:0.6") == "d8p1b0.05-0.6"
+    assert registry.topology_key(
+        8, 1, wire_dtype="int8", band="0.05:0.6") == "d8p1qint8b0.05-0.6"
 
 
 def test_registry_band_and_final_dtype_keying():
-    from commefficient_tpu.telemetry import registry
     man = {"config": {"autopilot": "on",
                       "autopilot_band": "0.05:0.6",
                       "sketch_dtype": "f32", "mode": "sketch"},

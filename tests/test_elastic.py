@@ -10,13 +10,12 @@ same bound tests/test_mesh2d.py pins).
 
 Also covered here: asyncfed backlog survival across a resize, the
 crafted multi-process clientstore shard merge, the sync-restore-of-
-pending-async refusal, and the perf gate's refusal to resolve a
-baseline pin for a ledger that spans topologies.
+pending-async refusal, and the registry's flag on a ledger that spans
+topologies.
 """
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -287,79 +286,7 @@ def test_multiprocess_store_shards_merge_on_restore(tmp_path):
     model2.finalize()
 
 
-# -- perf gate refuses a cross-topology ledger --------------------------
-
-
-def _round_rec(r):
-    return {"schema": 1, "kind": "round", "ts": 1000.0 + r, "round": r,
-            "spans": {"round": 0.01 + 0.001 * r}, "counters": {},
-            "uplink_bytes": None, "downlink_bytes": None,
-            "host_rss_peak_bytes": None, "hbm_peak_bytes": None}
-
-
-def _write_runs_dir(tmp_path, segments):
-    runs = tmp_path / "runs"
-    (runs / "manifests").mkdir(parents=True)
-    ledger = runs / "led.jsonl"
-    with open(ledger, "w") as f:
-        for r in range(4):
-            f.write(json.dumps(_round_rec(r)) + "\n")
-    manifest = {
-        "schema": 1, "kind": "run_manifest", "ts": 1, "git_sha": "",
-        "config_hash": "cafe" * 10, "config": {}, "argv": [],
-        "ledger": str(ledger), "bench": {}, "mesh_shape": None,
-        "device_count": 8, "process_count": 1,
-        "topology_segments": segments,
-    }
-    with open(runs / "manifests" / "run_1_cafecafe.json", "w") as f:
-        json.dump(manifest, f)
-    return str(runs)
-
-
-def test_perf_gate_refuses_cross_topology_ledger(tmp_path, capsys):
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    import perf_gate
-    segs = [
-        {"device_count": 8, "process_count": 2,
-         "mesh_shape": {"clients": 4, "model": 2}, "round_index": 3},
-        {"device_count": 4, "process_count": 1,
-         "mesh_shape": {"clients": 2, "model": 2}, "round_index": 6},
-    ]
-    runs = _write_runs_dir(tmp_path, segs)
-    rc = perf_gate.main(["--runs_dir", runs, "--check",
-                         "--baseline", str(tmp_path / "missing.json")])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REFUSED" in out
-    assert "2 segments" in out
-    # the refusal blocks re-baselining too: a mixed ledger must never
-    # become anyone's pin
-    rc = perf_gate.main(["--runs_dir", runs, "--write-baseline",
-                         str(tmp_path / "new.json")])
-    assert rc == 1
-    assert not os.path.exists(tmp_path / "new.json")
-
-
-def test_perf_gate_accepts_unresized_resume(tmp_path, capsys):
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    import perf_gate
-    # resumed WITHOUT a topology change: same topology in every
-    # segment — this is one comparable run, the gate pins it normally
-    segs = [
-        {"device_count": 8, "process_count": 1,
-         "mesh_shape": {"clients": 8, "model": 1}, "round_index": 3},
-        {"device_count": 8, "process_count": 1,
-         "mesh_shape": {"clients": 8, "model": 1}, "round_index": 6},
-    ]
-    runs = _write_runs_dir(tmp_path, segs)
-    rc = perf_gate.main(["--runs_dir", runs, "--write-baseline",
-                         str(tmp_path / "base.json")])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "REFUSED" not in out
-    assert os.path.exists(tmp_path / "base.json")
+# -- a resumed run that crossed a topology boundary ---------------------
 
 
 def test_run_topology_changed_semantics():

@@ -180,7 +180,7 @@ class TestDeterminism:
 
     def test_single_job_daemon_parity(self, tmp_path):
         """The J=1 daemon adds zero noise — the reason j1 keeps the
-        bare perf-gate key."""
+        bare registry key."""
         R = 3
         solo = _solo_run(5, _batches(11, R))
         svc = FedService(_svc_cfg())

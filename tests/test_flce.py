@@ -110,7 +110,7 @@ def test_dxp_guard_scales_with_vmap_multiplicity(monkeypatch):
     """The dX-partials OOM guard must account for the vmapped client
     axis: N clients materialise N partials buffers concurrently, so a
     geometry that fits per-call can still blow the cap under vmap
-    (ADVICE.md: 8 x 315 MB passing a 512 MB check)."""
+    (8 x 315 MB once passed a 512 MB check)."""
     import warnings
 
     from commefficient_tpu.ops import flce_pallas

@@ -1,13 +1,12 @@
 """JoyAI-LLM-Flash's share (models/joyai.py) against its plain float32
-reference (models/joyai_reference.py): one client's loss and gradient,
-three FetchSGD rounds through ``FedModel``, the shares adding up to the
-uncut layers, the expert layer's counters, the MTP targets, the trainer
+reference (benchmark/reference/joyai-llm-flash-ep32.py): one client's
+loss and gradient, three FetchSGD rounds through ``FedModel``, the
+shares adding up to the uncut layers, the expert layer's counters, the MTP targets, the trainer
 end to end. GPT-2's comparison with *its* reference
 (benchmark/reference/gpt2-124m-personachat.py) is a case of the first.
 Tiny sizes, seeded weights, float32, CPU."""
 
 import importlib.util
-import inspect
 import json
 import os
 
@@ -17,7 +16,6 @@ import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
-from commefficient_tpu.models import joyai_reference as ref
 from commefficient_tpu.models.joyai import (MOE_STATS, MLA, ExpertLayer,
                                             JoyAIConfig, JoyAIFlashLM,
                                             causal_lm_loss)
@@ -37,6 +35,9 @@ def _bench_ref(config):
     return _load(os.path.join(ROOT, "benchmark", "reference",
                               config + ".py"),
                  "bench_ref_" + config.replace("-", "_"))
+
+
+ref = _bench_ref("joyai-llm-flash-ep32")
 
 
 def _rel(a, b):
@@ -270,20 +271,6 @@ def test_mtp_predicts_the_token_after_next():
     # x_{t+1}, the main head's target, is not what it was trained on
     assert not np.allclose(mean_nll(mtp[:, :-1], ids[:, 1:]), mtp_ref,
                            rtol=1e-3)
-
-
-# --- the reference's two copies ---------------------------------------------
-
-def test_the_repos_reference_and_the_benchmarks_copy_are_one():
-    bench = _bench_ref("joyai-llm-flash-ep32")
-
-    def functions(mod):
-        return {n: inspect.getsource(f) for n, f in vars(mod).items()
-                if inspect.isfunction(f) and f.__module__ == mod.__name__}
-
-    assert functions(bench) == functions(ref) and functions(ref)
-    assert bench.LIMITS == ref.LIMITS
-    assert bench.CLIENTS_PER_BLOCK == ref.CLIENTS_PER_BLOCK == 1
 
 
 def test_the_configuration_keeps_every_published_width():

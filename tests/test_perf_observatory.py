@@ -1,7 +1,6 @@
-"""Performance-observatory tests: device-time trace attribution,
-roofline FLOP counting, the noise-aware perf gate, the run registry,
-the step-time alarm, stale-waiver detection, and the end-to-end
-``--profile`` path on a real CPU mesh.
+"""Performance-observatory tests: device-time trace attribution, the
+run registry and its topology keys, the step-time alarm, stale-waiver
+detection, and the end-to-end ``--profile`` path on a real CPU mesh.
 
 The golden-trace test runs against ``tests/fixtures/mini.trace.json.gz``
 — a hand-authored Chrome trace-event dump with two ``fed_round``
@@ -18,12 +17,11 @@ import os
 import numpy as np
 import pytest
 
-from commefficient_tpu.telemetry import gate, registry, trace
+from commefficient_tpu.telemetry import registry, trace
 from commefficient_tpu.telemetry.alarms import (AlarmEngine,
                                                 DivergenceAbort)
 from commefficient_tpu.telemetry.core import Telemetry
-from commefficient_tpu.telemetry.record import (make_bench_record,
-                                                make_round_record,
+from commefficient_tpu.telemetry.record import (make_round_record,
                                                 validate_record)
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
@@ -117,249 +115,7 @@ class TestTraceAttribution:
         assert trace.attribute_rounds(events) == {}
 
 
-# --- roofline FLOP inventory ------------------------------------------
-
-
-CANNED_STABLEHLO = """
-module @round {
-  func.func public @main(%arg0: tensor<8x32xf32>, %arg1: tensor<32x16xf32>) -> tensor<8x16xf32> {
-    %0 = stablehlo.dot_general %arg0, %arg1, contracting_dims = [1] x [0] : (tensor<8x32xf32>, tensor<32x16xf32>) -> tensor<8x16xf32>
-    %1 = stablehlo.convolution(%arg2, %arg3) dim_numbers = [b, 0, 1, f]x[0, 1, i, o]->[b, 0, 1, f], window = {stride = [1, 1]} : (tensor<1x8x8x3xf32>, tensor<3x3x3x16xf32>) -> tensor<1x8x8x16xf32>
-    return %0 : tensor<8x16xf32>
-  }
-}
-"""
-
-
-class TestFlopInventory:
-    def test_dot_and_conv_macs(self):
-        from commefficient_tpu.analysis.hlo import flop_inventory
-        inv = flop_inventory(CANNED_STABLEHLO)
-        # dot: 2 x numel(8x16) x K=32; conv: 2 x numel(1x8x8x16) x
-        # (numel(3x3x3x16) / O=16) = 2 x 1024 x 27
-        assert inv["dot_flops"] == 2 * 8 * 16 * 32
-        assert inv["conv_flops"] == 2 * (8 * 8 * 16) * (3 * 3 * 3)
-        assert inv["total_flops"] == inv["dot_flops"] + inv["conv_flops"]
-        assert inv["dot_count"] == 1 and inv["conv_count"] == 1
-        assert inv["by_dtype"] == {"f32": inv["total_flops"]}
-
-    def test_cost_model_floors(self):
-        from commefficient_tpu.analysis.cost import build_cost_model
-        cost = build_cost_model(
-            CANNED_STABLEHLO, backend="cpu", device_kind="cpu",
-            n_devices=8, allreduce_payload_bytes=4.0 * 50_000,
-            label="test/8dev")
-        assert cost["total_flops"] == 2 * 8 * 16 * 32 + 2 * 1024 * 27
-        assert cost["expected_round_s"] > 0
-        assert cost["expected_round_s"] >= cost["compute_floor_s"]
-        assert cost["expected_round_s"] >= cost["collective_floor_s"]
-
-    def test_chip_spec_is_a_lookup_not_a_guess(self):
-        from commefficient_tpu.analysis.cost import CHIP_SPECS, chip_spec
-        assert chip_spec("tpu", "TPU v5 lite") is CHIP_SPECS["tpu-v5e"]
-        assert chip_spec("cpu", "cpu") is CHIP_SPECS["cpu"]
-        # an unknown TPU is not a v4, and only the cpu backend gets
-        # the cpu stand-in
-        with pytest.raises(ValueError, match="TPU v9"):
-            chip_spec("tpu", "TPU v9")
-        with pytest.raises(ValueError, match="rocm"):
-            chip_spec("rocm", "")
-
-
-# --- perf-gate math ---------------------------------------------------
-
-
-def _metric(median, mad=0.0, better="lower", n=8):
-    return {"median": median, "mad": mad, "n": n, "p50": median,
-            "p95": median, "better": better}
-
-
-class TestGateMath:
-    def test_noise_within_band_passes(self):
-        base = gate.make_baseline(
-            {"span:round_dispatch:ms": _metric(10.0, mad=0.5)})
-        verdict = gate.compare(
-            base, {"span:round_dispatch:ms": _metric(12.0)})
-        assert verdict["checked"] == 1
-        assert verdict["regressions"] == []
-
-    def test_regression_beyond_band_fails(self):
-        base = gate.make_baseline(
-            {"span:round_dispatch:ms": _metric(10.0, mad=0.5)})
-        verdict = gate.compare(
-            base, {"span:round_dispatch:ms": _metric(20.0)})
-        assert len(verdict["regressions"]) == 1
-        r = verdict["regressions"][0]
-        assert r["metric"] == "span:round_dispatch:ms"
-        # band = max(0.25 * 10, 5 * 0.5) = 2.5ms; delta = 10ms
-        assert r["tolerance"] == pytest.approx(2.5)
-
-    def test_mad_band_dominates_when_noisy(self):
-        # mad 2ms -> band 10ms: a 9ms jump is still noise
-        base = gate.make_baseline(
-            {"span:h2d:ms": _metric(10.0, mad=2.0)})
-        verdict = gate.compare(base, {"span:h2d:ms": _metric(19.0)})
-        assert verdict["regressions"] == []
-
-    def test_higher_is_better_metrics_gate_downward(self):
-        base = gate.make_baseline(
-            {"bench:clients_per_s": _metric(100.0, better="higher")})
-        bad = gate.compare(
-            base, {"bench:clients_per_s": _metric(50.0,
-                                                  better="higher")})
-        good = gate.compare(
-            base, {"bench:clients_per_s": _metric(200.0,
-                                                  better="higher")})
-        assert len(bad["regressions"]) == 1
-        assert bad["improvements"] == []
-        assert good["regressions"] == []
-        assert len(good["improvements"]) == 1
-
-    def test_one_sided_metrics_skip(self):
-        base = gate.make_baseline({"span:a:ms": _metric(1.0)})
-        verdict = gate.compare(base, {"span:b:ms": _metric(1.0)})
-        assert verdict["checked"] == 0
-        reasons = {s["metric"]: s["reason"]
-                   for s in verdict["skipped"]}
-        assert reasons == {"span:a:ms": "not in current run",
-                           "span:b:ms": "not in baseline"}
-
-    def test_sub_resolution_baseline_skipped(self):
-        # 0.01 ms median is below scheduler resolution: a 100x blowup
-        # is not gateable signal
-        base = gate.make_baseline({"span:tiny:ms": _metric(0.01)})
-        verdict = gate.compare(base, {"span:tiny:ms": _metric(1.0)})
-        assert verdict["checked"] == 0
-        assert verdict["skipped"][0]["reason"] == \
-            "below timing resolution"
-
-    def test_roofline_utilization_never_floored(self):
-        base = gate.make_baseline(
-            {"device:roofline_utilization": _metric(0.0005,
-                                                    better="higher")})
-        verdict = gate.compare(
-            base, {"device:roofline_utilization": _metric(
-                0.0001, better="higher")})
-        assert verdict["checked"] == 1
-        assert len(verdict["regressions"]) == 1
-
-    def test_schema_mismatch_raises(self):
-        with pytest.raises(ValueError, match="schema"):
-            gate.compare({"schema": 99, "metrics": {}}, {})
-
-    def test_metrics_from_records_shapes(self):
-        rec = make_round_record(0)
-        rec["spans"] = {"h2d": 0.002, "server": 0.001}
-        rec["device_time"] = {"busy_s": 0.5, "compute_s": 0.4,
-                              "roofline_utilization": 0.31}
-        bench = make_bench_record("clients_per_s", 120.0, "1/s",
-                                  round_times_s=[0.1, 0.11, 0.09])
-        metrics = gate.metrics_from_records([rec, bench])
-        assert metrics["span:h2d:ms"]["median"] == \
-            pytest.approx(2.0)
-        assert metrics["span:h2d:ms"]["better"] == "lower"
-        assert metrics["device:busy_s"]["better"] == "lower"
-        assert metrics["device:roofline_utilization"]["better"] == \
-            "higher"
-        assert metrics["bench:clients_per_s"]["median"] == 120.0
-        assert metrics["bench:clients_per_s"]["better"] == "higher"
-        assert metrics["bench:clients_per_s:round_s"]["n"] == 3
-        assert metrics["bench:clients_per_s:round_s"]["better"] == \
-            "lower"
-
-
-# --- perf_gate CLI ----------------------------------------------------
-
-
-def _load_perf_gate():
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "perf_gate.py")
-    spec = importlib.util.spec_from_file_location("_perf_gate", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_ledger(path, round_s):
-    """A synthetic ledger whose round_dispatch span is ``round_s``."""
-    with open(path, "w") as f:
-        for r in range(8):
-            rec = make_round_record(r)
-            rec["spans"] = {"round_dispatch": round_s}
-            rec["uplink_bytes"] = rec["downlink_bytes"] = 1024.0
-            rec["device_time"] = {"window_s": round_s,
-                                  "busy_s": 0.8 * round_s,
-                                  "compute_s": 0.7 * round_s,
-                                  "collective_s": 0.1 * round_s,
-                                  "transfer_s": 0.0,
-                                  "host_gap_s": 0.2 * round_s}
-            f.write(json.dumps(rec) + "\n")
-
-
-class TestPerfGateCLI:
-    def test_baseline_check_regress_refuse_cycle(self, tmp_path):
-        pg = _load_perf_gate()
-        good = str(tmp_path / "good.jsonl")
-        slow = str(tmp_path / "slow.jsonl")
-        baseline = str(tmp_path / "perf_baseline.json")
-        _write_ledger(good, 0.050)
-        _write_ledger(slow, 0.200)  # 4x: far outside any noise band
-
-        assert pg.main(["--ledger", good,
-                        "--write-baseline", baseline]) == 0
-        assert os.path.exists(baseline)
-        base = gate.load_baseline(baseline)
-        assert base["schema"] == gate.BASELINE_SCHEMA
-        # the synthetic ledger carries no topology info, so it lands
-        # under the "any" bucket of the schema-2 topology map
-        entry = gate.baseline_entry(base, None, None)
-        assert "span:round_dispatch:ms" in entry["metrics"]
-
-        # same run gates green against its own baseline
-        assert pg.main(["--ledger", good, "--baseline", baseline,
-                        "--check"]) == 0
-        # the synthetically slowed ledger fails
-        assert pg.main(["--ledger", slow, "--baseline", baseline,
-                        "--check"]) == 1
-        # re-baselining over a regression is refused without --force
-        assert pg.main(["--ledger", slow, "--baseline", baseline,
-                        "--write-baseline", baseline]) == 1
-        assert gate.baseline_entry(
-            gate.load_baseline(baseline), None, None)["metrics"][
-            "span:round_dispatch:ms"]["median"] == pytest.approx(50.0)
-        # --force is the explicit trade-off escape hatch
-        assert pg.main(["--ledger", slow, "--baseline", baseline,
-                        "--write-baseline", baseline,
-                        "--force"]) == 0
-        assert gate.baseline_entry(
-            gate.load_baseline(baseline), None, None)["metrics"][
-            "span:round_dispatch:ms"]["median"] == pytest.approx(200.0)
-
-    def test_empty_ledger_is_an_error(self, tmp_path):
-        pg = _load_perf_gate()
-        empty = str(tmp_path / "empty.jsonl")
-        open(empty, "w").close()
-        assert pg.main(["--ledger", empty, "--check"]) == 1
-
-    def test_runs_dir_discovery(self, tmp_path):
-        pg = _load_perf_gate()
-        ledger = str(tmp_path / "run.jsonl")
-        _write_ledger(ledger, 0.050)
-        registry.write_manifest(str(tmp_path / "runs"), args=None,
-                                ledger=ledger)
-        baseline = str(tmp_path / "perf_baseline.json")
-        assert pg.main(["--runs_dir", str(tmp_path / "runs"),
-                        "--write-baseline", baseline]) == 0
-        assert pg.main(["--runs_dir", str(tmp_path / "runs"),
-                        "--baseline", baseline, "--check"]) == 0
-
-    def test_runs_dir_without_manifests_errors(self, tmp_path):
-        pg = _load_perf_gate()
-        assert pg.main(["--runs_dir", str(tmp_path),
-                        "--check"]) == 1
-
-
-# --- run registry -----------------------------------------------------
+# --- run registry ----------------------------------------------------
 
 
 class _Cfg:
@@ -578,17 +334,6 @@ class TestEmissionHold:
         assert [r["round"] for r in sink.records
                 if r["kind"] == "round"] == [0, 1, 2]
 
-    def test_roofline_utilization_derived_from_cost_model(self):
-        sink = _ListSink()
-        tel = Telemetry(sinks=[sink])
-        tel.expected_round_s = 0.25
-        tel.begin_round(0)
-        tel.merge_round_device_time(0, {"window_s": 1.0,
-                                        "busy_s": 0.5})
-        rec = tel._records[0]
-        assert rec["device_time"]["roofline_utilization"] == \
-            pytest.approx(0.5)
-
     def test_close_overrides_hold(self):
         sink = _ListSink()
         tel = Telemetry(sinks=[sink])
@@ -688,36 +433,17 @@ class TestProfileIntegration:
                 assert lane["wait_s"] + lane["wire_s"] == \
                     pytest.approx(lane["collective_s"], abs=1e-9)
             assert dt["skew"]["n_collectives"] >= 0
-            # the --profile cost model registered expected_round_s,
-            # so every traced round carries a utilization
-            assert 0 < dt["roofline_utilization"] <= 1.0
             total_window += dt["window_s"]
-        # windows tile the in-trace loop: round 1's window absorbs
-        # the one-off cost-model lowering, the last window extends to
-        # the trace stop — 10% relative + 50ms absolute covers both
+        # windows tile the in-trace loop: the last window extends to
+        # the trace stop — 10% relative + 50ms absolute covers it
         assert abs(total_window - loop_wall) <= \
             0.1 * loop_wall + 0.05
-
-        cost_meta = [r for r in recs if r["kind"] == "meta"
-                     and r.get("cost_model")]
-        assert len(cost_meta) == 1
-        cm = cost_meta[0]["cost_model"]
-        assert cm["expected_round_s"] > 0
-        assert cm["total_flops"] > 0
 
         trace_meta = [r for r in recs if r["kind"] == "meta"
                       and r.get("trace_rounds")]
         assert len(trace_meta) == 1
         assert trace_meta[0]["trace_rounds"] == 4
         assert trace_meta[0]["trace_busy_s"] > 0
-
-        # the ledger gates end-to-end through the perf-gate CLI
-        pg = _load_perf_gate()
-        baseline = str(tmp_path / "perf_baseline.json")
-        assert pg.main(["--ledger", ledger,
-                        "--write-baseline", baseline]) == 0
-        assert pg.main(["--ledger", ledger, "--baseline", baseline,
-                        "--check"]) == 0
 
 
 # --- v4: per-device attribution + collective skew ---------------------
@@ -870,20 +596,6 @@ class TestSkewAttribution:
                          + b["transfer_s"] + b["host_gap_s"])
                 assert parts == pytest.approx(b["window_s"],
                                               abs=1e-12), fixture
-
-    def test_skew_metrics_reach_the_gate(self):
-        rec = make_round_record(0)
-        rec["device_time"] = {"busy_s": 0.5, "skew": {
-            "n_collectives": 3, "max_enter_delta_s": 0.02,
-            "p95_enter_delta_s": 0.01, "straggler_device": "TPU:1"}}
-        metrics = gate.metrics_from_records([rec])
-        assert metrics["device:skew_max_enter_delta_s"]["median"] == \
-            pytest.approx(0.02)
-        assert metrics["device:skew_max_enter_delta_s"]["better"] == \
-            "lower"
-        assert metrics["device:skew_p95_enter_delta_s"]["better"] == \
-            "lower"
-
 
 class _SkewAlarmCfg(_AlarmCfg):
     alarm_collective_skew = 0.4
@@ -1143,188 +855,23 @@ class TestLedgerShards:
         assert "shard p1" in rendered
 
 
-# --- topology-keyed gate ----------------------------------------------
-
-
-class TestTopologyGate:
-    def test_entries_are_isolated_per_topology(self):
-        base = gate.make_baseline(
-            {"span:round_dispatch:ms": _metric(10.0)},
-            device_count=8, process_count=1, config_hash="cafe")
-        entry = gate.baseline_entry(base, 8, 1)
-        assert entry["device_count"] == 8
-        assert entry["config_hash"] == "cafe"
-        assert gate.baseline_entry(base, 4, 1) is None
-        verdict = gate.compare(
-            base, {"span:round_dispatch:ms": _metric(11.0)},
-            device_count=8, process_count=1)
-        assert verdict["topology"] == "d8p1"
-        assert verdict["regressions"] == []
-        # an ungated topology point fails LOUDLY, never silently
-        with pytest.raises(ValueError, match="d4p1"):
-            gate.compare(base,
-                         {"span:round_dispatch:ms": _metric(11.0)},
-                         device_count=4, process_count=1)
-
-    def test_update_replaces_only_one_topology(self):
-        base = gate.make_baseline(
-            {"span:a:ms": _metric(10.0)}, device_count=1,
-            process_count=1)
-        base = gate.update_baseline(
-            base, {"span:a:ms": _metric(5.0)}, source="x",
-            device_count=8, process_count=1, config_hash="c8")
-        assert sorted(base["topologies"]) == ["d1p1", "d8p1"]
-        base = gate.update_baseline(
-            base, {"span:a:ms": _metric(4.0)}, source="y",
-            device_count=8, process_count=1, config_hash="c8")
-        assert base["topologies"]["d8p1"]["metrics"][
-            "span:a:ms"]["median"] == pytest.approx(4.0)
-        assert base["topologies"]["d1p1"]["metrics"][
-            "span:a:ms"]["median"] == pytest.approx(10.0)
-
-    def test_v1_baseline_resolves_for_any_topology(self):
-        """Legacy topology-blind baselines keep working (their
-        historical behaviour) until re-captured."""
-        v1 = {"schema": 1, "ts": 0.0, "source": "old",
-              "metrics": {"span:a:ms": _metric(10.0)}}
-        assert gate.baseline_entry(v1, 8, 1)["metrics"]
-        verdict = gate.compare(v1, {"span:a:ms": _metric(11.0)},
-                               device_count=8, process_count=1)
-        assert verdict["regressions"] == []
-        migrated = gate.migrate_baseline(v1)
-        assert migrated["schema"] == gate.BASELINE_SCHEMA
-        assert migrated["topologies"][gate.ANY_TOPOLOGY][
-            "metrics"]["span:a:ms"]["median"] == 10.0
-
-    def test_unreadable_schema_raises(self):
-        with pytest.raises(ValueError, match="schema"):
-            gate.baseline_entry({"schema": 99}, 1, 1)
-
-    def test_mesh_shape_extends_topology_key(self):
-        """2D-mesh runs key separately per shape; 1-D layouts keep
-        the historical mesh-less key (v2 pins stay valid)."""
-        ms = {"clients": 4, "model": 2}
-        assert gate.topology_key(8, 1, ms) == "d8p1m4x2"
-        assert gate.topology_key(8, 1, {"clients": 8, "model": 1}) \
-            == "d8p1"
-        assert gate.topology_key(8, 1, None) == "d8p1"
-        assert gate.topology_key(None, None, ms) == gate.ANY_TOPOLOGY
-        base = gate.make_baseline(
-            {"span:a:ms": _metric(10.0)}, device_count=8,
-            process_count=1, mesh_shape=ms)
-        assert sorted(base["topologies"]) == ["d8p1m4x2"]
-        assert base["topologies"]["d8p1m4x2"]["mesh_shape"] == ms
-        # distinct shapes on the same chips are distinct entries
-        base = gate.update_baseline(
-            base, {"span:a:ms": _metric(7.0)}, device_count=8,
-            process_count=1, mesh_shape={"clients": 2, "model": 4})
-        assert sorted(base["topologies"]) == ["d8p1m2x4", "d8p1m4x2"]
-        verdict = gate.compare(base, {"span:a:ms": _metric(10.5)},
-                               device_count=8, process_count=1,
-                               mesh_shape=ms)
-        assert verdict["topology"] == "d8p1m4x2"
-        assert verdict["regressions"] == []
-
-    def test_mesh_run_falls_back_to_meshless_pin(self):
-        """A pin captured before mesh keying existed keeps gating a
-        2D run (migration), but an exact mesh-keyed entry wins."""
-        base = gate.make_baseline(
-            {"span:a:ms": _metric(10.0)}, device_count=8,
-            process_count=1)
-        ms = {"clients": 4, "model": 2}
-        entry = gate.baseline_entry(base, 8, 1, ms)
-        assert entry is not None and "mesh_shape" not in entry
-        base = gate.update_baseline(
-            base, {"span:a:ms": _metric(5.0)}, device_count=8,
-            process_count=1, mesh_shape=ms)
-        assert gate.baseline_entry(base, 8, 1, ms)["metrics"][
-            "span:a:ms"]["median"] == pytest.approx(5.0)
-        # the 1-D key never sees the mesh entry
-        assert gate.baseline_entry(base, 8, 1)["metrics"][
-            "span:a:ms"]["median"] == pytest.approx(10.0)
-
-    def test_cli_topology_cycle(self, tmp_path, capsys):
-        """One baseline file guards several topology points
-        independently: a regression at d4p1 fails ONLY d4p1, and a
-        topology with no entry is a loud failure."""
-        pg = _load_perf_gate()
-        good = str(tmp_path / "good.jsonl")
-        slow = str(tmp_path / "slow.jsonl")
-        baseline = str(tmp_path / "perf_baseline.json")
-        _write_ledger(good, 0.050)
-        _write_ledger(slow, 0.200)
-
-        assert pg.main(["--ledger", good, "--write-baseline", baseline,
-                        "--device_count", "8",
-                        "--process_count", "1"]) == 0
-        # no d4p1 entry yet: --check fails loudly...
-        assert pg.main(["--ledger", good, "--baseline", baseline,
-                        "--check", "--device_count", "4",
-                        "--process_count", "1"]) == 1
-        assert "no d4p1 entry" in capsys.readouterr().out
-        # ...and --write-baseline captures it without gating
-        assert pg.main(["--ledger", good, "--write-baseline", baseline,
-                        "--device_count", "4",
-                        "--process_count", "1"]) == 0
-        base = gate.load_baseline(baseline)
-        assert sorted(base["topologies"]) == ["d4p1", "d8p1"]
-        # a regression at ONE topology point fails that point only
-        assert pg.main(["--ledger", slow, "--baseline", baseline,
-                        "--check", "--device_count", "4",
-                        "--process_count", "1"]) == 1
-        assert pg.main(["--ledger", good, "--baseline", baseline,
-                        "--check", "--device_count", "8",
-                        "--process_count", "1"]) == 0
-
-    def test_cli_reads_topology_from_ledger_meta(self, tmp_path):
-        pg = _load_perf_gate()
-        ledger = str(tmp_path / "meta.jsonl")
-        with open(ledger, "w") as f:
-            f.write(json.dumps({"schema": 1, "kind": "meta",
-                                "ts": 0.0, "num_devices": 8}) + "\n")
-            rec = make_round_record(0)
-            rec["spans"] = {"round_dispatch": 0.05}
-            f.write(json.dumps(rec) + "\n")
-        records = pg.load_ledger_records(ledger)
-        # pre-fleet metas never recorded process_count: defaults to 1
-        assert pg.resolve_topology(None, records) == \
-            (8, 1, None, None, None, None, None, None, None)
-        # CLI overrides win
-        assert pg.resolve_topology(None, records,
-                                   device_count=2,
-                                   process_count=2) == \
-            (2, 2, None, None, None, None, None, None, None)
-        manifest = {"device_count": 16, "process_count": 4}
-        assert pg.resolve_topology(manifest, records) == \
-            (16, 4, None, None, None, None, None, None, None)
-
-    def test_resolve_mesh_shape_chain(self, tmp_path):
-        """Mesh layout resolution: CLI "CxM" wins, then the manifest
-        dict, then the ledger meta record; 1-D runs stay None."""
-        pg = _load_perf_gate()
-        ledger = str(tmp_path / "mesh.jsonl")
-        with open(ledger, "w") as f:
-            f.write(json.dumps({
-                "schema": 1, "kind": "meta", "ts": 0.0,
-                "num_devices": 8,
-                "mesh_shape": {"clients": 4, "model": 2}}) + "\n")
-        records = pg.load_ledger_records(ledger)
-        assert pg.resolve_topology(None, records) == \
-            (8, 1, {"clients": 4, "model": 2}, None, None, None,
-             None, None, None)
-        manifest = {"device_count": 8, "process_count": 1,
-                    "mesh_shape": {"clients": 2, "model": 4}}
-        assert pg.resolve_topology(manifest, records)[2] == \
-            {"clients": 2, "model": 4}
-        assert pg.resolve_topology(manifest, records,
-                                   mesh_shape="8x1")[2] == \
-            {"clients": 8, "model": 1}
-
-
 # --- registry topology keys -------------------------------------------
 
 
 class TestRegistryTopologyKeys:
+    def test_mesh_shape_extends_topology_key(self):
+        """2D-mesh runs key separately per shape; 1-D layouts keep
+        the historical mesh-less key."""
+        ms = {"clients": 4, "model": 2}
+        assert registry.topology_key(8, 1, ms) == "d8p1m4x2"
+        assert registry.topology_key(
+            8, 1, {"clients": 2, "model": 4}) == "d8p1m2x4"
+        assert registry.topology_key(
+            8, 1, {"clients": 8, "model": 1}) == "d8p1"
+        assert registry.topology_key(8, 1, None) == "d8p1"
+        assert registry.topology_key(None, None, ms) == \
+            registry.ANY_TOPOLOGY
+
     def test_run_topology_and_key(self):
         m = {"config_hash": "c", "device_count": 8, "process_count": 2}
         assert registry.run_topology(m) == (8, 2)
@@ -1386,6 +933,22 @@ class TestRegistryTopologyKeys:
 
 
 # --- scaling curves in the report -------------------------------------
+
+
+def _write_ledger(path, round_s):
+    """A synthetic ledger whose round_dispatch span is ``round_s``."""
+    with open(path, "w") as f:
+        for r in range(8):
+            rec = make_round_record(r)
+            rec["spans"] = {"round_dispatch": round_s}
+            rec["uplink_bytes"] = rec["downlink_bytes"] = 1024.0
+            rec["device_time"] = {"window_s": round_s,
+                                  "busy_s": 0.8 * round_s,
+                                  "compute_s": 0.7 * round_s,
+                                  "collective_s": 0.1 * round_s,
+                                  "transfer_s": 0.0,
+                                  "host_gap_s": 0.2 * round_s}
+            f.write(json.dumps(rec) + "\n")
 
 
 class TestScalingCurves:

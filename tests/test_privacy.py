@@ -707,61 +707,23 @@ class TestCheckpointContinuity:
 
 
 # ------------------------------------------------------------------ #
-# perf-gate privacy keying: p<eps> topology fragment, no fallback    #
+# run-registry privacy keying: the p<eps> topology fragment          #
 # ------------------------------------------------------------------ #
 
-class TestPerfGatePrivacyKeying:
+class TestPrivacyKeying:
     def test_privacy_suffix_forms(self):
-        from commefficient_tpu.telemetry import gate
+        from commefficient_tpu.telemetry import registry
 
-        assert gate.privacy_suffix(None) == ""
+        assert registry.privacy_suffix(None) == ""
         # 0.0 is DP with an unlimited budget, NOT an absence
-        assert gate.privacy_suffix(0.0) == "p0"
-        assert gate.privacy_suffix(3.5) == "p3.5"
-        assert gate.privacy_suffix(8) == "p8"
-        assert gate.topology_key(8, 1, dp_epsilon=3.5) == "d8p1p3.5"
-        assert gate.topology_key(8, 1, wire_dtype="int8",
-                                 band="0.05:0.6",
-                                 dp_epsilon=2.0) == \
-            "d8p1qint8b0.05-0.6p2"
-        assert gate.topology_key(dp_epsilon=1.5) == "any-p1.5"
-
-    def test_no_cross_budget_fallback(self):
-        from commefficient_tpu.telemetry import gate
-
-        m = {"round_total": {"median": 1.0, "mad": 0.1, "n": 5,
-                             "better": "lower"}}
-        base = gate.make_baseline(m, device_count=8, process_count=1)
-        base = gate.update_baseline(base, m, device_count=8,
-                                    process_count=1, dp_epsilon=2.5)
-        # a DP run resolves ONLY its own budget's pin
-        assert gate.baseline_entry(base, 8, 1,
-                                   dp_epsilon=2.5) is not None
-        assert gate.baseline_entry(base, 8, 1, dp_epsilon=4.0) is None
-        assert gate.baseline_entry(base, 8, 1, dp_epsilon=0.0) is None
-        # a DP run never resolves the noiseless pin, and a noiseless
-        # run never resolves a DP one
-        clean = gate.baseline_entry(base, 8, 1)
-        assert clean is not None and "dp_epsilon" not in clean
-        only_dp = gate.make_baseline(m, device_count=8,
-                                     process_count=1, dp_epsilon=2.5)
-        assert gate.baseline_entry(only_dp, 8, 1) is None
-        with pytest.raises(ValueError):
-            gate.compare(only_dp, m, device_count=8, process_count=1)
-        with pytest.raises(ValueError):
-            gate.compare(base, m, device_count=8, process_count=1,
-                         dp_epsilon=4.0)
-        # the budget is recorded on the entry for auditability
-        hit = gate.baseline_entry(base, 8, 1, dp_epsilon=2.5)
-        assert hit["dp_epsilon"] == 2.5
-        # mesh fallback keeps the privacy fragment (mesh is the ONLY
-        # fragment with a migration fallback)
-        assert gate.baseline_entry(
-            base, 8, 1, mesh_shape={"clients": 4, "model": 2},
-            dp_epsilon=2.5) is not None
-        assert gate.baseline_entry(
-            only_dp, 8, 1,
-            mesh_shape={"clients": 4, "model": 2}) is None
+        assert registry.privacy_suffix(0.0) == "p0"
+        assert registry.privacy_suffix(3.5) == "p3.5"
+        assert registry.privacy_suffix(8) == "p8"
+        assert registry.topology_key(8, 1, dp_epsilon=3.5) == "d8p1p3.5"
+        assert registry.topology_key(
+            8, 1, wire_dtype="int8", band="0.05:0.6",
+            dp_epsilon=2.0) == "d8p1qint8b0.05-0.6p2"
+        assert registry.topology_key(dp_epsilon=1.5) == "any-p1.5"
 
     def test_registry_run_key_privacy_fragment(self):
         from commefficient_tpu.telemetry import registry
@@ -779,33 +741,6 @@ class TestPerfGatePrivacyKeying:
         man["config"]["dp"] = "off"
         assert registry.run_dp_epsilon(man) is None
         assert registry.run_key(man) == ("abc", 8, 1)
-
-    def test_perf_gate_resolves_dp_epsilon(self):
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))), "scripts"))
-        import perf_gate
-
-        man = {"config": {"mode": "sketch", "dp": "sketch",
-                          "dp_epsilon": 3.5},
-               "device_count": 2, "process_count": 1}
-        assert perf_gate.resolve_topology(man)[7] == 3.5
-        # ledger meta plan carries enough to re-derive the key
-        recs = [{"kind": "meta", "num_devices": 4,
-                 "plan": {"dp": {"mode": "sketch", "clip": 1.0,
-                                 "noise_mult": 1.0, "delta": 1e-5,
-                                 "epsilon_budget": 2.0}}}]
-        assert perf_gate.resolve_topology(None, recs)[7] == 2.0
-        # an unlimited budget survives the chain as 0.0, never None
-        recs[0]["plan"]["dp"]["epsilon_budget"] = 0.0
-        assert perf_gate.resolve_topology(None, recs)[7] == 0.0
-        # CLI override wins; noiseless runs resolve to None
-        assert perf_gate.resolve_topology(man, dp_epsilon=9.0)[7] \
-            == 9.0
-        man["config"]["dp"] = "off"
-        assert perf_gate.resolve_topology(man)[7] is None
 
     def test_round_plan_records_dp_block(self):
         from commefficient_tpu.core.rounds import round_plan

@@ -146,8 +146,8 @@ def test_sp_no_full_vocab_logits_buffer():
 
 def test_sp_tokens_per_chunk_threading(monkeypatch):
     """--tokens_per_chunk reaches the chunked vocab CE: 0 resolves to
-    the auto default (256 — the measured memory knee, BENCHMARKS.md SP
-    table), an explicit value passes through unchanged (round-3 review
+    the auto default (256, the measured memory knee: core/rounds_sp.py),
+    an explicit value passes through unchanged (round-3 review
     weak #3: the knee was hard-coded out of reach)."""
     from commefficient_tpu.core import rounds_sp
     from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
