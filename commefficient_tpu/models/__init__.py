@@ -40,7 +40,8 @@ def _ensure_loaded():
     import importlib
     import importlib.util
     for mod in ("resnet9", "fixup_resnet9", "resnet18", "resnets", "gpt2",
-                "joyai", "nemotron_h", "granite_hybrid", "smallthinker"):
+                "joyai", "nemotron_h", "granite_hybrid", "smallthinker",
+                "ouro"):
         name = f"commefficient_tpu.models.{mod}"
         # skip modules not yet written, but let real import errors
         # inside existing ones propagate

@@ -265,11 +265,15 @@ def _zeros(shape, dtype, like):
     return jnp.zeros(shape, dtype) + (jnp.ravel(like)[0] * 0).astype(dtype)
 
 
-def _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk):
+def _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk, weights=None):
     """Every position carries a label (raw token ids): straight slices
     of ``tokens_per_chunk`` rows through a ``lax.scan``, each chunk's
     logits recomputed in the backward (``jax.checkpoint``). No
-    partition, gather or write-back."""
+    partition, gather or write-back. ``weights`` (E, Tm) float32, where
+    given, multiply each position's nll before the sum (Σ valid stays
+    the count) and are differentiated through: a position's cotangent
+    is its nll, from the chunk's recomputed logits. With none the
+    program is the one it was before the argument existed."""
     E, Tm, C = h.shape
     pad_label = -1  # no token id; masks the positions padded below
     tc = max(1, min(Tm, tokens_per_chunk // max(E, 1)))
@@ -284,19 +288,23 @@ def _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk):
         lp = jnp.pad(labels, ((0, 0), (0, pad)),
                      constant_values=pad_label)
         wte_c = wte.astype(dtype)  # cast once, outside the scan
+        wp = None if weights is None else jnp.pad(
+            weights.astype(jnp.float32), ((0, 0), (0, pad)))
 
     @jax.checkpoint
-    def chunk_sums(hc, lc, w):
+    def chunk_sums(hc, lc, w, *pc):
         logits = jnp.einsum("etc,vc->etv", hc, w,
                             preferred_element_type=jnp.float32)
         nll, valid = token_nll(logits, lc, pad_label)
+        for c in pc:        # the chunk's weights, where there are any
+            nll = nll * c
         return jnp.sum(nll * valid, -1), jnp.sum(valid, -1)
 
     def body(carry, i):
         sn, sv = carry
-        hc = jax.lax.dynamic_slice_in_dim(hp, i * tc, tc, axis=1)
-        lc = jax.lax.dynamic_slice_in_dim(lp, i * tc, tc, axis=1)
-        n, v = chunk_sums(hc, lc, wte_c)
+        hc, lc, *pc = (jax.lax.dynamic_slice_in_dim(a, i * tc, tc, axis=1)
+                       for a in (hp, lp) + (() if wp is None else (wp,)))
+        n, v = chunk_sums(hc, lc, wte_c, *pc)
         return (sn + n, sv + v), None
 
     # the zero init is derived from the inputs (x*0 sums) rather than
@@ -474,7 +482,7 @@ def head_compacts(ignore_index) -> bool:
 
 
 def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
-                        tokens_per_chunk=1024):
+                        tokens_per_chunk=1024, weights=None):
     """Per-example (Σ nll, Σ valid) of the tied-head LM cross-entropy
     without materialising the (E, T, V) logits tensor.
 
@@ -495,7 +503,10 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     the positions). ``ignore_index=None`` says that every position
     carries a label (the causal LMs' raw token ids): nothing is
     ordered, gathered or written back, the chunks are straight slices
-    and their count is static (``_dense_nll_sums``).
+    and their count is static (``_dense_nll_sums``). That path alone
+    takes ``weights`` (E, Tm): Σ nll becomes Σ weight · nll, a
+    position at a time, differentiable in the weights too (a looped
+    LM's expected loss over its exit distribution: ``models/ouro.py``).
 
     Inside a ``vmap`` over clients that share the table and whose
     losses are summed before they are differentiated, which its
@@ -509,7 +520,11 @@ def lm_nll_sums_chunked(h, wte, labels, dtype, ignore_index=-100,
     (``vmap`` of ``grad``: core/rounds.py ``client_round``), where a
     sum over the clients would be every client's wrong answer."""
     if not head_compacts(ignore_index):
-        return _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk)
+        return _dense_nll_sums(h, wte, labels, dtype, tokens_per_chunk,
+                               weights)
+    if weights is not None:
+        raise ValueError("position weights go with ignore_index=None: the "
+                         "compacted head sums a sequence's nll unweighted")
     with jax.named_scope("lm_head"):
         sv = jnp.sum(labels != ignore_index, axis=1, dtype=jnp.float32)
         hd = h.astype(dtype)

@@ -47,8 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.models import register_model
-from commefficient_tpu.models.mixers import (GQAttention, Mamba2Mixer,
-                                             Weights, attn_plan)
+from commefficient_tpu.models.mixers import (GatedMLP, GQAttention,
+                                             Mamba2Mixer, attn_plan)
 from commefficient_tpu.models.norms import RMSNorm
 
 #: a client's counts, which ``causal_lm_loss`` returns beside the loss:
@@ -158,18 +158,6 @@ class GraniteHybridConfig:
         return self.layer_types.count(kind)
 
 
-class GatedMLP(Weights):
-    @nn.compact
-    def __call__(self, x):
-        cfg, dt = self.cfg, self.cfg.dtype
-        C, F = cfg.hidden_size, cfg.shared_intermediate_size
-        w_in, w_out = self.mat("w_in", (C, 2 * F)), self.mat("w_out", (F, C))
-        with jax.named_scope("dense_mlp"):
-            ab = x @ w_in.astype(dt)
-            return (jax.nn.silu(ab[..., :F]) * ab[..., F:]) \
-                @ w_out.astype(dt)
-
-
 class Block(nn.Module):
     """``(h after both sub-layers, (chunks scanned, attention built
     blocked, attention built dense))``, the counts as the mixer built
@@ -193,7 +181,8 @@ class Block(nn.Module):
             raise ValueError(f"no mixer for layer type {self.kind!r}")
         x = x + (r * y).astype(dt)
         h = RMSNorm(cfg.rms_norm_eps, name="norm2")(x).astype(dt)
-        return x + (r * GatedMLP(cfg, name="mlp")(h)).astype(dt), built
+        y = GatedMLP(cfg, cfg.shared_intermediate_size, name="mlp")(h)
+        return x + (r * y).astype(dt), built
 
 
 @register_model("GraniteHybridLM")

@@ -1,7 +1,9 @@
 """The Mamba-2 state-space mixer and grouped-query attention that the
 hybrid language models share (``models/nemotron_h.py``,
 ``models/granite_hybrid.py``), as ``models/moe.py`` is the expert layer
-two models route with. One code, parametrised by what differs between
+two models route with; and the gated SiLU feed-forward part of the
+dense blocks (``GatedMLP``: ``models/granite_hybrid.py``,
+``models/ouro.py``). One code, parametrised by what differs between
 the models: attention's score scale, and per layer whether q and k
 are rotated by their positions and whether a query sees a window of
 its past only.
@@ -599,3 +601,22 @@ class GQAttention(Weights):
                 self.query_block, self.window)
         out = out.reshape(S, T, Hq * D) @ wo.astype(dt)
         return (out, blocked) if self.with_form else out
+
+
+class GatedMLP(Weights):
+    """The gated SiLU feed-forward part, ``(silu(x W_g) * x W_u) W_d``
+    with ``w_in`` = [W_g | W_u] one (C, 2 ``width``) matrix and
+    ``w_out`` = W_d, no bias (``models/granite_hybrid.py``,
+    ``models/ouro.py``); scope ``dense_mlp`` holds both products and
+    the gate, the norm before it lies outside."""
+    width: int = 0
+
+    @nn.compact
+    def __call__(self, x):
+        dt = self.cfg.dtype
+        C, F = x.shape[-1], self.width
+        w_in, w_out = self.mat("w_in", (C, 2 * F)), self.mat("w_out", (F, C))
+        with jax.named_scope("dense_mlp"):
+            ab = x @ w_in.astype(dt)
+            return (jax.nn.silu(ab[..., :F]) * ab[..., F:]) \
+                @ w_out.astype(dt)

@@ -50,7 +50,7 @@ MAX_SEQ_LEN = 256  # static pad length (persona sequences are short)
 #: ``COUNTERS``); any other value (the shared parser's default is a CV
 #: model) means GPT2DoubleHeads, as before the flag was read here
 CAUSAL_LMS = ("JoyAIFlashLM", "NemotronHLM", "GraniteHybridLM",
-              "SmallThinkerLM")
+              "SmallThinkerLM", "OuroLM")
 
 
 def is_causal_lm(args) -> bool:

@@ -651,7 +651,8 @@ def _traced_ctx(ops, cell, config, monkeypatch):
 
 @pytest.mark.parametrize("cell,config,least_ms", [
     ("smallthinker_fetchsgd_w2_t8192", "smallthinker-21ba3b-ep8", 47.616),
-    ("granite4hm_fetchsgd_w4_t2048", CONFIG, 1.047)])
+    ("granite4hm_fetchsgd_w4_t2048", CONFIG, 1.047),
+    ("ouro_fetchsgd_w2_t2048", "ouro-2.6b-pp6-l8", 16.752)])
 def test_the_attention_roofline_reader(cell, config, least_ms, monkeypatch):
     """On a trace that names no kernel operation the reader finds
     nothing, and says so by None (the parent's program, a cell off the
@@ -674,8 +675,8 @@ def test_the_attention_roofline_reader(cell, config, least_ms, monkeypatch):
     T = ctx["cell"]["sequence_length"]
     causal = T * (T + 1) // 2
     # Granite's cut holds one attention layer; SmallThinker's one full
-    # and three that see 4,096 keys
-    pairs = causal if "kinds" in z else causal + 3 * (
-        4096 * 4097 // 2 + (T - 4096) * 4096)
+    # and three that see 4,096 keys; Ouro's 8 full ones run 4 times
+    pairs = causal if "kinds" in z else 32 * causal if "steps" in z \
+        else causal + 3 * (4096 * 4097 // 2 + (T - 4096) * 4096)
     flops = 12 * z["D"] * z["Hq"] * pairs * ctx["cell"]["clients_per_round"]
     assert 100.0 * flops / 197e12 / 0.1 == pytest.approx(least_ms, rel=1e-3)
