@@ -17,8 +17,22 @@ reference this module is tested against. What is TPU-shaped here:
   expectation, so one pass at a quarter full is the rule.
 - bf16 compute on float32 parameters as ``models/gpt2.py``: norms,
   RoPE, softmax, router scores and selection in float32.
+- **On the chip the latent attention is one flash kernel a layer**
+  (``models/mixers.py gqa_attention``, the code the other LMs'
+  attention runs): q = [q_nope | RoPE(q_rope)] and k = [k_nope |
+  RoPE(k_rope), the same for every head] are 192 wide, v 128, a group
+  of one query head a key/value head, so the float32 (heads, T, T)
+  scores never leave VMEM. The kernel takes no scale: it is folded
+  into q where q is still float32 (the ``q_b`` product's accumulator),
+  so q is rounded to bf16 once. Off the chip, and where T is not
+  whole tiles, the two score products on a materialised float32 score
+  tensor stay as they were (``mla_plan``: the platform and the shapes
+  decide, no flag). RoPE is ``rope`` in both.
 
-Scopes (``PERF.md`` section 3): ``mla_attn``, ``moe_route`` (scores,
+Scopes (``PERF.md`` section 3): ``mla_attn`` (RoPE, then either the
+two score products, softmax and the value product, or the
+concatenations to the kernel's operands, its transposes and its three
+device operations; the latent projections outside it), ``moe_route`` (scores,
 top-k, dispatch order and gather), ``moe_experts`` (the ragged
 products), ``moe_combine`` (gates, scatter-add, shared expert add),
 ``mtp``; the heads' ``lm_head`` is ``lm_nll_sums_chunked``'s. Inside
@@ -34,14 +48,28 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from commefficient_tpu.models import register_model
+from commefficient_tpu.models.mixers import attn_plan, gqa_attention
 from commefficient_tpu.models.norms import RMSNorm
-from commefficient_tpu.models.moe import MOE_COUNTERS as COUNTERS  # noqa: F401
-from commefficient_tpu.models.moe import MOE_STATS  # noqa: F401
-from commefficient_tpu.models.moe import (client_stats, dispatch, fold_stats,
+from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS,
+                                          client_stats, dispatch, fold_stats,
                                           layer_stats, no_stats, route,
                                           routed_experts)
+
+#: a client's counts, which ``causal_lm_loss`` returns beside the loss:
+#: ``models/moe.py``'s six; how many latent-attention layers (the MTP
+#: module's among them) the flash kernel built (``mla_plan``: on a TPU,
+#: T whole tiles); the (query, key) scores the client's attention
+#: computes over heads, sequences and layers, and how many of them the
+#: causal mask needs
+STATS = MOE_STATS + ("attn_kernel_layers", "attn_pairs", "attn_pairs_needed")
+
+#: how ``FedModel`` folds them into the round record's counters
+COUNTERS = MOE_COUNTERS + (
+    ("attn.kernel_layers", np.max), ("attn.pairs", np.sum),
+    ("attn.pairs_needed", np.sum))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +166,51 @@ class _Weights(nn.Module):
         return self.param(name, init, shape)
 
 
+def mla_plan(cfg, S, T):
+    """How a latent-attention layer of (S, T) is built, as
+    ``gqa_attention`` would build it from the platform and the shapes
+    (``models/mixers.py attn_plan``): with the plan's ``kernel`` the
+    flash kernel on 192-wide q and k and 128-wide v, without it the
+    dense form."""
+    return attn_plan(S, T, cfg.num_attention_heads, head_dim=(
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim))
+
+
+def mla_attention(cfg, qh, kvh, k_rope, kernel):
+    """What ``mla_attn`` holds: ``qh`` (S, T, H, dn + dr) = [q_nope |
+    q_rope], ``kvh`` (S, T, H, dn + dv) = [k_nope | v], ``k_rope``
+    (S, T, dr), the same for every head -> (S, T, H, dv) in
+    ``cfg.dtype``. RoPE in float32 on both rotated parts, then, with
+    ``kernel`` (``mla_plan``'s), one 192-wide score product through
+    ``gqa_attention`` at a group of one query head a key/value head
+    (the kernel takes no scale: q gets it here in float32, which is
+    what ``MLA`` hands over there, the ``q_b`` product's accumulator,
+    so q is rounded to ``cfg.dtype`` once, and ``gqa_attention`` is
+    told ``scale`` None: q carries it); without it the two score
+    products, their float32 (S, H, T, T) sum scaled and masked, the
+    plain softmax and the value product."""
+    dt, dn = cfg.dtype, cfg.qk_nope_head_dim
+    S, T, H, _ = qh.shape
+    scale = float(qh.shape[-1] ** -0.5)
+    if kernel:
+        qh = qh.astype(jnp.float32) * scale
+    qr = rope(qh[..., dn:].astype(jnp.float32), cfg.rope_theta)
+    kr = rope(k_rope.astype(jnp.float32), cfg.rope_theta)
+    if kernel:
+        q = jnp.concatenate([qh[..., :dn], qr], axis=-1).astype(dt)
+        k = jnp.concatenate([kvh[..., :dn], jnp.broadcast_to(
+            kr.astype(dt)[:, :, None], (S, T, H, kr.shape[-1]))], axis=-1)
+        return gqa_attention(q[:, :, :, None], k, kvh[..., dn:], None)[0][
+            :, :, :, 0]
+    att = (jnp.einsum("sthd,suhd->shtu", qh[..., :dn], kvh[..., :dn],
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("sthd,sud->shtu", qr.astype(dt), kr.astype(dt),
+                        preferred_element_type=jnp.float32)) * scale
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
+    return jnp.einsum("shtu,suhd->sthd", att.astype(dt), kvh[..., dn:])
+
+
 class MLA(_Weights):
     @nn.compact
     def __call__(self, x):
@@ -154,22 +227,15 @@ class MLA(_Weights):
         ckv = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(kv[..., :r])
         kv_b = self.mat("kv_b", (r, H * (dn + dv)))
         o = self.mat("o", (H * dv, C))
-        qh = (cq.astype(dt) @ q_b.astype(dt)).reshape(S, T, H, dn + dr)
+        kernel = mla_plan(cfg, S, T).kernel
+        # beside the kernel q stays the product's float32 accumulator:
+        # ``mla_attention`` folds the scale in before the one rounding
+        qh = jnp.matmul(cq.astype(dt), q_b.astype(dt),
+                        preferred_element_type=jnp.float32 if kernel
+                        else None).reshape(S, T, H, dn + dr)
         kvh = (ckv.astype(dt) @ kv_b.astype(dt)).reshape(S, T, H, dn + dv)
         with jax.named_scope("mla_attn"):
-            qr = rope(qh[..., dn:].astype(jnp.float32), cfg.rope_theta)
-            kr = rope(kv[..., r:].astype(jnp.float32), cfg.rope_theta)
-            att = (jnp.einsum("sthd,suhd->shtu", qh[..., :dn],
-                              kvh[..., :dn],
-                              preferred_element_type=jnp.float32)
-                   + jnp.einsum("sthd,sud->shtu", qr.astype(dt),
-                                kr.astype(dt),
-                                preferred_element_type=jnp.float32)) \
-                * float((dn + dr) ** -0.5)
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            att = jax.nn.softmax(jnp.where(causal, att, -jnp.inf), axis=-1)
-            out = jnp.einsum("shtu,suhd->sthd", att.astype(dt),
-                             kvh[..., dn:])
+            out = mla_attention(cfg, qh, kvh, kv[..., r:], kernel)
         return out.reshape(S, T, H * dv) @ o.astype(dt)
 
 
@@ -302,7 +368,7 @@ class JoyAIFlashLM(nn.Module):
 
 def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     """Per-sequence loss (main head's mean NLL + ``mtp_loss_weight`` x
-    the MTP head's) and the ``MOE_STATS`` scalars."""
+    the MTP head's) and the ``STATS`` scalars."""
     from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
     cfg = module.cfg
     final, mtp, head, stats = module.apply({"params": params}, input_ids)
@@ -318,4 +384,13 @@ def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
         loss = loss + cfg.mtp_loss_weight * mn / jnp.maximum(mv, 1.0)
     expert_layers = (cfg.num_hidden_layers - cfg.first_k_dense_replace
                      + cfg.num_nextn_predict_layers)
-    return loss, client_stats(stats, expert_layers * cfg.n_held_experts)
+    # as ``MLA`` builds each layer, from the shapes alone
+    S, T = input_ids.shape
+    plan = mla_plan(cfg, S, T)
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    heads = S * cfg.num_attention_heads * layers
+    attn = (layers * (plan.kernel is not None), heads * plan.pairs,
+            heads * plan.needed)
+    return loss, client_stats(
+        stats, expert_layers * cfg.n_held_experts) + tuple(
+            jnp.float32(v) for v in attn)

@@ -61,31 +61,39 @@ What is TPU-shaped here:
   ``lax.dynamic_slice`` from keys padded in front, masked to the exact
   band, the plain float32 softmax of the short rows (``attn_plan``).
   A window of T or more is the plain causal layer and is built as one.
-- **On the chip both blocked forms are one flash kernel.** Where the
-  program runs on a TPU and would build a blocked form (the full
-  layer's or the band's), with no ``query_block`` stated, T whole
-  tiles and a head size of 64 or 128, ``gqa_attention`` calls the
-  library's splash kernel instead (``jax.experimental.pallas.ops.tpu.
-  splash_attention``, its multi-query form: one call a key/value head
-  with its group of query heads, under a ``vmap`` over key/value heads
-  and one over sequences): online softmax over (tile, tile) blocks of
-  scores that never leave VMEM, float32 scores, maxima, sums and
-  accumulator, bf16 operands; a ``CausalMask`` or a ``LocalMask((T, T),
-  (window - 1, 0), 0)``, whose tables are built at trace time, once a
-  shape, and name the tiles the grid visits: the causal half, the
-  band. The backward pass is the kernel's own (dq and dk/dv kernels
-  that recompute the tiles from the kept output and log-sum-exp), so
-  the blocked forms' ``jax.checkpoint`` and its forward pass are gone.
-  The tile is 1,024 where that wastes at most 15 % on what the mask
-  hides, else 512 (``attn_kernel_block``; PERF.md section 6, PR 42:
-  SmallThinker's full layer 204.7 -> 45.1 ms, a window layer 108.9 ->
-  41.7, Granite's layer 29.5 -> 11.8, forward + backward alone). The
-  scale is folded into q (float32, rounded to bf16 once more; exact
-  where it is a power of two). Off the chip, with a ``query_block``
-  (``SmallThinkerConfig.attn_query_block``, the probe's ``blocked_*``
-  and ``band_*`` rows) and at any other shape the ``jax.numpy`` forms
-  above are built, and the dense form is untouched: Nemotron's 4 query
-  heads at T 2,048 stay dense on the chip too.
+- **On the chip every layer the kernel can take is one flash kernel.**
+  Where the program runs on a TPU, with no ``query_block`` stated, T
+  whole tiles and head sizes the kernel is given
+  (``ATTN_KERNEL_HEAD_DIMS``: 64 or 128 for q, k and v; 192 for q and
+  k beside 128 for v, ``models/joyai.py``'s latent attention),
+  ``gqa_attention`` calls the library's splash kernel
+  (``jax.experimental.pallas.ops.tpu.splash_attention``, its
+  multi-query form: one call a key/value head with its group of query
+  heads, under a ``vmap`` over key/value heads and one over
+  sequences), whatever the size of the scores: the dense form's
+  float32 (heads, T, T) tensor costs the chip its bandwidth several
+  times a layer, forward, under ``--remat`` and backward (PERF.md
+  section 6, PR 49: JoyAI's 4 heads at T 1,024 and Nemotron's 4 at
+  2,048, 67 MB a client, both dense until then). Online softmax over
+  (tile, tile) blocks of scores that never leave VMEM, float32 scores,
+  maxima, sums and accumulator, bf16 operands; a ``CausalMask`` or a
+  ``LocalMask((T, T), (window - 1, 0), 0)``, whose tables are built at
+  trace time, once a shape, and name the tiles the grid visits: the
+  causal half, the band. The kernel reads q and k's head size from q
+  and v's from v, so the two may differ. The backward pass is the
+  kernel's own (dq and dk/dv kernels that recompute the tiles from the
+  kept output and log-sum-exp), so the blocked forms'
+  ``jax.checkpoint`` and its forward pass are gone. The tile is 1,024
+  where that wastes at most 15 % on what the mask hides, else 512
+  (``attn_kernel_block``; PERF.md section 6, PR 42: SmallThinker's
+  full layer 204.7 -> 45.1 ms, a window layer 108.9 -> 41.7, Granite's
+  layer 29.5 -> 11.8, forward + backward alone). The scale is folded
+  into q (float32, rounded to bf16 once more; exact where it is a
+  power of two; a caller whose q carries its scale already says
+  ``scale`` None and q goes in as it is). Off the chip, with a
+  ``query_block`` (``SmallThinkerConfig.attn_query_block``, the
+  probe's ``blocked_*`` and ``band_*`` rows) and at any other shape
+  the ``jax.numpy`` forms above are built, untouched.
 
 Every form is chosen from the platform and the shapes alone
 (``attn_plan``): no flag, no environment variable, no model's name.
@@ -130,10 +138,11 @@ ATTN_BLOCK_BYTES = 1 << 25
 #: more of what the mask hides: it is taken where the tiles visited hold
 #: at most ``ATTN_KERNEL_WASTE`` scores for each one needed (the causal
 #: half of 8,192: 1.12; its band of 4,096: 1.25, so 512 there). And the
-#: head sizes the kernel is given
+#: head sizes the kernel is given, (q and k's, v's): the library reads
+#: the one from q and the other from v
 ATTN_KERNEL_BLOCKS = (1024, 512)
 ATTN_KERNEL_WASTE = 1.15
-ATTN_KERNEL_HEAD_DIMS = (64, 128)
+ATTN_KERNEL_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 
 # --- the Mamba-2 recurrence, by chunks --------------------------------------
@@ -320,10 +329,12 @@ def attn_plan(S, T, Hq, window=None, query_block=None, head_dim=None,
 
     The flash kernel takes the layer where all of this holds, and
     nothing else is asked: the program runs on a TPU (``platform``,
-    default ``_platform()``); no ``query_block`` is given; the
-    ``jax.numpy`` form would be a blocked one; T is whole tiles
-    (``attn_kernel_block``); ``head_dim`` is one of
-    ``ATTN_KERNEL_HEAD_DIMS`` (None: not said, no kernel)."""
+    default ``_platform()``); no ``query_block`` is given; T is whole
+    tiles (``attn_kernel_block``); ``head_dim`` (one size for q, k and
+    v, or the pair (q and k's, v's); None: not said, no kernel) is one
+    of ``ATTN_KERNEL_HEAD_DIMS``. How large the ``jax.numpy`` form's
+    scores would be is not asked there: it chooses between the dense
+    and the blocked form off the chip."""
     if window is not None and window < 1:
         raise ValueError(f"a window of {window} keys sees nothing")
     w = None if window is None or window >= T else int(window)
@@ -333,8 +344,10 @@ def attn_plan(S, T, Hq, window=None, query_block=None, head_dim=None,
     blocked = w is not None or bq < T
     kernel = {"tpu": "splash", "interpret": "splash_interpret"}.get(
         _platform() if platform is None else platform)
-    tile = attn_kernel_block(T, needed, w) if kernel and blocked \
-        and not query_block and head_dim in ATTN_KERNEL_HEAD_DIMS else None
+    dims = tuple(head_dim) if isinstance(head_dim, (tuple, list)) \
+        else (head_dim, head_dim)
+    tile = attn_kernel_block(T, needed, w) if kernel and not query_block \
+        and dims in ATTN_KERNEL_HEAD_DIMS else None
     if tile:
         tiles, widest = kernel_tiles(T, tile, w)
         return AttnPlan(tile, widest * tile, True, w is not None,
@@ -394,7 +407,8 @@ def _banded_attention(q, k, v, scale, plan, window):
     qp = jnp.moveaxis(qp.reshape(S, nq, bq, Hkv, g, D), 1, 0)
     out = jax.lax.map(lambda a: band(*a),
                       (qp, jnp.arange(nq, dtype=jnp.int32) * bq))
-    return jnp.moveaxis(out, 0, 1).reshape(S, nq * bq, Hkv, g, D)[:, :T]
+    return jnp.moveaxis(out, 0, 1).reshape(
+        S, nq * bq, Hkv, g, v.shape[-1])[:, :T]
 
 
 @functools.lru_cache(maxsize=32)
@@ -426,11 +440,14 @@ def _kernel_attention(q, k, v, scale, plan, window):
     the tiles the mask lets through, the scores never outside VMEM;
     its backward pass (two more kernels) keeps the output and the rows'
     log-sum-exp and recomputes the tiles. The kernel takes no scale:
-    it is folded into q, in float32, rounded once more to q's dtype."""
+    it is folded into q, in float32, rounded once more to q's dtype;
+    ``scale`` None says that q carries it already. v's head size may
+    be another than q and k's."""
     S, T, Hkv, g, D = q.shape
     kernel = _splash_kernel(T, int(window) if plan.banded else None, g,
                             plan.block, plan.kernel == "splash_interpret")
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qs = q if scale is None \
+        else (q.astype(jnp.float32) * scale).astype(q.dtype)
     heads = jax.vmap(jax.vmap(kernel))      # sequences, key/value heads
     out = heads(jnp.transpose(qs, (0, 2, 3, 1, 4)),
                 jnp.transpose(k, (0, 2, 1, 3)),
@@ -440,10 +457,11 @@ def _kernel_attention(q, k, v, scale, plan, window):
 
 def gqa_attention(q, k, v, scale, query_block=None, window=None):
     """Causal softmax attention of grouped query heads, exact: ``q``
-    (S, T, Hkv, Hq / Hkv, D), ``k`` / ``v`` (S, T, Hkv, D) ->
-    ((S, T, Hkv, Hq / Hkv, D) in q's dtype, whether the (heads, T, T)
-    scores never exist: a blocked form or the kernel); scores, softmax
-    and its statistics float32, the value product in q's dtype.
+    (S, T, Hkv, Hq / Hkv, D), ``k`` (S, T, Hkv, D), ``v`` (S, T, Hkv,
+    Dv) -> ((S, T, Hkv, Hq / Hkv, Dv) in q's dtype, whether the (heads,
+    T, T) scores never exist: a blocked form or the kernel); scores,
+    softmax and its statistics float32, the value product in q's dtype.
+    ``scale`` multiplies the scores; None where q carries it already.
     ``query_block`` queries at a time (default: ``attn_query_block`` of
     the shapes); with fewer than T the (heads, T, T) scores never
     exist: each block of queries meets every key, masks what lies ahead
@@ -452,11 +470,14 @@ def gqa_attention(q, k, v, scale, query_block=None, window=None):
     the keys j <= i with i - j < window, and a block of queries meets
     only the slice of keys its band reaches (``attn_plan``): the same
     softmax of shorter rows. On a TPU, with no ``query_block`` given,
-    both blocked forms are the flash kernel's (``attn_plan``)."""
+    every form is the flash kernel's where it takes the shapes
+    (``attn_plan``)."""
     S, T, Hkv, g, D = q.shape
-    plan = attn_plan(S, T, Hkv * g, window, query_block, D)
+    plan = attn_plan(S, T, Hkv * g, window, query_block,
+                     (D, v.shape[-1]))
     if plan.kernel:
         return _kernel_attention(q, k, v, scale, plan, window), True
+    scale = 1.0 if scale is None else scale
     if plan.banded:
         return _banded_attention(q, k, v, scale, plan, int(window)), True
     bq = plan.block
@@ -483,7 +504,8 @@ def gqa_attention(q, k, v, scale, query_block=None, window=None):
     qp = jnp.moveaxis(qp.reshape(S, nq, bq, Hkv, g, D), 1, 0)
     out = jax.lax.map(lambda a: rows(*a),
                       (qp, jnp.arange(nq, dtype=jnp.int32) * bq))
-    out = jnp.moveaxis(out, 0, 1).reshape(S, nq * bq, Hkv, g, D)[:, :T]
+    out = jnp.moveaxis(out, 0, 1).reshape(
+        S, nq * bq, Hkv, g, v.shape[-1])[:, :T]
     return out, True
 
 

@@ -54,7 +54,8 @@ import numpy as np
 
 from commefficient_tpu.models import register_model
 from commefficient_tpu.models.mixers import (GQAttention,  # noqa: F401
-                                             Mamba2Mixer, ssd_chunked)
+                                             Mamba2Mixer, attn_plan,
+                                             ssd_chunked)
 from commefficient_tpu.models.mixers import Weights as _Weights
 from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS,
                                           client_stats, dispatch, fold_stats,
@@ -63,12 +64,15 @@ from commefficient_tpu.models.moe import (MOE_COUNTERS, MOE_STATS,
 from commefficient_tpu.models.norms import RMSNorm
 
 #: a client's counts, which ``causal_lm_loss`` returns beside the loss:
-#: ``models/moe.py``'s four, and the chunks its Mamba-2 mixers scanned
-#: (sequences x chunks a sequence x ``M`` layers)
-STATS = MOE_STATS + ("ssm_chunks",)
+#: ``models/moe.py``'s six, the chunks its Mamba-2 mixers scanned
+#: (sequences x chunks a sequence x ``M`` layers), and how many of its
+#: attention layers the flash kernel built (``models/mixers.py
+#: attn_plan``)
+STATS = MOE_STATS + ("ssm_chunks", "attn_kernel_layers")
 
 #: how ``FedModel`` folds them into the round record's counters
-COUNTERS = MOE_COUNTERS + (("ssm.chunks", np.sum),)
+COUNTERS = MOE_COUNTERS + (("ssm.chunks", np.sum),
+                           ("attn.kernel_layers", np.max))
 
 #: the 88 published layers
 PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*E"
@@ -234,8 +238,8 @@ class Block(nn.Module):
 @register_model("NemotronHLM")
 class NemotronHLM(nn.Module):
     """(S, T) token ids -> (final hidden (S, T, C) float32, head weight
-    (V, C), the expert layers' ``layer_stats`` folded over layers, chunks
-    scanned). The head is applied
+    (V, C), the expert layers' ``layer_stats`` folded over layers, (chunks
+    scanned, attention layers the flash kernel built)). The head is applied
     by the loss in token chunks (``models/gpt2.py
     lm_nll_sums_chunked``), so no (tokens, vocab) logits tensor
     exists."""
@@ -258,17 +262,21 @@ class NemotronHLM(nn.Module):
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             h, s, n = block_cls(cfg, kind, name=f"layer_{i}")(h)
             stats, chunks = fold_stats(stats, s), chunks + n
+        # as ``gqa_attention`` builds the attention layers, from the shapes
+        plan = attn_plan(*input_ids.shape, cfg.num_attention_heads,
+                         head_dim=cfg.head_dim)
+        kernel = cfg.count("*") * (plan.kernel is not None)
         return (RMSNorm(cfg.layer_norm_epsilon, name="norm")(h), head,
-                stats, jnp.float32(chunks))
+                stats, (jnp.float32(chunks), jnp.float32(kernel)))
 
 
 def causal_lm_loss(module, params, input_ids, tokens_per_chunk=1024):
     """Per-sequence mean next-token NLL and the ``STATS`` scalars."""
     from commefficient_tpu.models.gpt2 import lm_nll_sums_chunked
     cfg = module.cfg
-    final, head, stats, chunks = module.apply({"params": params}, input_ids)
+    final, head, stats, counts = module.apply({"params": params}, input_ids)
     sn, sv = lm_nll_sums_chunked(final[:, :-1], head, input_ids[:, 1:],
                                  cfg.dtype, ignore_index=None,
                                  tokens_per_chunk=tokens_per_chunk)
     return sn / jnp.maximum(sv, 1.0), client_stats(
-        stats, cfg.count("E") * cfg.n_held_experts) + (chunks,)
+        stats, cfg.count("E") * cfg.n_held_experts) + counts

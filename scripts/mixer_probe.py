@@ -6,6 +6,7 @@ backward under the clients ``vmap``, at a cell's shapes?
     python3 scripts/mixer_probe.py [--clients 4] [--seq 2048] [--reps 5]
         [--attn 32,8,64] [--ssd 64,1,64,128,256] [--rehearse]
         [--window 4096] [--rope_theta 1.5e6] [--kernel_blocks 256,512]
+        [--sequences 1] [--mla 4,128,64,128]
 
 Attention (``--attn`` query heads, key/value heads, head size): the
 dense form (the float32 (heads, T, T) scores written out), the blocked
@@ -25,6 +26,17 @@ clients of the ``jax.checkpoint``-ed form on bf16 operands, compiled
 temporaries pass the chip is reported and skipped), run once, then
 timed over ``--reps`` calls. Every form's gradient is compared with
 the first's that ran. PR 34's step 1 (PERF.md section 6).
+
+With ``--mla`` (heads, the q and k head's part without positions, its
+rotated part, v's head size: q and k are the two parts wide, v its own
+size) one latent-attention layer is timed alone, ``--sequences`` a
+client, and nothing else: ``models/joyai.py mla_attention``, what the
+``mla_attn`` scope holds, in its dense form (two score products on a
+float32 (S, H, T, T) tensor) and through the flash kernel once a
+``--kernel_blocks`` tile (``mla.kernel_*``; off the chip the row says
+that no kernel was built and times the dense form again). PR 49's
+probe: ``--clients 8 --sequences 4 --seq 1024 --mla 4,128,64,128
+--kernel_blocks 1024,512,256``.
 
 With ``--window`` one attention layer is timed alone (no scan): the
 whole-row forms above, which are what a window layer costs when it is
@@ -61,7 +73,12 @@ def main(argv=None):
     ap.add_argument("--rope_theta", type=float, default=None,
                     help="rotate q and k by their positions first")
     ap.add_argument("--kernel_blocks", default=None,
-                    help="tile sizes of the attn.kernel rows")
+                    help="tile sizes of the attn.kernel / mla.kernel rows")
+    ap.add_argument("--sequences", type=int, default=1,
+                    help="sequences a client (the --mla rows)")
+    ap.add_argument("--mla", default=None,
+                    help="heads, nope, rope, v: a latent-attention "
+                         "layer alone, and nothing else")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on whatever backend there is")
     a = ap.parse_args(argv)
@@ -118,6 +135,51 @@ def main(argv=None):
               flush=True)
         return first
 
+    def each_tile():
+        """Once a ``--kernel_blocks`` tile, set as the program's only
+        one; once with the program's own choice where none is given."""
+        kept = mixers.ATTN_KERNEL_BLOCKS
+        try:
+            for b in a.kernel_blocks.split(",") if a.kernel_blocks \
+                    else [None]:
+                if b is not None:
+                    mixers.ATTN_KERNEL_BLOCKS = (int(b),)
+                yield
+        finally:
+            mixers.ATTN_KERNEL_BLOCKS = kept
+
+    # --- latent attention --------------------------------------------------
+    if a.mla:
+        from commefficient_tpu.models import joyai
+        Hm, dn, dr, dv = map(int, a.mla.split(","))
+        if a.rehearse:
+            Hm, dn, dr, dv = 2, 8, 4, 8
+        S = a.sequences
+        cfg = joyai.JoyAIConfig(num_attention_heads=Hm, qk_nope_head_dim=dn,
+                                qk_rope_head_dim=dr, v_head_dim=dv, dtype=dt)
+        k = jax.random.split(jax.random.PRNGKey(2), 3)
+        qh = jax.random.normal(k[0], (W, S, T, Hm, dn + dr), dt)
+        kvh = jax.random.normal(k[1], (W, S, T, Hm, dn + dv), dt)
+        kr = jax.random.normal(k[2], (W, S, T, dr), dt)
+        print(json.dumps({"mla": {"clients": W, "sequences": S, "T": T,
+                                  "H": Hm, "qk": dn + dr, "v": dv}}))
+
+        def mla_loss(kernel):
+            one = jax.checkpoint(lambda qh, kvh, kr: joyai.mla_attention(
+                cfg, qh, kvh, kr, kernel))
+            return lambda qh, kvh, kr: jnp.sum(jnp.sin(
+                jax.vmap(one)(qh, kvh, kr).astype(jnp.float32)))
+
+        first = measure("mla.dense", mla_loss(None), (qh, kvh, kr), None)
+        for _ in each_tile():
+            plan = joyai.mla_plan(cfg, S, T)
+            print(json.dumps({"mla.kernel": {
+                "kernel": plan.kernel, "block": plan.block,
+                "pairs_over_needed": plan.pairs / plan.needed}}))
+            measure(f"mla.kernel_{plan.block}", mla_loss(plan.kernel),
+                    (qh, kvh, kr), first)
+        return 0
+
     # --- attention ---------------------------------------------------------
     k = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(k[0], (W, 1, T, Hkv, Hq // Hkv, D), dt)
@@ -162,10 +224,7 @@ def main(argv=None):
 
     def kernel_rows(name, window, first):
         """``gqa_attention`` as the cells call it: no block given."""
-        kept = mixers.ATTN_KERNEL_BLOCKS
-        for b in a.kernel_blocks.split(",") if a.kernel_blocks else [None]:
-            if b is not None:
-                mixers.ATTN_KERNEL_BLOCKS = (int(b),)
+        for _ in each_tile():
             plan = attn_plan(1, T, Hq, window, None, D)
             print(json.dumps({name: {
                 "kernel": plan.kernel, "block": plan.block,
@@ -173,7 +232,6 @@ def main(argv=None):
                 "pairs_over_needed": plan.pairs / plan.needed}}))
             measure(f"attn.{name}_{plan.block}", attn_loss(None, window),
                     (q, kk, v), first)
-        mixers.ATTN_KERNEL_BLOCKS = kept
 
     first = None
     for name, block in [("dense", T), ("blocked_128", 128),

@@ -506,23 +506,26 @@ def flash_attention_parity():
     return "; ".join(details)
 
 
+def _rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
 def gqa_kernel_parity():
     """``models/mixers.py gqa_attention`` on the kernel path (what it
     builds on the chip with no block given) against its blocked form at
     128 queries a block, outputs and the three gradients in bf16 under
     a 2-client ``vmap`` and ``jax.checkpoint``: SmallThinker's window
-    and full layers (8,192 x 28 / 4 heads of 128, window 4,096) and
-    Granite's layer (2,048 x 32 / 8 heads of 64)."""
+    and full layers (8,192 x 28 / 4 heads of 128, window 4,096),
+    Granite's layer (2,048 x 32 / 8 heads of 64) and Nemotron's (2,048
+    x 4 / 1 heads of 128: a dense layer off the chip)."""
     from commefficient_tpu.models.mixers import attn_plan, gqa_attention
-
-    def rel(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
     details = []
     for T, Hq, Hkv, D, window in ((8192, 28, 4, 128, 4096),
                                   (8192, 28, 4, 128, None),
-                                  (2048, 32, 8, 64, None)):
+                                  (2048, 32, 8, 64, None),
+                                  (2048, 4, 1, 128, None)):
         plan = attn_plan(1, T, Hq, window, None, D)
         assert plan.kernel == "splash", plan
         k = jax.random.split(jax.random.PRNGKey(T + D), 3)
@@ -542,11 +545,46 @@ def gqa_kernel_parity():
                 loss, argnums=(0, 1, 2))(*a))
 
         got, want = both(None)(q, kk, v), both(128)(q, kk, v)
-        worst = max(rel(a, b) for a, b in zip(got, want))
+        worst = max(_rel(a, b) for a, b in zip(got, want))
         assert worst < 2e-2, (T, D, window, worst)
         details.append(f"T={T} D={D} window={window} tile {plan.block} "
                        f"worst rel {worst:.1e}")
     return "; ".join(details)
+
+
+def mla_kernel_parity():
+    """``models/joyai.py mla_attention`` through the flash kernel (what
+    ``MLA`` builds on the chip: one 192-wide score product beside a
+    128-wide value product, a group of one query head) against its
+    dense form (two score products on float32 (S, H, T, T) scores),
+    outputs and the three gradients in bf16 under a 2-client ``vmap``
+    and ``jax.checkpoint`` at the JoyAI cell's shape (4 sequences x
+    1,024 x 4 heads)."""
+    from commefficient_tpu.models import joyai
+
+    cfg = joyai.JoyAIConfig(num_attention_heads=4, dtype=jnp.bfloat16)
+    S, T, H = 4, 1024, cfg.num_attention_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k = jax.random.split(jax.random.PRNGKey(49), 3)
+    qh = jax.random.normal(k[0], (2, S, T, H, dn + dr), jnp.bfloat16)
+    kvh = jax.random.normal(k[1], (2, S, T, H, dn + dv), jnp.bfloat16)
+    kr = jax.random.normal(k[2], (2, S, T, dr), jnp.bfloat16)
+
+    def both(kernel):
+        out = jax.vmap(jax.checkpoint(lambda qh, kvh, kr: (
+            joyai.mla_attention(cfg, qh, kvh, kr, kernel))))
+
+        def loss(*a):
+            return jnp.sum(jnp.sin(out(*a).astype(jnp.float32)))
+        return jax.jit(lambda *a: (out(*a),) + jax.grad(
+            loss, argnums=(0, 1, 2))(*a))
+
+    plan = joyai.mla_plan(cfg, S, T)
+    assert plan.kernel == "splash", plan
+    got, want = both(plan.kernel)(qh, kvh, kr), both(None)(qh, kvh, kr)
+    worst = max(_rel(a, b) for a, b in zip(got, want))
+    assert worst < 2e-2, (plan.block, worst)
+    return f"tile {plan.block} worst rel {worst:.1e}"
 
 
 def moe_pool_parity():
@@ -1116,6 +1154,7 @@ def main():
               ("elastic_smoke", elastic_smoke),
               ("flash_attention_parity", flash_attention_parity),
               ("gqa_kernel_parity", gqa_kernel_parity),
+              ("mla_kernel_parity", mla_kernel_parity),
               ("moe_pool_parity", moe_pool_parity),
               ("chaos_smoke", chaos_smoke),
               ("dp_smoke", dp_smoke),

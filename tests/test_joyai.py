@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
-from commefficient_tpu.models.joyai import (MOE_STATS, MLA, ExpertLayer,
+from commefficient_tpu.models.joyai import (MOE_STATS, STATS, MLA, ExpertLayer,
                                             JoyAIConfig, JoyAIFlashLM,
                                             causal_lm_loss)
 
@@ -143,7 +143,7 @@ def test_under_the_clients_vmap_the_gradient_is_the_references():
         lr, gr = jax.jit(jax.value_and_grad(reference))(params)
     assert abs(float(lp) - float(lr)) <= 2e-6 * float(lr)
     assert _rel(gp, gr) <= 2e-5
-    assert [s.shape for s in stats] == [(4,)] * len(MOE_STATS)
+    assert [s.shape for s in stats] == [(4,)] * len(STATS)
     assert not np.any(np.asarray(stats[MOE_STATS.index("dropped")]))
 
 

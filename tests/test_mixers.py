@@ -454,26 +454,40 @@ def test_the_kernel_is_the_dense_masked_form(interpreted, mask, g, D):
         assert _rel(a, truth) < 1.5 * _rel(b, truth) + 1e-3
 
 
-# The six cells' attention, a client at a time as the rounds' vmap hands
-# it over: (S, T, query heads, head size, window). ResNet9 has none;
-# GPT-2 (``models/gpt2.py --attn_impl``) and JoyAI (``models/joyai.py
-# mla_attn``: 192- and 128-wide heads) run code of their own and never
-# ask ``attn_plan``; asked at their shapes it would say what follows.
+# The seven cells' attention, a client at a time as the rounds' vmap
+# hands it over: (S, T, query heads, head size or (q and k's, v's),
+# window). ResNet9 has none; GPT-2 (``models/gpt2.py --attn_impl``)
+# runs code of its own and never asks ``attn_plan``; asked at its
+# shape it would say what follows. JoyAI's latent attention asks
+# through ``models/joyai.py mla_plan``.
 CELL_SHAPES = {
     "gpt2": (1, 256, 12, 64, None),
-    "joyai": (1, 1024, 32, 192, None),
+    "joyai": (4, 1024, 4, (192, 128), None),
     "nemotron": (1, 2048, 4, 128, None),
     "granite": (1, 2048, 32, 64, None),
+    "ouro": (1, 2048, 16, 128, None),
     "smallthinker-full": (1, 8192, 28, 128, None),
     "smallthinker-window": (1, 8192, 28, 128, 4096)}
 # on the chip / off it: (form, queries a block or the tile's edge)
 CELL_FORMS = {
-    "gpt2": (("dense", 256), ("dense", 256)),
-    "joyai": (("dense", 1024), ("dense", 1024)),        # 128 MiB: dense
-    "nemotron": (("dense", 2048), ("dense", 2048)),
+    "gpt2": (("dense", 256), ("dense", 256)),       # T no whole tile
+    "joyai": (("kernel", 512), ("dense", 1024)),    # 64 MiB a client
+    "nemotron": (("kernel", 512), ("dense", 2048)),         # 64 MiB
     "granite": (("kernel", 512), ("blocked", 128)),
+    "ouro": (("kernel", 512), ("blocked", 256)),
     "smallthinker-full": (("kernel", 1024), ("blocked", 128)),
     "smallthinker-window": (("kernel-band", 512), ("band", 128))}
+# the whole plans on the chip, as they were before the kernel took the
+# layers whose scores stay under ``ATTN_SCORE_BYTES`` (PR 49), for the
+# cells it had already: (block, keys, blocked, banded, pairs, needed)
+KERNEL_PLANS = {
+    "granite": (512, 2048, True, False, 2621440, 2098176),
+    "ouro": (512, 2048, True, False, 2621440, 2098176),
+    "smallthinker-full": (1024, 8192, True, False, 37748736, 33558528),
+    "smallthinker-window": (512, 4608, True, True, 28311552, 25167872),
+    # and the two it takes since
+    "joyai": (512, 1024, True, False, 786432, 524800),
+    "nemotron": (512, 2048, True, False, 2621440, 2098176)}
 
 
 def _form(plan):
@@ -497,21 +511,43 @@ def test_the_kernel_is_chosen_from_the_platform_and_the_shapes(cell):
         == attn_plan(S, T, Hq, window, None, None, platform="tpu") \
         == attn_plan(S, T, Hq, window)
     assert off.kernel is None and on.needed == off.needed
-    assert on.blocked is off.blocked and on.banded is off.banded
+    assert on.banded is off.banded
+    # off the chip the scores' size chooses dense or blocked; on it the
+    # kernel takes the layer either way
+    assert off.blocked is (window is not None
+                           or S * Hq * T * T * 4 > mixers.ATTN_SCORE_BYTES)
     for plan in (attn_plan(S, T, Hq, window, 128, D, platform="tpu"),
                  attn_plan(S, T + 128, Hq, window, None, D, platform="tpu"),
                  attn_plan(S, T, Hq, window, None, 96, platform="tpu"),
+                 attn_plan(S, T, Hq, window, None, (128, 192),
+                           platform="tpu"),
                  attn_plan(S, T, Hq, window, None, D, platform="gpu")):
         assert plan.kernel is None
     if on.kernel:
-        assert on.pairs < off.pairs and on.pairs <= 1.25 * on.needed
+        assert tuple(on)[:-1] == KERNEL_PLANS[cell] and on.blocked
+        assert on.pairs < off.pairs and on.pairs <= 1.5 * on.needed
+        assert on.pairs <= 1.25 * on.needed or T < 2048
         assert attn_plan(S, T, Hq, window, None, D,
                          platform="interpret")._replace(kernel="splash") == on
+    else:
+        assert cell not in KERNEL_PLANS
     if cell == "smallthinker-window":
         full = attn_plan(*CELL_SHAPES["smallthinker-full"][:3], None, None,
                          D, platform="tpu")
         ratio = (full.pairs + 3 * on.pairs) / (full.needed + 3 * on.needed)
         assert 1.12 < ratio < 1.13          # 1.57 off the chip
+
+
+def test_one_head_size_is_the_pair_of_it():
+    """``head_dim`` as one size or as (q and k's, v's): the same plan;
+    the kernel's list holds pairs, MLA's 192 / 128 among them."""
+    for D in (64, 128):
+        assert attn_plan(1, 2048, 8, None, None, D, platform="tpu") \
+            == attn_plan(1, 2048, 8, None, None, (D, D), platform="tpu") \
+            == attn_plan(1, 2048, 8, None, None, [D, D], platform="tpu")
+    assert (192, 128) in mixers.ATTN_KERNEL_HEAD_DIMS
+    assert attn_plan(1, 2048, 8, None, None, 192, platform="tpu").kernel \
+        is None
 
 
 TILINGS = [(1024, 256, None), (1024, 256, 512), (1024, 256, 300),
@@ -623,6 +659,213 @@ def test_lowered_for_the_tpu_the_client_round_holds_the_kernels(
     # the full layer's and the band's loops over blocks of queries, one
     # a pass of each kind, are gone
     assert chip.count("stablehlo.while") < host.count("stablehlo.while")
+
+
+# --- JoyAI's latent attention on the kernel path ----------------------------
+
+def _mla_cfg(**over):
+    """JoyAI's tiny preset with the published head sizes (128 + 64 for
+    q and k, 128 for v), which are what the kernel is given."""
+    from commefficient_tpu.models.joyai import JoyAIConfig
+    return dataclasses.replace(JoyAIConfig.tiny(), **dict(dict(
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128), **over))
+
+
+@pytest.mark.parametrize("query_block,window", [(None, None), (16, None),
+                                                 (16, 24)],
+                         ids=["dense", "blocked", "banded"])
+def test_a_q_that_carries_its_scale_says_none(query_block, window):
+    """``gqa_attention(scale=None)`` on a q already multiplied by the
+    scale (a power of two: exact) is ``gqa_attention(scale)`` on the
+    plain q, in every ``jax.numpy`` form, values and form."""
+    k = jax.random.split(jax.random.PRNGKey(50), 3)
+    q = jax.random.normal(k[0], (2, 48, 2, 2, 16))
+    kk, v = (jax.random.normal(k[i], (2, 48, 2, 16)) for i in (1, 2))
+    want, form = gqa_attention(q, kk, v, 0.25, query_block, window)
+    got, same = gqa_attention(q * 0.25, kk, v, None, query_block, window)
+    assert form is same and form is (query_block is not None)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("T,kernel", [(256, True), (200, False)],
+                         ids=["whole-tiles", "odd-T-falls-back"])
+def test_mla_through_the_kernel_is_mlas_dense_form(interpreted, T, kernel,
+                                                   monkeypatch):
+    """``MLA`` itself (its projections too) on the kernel path, one
+    192-wide score product beside a 128-wide value product at a group
+    of one query head, against the dense form of the same weights (the
+    platform undone): the output and the gradient to every weight and
+    to the input, float32 to 1e-5, under the clients ``vmap`` and
+    ``jax.checkpoint``. A T that is not whole tiles builds the dense
+    form on the kernel's platform too: the same program, bit for bit."""
+    from commefficient_tpu.models import joyai
+    cfg = _mla_cfg()
+    assert (joyai.mla_plan(cfg, 2, T).kernel == "splash_interpret") is kernel
+    module = joyai.MLA(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(41), (2, 2, T, cfg.hidden_size))
+    params = module.init(jax.random.PRNGKey(42), x[0, :, :8])["params"]
+
+    def both(p, x):
+        fn = jax.checkpoint(lambda x: module.apply({"params": p}, x))
+        out = jax.vmap(fn)(x)
+        grads = jax.grad(lambda p, x: jnp.sum(jnp.sin(jax.vmap(
+            jax.checkpoint(lambda x: module.apply({"params": p}, x)))(x))),
+            argnums=(0, 1))(p, x)
+        return out, grads
+
+    with HIGHEST:
+        got = jax.jit(both)(params, x)
+        monkeypatch.setattr(mixers, "_platform", lambda: "cpu")
+        assert joyai.mla_plan(cfg, 2, T).kernel is None
+        want = jax.jit(both)(params, x)
+    flat = [jax.tree_util.tree_leaves(t) for t in (got, want)]
+    assert got[0].shape == (2, 2, T, cfg.hidden_size)
+    for a, b in zip(*flat):
+        assert a.shape == b.shape
+        if kernel:
+            assert _rel(a, b) < 1e-5
+        else:
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_mla_in_bf16_is_no_further_from_the_truth_than_the_dense_form(
+        interpreted, monkeypatch):
+    """``mla_attention`` on bf16 operands: through the kernel (the
+    scale folded into q in float32, one rounding) it lies no further
+    from the float32 dense truth than the bf16 dense form does."""
+    from commefficient_tpu.models import joyai
+    T, S, H = 256, 2, 2
+    k = jax.random.split(jax.random.PRNGKey(43), 3)
+    qh = jax.random.normal(k[0], (S, T, H, 192))
+    kvh = jax.random.normal(k[1], (S, T, H, 256))
+    kr = jax.random.normal(k[2], (S, T, 64))
+
+    def run(dtype, kernel):
+        cfg = _mla_cfg(num_attention_heads=H, dtype=dtype)
+        return joyai.mla_attention(cfg, qh.astype(dtype), kvh.astype(dtype),
+                                   kr.astype(dtype), kernel)
+
+    with HIGHEST:
+        truth = run(jnp.float32, None)
+    kernel = run(jnp.bfloat16, "splash_interpret")
+    dense = run(jnp.bfloat16, None)
+    assert kernel.dtype == dense.dtype == jnp.bfloat16
+    assert _rel(kernel, truth) < 1.5 * _rel(dense, truth) + 1e-3
+
+
+def test_a_joyai_record_counts_the_kernels_layers(interpreted, monkeypatch):
+    """``attn.kernel_layers``: 6 at the cell's depth (5 trunk layers
+    and the MTP module's block) where the plan's platform is the
+    kernel's, 0 off it; ``attn.pairs`` the tiles visited; the loss the
+    dense form's; and the trainer hands the benchmark's builder these
+    counters under the name it reads them by."""
+    from commefficient_tpu.models import joyai
+    from commefficient_tpu.train import gpt2_train
+    cfg = _mla_cfg(num_hidden_layers=5)
+    module = joyai.JoyAIFlashLM(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(44), (1, 256), 0, 96)
+    params = module.init(jax.random.PRNGKey(45), ids[:, :8])["params"]
+
+    def run():
+        with HIGHEST:
+            loss, stats = joyai.causal_lm_loss(module, params, ids)
+        return float(loss[0]), dict(zip(joyai.STATS, map(float, stats)))
+
+    loss, stats = run()
+    assert stats["attn_kernel_layers"] == 6
+    # tiles of 128 at T = 256: 3 of 4; 2 heads, 6 layers, 1 sequence
+    assert stats["attn_pairs"] == 2 * 6 * 3 * 128 * 128
+    assert stats["attn_pairs_needed"] == 2 * 6 * (256 * 257 // 2)
+    monkeypatch.setattr(mixers, "_platform", lambda: "cpu")
+    dense, counts = run()
+    assert counts["attn_kernel_layers"] == 0
+    assert counts["attn_pairs"] == 2 * 6 * 256 * 256
+    assert abs(loss - dense) <= 1e-5 * abs(dense)
+    assert joyai.STATS[:len(joyai.MOE_STATS)] == joyai.MOE_STATS
+    assert [n for n, _ in joyai.COUNTERS[-3:]] == [
+        "attn.kernel_layers", "attn.pairs", "attn.pairs_needed"]
+    assert [f for _, f in joyai.COUNTERS[-3:]] == [np.max, np.sum, np.sum]
+    assert len(joyai.STATS) == len(joyai.COUNTERS)
+    # the stop-gap alias its builder reads (ROADMAP yardstick (m))
+    assert gpt2_train.MOE_COUNTERS is joyai.COUNTERS
+
+
+def test_a_nemotron_record_counts_the_kernels_layer(interpreted,
+                                                    monkeypatch):
+    """``attn.kernel_layers`` on Nemotron's records: its attention
+    layers where the plan's platform is the kernel's (head size 128, T
+    whole tiles), 0 off it."""
+    from commefficient_tpu.models import nemotron_h as nh
+    cfg = dataclasses.replace(nh.NemotronHConfig.tiny(), head_dim=128,
+                              hybrid_override_pattern="M*E*")
+    module = nh.NemotronHLM(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(46), (1, 256), 0, 96)
+    params = module.init(jax.random.PRNGKey(47), ids[:, :8])["params"]
+
+    def run():
+        with HIGHEST:
+            loss, stats = nh.causal_lm_loss(module, params, ids)
+        return float(loss[0]), dict(zip(nh.STATS, map(float, stats)))
+
+    loss, stats = run()
+    assert stats["attn_kernel_layers"] == 2
+    monkeypatch.setattr(mixers, "_platform", lambda: "cpu")
+    dense, counts = run()
+    assert counts["attn_kernel_layers"] == 0
+    assert counts["ssm_chunks"] == stats["ssm_chunks"]
+    assert abs(loss - dense) <= 1e-5 * abs(dense)
+    assert dict(nh.COUNTERS)["attn.kernel_layers"] is np.max
+    assert len(nh.STATS) == len(nh.COUNTERS)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lowered_for_the_tpu_joyais_client_round_holds_the_kernels(
+        remat, monkeypatch):
+    """JoyAI's cell (8 clients x 4 sequences x 1,024 tokens, bf16), the
+    clients' loss and its gradient lowered for the TPU with nothing
+    run. With this host's platform: no Mosaic call, and float32 (8, 4,
+    4, 1024, 1024) scores. With the chip's: the three kernels, each one
+    function of the program that all six layers call (one mask table,
+    one kernel object: ``_splash_kernel`` is cached by shape), the
+    forward a second one under ``--remat`` (the recomputation's), and
+    no float32 value with two axes of T anywhere."""
+    import collections
+    import re
+    from commefficient_tpu.models import joyai
+    cfg = dataclasses.replace(
+        joyai.JoyAIConfig.from_hf(_json("configs", "joyai-llm-flash-ep32")),
+        dtype=jnp.bfloat16, remat=remat)
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    assert layers == 6
+    module = joyai.JoyAIFlashLM(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    ids = jax.ShapeDtypeStruct((8, 4, 1024), jnp.int32)
+
+    def loss(p, ids):
+        losses, _ = jax.vmap(
+            lambda i: joyai.causal_lm_loss(module, p, i))(ids)
+        return jnp.sum(losses)
+
+    def lowered():
+        return jax.jit(jax.grad(loss)).trace(params, ids).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    scores = re.compile(r"tensor<[0-9x]*1024x1024xf32>")
+    host = lowered()
+    assert "tpu_custom_call" not in host and scores.search(host)
+    monkeypatch.setattr(mixers, "_platform", lambda: "tpu")
+    mixers._splash_kernel.cache_clear()
+    chip = lowered()
+    assert mixers._splash_kernel.cache_info().currsize == 1
+    assert not scores.search(chip)
+    names = collections.Counter(
+        re.findall(r'kernel_name = "([^"]+)"', chip))
+    # SmallThinker's program holds each twice: its two kinds of layer
+    assert names == {"splash_mqa_fwd_residuals": 1 + remat,
+                     "splash_mqa_dq_no_residuals": 1,
+                     "splash_mqa_dkv_no_residuals": 1}
+    assert chip.count("tpu_custom_call") == sum(names.values())
 
 
 # --- the benchmark's reader of the kernel's share of its roofline ----------

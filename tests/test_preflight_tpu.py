@@ -176,3 +176,41 @@ def test_flce_kernels_lower_at_gpt2_head(monkeypatch):
 
     assert tpu_kernels(jax.value_and_grad(mean_nll, (0, 1)),
                        sds((e, tm, c)), sds((v, c)), lab) == 2
+
+
+def test_mla_kernel_path_lowers_at_the_joyai_cells_shape(monkeypatch):
+    """JoyAI's latent attention as the chip builds it (``models/joyai.py
+    MLA`` with the platform a TPU: q and k 192 wide, v 128, a group of
+    one query head), one layer's forward and backward under the clients
+    ``vmap`` and ``jax.checkpoint`` at the cell's shape (8 clients x 4
+    sequences x 1,024, bf16): the library's three kernels lower, and
+    the float32 (heads, T, T) scores are in no value of the program."""
+    import dataclasses
+    import json
+    import os
+    import re
+    from commefficient_tpu.models import joyai, mixers
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "joyai-llm-flash-ep32.json")) as f:
+        cfg = dataclasses.replace(joyai.JoyAIConfig.from_hf(json.load(f)),
+                                  dtype=jnp.bfloat16)
+    monkeypatch.setattr(mixers, "_platform", lambda: "tpu")
+    plan = joyai.mla_plan(cfg, 4, 1024)
+    assert plan.kernel == "splash" and plan.block in mixers.ATTN_KERNEL_BLOCKS
+    module = joyai.MLA(cfg)
+    params = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0),
+        jnp.zeros((1, 8, cfg.hidden_size), jnp.bfloat16))["params"])
+    x = sds((8, 4, 1024, cfg.hidden_size), jnp.bfloat16)
+
+    def loss(p, x):
+        layer = jax.checkpoint(lambda x: module.apply({"params": p}, x))
+        return jnp.sum(jnp.sin(jax.vmap(layer)(x).astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == [
+        "splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals",
+        "splash_mqa_fwd_residuals", "splash_mqa_fwd_residuals"]
+    assert not re.search(r"tensor<[0-9x]*1024x1024xf32>", text)
